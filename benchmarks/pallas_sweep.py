@@ -2,7 +2,8 @@
 
 Each (CHUNK, GROUP_TILE) configuration runs in a SUBPROCESS so the env
 override re-imports pinot_tpu.ops.groupby_pallas with that geometry. Prints
-one JSON line per configuration; run when a chip is attached:
+one JSON line per configuration. The parent never imports jax, so each child
+can own the chip in turn; run where there is one:
 
     python -m benchmarks.pallas_sweep            # default shape set
     PINOT_TPU_SWEEP_DOCS=8000000 python -m benchmarks.pallas_sweep
@@ -58,9 +59,6 @@ def main() -> None:
     for chunk, gtile in CONFIGS:
         for ng in GROUPS:
             env = dict(os.environ)
-            # the byte-plane kernel (what this sweep measures) reads the
-            # _PLANES knob; keep the f32-kernel knob in step for column pad
-            env["PINOT_TPU_PALLAS_CHUNK"] = str(chunk)
             env["PINOT_TPU_PALLAS_CHUNK_PLANES"] = str(chunk)
             env["PINOT_TPU_PALLAS_GTILE"] = str(gtile)
             try:

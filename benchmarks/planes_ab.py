@@ -1,6 +1,7 @@
 """On-chip A/B of the flat vs two-level byte-plane group-by kernels.
 
-Run on real TPU (single client on the link!):
+Run where there is a TPU (the parent never imports jax, so each child can own
+the chip in turn):
     python -m benchmarks.planes_ab
 Flip the default in ops/groupby_pallas.py (planes_v2_enabled) if v2 wins —
 theory says the (r*G2 x chunk) @ (chunk x G1) form lifts MXU row

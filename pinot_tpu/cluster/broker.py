@@ -159,10 +159,7 @@ class Broker:
         # applies the deployment's knobs here
         from pinot_tpu.common.kernel_obs import KERNELS
 
-        KERNELS.configure(
-            enabled=self.obs_config.kernel_obs_enabled,
-            hbm_peak_gbps=self.obs_config.hbm_peak_gbps,
-        )
+        KERNELS.configure(enabled=self.obs_config.kernel_obs_enabled)
         # scan-path attribution shares the same deployment entry point
         from pinot_tpu.query import scan_stats
 

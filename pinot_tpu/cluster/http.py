@@ -332,11 +332,18 @@ def _serve_workload(handler) -> None:
 def _serve_ready(handler, readiness_fn) -> None:
     """GET /health/ready: 200 + component detail when ready, 503 + the
     failing components otherwise (readiness, distinct from the bare
-    liveness `/health`)."""
+    liveness `/health`). `runtime` says what the process runs on: platform,
+    device kind and ids, compile cache, native library (common/runtime.py)."""
+    from pinot_tpu.common import runtime
+
     ready, components = readiness_fn()
     _send_json(
         handler,
-        {"status": "ready" if ready else "not ready", "components": components},
+        {
+            "status": "ready" if ready else "not ready",
+            "components": components,
+            "runtime": runtime.describe(),
+        },
         status=200 if ready else 503,
     )
 
@@ -941,22 +948,25 @@ class RemoteServerClient:
     instead of a fresh connect per request."""
 
     def __init__(self, base_url: str, timeout: float = 10.0):
-        """timeout: per-hop data-plane timeout (Pinot brokerTimeoutMs analog).
-        A dead/hung server must fail the query quickly, not stall the broker."""
+        """timeout: per-hop timeout of calls that carry no query deadline
+        (control plane, tests). A query's hops are bounded by its deadline."""
         self.base_url = base_url.rstrip("/")
         self.timeout = timeout
         self._host, self._port = _host_port(self.base_url)
 
     def _hop_timeout(self, hints: dict | None) -> float:
-        """Per-call socket timeout bounded by the query deadline riding in the
-        hints markers: a hop must not outlive the query (+0.5s grace so the
-        server-side deadline error wins the race and reaches the broker)."""
+        """Per-call socket timeout: the query deadline riding in the hints
+        markers (+0.5s grace so the server-side deadline error wins the race
+        and reaches the broker). A hop may take as long as its query is
+        allowed to: the first run of a plan shape on a chip compiles for
+        longer than any fixed per-hop cap, and `SET timeoutMs` is how a
+        caller grants that time."""
         import time as _time
 
         dl = (hints or {}).get("__deadlineTs__")
         if dl is None:
             return self.timeout
-        return max(0.1, min(self.timeout, float(dl) - _time.time() + 0.5))
+        return max(0.1, float(dl) - _time.time() + 0.5)
 
     @staticmethod
     def _trace_headers(hints: dict) -> dict:

@@ -391,10 +391,14 @@ class ClusterMetricsAggregator(ControllerPeriodicTask):
                 except Exception:  # noqa: BLE001  # pinotlint: disable=deadline-swallow — exemplars are best-effort garnish on the scrape
                     slow = []
             roofline = []
+            roofline_peak = None
             segments = []
             if ep["role"] == "server":
                 try:
-                    roofline = (json.loads(self.fetch(f"{base}/debug/roofline")) or {}).get("kernels") or []
+                    roof_doc = json.loads(self.fetch(f"{base}/debug/roofline")) or {}
+                    roofline = roof_doc.get("kernels") or []
+                    # the server knows its device; the roof is its statement
+                    roofline_peak = roof_doc.get("hbmPeakGBps")
                 except Exception:  # noqa: BLE001  # pinotlint: disable=deadline-swallow — optional surface; a node without /debug/roofline still contributes metrics
                     roofline = []
                 try:
@@ -410,8 +414,8 @@ class ClusterMetricsAggregator(ControllerPeriodicTask):
             except Exception:  # noqa: BLE001  # pinotlint: disable=deadline-swallow — optional surface; a node without /debug/frontend still contributes metrics
                 frontend = None
             return {"ok": True, "snapshot": snap, "workload": workload, "slow": slow,
-                    "roofline": roofline, "segments": segments, "frontend": frontend,
-                    "error": None}
+                    "roofline": roofline, "rooflinePeakGBps": roofline_peak,
+                    "segments": segments, "frontend": frontend, "error": None}
         except Exception as e:  # noqa: BLE001  # pinotlint: disable=deadline-swallow — the federated scrape must never raise: a down/malformed node marks its series stale and the sweep continues
             return {"ok": False, "snapshot": None, "workload": [], "slow": [],
                     "roofline": [], "segments": [], "frontend": None,
@@ -519,6 +523,7 @@ class ClusterMetricsAggregator(ControllerPeriodicTask):
                 else:
                     acc[f] += max(0, v - prev.get(f, 0))
         st["roofline"] = res.get("roofline") or st["roofline"]
+        st["rooflinePeakGBps"] = res.get("rooflinePeakGBps") or st.get("rooflinePeakGBps")
         st["segments"] = res.get("segments") or st["segments"]
         st["frontend"] = res.get("frontend") or st["frontend"]
 
@@ -898,6 +903,14 @@ class ClusterMetricsAggregator(ControllerPeriodicTask):
             # calls/ms/bytes/flops sum across servers; achieved bandwidth and
             # the gap are recomputed from the merged totals
             roof: dict[tuple[str, str], dict] = {}
+            # the roof is what the servers state for their own devices (the
+            # lowest, should a fleet ever mix them); none stated, no percentages
+            peaks = [
+                float(s["rooflinePeakGBps"])
+                for s in self._nodes.values()
+                if s.get("rooflinePeakGBps")
+            ]
+            peak_gbps = min(peaks) if peaks else None
             for s in self._nodes.values():
                 for r in s.get("roofline") or []:
                     key = (r.get("kernel") or "", r.get("shape") or "")
@@ -927,14 +940,11 @@ class ClusterMetricsAggregator(ControllerPeriodicTask):
                     agg["deviceMs"] += float(r.get("deviceMs") or 0.0)
                     agg["heat"] += float(r.get("heat") or 0.0)
                     agg["lastAccessMs"] = max(agg["lastAccessMs"], float(r.get("lastAccessMs") or 0.0))
-        from pinot_tpu.common.kernel_obs import KERNELS
-
-        peak_gbps = KERNELS.hbm_peak_gbps
         roofline_rows = []
         for (kernel, shape), agg in sorted(roof.items()):
             dev_s = agg["deviceMs"] / 1e3
             achieved = (agg["bytesMoved"] / dev_s / 1e9) if dev_s > 0 else 0.0
-            pct = (100.0 * achieved / peak_gbps) if peak_gbps > 0 else 0.0
+            pct = (100.0 * achieved / peak_gbps) if peak_gbps else None
             roofline_rows.append(
                 {
                     "kernel": kernel,
@@ -947,9 +957,15 @@ class ClusterMetricsAggregator(ControllerPeriodicTask):
                     "arithmeticIntensity": (
                         round(agg["flops"] / agg["bytesMoved"], 4) if agg["bytesMoved"] else 0.0
                     ),
-                    "pctOfPeak": round(pct, 3),
-                    "rooflineGap": round(peak_gbps / achieved, 1) if achieved > 0 else None,
-                    "lostMs": round(agg["deviceMs"] * max(1.0 - pct / 100.0, 0.0), 3),
+                    "pctOfPeak": None if pct is None else round(pct, 3),
+                    "rooflineGap": (
+                        round(peak_gbps / achieved, 1) if peak_gbps and achieved > 0 else None
+                    ),
+                    "lostMs": (
+                        None
+                        if pct is None
+                        else round(agg["deviceMs"] * max(1.0 - pct / 100.0, 0.0), 3)
+                    ),
                 }
             )
         roofline_offenders = sorted(

@@ -54,10 +54,6 @@ class ObservabilityConfig:
     #: kernel_obs.py). On by default: the disabled guard only matters when a
     #: deployment wants the last fraction of a percent back.
     kernel_obs_enabled: bool = True
-    #: HBM peak bandwidth (GB/s) the roofline report compares achieved
-    #: bandwidth against. Default is v5e-class HBM; a config number rather
-    #: than a probed one so CPU tier-1 roofline output stays deterministic.
-    hbm_peak_gbps: float = 819.0
     #: instrument the HTTP plane with per-request wire-phase timelines and
     #: connection gauges (common/frontend_obs.py, GET /debug/frontend). On
     #: by default — the bookkeeping is a few dict writes per request.
@@ -81,7 +77,6 @@ class ObservabilityConfig:
             "profilerRingMaxStacks": self.profiler_ring_max_stacks,
             "sloObjectives": dict(self.slo_objectives),
             "kernelObsEnabled": self.kernel_obs_enabled,
-            "hbmPeakGBps": self.hbm_peak_gbps,
             "frontendObsEnabled": self.frontend_obs_enabled,
             "schedLagIntervalMs": self.sched_lag_interval_ms,
             "scanObsEnabled": self.scan_obs_enabled,
@@ -99,7 +94,6 @@ class ObservabilityConfig:
             d.get("profilerRingMaxStacks", 2048),
             dict(d.get("sloObjectives", {})),
             d.get("kernelObsEnabled", True),
-            d.get("hbmPeakGBps", 819.0),
             d.get("frontendObsEnabled", True),
             d.get("schedLagIntervalMs", 50.0),
             d.get("scanObsEnabled", True),

@@ -1,9 +1,9 @@
 """Device-link profiling: measured RTT + bandwidth of the host<->device path.
 
-The same query engine runs against very different attachments: a co-located
-chip (PCIe/HBM, GB/s, ~0.1ms sync) or a tunneled remote TPU (tens of ms per
-round trip, ~15MB/s). Size thresholds that are right for one are wrong by
-100x for the other, so operators that ship per-row data (the multistage
+The same query engine can run against very different attachments: a
+co-located chip (PCIe/HBM, GB/s, sub-millisecond sync) or a remote one (tens
+of ms per round trip, MB/s). Size thresholds that are right for one are wrong
+by 100x for the other, so operators that ship per-row data (the multistage
 device join's index readbacks) gate on THIS measured profile instead of a
 static row count — the AdaptiveServerSelector philosophy
 (reference: pinot-broker/.../routing/adaptiveserverselector/) applied to the

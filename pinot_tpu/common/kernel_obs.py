@@ -12,14 +12,16 @@ per-operator `Tracing` SPI / `ExecutionStatistics` accounting:
   RTT subtracted, the same split `bench.py` computes) and folded into
   labelled `engine.kernel.*{kernel=,shape=}` Timer/Meter families, per-query
   device-ms + peak-HBM totals in the accountant, and `kernel.execute` span
-  events on the active trace.
-- HBM accounting: live/peak bytes from `device.memory_stats()` when the
-  backend exposes it, else a deterministic host-side estimator so CPU
-  tier-1 sees the same math the TPU path uses.
-- `roofline()`: per-(kernel, shape-bucket) achieved GB/s vs. the configured
-  peak (`ObservabilityConfig.hbm_peak_gbps`), arithmetic intensity, and the
-  top roofline-gap offenders — served as `GET /debug/roofline` and merged
-  into the controller's `/debug/cluster`.
+  events on the active trace. A kernel traced into an outer jit has nothing
+  concrete to time; it is counted as inlined instead.
+- HBM accounting: live/peak bytes from `device.memory_stats()` on an
+  accelerator; on the CPU backend, which reports none, a deterministic
+  host-side estimator so CPU tier-1 sees the same math the TPU path uses.
+- `roofline()`: per-(kernel, shape-bucket) achieved GB/s vs. the HBM peak of
+  the device the process runs on (`DEVICE_PEAKS`, keyed by `device_kind`;
+  a device without an entry gets no percentage), arithmetic intensity, and
+  the top roofline-gap offenders — served as `GET /debug/roofline` and
+  merged into the controller's `/debug/cluster`.
 
 Shape labels are power-of-two buckets, never raw shapes, so metric label
 cardinality stays bounded no matter what the workload looks like.
@@ -36,11 +38,15 @@ from pinot_tpu.common.accounting import default_accountant
 from pinot_tpu.common.metrics import server_metrics
 from pinot_tpu.common.trace import ServerQueryPhase, active_trace, trace_event
 
-#: default HBM peak bandwidth assumed for roofline math when the deployment
-#: doesn't configure one (TPU v5e-class HBM; override with
-#: `ObservabilityConfig.hbm_peak_gbps`). Deliberately a config number, not a
-#: probed one, so CPU tier-1 roofline output is deterministic.
-DEFAULT_HBM_PEAK_GBPS = 819.0
+#: published HBM peak bandwidth per `device_kind`, with its source. The
+#: roofline compares against the entry of the device the process runs on; a
+#: device that is not listed has no roof here, and gets no percentage.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "hbmGBps": 819.0,
+        "source": "Google Cloud documentation, TPU v5e: 16 GB HBM2e at 819 GB/s per chip",
+    },
+}
 
 # -- shape buckets ----------------------------------------------------------
 
@@ -68,19 +74,17 @@ _link_lock = threading.Lock()
 
 
 def _link_rtt_ms() -> float:
-    """Memoized host<->device link RTT in ms from `devlink.link_profile()`;
-    0.0 when the probe fails (e.g. no device runtime at all)."""
+    """Memoized host<->device link RTT in ms from `devlink.link_profile()`.
+    A probe that fails raises: a device that cannot round-trip eight bytes
+    is not one to report timings from."""
     global _link_rtt_ms_cached
     if _link_rtt_ms_cached is _UNSET:
         with _link_lock:
             if _link_rtt_ms_cached is _UNSET:
-                try:
-                    from pinot_tpu.common import devlink
+                from pinot_tpu.common import devlink
 
-                    rtt_s, _ = devlink.link_profile()
-                    _link_rtt_ms_cached = max(float(rtt_s) * 1e3, 0.0)
-                except Exception:
-                    _link_rtt_ms_cached = 0.0
+                rtt_s, _ = devlink.link_profile()
+                _link_rtt_ms_cached = max(float(rtt_s) * 1e3, 0.0)
     return _link_rtt_ms_cached
 
 
@@ -93,23 +97,9 @@ def _reset_link_rtt() -> None:
 def _has_tracer(out) -> bool:
     """True when `out` contains jax tracers (we are inside an outer trace;
     there is nothing concrete to fence or time)."""
-    try:
-        import jax
+    import jax
 
-        return any(
-            isinstance(leaf, jax.core.Tracer) for leaf in jax.tree_util.tree_leaves(out)
-        )
-    except Exception:
-        return False
-
-
-def _block(out):
-    try:
-        import jax
-
-        return jax.block_until_ready(out)
-    except Exception:
-        return out
+    return any(isinstance(leaf, jax.core.Tracer) for leaf in jax.tree_util.tree_leaves(out))
 
 
 # -- HBM accounting ---------------------------------------------------------
@@ -165,16 +155,19 @@ class HostHbmEstimator:
 
 
 def device_hbm_stats() -> dict | None:
-    """live/peak bytes summed over `jax.local_devices()`, or None when the
-    backend doesn't report memory stats (CPU)."""
-    try:
-        import jax
+    """live/peak bytes summed over `jax.local_devices()`. None on the CPU
+    backend, which reports no memory stats; an accelerator that reports none
+    is an error, not a reason to fall back to the estimator."""
+    import jax
 
-        stats = [d.memory_stats() for d in jax.local_devices()]
-    except Exception:
-        return None
-    if not stats or any(not isinstance(s, dict) or "bytes_in_use" not in s for s in stats):
-        return None
+    devices = jax.local_devices()
+    stats = [d.memory_stats() for d in devices]
+    if any(not isinstance(s, dict) or "bytes_in_use" not in s for s in stats):
+        if devices[0].platform == "cpu":
+            return None
+        raise RuntimeError(
+            f"{devices[0].platform} device reports no memory_stats(): {stats!r}"
+        )
     return {
         "liveBytes": sum(int(s.get("bytes_in_use", 0)) for s in stats),
         "peakBytes": sum(
@@ -208,12 +201,13 @@ class _KernelStats:
 class KernelRegistry:
     """Registry + device-time ledger for every compiled kernel root."""
 
-    def __init__(self, hbm_peak_gbps: float = DEFAULT_HBM_PEAK_GBPS):
+    def __init__(self):
         self._lock = threading.Lock()
         self._enabled = True
-        self._hbm_peak_gbps = float(hbm_peak_gbps)
         self._kernels: dict[str, RegisteredKernel] = {}
         self._stats: dict[tuple[str, str], _KernelStats] = {}
+        #: kernel -> times it was traced into an outer jitted program
+        self._inlined: dict[str, int] = {}
         self.hbm = HostHbmEstimator()
 
     # -- configuration ------------------------------------------------------
@@ -222,16 +216,10 @@ class KernelRegistry:
     def enabled(self) -> bool:
         return self._enabled
 
-    @property
-    def hbm_peak_gbps(self) -> float:
-        return self._hbm_peak_gbps
-
-    def configure(self, enabled: bool | None = None, hbm_peak_gbps: float | None = None) -> None:
+    def configure(self, enabled: bool | None = None) -> None:
         with self._lock:
             if enabled is not None:
                 self._enabled = bool(enabled)
-            if hbm_peak_gbps is not None:
-                self._hbm_peak_gbps = float(hbm_peak_gbps)
 
     # -- registration -------------------------------------------------------
 
@@ -307,11 +295,15 @@ class KernelRegistry:
         through."""
         if not self._enabled:
             return fn()
+        import jax
+
         t0 = time.perf_counter()
         out = fn()
         if _has_tracer(out):
+            with self._lock:
+                self._inlined[name] = self._inlined.get(name, 0) + 1
             return out
-        out = _block(out)
+        out = jax.block_until_ready(out)
         wall_ms = (time.perf_counter() - t0) * 1e3
         self.record(name, max(wall_ms - _link_rtt_ms(), 0.0), **shape)
         return out
@@ -322,6 +314,7 @@ class KernelRegistry:
         dev = device_hbm_stats()
         if dev is not None:
             return {**dev, "source": "device"}
+        # CPU backend only (device_hbm_stats raises for a silent accelerator)
         return {"liveBytes": self.hbm.live, "peakBytes": self.hbm.peak, "source": "estimator"}
 
     def stats_snapshot(self) -> dict[tuple[str, str], dict]:
@@ -340,17 +333,22 @@ class KernelRegistry:
         with self._lock:
             return sum(s.device_ms for s in self._stats.values())
 
-    def roofline(self, peak_gbps: float | None = None, top: int = 10) -> dict:
-        """The `/debug/roofline` document: per-(kernel, shape-bucket) achieved
-        GB/s vs. peak, arithmetic intensity, and the top offenders ranked by
-        device-ms spent below the roof (gap alone would rank microscopic
-        kernels first)."""
-        peak = float(peak_gbps) if peak_gbps is not None else self._hbm_peak_gbps
+    def roofline(self, top: int = 10) -> dict:
+        """The `/debug/roofline` document: the device this process runs on,
+        per-(kernel, shape-bucket) achieved GB/s vs. that device's HBM peak,
+        arithmetic intensity, and the top offenders ranked by device-ms spent
+        below the roof (gap alone would rank microscopic kernels first). A
+        device with no known peak gets achieved numbers and no percentages."""
+        import jax
+
+        device = jax.local_devices()[0]
+        entry = DEVICE_PEAKS.get(device.device_kind)
+        peak, peak_source = (entry["hbmGBps"], entry["source"]) if entry else (None, None)
         rows = []
         for (name, bucket), s in sorted(self.stats_snapshot().items()):
             dev_s = s["deviceMs"] / 1e3
             achieved = (s["bytesMoved"] / dev_s / 1e9) if dev_s > 0 else 0.0
-            pct = (100.0 * achieved / peak) if peak > 0 else 0.0
+            pct = (100.0 * achieved / peak) if peak else None
             rows.append(
                 {
                     "kernel": name,
@@ -363,21 +361,31 @@ class KernelRegistry:
                     "arithmeticIntensity": (
                         round(s["flops"] / s["bytesMoved"], 4) if s["bytesMoved"] else 0.0
                     ),
-                    "pctOfPeak": round(pct, 3),
-                    "rooflineGap": round(peak / achieved, 1) if achieved > 0 else None,
-                    "lostMs": round(s["deviceMs"] * max(1.0 - pct / 100.0, 0.0), 3),
+                    "pctOfPeak": None if pct is None else round(pct, 3),
+                    "rooflineGap": round(peak / achieved, 1) if peak and achieved > 0 else None,
+                    "lostMs": (
+                        None
+                        if pct is None
+                        else round(s["deviceMs"] * max(1.0 - pct / 100.0, 0.0), 3)
+                    ),
                 }
             )
         offenders = sorted(
             (r for r in rows if r["rooflineGap"] is not None),
             key=lambda r: -r["lostMs"],
         )[: max(int(top), 0)]
+        with self._lock:
+            inlined = dict(self._inlined)
         return {
+            "platform": device.platform,
+            "deviceKind": device.device_kind,
             "hbmPeakGBps": peak,
+            "hbmPeakSource": peak_source,
             "enabled": self._enabled,
             "linkRttMs": round(_link_rtt_ms(), 4) if self._stats else 0.0,
             "kernels": rows,
             "offenders": offenders,
+            "inlined": inlined,
             "hbm": self.hbm_snapshot(),
             "registered": self.kernel_names(),
         }
@@ -387,14 +395,15 @@ class KernelRegistry:
     def reset_stats(self) -> None:
         with self._lock:
             self._stats.clear()
+            self._inlined.clear()
         self.hbm.reset()
 
     def reset(self) -> None:
         with self._lock:
             self._kernels.clear()
             self._stats.clear()
+            self._inlined.clear()
             self._enabled = True
-            self._hbm_peak_gbps = DEFAULT_HBM_PEAK_GBPS
         self.hbm.reset()
 
 

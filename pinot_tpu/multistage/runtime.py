@@ -378,9 +378,9 @@ def _device_scan_economical(
     """THE economic gate for device intermediate ops that ship whole columns
     and read results back (sort perms, window scans, join probes): the
     modeled link cost must beat the host cost. On a co-located chip the link
-    moves GB/s and the gate always passes above the size thresholds; on a
-    tunneled attachment (~tens of ms RTT, ~15MB/s) it correctly declines —
-    the AdaptiveServerSelector philosophy applied to the accelerator link.
+    moves GB/s and the gate passes above the size thresholds; on a slow
+    attachment (tens of ms per round trip, MB/s) it declines — the
+    AdaptiveServerSelector philosophy applied to the accelerator link.
     Callers must run their cheap dtype/shape rejections FIRST: pricing the
     link triggers the one-time devlink probe (~2 RTTs + 8MB)."""
     from pinot_tpu.common.devlink import transfer_cost_s
@@ -584,8 +584,8 @@ def _device_equi_join(
     indices, right row indices) of matched pairs, or None when dtypes/NaNs/
     pair-count don't fit — or when the measured device link makes shipping
     both sides plus the per-row index readback slower than a host hash join
-    (a tunneled TPU attachment moves ~15MB/s; a co-located chip moves GB/s —
-    the decision MUST come from the link profile, not a row threshold).
+    (attachments differ by orders of magnitude in bytes/s, so the decision
+    comes from the measured link profile, not a row threshold).
     `force` skips that economic gate (benchmarks measuring the device path)."""
     import jax.numpy as jnp
 

@@ -16,6 +16,7 @@ estimate, masked_stats, group_* loops, hash_group_ids, crc32.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from pathlib import Path
@@ -25,34 +26,50 @@ import numpy as np
 _HERE = Path(__file__).parent
 _SRC = _HERE / "csrc" / "pinot_native.cpp"
 _BUILD = _HERE / "_build"
-_LIB_PATH = _BUILD / "libpinot_native.so"
 
 _lib = None
+#: why the numpy fallbacks are in use, when they are (None once the library loaded)
+_fallback_reason: str | None = None
+
+
+def _lib_path() -> Path:
+    """The built library is keyed by a hash of its source: `_build/` is not
+    committed, so a stale .so left by another checkout of the tree can never
+    be the one that loads."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD / f"libpinot_native.{digest}.so"
 
 
 def _try_build_and_load():
-    global _lib
+    global _lib, _fallback_reason
     if os.environ.get("PINOT_TPU_NO_NATIVE"):
+        _fallback_reason = "PINOT_TPU_NO_NATIVE set"
         return
     try:
-        if not _LIB_PATH.exists() or _LIB_PATH.stat().st_mtime < _SRC.stat().st_mtime:
+        lib_path = _lib_path()
+        if not lib_path.exists():
             _BUILD.mkdir(exist_ok=True)
             # per-process tmp name: concurrent first imports must not tear the .so
-            tmp = _BUILD / f"libpinot_native.so.tmp.{os.getpid()}"
+            tmp = _BUILD / f"{lib_path.name}.tmp.{os.getpid()}"
             subprocess.run(
                 ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", str(_SRC), "-o", str(tmp), "-ldl"],
                 check=True,
                 capture_output=True,
                 timeout=300,
             )
-            os.replace(tmp, _LIB_PATH)
-        lib = ctypes.CDLL(str(_LIB_PATH))
-        if lib.pt_abi_version() != 1:
+            os.replace(tmp, lib_path)
+        lib = ctypes.CDLL(str(lib_path))
+        abi = lib.pt_abi_version()
+        if abi != 1:
+            _fallback_reason = f"abi version {abi} != 1"
             return
         _declare(lib)
         _lib = lib
-    except Exception:
-        _lib = None
+    except subprocess.CalledProcessError as e:
+        _fallback_reason = f"g++ exit {e.returncode}: {e.stderr.decode(errors='replace').strip()[-200:]}"
+    except (OSError, subprocess.TimeoutExpired, AttributeError) as e:
+        # no toolchain, unloadable library, build timeout, missing symbol
+        _fallback_reason = f"{type(e).__name__}: {e}"
 
 
 def _declare(lib):
@@ -126,6 +143,11 @@ _try_build_and_load()
 def available() -> bool:
     """True when the C++ library compiled and loaded."""
     return _lib is not None
+
+
+def status() -> str:
+    """`built`, or `fallback:<reason>` when the numpy paths are in use."""
+    return "built" if _lib is not None else f"fallback:{_fallback_reason}"
 
 
 def _ptr(a: np.ndarray):

@@ -1,17 +1,2 @@
-from pinot_tpu.ops.groupby_pallas import (
-    pallas_enabled,
-    pallas_grouped_count,
-    pallas_grouped_max,
-    pallas_grouped_min,
-    pallas_grouped_sum,
-    pallas_presence,
-)
-
-__all__ = [
-    "pallas_enabled",
-    "pallas_grouped_sum",
-    "pallas_grouped_count",
-    "pallas_grouped_min",
-    "pallas_grouped_max",
-    "pallas_presence",
-]
+"""Hand-written device kernels (Pallas). The engine reaches them through
+`pinot_tpu.ops.groupby_pallas`; nothing is re-exported here."""

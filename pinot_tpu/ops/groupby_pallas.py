@@ -4,20 +4,19 @@ one-hot matmul on the MXU.
 Reference parity: the inner loops of DefaultGroupByExecutor +
 DictionaryBasedGroupKeyGenerator (pinot-core/.../query/aggregation/groupby/
 DefaultGroupByExecutor.java:191, DictionaryBasedGroupKeyGenerator.java:119-130)
-and the count/sum/min/max result holders. On TPU the dense-group-id
-reduction maps to the systolic array: for a doc chunk of C docs and a group
-tile of G groups, the one-hot matrix onehot[c, g] = (gid[c] == g) turns
+and the count/sum result holders. On TPU the dense-group-id reduction maps to
+the systolic array: for a doc chunk of C docs and a group tile of G groups,
+the one-hot matrix onehot[c, g] = (gid[c] == g) turns
 
     out[g] += sum_c masked_values[c] * onehot[c, g]
 
-into a (1, C) x (C, G) matmul — the MXU does the scatter-add. MIN/MAX and
-DISTINCT presence use the same one-hot tile with a VPU column reduction.
-The grid walks (group_tile, chunk) with the chunk axis innermost so each
-output tile stays resident in VMEM while all chunks accumulate into it.
+into a (planes, C) x (C, G) matmul — the MXU does the scatter-add. The grid
+walks (group_tile, chunk) with the chunk axis innermost so each output tile
+stays resident in VMEM while all chunks accumulate into it.
 
-These kernels are the bench/fast path (float32 accumulation); the default
-engine path keeps XLA segment_sum with float64 parity accumulators. Enable
-with PINOT_TPU_PALLAS=1 (TPU backend) — kernels.py consults pallas_enabled().
+The kernels here are exact (integer byte planes, see below) and are what the
+engine's fused programs call on TPU (kernels._grouped_all via pallas_auto);
+MIN/MAX and float aggregates stay on XLA segment reductions.
 """
 
 from __future__ import annotations
@@ -30,20 +29,13 @@ import jax.numpy as jnp
 
 from pinot_tpu.common.kernel_obs import KERNELS
 
-# Tile geometry. Each grid step costs ~2us of fixed dispatch overhead on TPU,
-# so for a (chunks x group-tiles) grid the step count — not the MACs — is the
-# dominant cost at bench shapes (4M docs x 4.4k groups was 74k steps at
-# 1024/256). CHUNK*255 < 2^24 keeps the per-chunk plane dot exact.
-# CHUNK=2048 + the ADAPTIVE group tile below come from an on-chip A/B over
-# the Q4 headline (16M docs x 5000 groups, TPU v5 lite): 2048/1024 measured
-# 200ms e2e vs 298ms at the old 4096/256 — wider group tiles amortize the
-# per-step overhead across more MXU columns. Overridable for hardware
-# sweeps (benchmarks/pallas_sweep.py).
-CHUNK = int(os.environ.get("PINOT_TPU_PALLAS_CHUNK", "2048"))
-#: chunk for the exact byte-plane kernel only. Its one-hot tile is bf16
-#: (plane values <=255 are exact in bf16's 8 mantissa bits), so a 4096-doc
-#: chunk costs the same 8MB of VMEM as the f32 kernels' 2048 — and HALVES the
-#: grid-step count, which dominates at bench shapes (~2us fixed cost/step).
+# Tile geometry. Each grid step carries a fixed dispatch overhead on TPU, so
+# for a (chunks x group-tiles) grid the step count — not the MACs — dominates
+# at bench shapes (4M docs x 4.4k groups). The one-hot tile is bf16 (plane
+# values <=255 are exact in bf16's 8 mantissa bits), so a 4096-doc chunk
+# against a 1024-group tile is an 8MB operand. CHUNK*255 < 2^24 keeps the
+# per-chunk plane dot exact. Overridable for hardware sweeps
+# (benchmarks/pallas_sweep.py).
 PLANES_CHUNK = int(os.environ.get("PINOT_TPU_PALLAS_CHUNK_PLANES", "4096"))
 _GTILE_ENV = os.environ.get("PINOT_TPU_PALLAS_GTILE", "")
 
@@ -52,8 +44,7 @@ def gtile_for(ng: int) -> int:
     """Group-tile width for a given group count. Wide tiles win at high
     cardinality (per-step overhead amortized over more MXU columns) but a
     small GROUP BY padded to a 1024-wide tile would do 4x the one-hot cell
-    work — and the extreme kernels' (CHUNK, tile) where-intermediates would
-    quadruple their VMEM footprint — for nothing, so the tile tracks ng."""
+    work for nothing, so the tile tracks ng."""
     if _GTILE_ENV:
         return int(_GTILE_ENV)
     for t in (256, 512, 1024):
@@ -64,19 +55,16 @@ def gtile_for(ng: int) -> int:
 
 # exactness invariant of the byte-plane SUM: one chunk's plane dot must stay
 # below the f32 exact-integer bound. Fail loudly on bad sweep overrides.
-for _nm, _ck in (("PINOT_TPU_PALLAS_CHUNK", CHUNK), ("PINOT_TPU_PALLAS_CHUNK_PLANES", PLANES_CHUNK)):
-    if _ck * 255 >= 2**24:
-        raise ValueError(f"{_nm}={_ck}: CHUNK*255 must stay < 2^24 for lossless sums")
-    if _ck % 128:
-        raise ValueError(f"{_nm}={_ck}: must be a multiple of 128 (lane tiling)")
+if PLANES_CHUNK * 255 >= 2**24:
+    raise ValueError(
+        f"PINOT_TPU_PALLAS_CHUNK_PLANES={PLANES_CHUNK}: CHUNK*255 must stay < 2^24 for lossless sums"
+    )
+if PLANES_CHUNK % 128:
+    raise ValueError(
+        f"PINOT_TPU_PALLAS_CHUNK_PLANES={PLANES_CHUNK}: must be a multiple of 128 (lane tiling)"
+    )
 if _GTILE_ENV and int(_GTILE_ENV) % 128:
     raise ValueError("PINOT_TPU_PALLAS_GTILE must be a multiple of 128 (lane tiling)")
-
-
-def pallas_enabled() -> bool:
-    """Lossy-f32 fast path opt-in: PINOT_TPU_PALLAS=1 (the exact byte-plane
-    kernels below are governed by pallas_auto and need no opt-in)."""
-    return os.environ.get("PINOT_TPU_PALLAS", "") == "1"
 
 
 def pallas_auto() -> bool:
@@ -90,182 +78,18 @@ def pallas_auto() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Interpret the kernels only where the process was explicitly put on the
+    CPU (JAX_PLATFORMS=cpu / jax_platforms=cpu: tests, CI, rehearsal). A
+    process that merely failed to get its chip does not interpret silently:
+    the Mosaic lowering then fails on the backend it landed on."""
+    return jax.config.jax_platforms == "cpu"
 
 
-def _pad_inputs(gid, values, mask, chunk: int = 0):
-    chunk = chunk or CHUNK
-    n = gid.shape[0]
-    pad = (-n) % chunk
-    if pad:
-        gid = jnp.pad(gid, (0, pad))
-        mask = jnp.pad(mask, (0, pad))
-        if values is not None:
-            values = jnp.pad(values, (0, pad))
-    return gid, values, mask, n + pad
-
-
-def _grids(n_padded: int, ng: int, chunk: int = 0):
-    chunk = chunk or CHUNK
+def _grids(n_padded: int, ng: int, chunk: int):
     gtile = gtile_for(ng)
     ng_pad = max(gtile, ((ng + gtile - 1) // gtile) * gtile)
     return n_padded // chunk, ng_pad // gtile, ng_pad, gtile
-
-
-# -- sum / count: MXU one-hot matmul ----------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
-def _make_sum_kernel(gtile: int):
-    from jax.experimental import pallas as pl
-
-    def kernel(gid_ref, val_ref, out_ref):
-        ci = pl.program_id(1)  # chunk index (innermost: accumulates in VMEM)
-        gi = pl.program_id(0)  # group-tile index
-
-        @pl.when(ci == 0)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        gid = gid_ref[0, :]  # (CHUNK,) int32, already offset to this tile
-        vals = val_ref[0:1, :]  # (1, CHUNK) f32, mask pre-applied
-        base = gi * gtile
-        onehot = (
-            gid[:, None] == (base + jax.lax.broadcasted_iota(jnp.int32, (CHUNK, gtile), 1))
-        ).astype(jnp.float32)
-        # (1, CHUNK) @ (CHUNK, gtile): the MXU performs the scatter-add
-        out_ref[:] = out_ref[:] + jnp.dot(vals, onehot, preferred_element_type=jnp.float32)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("ng",))
-def _grouped_sum_impl(gid, masked_vals, ng: int):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_padded = gid.shape[0]
-    n_chunks, n_gtiles, ng_pad, gtile = _grids(n_padded, ng)
-    gid2 = gid.reshape(1, n_padded)
-    vals2 = masked_vals.reshape(1, n_padded)
-    out = pl.pallas_call(
-        _make_sum_kernel(gtile),
-        grid=(n_gtiles, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, gtile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, ng_pad), jnp.float32),
-        interpret=_interpret(),
-    )(gid2, vals2)
-    return out[0, :ng]
-
-
-def pallas_grouped_sum(values, gid, mask, ng: int):
-    """sum of values per group id in [0, ng); masked docs contribute 0."""
-    gid, values, mask, _ = _pad_inputs(
-        gid.astype(jnp.int32), values.astype(jnp.float32), mask
-    )
-    masked = jnp.where(mask, values, 0.0)
-    return KERNELS.timed_sync(
-        "ops.grouped_sum",
-        lambda: _grouped_sum_impl(gid, masked, ng),
-        rows=gid.shape[0],
-        groups=ng,
-    )
-
-
-def pallas_grouped_count(gid, mask, ng: int):
-    """count of masked docs per group (COUNT result holder)."""
-    gid, _, mask, _ = _pad_inputs(gid.astype(jnp.int32), None, mask)
-    return KERNELS.timed_sync(
-        "ops.grouped_sum",
-        lambda: _grouped_sum_impl(gid, mask.astype(jnp.float32), ng),
-        rows=gid.shape[0],
-        groups=ng,
-    )
-
-
-# -- min / max / presence: one-hot select + VPU column reduce ----------------
-
-
-@functools.lru_cache(maxsize=None)
-def _make_extreme_kernel(is_min: bool, gtile: int):
-    from jax.experimental import pallas as pl
-
-    fill = jnp.inf if is_min else -jnp.inf
-
-    def kernel(gid_ref, val_ref, mask_ref, out_ref):
-        ci = pl.program_id(1)
-        gi = pl.program_id(0)
-
-        @pl.when(ci == 0)
-        def _():
-            out_ref[:] = jnp.full_like(out_ref, fill)
-
-        gid = gid_ref[0, :]
-        vals = val_ref[0, :]
-        base = gi * gtile
-        hit = gid[:, None] == (
-            base + jax.lax.broadcasted_iota(jnp.int32, (CHUNK, gtile), 1)
-        )
-        # minor-dim insertion must happen on 32-bit values (Mosaic tiling
-        # constraint): broadcast the int32 mask, then compare
-        maskcol = mask_ref[0, :][:, None] != 0
-        w = jnp.where(hit & maskcol, vals[:, None], fill)
-        # keepdims: the (1, gtile) shape matches out_ref's block layout
-        col = jnp.min(w, axis=0, keepdims=True) if is_min else jnp.max(w, axis=0, keepdims=True)
-        out_ref[:] = jnp.minimum(out_ref[:], col) if is_min else jnp.maximum(out_ref[:], col)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("ng", "is_min"))
-def _grouped_extreme_impl(gid, values, mask, ng: int, is_min: bool):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_padded = gid.shape[0]
-    n_chunks, n_gtiles, ng_pad, gtile = _grids(n_padded, ng)
-    out = pl.pallas_call(
-        _make_extreme_kernel(is_min, gtile),
-        grid=(n_gtiles, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, gtile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, ng_pad), jnp.float32),
-        interpret=_interpret(),
-    )(
-        gid.reshape(1, n_padded),
-        values.reshape(1, n_padded),
-        mask.astype(jnp.int32).reshape(1, n_padded),
-    )
-    return out[0, :ng]
-
-
-def pallas_grouped_min(values, gid, mask, ng: int):
-    gid, values, mask, _ = _pad_inputs(gid.astype(jnp.int32), values.astype(jnp.float32), mask)
-    return KERNELS.timed_sync(
-        "ops.grouped_extreme",
-        lambda: _grouped_extreme_impl(gid, values, mask, ng, True),
-        rows=gid.shape[0],
-        groups=ng,
-    )
-
-
-def pallas_grouped_max(values, gid, mask, ng: int):
-    gid, values, mask, _ = _pad_inputs(gid.astype(jnp.int32), values.astype(jnp.float32), mask)
-    return KERNELS.timed_sync(
-        "ops.grouped_extreme",
-        lambda: _grouped_extreme_impl(gid, values, mask, ng, False),
-        rows=gid.shape[0],
-        groups=ng,
-    )
 
 
 # -- exact integer sum+count: byte-plane one-hot matmul ----------------------
@@ -294,8 +118,8 @@ def _make_planes_kernel(r: int, gtile: int, chunk: int):
         gid = gid_ref[0, :]
         # bf16 is exact here: plane bytes are integers in [-128, 255] and the
         # one-hot is 0/1 — both inside bf16's 2^8 exact-integer range. The
-        # halved one-hot tile is what buys PLANES_CHUNK=2*CHUNK at equal VMEM,
-        # and the MXU runs bf16 at twice the f32 rate.
+        # one-hot tile is half the size it would be in f32, and the MXU runs
+        # bf16 at twice the f32 rate.
         planes = planes_ref[:].astype(jnp.bfloat16)  # (r, chunk), pre-masked
         base = gi * gtile
         onehot = (
@@ -324,7 +148,7 @@ def _planes_impl(gid, planes, ng: int, r: int):
         ],
         out_specs=pl.BlockSpec((r, gtile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((r, ng_pad), jnp.int32),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(gid.reshape(1, n_padded), planes)
 
 
@@ -390,7 +214,7 @@ def _planes2_impl(gid, planes, ng: int, r: int):
     g1 = -(-ng // G2)
     # lane-tile floor: the MXU N dimension is 128-wide — a narrower block
     # pads internally and wastes columns (same constraint the module-load
-    # guards enforce on CHUNK/GTILE)
+    # guards enforce on PLANES_CHUNK/GTILE)
     g1tile = min(256, max(128, -(-g1 // 128) * 128))
     g1_pad = -(-g1 // g1tile) * g1tile
     out = pl.pallas_call(
@@ -404,15 +228,12 @@ def _planes2_impl(gid, planes, ng: int, r: int):
             (r * G2, g1tile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((r * G2, g1_pad), jnp.int32),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(gid.reshape(1, n_padded), planes)
     # out[(p*G2 + l), h] holds group h*G2+l: -> (r, G2, g1_pad) -> (r, ng)
     cube = out.reshape(r, G2, g1_pad)
     flat = jnp.transpose(cube, (0, 2, 1)).reshape(r, g1_pad * G2)
     return flat[:, :ng]
-
-
-_V2_BROKEN = False  # set on first lowering failure; logged once
 
 
 def planes_v2_enabled() -> bool:
@@ -427,15 +248,18 @@ def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
     value array plus the group count, in ONE pallas pass. Returns
     ([f64 (ng,) sum per input], i64 (ng,) counts).
 
-    Exactness requires the flat doc count <= SAFE_DOCS (asserted)."""
-    global _V2_BROKEN
+    Exactness requires the flat doc count <= SAFE_DOCS (asserted). A kernel
+    the compiler refuses raises: nothing substitutes another one."""
     if gid.shape[0] > SAFE_DOCS:  # not assert: must survive python -O
         raise ValueError(
             f"pallas byte-plane accumulator overflows past {SAFE_DOCS} docs; "
             "use the XLA two-level path for larger inputs"
         )
     k = len(values_list)
-    gid, _, mask, n_padded = _pad_inputs(gid.astype(jnp.int32), None, mask, PLANES_CHUNK)
+    pad = (-gid.shape[0]) % PLANES_CHUNK
+    n_padded = gid.shape[0] + pad
+    gid = jnp.pad(gid.astype(jnp.int32), (0, pad))
+    mask = jnp.pad(mask, (0, pad))
     rows = []
     for v in values_list:
         v = jnp.pad(v.astype(jnp.int32), (0, n_padded - v.shape[0]))
@@ -453,42 +277,14 @@ def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
     while len(rows) < r:
         rows.append(jnp.zeros((n_padded,), jnp.float32))
     planes = jnp.stack(rows)
-    if planes_v2_enabled() and not _V2_BROKEN:
-        try:
-            out = KERNELS.timed_sync(
-                "ops.grouped_planes2",
-                lambda: _planes2_impl(gid, planes, ng, r),
-                rows=n_padded,
-                groups=ng,
-                planes=r,
-            )
-        except Exception as e:
-            # Covers eager execution and trace-time failures only: when this
-            # function is traced inside an OUTER jit (the fused query
-            # kernels), a Mosaic rejection surfaces at that jit's compile,
-            # beyond this except — the v2 opt-in is validated by
-            # benchmarks/planes_ab.py (subprocess-isolated) for that reason.
-            _V2_BROKEN = True  # known bad: don't re-pay the failed attempt
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "two-level planes kernel failed (%r); using flat kernel", e, exc_info=True
-            )
-            out = KERNELS.timed_sync(
-                "ops.grouped_planes",
-                lambda: _planes_impl(gid, planes, ng, r),
-                rows=n_padded,
-                groups=ng,
-                planes=r,
-            )
-    else:
-        out = KERNELS.timed_sync(
-            "ops.grouped_planes",
-            lambda: _planes_impl(gid, planes, ng, r),
-            rows=n_padded,
-            groups=ng,
-            planes=r,
-        )
+    name, impl = (
+        ("ops.grouped_planes2", _planes2_impl)
+        if planes_v2_enabled()
+        else ("ops.grouped_planes", _planes_impl)
+    )
+    out = KERNELS.timed_sync(
+        name, lambda: impl(gid, planes, ng, r), rows=n_padded, groups=ng, planes=r
+    )
     sums = []
     for i in range(k):
         p = out[4 * i : 4 * i + 4, :ng].astype(jnp.float64)
@@ -527,41 +323,12 @@ def pallas_grouped_sum_count_exact(values_i32, gid, mask, ng: int):
     return sums[0], counts
 
 
-def pallas_grouped_count_exact(gid, mask, ng: int):
-    """Lossless count per group (mask plane only, i32 accumulator)."""
-    return pallas_grouped_multi_sum([], gid, mask, ng)[1]
-
-
-def pallas_presence(dict_ids, mask, cardinality: int):
-    """DISTINCTCOUNT presence bitmap: presence[d] = any masked doc with
-    dict id d (the scatter-max over the valid-doc mask)."""
-    ids, _, mask, _ = _pad_inputs(dict_ids.astype(jnp.int32), None, mask)
-    counts = KERNELS.timed_sync(
-        "ops.grouped_sum",
-        lambda: _grouped_sum_impl(ids, mask.astype(jnp.float32), cardinality),
-        rows=ids.shape[0],
-        groups=cardinality,
-    )
-    return counts > 0
-
-
 # -- kernel registry: cost models for the roofline report --------------------
 #
 # Bytes model what each grid actually streams through VMEM: every doc chunk
 # is re-read once per group tile (the chunk axis is innermost), so traffic
 # scales with rows x group-tiles, not rows alone. FLOPs count the one-hot
 # build (1 compare) + MXU MAC (2) per (doc, group) pair.
-
-
-def _onehot_cost(n_streams: float):
-    def cost(shape: dict) -> tuple[float, float]:
-        rows = max(float(shape.get("rows", 0)), 0.0)
-        groups = max(float(shape.get("groups", 1)), 1.0)
-        gtile = float(gtile_for(int(groups)))
-        n_gtiles = max(-(-groups // gtile), 1.0)
-        return rows * n_streams * 4.0 * n_gtiles, rows * groups * 3.0
-
-    return cost
 
 
 def _planes_cost(shape: dict) -> tuple[float, float]:
@@ -573,18 +340,6 @@ def _planes_cost(shape: dict) -> tuple[float, float]:
     return rows * (planes + 1.0) * 4.0 * n_gtiles, rows * groups * (2.0 * planes + 1.0)
 
 
-KERNELS.register(
-    "ops.grouped_sum",
-    _grouped_sum_impl,
-    cost_model=_onehot_cost(2.0),
-    description="one-hot matmul grouped SUM/COUNT/presence (gid + value streams)",
-)
-KERNELS.register(
-    "ops.grouped_extreme",
-    _grouped_extreme_impl,
-    cost_model=_onehot_cost(3.0),
-    description="one-hot select + VPU column reduce MIN/MAX (gid + value + mask)",
-)
 KERNELS.register(
     "ops.grouped_planes",
     _planes_impl,

@@ -34,10 +34,10 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pinot_tpu.common.kernel_obs import KERNELS
-from pinot_tpu.parallel.compat import shard_map
 
 # Multi-device collective launches must not interleave: two host threads
 # each enqueueing an all_to_all across the same mesh can order their

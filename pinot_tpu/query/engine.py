@@ -536,6 +536,11 @@ class QueryEngine:
             # operand, so upsert tables run the fused device path too
             plan = plan_segment(seg, ctx, valid_mask=vmask)
         except DeviceFallback:
+            from pinot_tpu.common.metrics import ServerMeter, server_metrics
+
+            # the same meter the multistage leaf marks: a segment that left
+            # the device path is counted, whichever engine it ran under
+            server_metrics().meter(ServerMeter.DEVICE_FALLBACKS).mark()
             return ("ready",) + self._host_segment(seg, ctx, extra_mask=vmask) + ("host",)
         return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(seg)), vmask)
 

@@ -44,7 +44,22 @@ def _block(services, seconds: float):
                 stop()
 
 
+def _log_backend(who: str, rt: dict) -> None:
+    """The one start-up line that says what this process runs on."""
+    print(
+        f"{who} backend: platform={rt['platform']} device_kind={rt['deviceKind']!r} "
+        f"devices={[d['id'] for d in rt['devices']]} "
+        f"compile_cache={rt['compileCache']['dir']} native={rt['native']}",
+        flush=True,
+    )
+
+
 def cmd_start_controller(args) -> dict:
+    from pinot_tpu.common import runtime
+
+    # the controller never owns a chip: pin before anything can touch a device
+    _log_backend("controller", runtime.pin_cpu())
+
     from pinot_tpu.cluster import Controller, PropertyStore
     from pinot_tpu.cluster.http import ControllerHTTPService
     from pinot_tpu.minion import PinotTaskManager
@@ -97,6 +112,12 @@ def cmd_start_controller(args) -> dict:
 
 
 def cmd_start_server(args) -> dict:
+    from pinot_tpu.common import runtime
+
+    # the server is the process that owns the chip: without JAX_PLATFORMS in
+    # the environment it must get a TPU or fail here, before it registers
+    _log_backend(f"server {args.server_id}", runtime.require_device())
+
     from pinot_tpu.cluster import Server
     from pinot_tpu.cluster.http import RemoteControllerClient, ServerHTTPService
     from pinot_tpu.common.config import SchedulerConfig
@@ -121,6 +142,12 @@ def cmd_start_server(args) -> dict:
 
 def cmd_start_broker(args) -> dict:
     import json as _json
+
+    from pinot_tpu.common import runtime
+
+    # the broker's root stages and embedded engine run on the CPU: a chip
+    # belongs to its server, and a second process reaching for it would fail
+    _log_backend("broker", runtime.pin_cpu())
 
     from pinot_tpu.cluster.broker import Broker
     from pinot_tpu.cluster.failure import FailureDetector

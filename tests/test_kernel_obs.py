@@ -35,8 +35,14 @@ def zero_rtt(monkeypatch):
     monkeypatch.setattr(kernel_obs, "_link_rtt_ms", lambda: 0.0)
 
 
-def _registry(**kw):
-    r = KernelRegistry(**kw)
+@pytest.fixture
+def cpu_roof(monkeypatch):
+    """A 10 GB/s roof for the CPU test device (it has no published peak)."""
+    monkeypatch.setitem(kernel_obs.DEVICE_PEAKS, "cpu", {"hbmGBps": 10.0, "source": "test"})
+
+
+def _registry():
+    r = KernelRegistry()
     r.register(
         "unit.k",
         cost_model=lambda s: (s.get("rows", 0) * 8.0, s.get("rows", 0) * 2.0),
@@ -143,13 +149,14 @@ def test_hbm_snapshot_is_deterministic_on_cpu(zero_rtt):
 # -- roofline math -----------------------------------------------------------
 
 
-def test_roofline_math(zero_rtt):
-    r = KernelRegistry(hbm_peak_gbps=10.0)
+def test_roofline_math(zero_rtt, cpu_roof):
+    r = KernelRegistry()
     # 1e9 bytes in 1s -> 1 GB/s achieved against a 10 GB/s roof
     r.register("m.k", cost_model=lambda s: (1e9, 2e9))
     r.record("m.k", 1000.0, rows=16)
     doc = r.roofline()
-    assert doc["hbmPeakGBps"] == 10.0
+    assert doc["hbmPeakGBps"] == 10.0 and doc["hbmPeakSource"] == "test"
+    assert doc["platform"] == "cpu" and doc["deviceKind"] == "cpu"
     (row,) = doc["kernels"]
     assert row["kernel"] == "m.k" and row["shape"] == "2^4"
     assert row["achievedGBps"] == pytest.approx(1.0)
@@ -161,8 +168,33 @@ def test_roofline_math(zero_rtt):
     assert doc["registered"] == ["m.k"]
 
 
-def test_roofline_offenders_ranked_by_lost_ms_not_gap(zero_rtt):
-    r = KernelRegistry(hbm_peak_gbps=10.0)
+def test_roofline_without_a_known_peak_gives_no_percentages(zero_rtt):
+    """A device that is not in DEVICE_PEAKS (the CPU test device) gets its
+    achieved numbers and no roof: nothing assumes another device's peak."""
+    r = KernelRegistry()
+    r.register("m.k", cost_model=lambda s: (1e9, 2e9))
+    r.record("m.k", 1000.0, rows=16)
+    doc = r.roofline()
+    assert doc["hbmPeakGBps"] is None and doc["hbmPeakSource"] is None
+    (row,) = doc["kernels"]
+    assert row["achievedGBps"] == pytest.approx(1.0)
+    assert row["pctOfPeak"] is None and row["rooflineGap"] is None and row["lostMs"] is None
+    assert doc["offenders"] == []
+
+
+def test_kernel_traced_into_outer_jit_is_counted_inlined(zero_rtt):
+    import jax
+    import jax.numpy as jnp
+
+    r = _registry()
+    outer = jax.jit(lambda x: r.timed_sync("unit.k", lambda: x * 2, rows=8))
+    outer(jnp.arange(8))
+    doc = r.roofline()
+    assert doc["inlined"] == {"unit.k": 1} and doc["kernels"] == []
+
+
+def test_roofline_offenders_ranked_by_lost_ms_not_gap(zero_rtt, cpu_roof):
+    r = KernelRegistry()
     # `tiny` has the worse gap (1000x) but is microscopic; `big` burns real
     # time below the roof and must rank first
     r.register("tiny", cost_model=lambda s: (1e4, 0.0))
@@ -282,7 +314,7 @@ def test_engine_query_records_fused_kernel(zero_rtt):
 # -- HTTP surfaces -----------------------------------------------------------
 
 
-def test_debug_roofline_endpoint(zero_rtt):
+def test_debug_roofline_endpoint(zero_rtt, cpu_roof):
     import pinot_tpu.query.kernels  # noqa: F401 — registers the query.* roots
     from pinot_tpu.cluster.http import ServerHTTPService
     from pinot_tpu.cluster.server import Server
@@ -331,7 +363,7 @@ def test_aggregator_merges_roofline_and_workload_into_cluster(tmp_path):
         if "/debug/workload" in url:
             return json.dumps({"rollups": per_node[host]["workload"]})
         if "/debug/roofline" in url:
-            return json.dumps({"kernels": per_node[host]["roofline"]})
+            return json.dumps({"kernels": per_node[host]["roofline"], "hbmPeakGBps": 819.0})
         raise AssertionError(f"unexpected scrape url {url}")
 
     controller = Controller(PropertyStore(), tmp_path / "deepstore")
@@ -348,7 +380,8 @@ def test_aggregator_merges_roofline_and_workload_into_cluster(tmp_path):
     assert merged["bytesMoved"] == 1_000_000_000
     # 1e9 bytes over 2s = 0.5 GB/s, recomputed from the merged totals
     assert merged["achievedGBps"] == pytest.approx(0.5)
-    assert roof["offenders"] and roof["hbmPeakGBps"] == KERNELS.hbm_peak_gbps
+    # the roof is the one the servers stated for their device
+    assert roof["offenders"] and roof["hbmPeakGBps"] == 819.0
 
     wl = doc["cluster"]["workload"]["gold/t"]
     assert wl["deviceMs"] == pytest.approx(10.0)  # sums across servers
@@ -359,11 +392,11 @@ def test_aggregator_merges_roofline_and_workload_into_cluster(tmp_path):
 
 
 def test_observability_config_kernel_obs_roundtrip():
-    cfg = ObservabilityConfig(kernel_obs_enabled=False, hbm_peak_gbps=1638.0)
+    cfg = ObservabilityConfig(kernel_obs_enabled=False)
     d = cfg.to_dict()
-    assert d["kernelObsEnabled"] is False and d["hbmPeakGBps"] == 1638.0
+    assert d["kernelObsEnabled"] is False
     back = ObservabilityConfig.from_dict(json.loads(json.dumps(d)))
-    assert back.kernel_obs_enabled is False and back.hbm_peak_gbps == 1638.0
+    assert back.kernel_obs_enabled is False
     # defaults stay on: the plane is live out of the box
     dflt = ObservabilityConfig.from_dict({})
-    assert dflt.kernel_obs_enabled is True and dflt.hbm_peak_gbps == 819.0
+    assert dflt.kernel_obs_enabled is True

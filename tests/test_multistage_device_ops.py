@@ -396,8 +396,8 @@ def test_device_window_sum_int32_does_not_wrap(monkeypatch):
     assert out[-1] == n * 2**30  # 2^36: far past int32 range
 
 
-def test_economic_gate_declines_on_tunnel_link(monkeypatch):
-    """With a tunnel-like measured link (70ms RTT, 15MB/s) the sort and
+def test_economic_gate_declines_on_slow_link(monkeypatch):
+    """With a slow measured link (70ms RTT, 15MB/s) the sort and
     window device paths must decline — per-row shipping loses to host
     compute there (devlink gate, AdaptiveServerSelector philosophy)."""
     from pinot_tpu.common import devlink

@@ -5,10 +5,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pinot_tpu.parallel import shuffle
-from pinot_tpu.parallel.compat import shard_map
 
 
 @pytest.fixture(scope="module")
