@@ -1,0 +1,15 @@
+"""Time a query's server thread blocked on device readbacks
+(`server.device_wait`, summed over its segments), median: the queue on the
+device plus the programs, as the host sees them."""
+
+from perfbench.layer_metrics._spans import median_difference
+
+LAYER = "server host: queue, plan, dispatch, unpack (cluster/server.py, query/engine.py)"
+UNIT = "ms"
+MOVES = "query_p50_ms"
+SOURCE = "program_counter"
+NEEDS_TRACE = False
+
+
+def read(run):
+    return median_difference(run, "server.device_wait")

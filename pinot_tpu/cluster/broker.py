@@ -377,10 +377,24 @@ class Broker:
         return found
 
     def execute(self, sql: str, identity: str | None = None) -> ResultTable:
+        """One query, under its phase ledger: `broker.request` spans all of
+        it, and the ledger (the broker's spans, the servers' merged in)
+        leaves with the answer as `spanTimesMs`, `spanSelfMs`, `counters`
+        and `deviceWork`."""
+        from pinot_tpu.common.trace import request_ledger, span
+
+        qid = f"q{next(_request_seq)}"
+        with request_ledger(qid, "broker") as ledger:
+            with span("broker.request"):
+                result = self._execute_request(sql, identity, qid)
+            result.span_stats = ledger.response_fields()
+        return result
+
+    def _execute_request(self, sql: str, identity: str | None, qid: str) -> ResultTable:
         import random
 
         from pinot_tpu.common.metrics import BrokerMeter, BrokerTimer, broker_metrics
-        from pinot_tpu.common.trace import TraceContext, start_trace
+        from pinot_tpu.common.trace import TraceContext, record_span, span, start_trace
         from pinot_tpu.query.context import (
             Deadline,
             QueryCancelledError,
@@ -392,7 +406,6 @@ class Broker:
         bm.meter(BrokerMeter.QUERIES).mark()
         table = ""
         t_entry = time.perf_counter()
-        qid = f"q{next(_request_seq)}"
         deadline: Deadline | None = None
         timeout_ms: float | None = None
         tctx = None
@@ -444,14 +457,12 @@ class Broker:
                 if self.admission is not None:
                     from pinot_tpu.cluster.admission import DEGRADE
 
-                    t_adm = time.perf_counter()
-                    decision = self.admission.decide(
-                        table or "_default", deadline=deadline, allow_partial=allow_partial
-                    )
-                    if wire_tl is not None:
-                        wire_tl.record_sub(
-                            "admission", (time.perf_counter() - t_adm) * 1e3
+                    with span("broker.admission") as adm:
+                        decision = self.admission.decide(
+                            table or "_default", deadline=deadline, allow_partial=allow_partial
                         )
+                    if wire_tl is not None:
+                        wire_tl.record_sub("admission", adm.ms)
                     if decision == DEGRADE:
                         partial.degrade = True
 
@@ -460,10 +471,11 @@ class Broker:
                 def run_query():
                     # dequeue-start minus submit = scheduler queue wait: the
                     # slice of `execute` spent waiting for an admission slot
+                    wait_ms = (time.perf_counter() - t_submit) * 1e3
+                    if self.admission is not None:
+                        record_span("broker.admission", wait_ms)
                     if wire_tl is not None:
-                        wire_tl.record_sub(
-                            "queueWait", (time.perf_counter() - t_submit) * 1e3
-                        )
+                        wire_tl.record_sub("queueWait", wait_ms)
                     return self._execute(
                         stmt, sql, deadline=deadline, qid=qid, partial=partial,
                         normalized=normalized,
@@ -477,7 +489,8 @@ class Broker:
                 # result-cache tier, AFTER quota + admission by design: hits
                 # still count against quotas and shed/degrade verdicts, but a
                 # hit bypasses the scheduler enqueue and the whole scatter
-                cache_state = self._cache_key(stmt, table, normalized)
+                with span("broker.route"):
+                    cache_state = self._cache_key(stmt, table, normalized)
                 hit_box = {"hit": False}
 
                 def run_cached():
@@ -600,10 +613,10 @@ class Broker:
         (deadline, tenant, trace context) never leaks between queries."""
         import copy
 
-        from pinot_tpu.common.trace import ServerQueryPhase, phase_timer
+        from pinot_tpu.common.trace import ServerQueryPhase, span
 
         def timer():
-            return phase_timer(ServerQueryPhase.REQUEST_COMPILATION, role="broker")
+            return span("broker.compile", phase=ServerQueryPhase.REQUEST_COMPILATION, role="broker")
 
         if stmt is None:
             if self.caches is None:
@@ -966,10 +979,13 @@ class Broker:
 
                 stmt = copy.deepcopy(stmt)
             return self._execute_multistage(stmt, sql, deadline=deadline, qid=qid)
+        from pinot_tpu.common.trace import ServerQueryPhase, active_trace, span
+
         table = stmt.from_table
-        offline_cfg = self.controller.get_table(table)
         rt_name = f"{table}_REALTIME"
-        rt_cfg = self.controller.get_table(rt_name) if not table.endswith("_REALTIME") else None
+        with span("broker.route"):
+            offline_cfg = self.controller.get_table(table)
+            rt_cfg = self.controller.get_table(rt_name) if not table.endswith("_REALTIME") else None
         if offline_cfg is None and rt_cfg is None:
             raise KeyError(f"no such table: {table}")  # BrokerResponse TableDoesNotExist parity
         # broker-tenant gate: a tagged broker serves only tables whose broker
@@ -986,18 +1002,17 @@ class Broker:
                         f"table {cfg.table_name!r} belongs to broker tenant tag {want!r}; "
                         f"this broker serves {self.tenant_tags}"
                     )
-        from pinot_tpu.common.trace import ServerQueryPhase, phase_timer
-
-        schema = self.controller.get_schema(table) or self.controller.get_schema(rt_name)
-        # plan epoch: the (offline, realtime) routing versions — schema and
-        # segment-set changes both land as bumps, re-keying the cached plan
-        epoch = None
-        if self.caches is not None and normalized is not None:
-            try:
-                epoch = tuple(sorted(self.controller.routing_versions([table, rt_name]).items()))
-            except ConnectionError:
-                # controller failover in progress: plan uncached this round
-                epoch = None
+        with span("broker.route"):
+            schema = self.controller.get_schema(table) or self.controller.get_schema(rt_name)
+            # plan epoch: the (offline, realtime) routing versions — schema and
+            # segment-set changes both land as bumps, re-keying the cached plan
+            epoch = None
+            if self.caches is not None and normalized is not None:
+                try:
+                    epoch = tuple(sorted(self.controller.routing_versions([table, rt_name]).items()))
+                except ConnectionError:
+                    # controller failover in progress: plan uncached this round
+                    epoch = None
         stmt, ctx = self._compile(
             sql, stmt=stmt, schema=schema, table=table, normalized=normalized, epoch=epoch
         )
@@ -1017,7 +1032,6 @@ class Broker:
             ctx.hints["__deadlineTs__"] = deadline.deadline_ts
         if qid is not None:
             ctx.hints["__queryId__"] = qid
-        from pinot_tpu.common.trace import active_trace
 
         tr = active_trace()
         if tr is not None and tr.context is not None:
@@ -1025,26 +1039,27 @@ class Broker:
             # sends a real `traceparent` header instead
             ctx.hints["__traceCtx__"] = tr.context.to_dict()
 
-        # legs: (physical table, sql text). Hybrid tables split on the time
-        # boundary (TimeBoundaryManager parity): offline <= boundary < realtime
-        if offline_cfg is not None and rt_cfg is not None and offline_cfg.time_column:
-            from pinot_tpu.cluster.routing import TimeBoundary
+        with span("broker.route"):
+            # legs: (physical table, sql text). Hybrid tables split on the time
+            # boundary (TimeBoundaryManager parity): offline <= boundary < realtime
+            if offline_cfg is not None and rt_cfg is not None and offline_cfg.time_column:
+                from pinot_tpu.cluster.routing import TimeBoundary
 
-            offline_meta = self.controller.all_segment_metadata(table)
-            tb = TimeBoundary.compute(offline_meta, offline_cfg.time_column)
-            if tb is None:
-                legs = [(rt_name, sql)]
+                offline_meta = self.controller.all_segment_metadata(table)
+                tb = TimeBoundary.compute(offline_meta, offline_cfg.time_column)
+                if tb is None:
+                    legs = [(rt_name, sql)]
+                else:
+                    legs = [(table, tb.offline_sql(sql)), (rt_name, tb.realtime_sql(sql))]
+            elif offline_cfg is not None:
+                legs = [(table, sql)]
             else:
-                legs = [(table, tb.offline_sql(sql)), (rt_name, tb.realtime_sql(sql))]
-        elif offline_cfg is not None:
-            legs = [(table, sql)]
-        else:
-            legs = [(rt_name, sql)]
+                legs = [(rt_name, sql)]
 
-        all_meta: dict[str, dict] = {}
-        for leg_table, _ in legs:
-            all_meta.update(self.controller.all_segment_metadata(leg_table))
-        self._compute_hints(ctx, all_meta)
+            all_meta: dict[str, dict] = {}
+            for leg_table, _ in legs:
+                all_meta.update(self.controller.all_segment_metadata(leg_table))
+            self._compute_hints(ctx, all_meta)
 
         if ctx.query_type == QueryType.SELECTION and ctx.gapfill is None:
             # plain SELECT: framed streaming with incremental reduce — broker
@@ -1072,7 +1087,7 @@ class Broker:
             scan["prunedByReason"]["value"] = scan["prunedByReason"].get("value", 0) + pruned
         by_reason = scan["prunedByReason"]
 
-        with phase_timer(ServerQueryPhase.BROKER_REDUCE, role="broker"):
+        with span("broker.reduce", phase=ServerQueryPhase.BROKER_REDUCE, role="broker"):
             rows = QueryEngine.reduce(ctx, partials)
         return build_result(
             ctx,
@@ -1330,24 +1345,31 @@ class Broker:
         scan_summary).
         When `partial` allows it, a failed failover records the loss and the
         reduce proceeds over the partials that did arrive."""
-        from pinot_tpu.cluster.routing import AdaptiveServerSelector
+        from pinot_tpu.common.trace import span
 
-        plan, servers, ideal, n_candidates, pruned = self._route_leg(ctx, table)
-        plan = self._degrade_plan(plan, partial, table)
+        with span("broker.route"):
+            plan, servers, ideal, n_candidates, pruned = self._route_leg(ctx, table)
+            plan = self._degrade_plan(plan, partial, table)
+        with span("broker.scatter", table=table, servers=len(plan)):
+            return self._scatter_routed(ctx, table, sql, partial, plan, servers, ideal, n_candidates, pruned)
+
+    def _scatter_routed(self, ctx, table, sql, partial, plan, servers, ideal, n_candidates, pruned):
+        """The scatter of one routed leg, submit to decoded partials (the
+        extent of `broker.scatter`)."""
+        from pinot_tpu.cluster.routing import AdaptiveServerSelector
+        from pinot_tpu.common.trace import active_ledger, active_trace, bind_request
+
+        trace = active_trace()
         hints = dict(ctx.hints)
         if partial is not None:
             partial.servers_queried += len(plan)
-
-        from pinot_tpu.common.trace import active_trace, run_traced
-
-        trace = active_trace()
         adaptive = self.selector if isinstance(self.selector, AdaptiveServerSelector) else None
 
         def scatter(item):
             sid, segs = item
             t0 = time.perf_counter()
             try:
-                out = run_traced(trace, servers[sid].execute_partials, table, sql, segs, hints)
+                out = servers[sid].execute_partials(table, sql, segs, hints)
             except RuntimeError as e:
                 # connection-class failures enter the failover/degradation
                 # path when a failure detector is watching OR the query opted
@@ -1374,6 +1396,9 @@ class Broker:
                 )
             return out
 
+        # pool threads inherit no context: the legs run under this request's
+        # trace, ledger and open span (`broker.scatter`)
+        scatter = bind_request(scatter)
         results = self._scatter_plan(scatter, plan, ideal, table)
         failed = [r for r in results if isinstance(r, tuple) and r and r[0] == "__failed__"]
         results = [r for r in results if not (isinstance(r, tuple) and r and r[0] == "__failed__")]
@@ -1420,17 +1445,27 @@ class Broker:
 
         partials, scanned = [], 0
         scan = scan_stats.new_scan_summary()
+        server_ledgers = []
         for out in results:
             partials.extend(out[0])
             scanned += out[1]
-            # remote servers append their span subtree as a 4th element;
-            # in-process handles share our trace and return the bare triple
-            if len(out) > 3 and out[3] and trace is not None:
-                trace.add_remote(out[3])
+            # 4th element: the server's phase ledger, and — from a remote
+            # server under a sampled trace — its span subtree beside it
+            # (in-process handles share our trace)
+            if len(out) > 3 and out[3]:
+                sub = dict(out[3])
+                server_ledger = sub.pop("ledger", None)
+                if server_ledger:
+                    server_ledgers.append(server_ledger)
+                if sub and trace is not None:
+                    trace.add_remote(sub)
             # 5th element: the server's scan-path summary. The hedged path
             # returns only the winning leg's tuple, so stats never double-count.
             if len(out) > 4:
                 scan_stats.merge_scan_summaries(scan, out[4])
+        ledger = active_ledger()
+        if ledger is not None:
+            ledger.merge_servers(server_ledgers)
         return partials, scanned, n_candidates, pruned, scan
 
     def _execute_multistage(self, stmt, sql: str, deadline=None, qid=None) -> ResultTable:

@@ -54,6 +54,11 @@ def _frontend_role(service_obj, role: str) -> str | None:
     return role if getattr(cfg, "frontend_obs_enabled", True) else None
 
 
+#: carries the broker's query id to a server, as `traceparent` carries the
+#: trace context: the server's spans are tagged with it from the first byte
+QUERY_ID_HEADER = "X-Pinot-Query-Id"
+
+
 def _tl_mark(name: str) -> None:
     """Close the current wire-phase interval on the active request timeline
     (no-op when the frontend plane is off)."""
@@ -754,13 +759,21 @@ class ServerHTTPService:
                 if self.path != "/query":
                     self.send_error(404)
                     return
-                from pinot_tpu.common.trace import ServerQueryPhase, phase_timer
+                from pinot_tpu.common.trace import request_ledger, span
+
+                # the broker's query id rides a header, so that the request's
+                # spans carry it from the first byte read
+                with request_ledger(self.headers.get(QUERY_ID_HEADER, ""), "server"), span("server.request"):
+                    self._serve_query()
+
+            def _serve_query(self):
+                from pinot_tpu.common.trace import ServerQueryPhase, span
 
                 n = int(self.headers.get("Content-Length", 0))
                 try:
                     raw = self.rfile.read(n)
                     _tl_mark("bodyRead")
-                    with phase_timer(ServerQueryPhase.REQUEST_DESERIALIZATION, role="server"):
+                    with span("server.wire.decode", phase=ServerQueryPhase.REQUEST_DESERIALIZATION, role="server", bytes=n):
                         body = json.loads(raw or b"{}")
                     _tl_mark("parse")
                     out = svc.server.execute_partials(
@@ -790,15 +803,17 @@ class ServerHTTPService:
                     self.end_headers()
                     self.wfile.write(payload)
                     return
-                with phase_timer(ServerQueryPhase.RESPONSE_SERIALIZATION, role="server"):
+                with span("server.wire.encode", phase=ServerQueryPhase.RESPONSE_SERIALIZATION, role="server") as enc:
                     # iovec encode: header scratch + zero-copy column views;
                     # writelines() gather-writes them without materializing
                     # the payload a second time (no BytesIO/getvalue concat)
                     segments = datatable.encode_segments(out)
+                    total = sum(len(s) for s in segments)
+                    enc.set_attr("bytes", total)
                 _tl_mark("serialize")
                 self.send_response(200)
                 self.send_header("Content-Type", "application/x-pinot-datatable")
-                self.send_header("Content-Length", str(sum(len(s) for s in segments)))
+                self.send_header("Content-Length", str(total))
                 self.end_headers()
                 self.wfile.writelines(segments)
                 _tl_mark("write")
@@ -907,10 +922,14 @@ class ServerHTTPService:
                 elif self.path.partition("?")[0] == "/metrics":
                     from pinot_tpu.common.metrics import ServerTimer, server_metrics
 
+                    from pinot_tpu.common.kernel_obs import KERNELS
+
                     reg = server_metrics()
                     # ensure the core latency families exist even before the
                     # first query hits this server (stable scrape schema)
                     reg.timer(ServerTimer.QUERY_EXECUTION)
+                    # the HBM gauges are taken when they are read, not per kernel record
+                    KERNELS.publish_hbm_gauges()
                     _serve_metrics(self, reg)
                 elif self.path == "/debug/resources":
                     # leak-tracker + scheduler backlog (NettyLeakListener-
@@ -974,6 +993,8 @@ class RemoteServerClient:
         header — tracing context travels as HTTP metadata on the wire, not
         inside the query payload."""
         headers = {"Content-Type": "application/json"}
+        if hints.get("__queryId__"):
+            headers[QUERY_ID_HEADER] = str(hints["__queryId__"])
         tctx = hints.pop("__traceCtx__", None)
         if tctx:
             from pinot_tpu.common.trace import TraceContext
@@ -982,11 +1003,15 @@ class RemoteServerClient:
         return headers
 
     def execute_partials(self, table: str, sql: str, segment_names: list[str], hints: dict | None = None):
+        from pinot_tpu.common.trace import count, span
+
         hints = dict(hints or {})
         headers = self._trace_headers(hints)
-        body = json.dumps(
-            {"table": table, "sql": sql, "segments": segment_names, "hints": hints}
-        ).encode()
+        with span("broker.wire.encode"):
+            body = json.dumps(
+                {"table": table, "sql": sql, "segments": segment_names, "hints": hints}
+            ).encode()
+        count("wireRequestBytes", len(body))
         try:
             with get_pool().request(
                 self._host,
@@ -1021,7 +1046,9 @@ class RemoteServerClient:
             if doc.get("killReason"):
                 err.kill_reason = doc["killReason"]  # re-attach across the HTTP hop
             raise err from None
-        return datatable.decode(payload)
+        count("wireResponseBytes", len(payload))
+        with span("broker.wire.decode", bytes=len(payload)):
+            return datatable.decode(payload)
 
     def cancel_query(self, qid: str) -> bool:
         """Fan-out target for Broker.cancel_query; False when the server

@@ -612,38 +612,40 @@ class Server:
         self, table: str, sql: str, segment_names: list[str], hints: dict | None = None, workload: str = "PRIMARY"
     ):
         """Run the per-segment half for the requested segments; returns
-        (partials, matched_docs, total_docs, trace_subtree | None,
-        scan_summary). The broker passes hints (e.g.
-        global percentile bounds) so partials merge across servers. With a
+        (partials, matched_docs, total_docs, {"ledger": phase ledger, plus
+        the trace subtree's keys when sampled over the wire}, scan_summary).
+        The broker passes hints (e.g. global percentile bounds) so partials
+        merge across servers. With a
         scheduler configured, execution queues behind its policy; the caller
         blocks on the future (QueryScheduler.submit parity)."""
+        from pinot_tpu.common.trace import request_ledger, span
+
+        # the request's phase ledger: the HTTP handler's when there is one,
+        # else (in-process handle) this call's own, as a remote server's
+        # would be. It leaves on element 3, beside the span subtree.
+        with request_ledger(str((hints or {}).get("__queryId__") or ""), "server") as ledger:
+            with span("server.execute"):
+                out = self._submit_partials(table, sql, segment_names, hints, workload)
+            return out[:3] + ({**(out[3] or {}), "ledger": ledger.to_wire()},) + out[4:]
+
+    def _submit_partials(self, table, sql, segment_names, hints, workload):
         if self._scheduler is not None:
-            from pinot_tpu.common.metrics import server_metrics
-            from pinot_tpu.common.trace import ServerQueryPhase, active_trace
+            from pinot_tpu.common.trace import ServerQueryPhase, record_span
 
-            from pinot_tpu.common.frontend_obs import active_timeline
-
-            trace = active_trace()
-            wire_tl = active_timeline()
             t_sub = time.perf_counter()
 
             def run():
-                wait_ms = (time.perf_counter() - t_sub) * 1e3
-                if trace is not None:
-                    trace.record_phase(ServerQueryPhase.SCHEDULER_WAIT, wait_ms)
-                if wire_tl is not None:
-                    # HTTP wire timeline sub-phase: the queue-wait slice of
-                    # this request's `execute` on the server side
-                    wire_tl.record_sub(ServerQueryPhase.SCHEDULER_WAIT.value, wait_ms)
-                # aggregate phase timer: /metrics carries scheduler wait even
-                # for untraced queries (phase_timer role= parity)
-                server_metrics().timer(
-                    f"server.phase.{ServerQueryPhase.SCHEDULER_WAIT.value}Ms"
-                ).update_ms(wait_ms)
+                # queue wait: submitted there, started here. Into the ledger,
+                # the trace's phaseTimesMs, the HTTP timeline's sub-phases and
+                # /metrics' `server.phase.schedulerWaitMs`, traced or not
+                record_span(
+                    "server.queue", (time.perf_counter() - t_sub) * 1e3,
+                    phase=ServerQueryPhase.SCHEDULER_WAIT, role="server",
+                )  # fmt: skip
                 return self._execute_partials(table, sql, segment_names, hints)
 
             # the scheduler snapshots the submitting contextvars per job, so
-            # the active trace crosses into the worker thread by itself
+            # the active trace, timeline, ledger and open span cross into the worker
             fut = self._scheduler.submit(run, table=table, workload=workload)
             return fut.result()
         return self._execute_partials(table, sql, segment_names, hints)
@@ -677,6 +679,7 @@ class Server:
             active_trace,
             phase_timer,
             run_traced,
+            span,
             trace_event,
         )
 
@@ -725,7 +728,7 @@ class Server:
                 qid, table=table, tenant=tenant
             ):
                 eng = self._engine(table)
-                with phase_timer(ServerQueryPhase.BUILD_QUERY_PLAN, role="server"):
+                with span("server.plan", phase=ServerQueryPhase.BUILD_QUERY_PLAN, role="server"):
                     ctx = eng.make_context(sql)
                 if hints:
                     ctx.hints.update(hints)

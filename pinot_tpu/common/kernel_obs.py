@@ -29,6 +29,7 @@ cardinality stays bounded no matter what the workload looks like.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -208,6 +209,10 @@ class KernelRegistry:
         self._stats: dict[tuple[str, str], _KernelStats] = {}
         #: kernel -> times it was traced into an outer jitted program
         self._inlined: dict[str, int] = {}
+        #: (program, rows) -> {kernel: {"calls", "bytes", "flops"}}: the static
+        #: work of the registered kernels traced into a named outer program
+        self._program_work: dict[tuple[str, int], dict] = {}
+        self._building = threading.local()
         self.hbm = HostHbmEstimator()
 
     # -- configuration ------------------------------------------------------
@@ -249,16 +254,20 @@ class KernelRegistry:
 
     # -- recording ----------------------------------------------------------
 
+    def _cost(self, name: str, shape: dict) -> tuple[float, float]:
+        """(bytes, flops) of one invocation by the kernel's registered cost model."""
+        k = self._kernels.get(name)
+        if k is None or k.cost_model is None:
+            return 0.0, 0.0
+        nbytes, flops = k.cost_model(shape)
+        return max(float(nbytes), 0.0), max(float(flops), 0.0)
+
     def record(self, name: str, device_ms: float, **shape) -> None:
         """Fold one timed invocation into the ledger, metrics, the current
         query's accountant tracker, and the active trace."""
-        k = self._kernels.get(name)
-        if k is None:
+        if name not in self._kernels:
             return
-        nbytes, flops = (0.0, 0.0)
-        if k.cost_model is not None:
-            nbytes, flops = k.cost_model(shape)
-            nbytes, flops = max(float(nbytes), 0.0), max(float(flops), 0.0)
+        nbytes, flops = self._cost(name, shape)
         bucket = shape_bucket(shape.get("rows", 0))
         with self._lock:
             s = self._stats.setdefault((name, bucket), _KernelStats())
@@ -272,9 +281,6 @@ class KernelRegistry:
         reg.meter("engine.kernel.invocations", kernel=name, shape=bucket).mark()
         if nbytes:
             reg.meter("engine.kernel.bytesMoved", kernel=name, shape=bucket).mark(int(nbytes))
-        hbm = self.hbm_snapshot()
-        reg.gauge("engine.hbm.liveBytes").set(hbm["liveBytes"])
-        reg.gauge("engine.hbm.peakBytes").set(hbm["peakBytes"])
         default_accountant.sample(device_ms=device_ms, hbm_bytes=footprint)
         trace_event(
             "kernel.execute",
@@ -302,13 +308,55 @@ class KernelRegistry:
         if _has_tracer(out):
             with self._lock:
                 self._inlined[name] = self._inlined.get(name, 0) + 1
+            building = getattr(self._building, "work", None)
+            if building is not None:
+                # no time to take under an outer trace, but the shape is
+                # static: the program being built gets this call's work
+                nbytes, flops = self._cost(name, shape)
+                ent = building.setdefault(name, {"calls": 0, "bytes": 0.0, "flops": 0.0})
+                ent["calls"] += 1
+                ent["bytes"] += nbytes
+                ent["flops"] += flops
             return out
         out = jax.block_until_ready(out)
         wall_ms = (time.perf_counter() - t0) * 1e3
         self.record(name, max(wall_ms - _link_rtt_ms(), 0.0), **shape)
         return out
 
+    # -- static work of named outer programs ----------------------------------
+
+    @contextlib.contextmanager
+    def building(self, program: str, rows: int):
+        """Around the traced body of a named outer program (query/kernels.py
+        `get_packed_kernel`): every registered kernel reached inside while jax
+        traces it (`timed_sync` sees tracers) adds its static shape's cost to
+        `program_work(program, rows)`. Runs once per trace, never per call."""
+        self._building.work = work = {}
+        try:
+            yield
+        finally:
+            self._building.work = None
+            with self._lock:
+                self._program_work[(program, int(rows))] = work
+
+    def program_work(self, program: str, rows: int) -> dict:
+        """{kernel: {"calls", "bytes", "flops"}} of one launch of `program` at
+        `rows` padded docs; empty before its first trace and for a program
+        with no registered kernel inside."""
+        with self._lock:
+            return self._program_work.get((program, int(rows))) or {}
+
     # -- reporting ----------------------------------------------------------
+
+    def publish_hbm_gauges(self) -> dict:
+        """Set `engine.hbm.liveBytes` / `peakBytes` from a fresh snapshot.
+        Called where they are read (`/metrics`, `/debug/roofline`), not per
+        kernel record: `device.memory_stats()` is no hot-path call."""
+        hbm = self.hbm_snapshot()
+        reg = server_metrics()
+        reg.gauge("engine.hbm.liveBytes").set(hbm["liveBytes"])
+        reg.gauge("engine.hbm.peakBytes").set(hbm["peakBytes"])
+        return hbm
 
     def hbm_snapshot(self) -> dict:
         dev = device_hbm_stats()
@@ -386,7 +434,7 @@ class KernelRegistry:
             "kernels": rows,
             "offenders": offenders,
             "inlined": inlined,
-            "hbm": self.hbm_snapshot(),
+            "hbm": self.publish_hbm_gauges(),
             "registered": self.kernel_names(),
         }
 

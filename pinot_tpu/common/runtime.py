@@ -38,6 +38,13 @@ def _on_jax_event(event: str, **_kw) -> None:
 jax.monitoring.register_event_listener(_on_jax_event)
 
 
+def compile_requests() -> int:
+    """Compile requests this process has made so far (jax's own event; a
+    request answered from the persistent cache counts too)."""
+    with _cache_lock:
+        return _cache_events["requests"]
+
+
 def pin_cpu() -> dict:
     """Broker / controller: the CPU is the only platform this process may
     initialise, whatever accelerator the host has."""

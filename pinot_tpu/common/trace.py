@@ -25,6 +25,17 @@ document served at broker `GET /debug/traces/{requestId}`. Spans carry
 retries, deadline checkpoints that fired, fault-injector hits, accountant
 kills) via the module-level `trace_event()` helper, a no-op when no trace
 is active.
+
+One timing primitive, `span(name, **attrs)`, times every layer boundary of
+the served v1 path, traced or not: it folds the duration into the request's
+`PhaseLedger` (per span name: total, self, count — what every broker
+response carries as `spanTimesMs` / `spanSelfMs`, with the request's
+`counters` and `deviceWork`), opens a `jax.profiler.TraceAnnotation` tagged
+with the broker's query id (inert without a profiler session; under one the
+span lies in the `.xplane.pb`'s host plane on the device trace's clock), and
+joins the `RequestTrace` tree when one is active. `phase_timer` and
+`InvocationScope` are `span` with a phase, or with a run-time name. The span
+names and what reads each are listed in PERF.md section 3.
 """
 
 from __future__ import annotations
@@ -36,6 +47,8 @@ import uuid
 from dataclasses import dataclass, field
 from enum import Enum
 
+from jax.profiler import TraceAnnotation
+
 
 class ServerQueryPhase(Enum):
     REQUEST_DESERIALIZATION = "requestDeserialization"
@@ -45,8 +58,11 @@ class ServerQueryPhase(Enum):
     QUERY_PLAN_EXECUTION = "queryPlanExecution"
     RESPONSE_SERIALIZATION = "responseSerialization"
     SCHEDULER_WAIT = "schedulerWait"
-    #: accelerator time attributed by kernel_obs (block_until_ready fenced,
-    #: link RTT subtracted) — the device-side slice of queryPlanExecution
+    #: kernel_obs' `deviceMs`: host wall of the blocking readback less a
+    #: memoized link RTT — everything queued on the device ahead of the
+    #: program plus the program, not the program's device time. The span
+    #: `server.device_wait` is the same wait, unadjusted; device time proper
+    #: is in the profiler trace (perfbench `device_busy_ms_per_query`)
     DEVICE_EXECUTION = "deviceExecution"
     # broker/transport phases (BrokerQueryPhase parity) — one enum keeps the
     # phaseTimesMs namespace flat across roles
@@ -315,66 +331,347 @@ class start_trace:
         return False
 
 
-class InvocationScope:
-    """Span around an operator/kernel invocation. No-op when tracing is off
-    (Tracing.java default NoOpTracer parity: near-zero overhead)."""
+# -- the request's phase ledger ------------------------------------------------
 
-    __slots__ = ("name", "attrs", "_trace", "_span", "_t0", "_parent")
 
-    def __init__(self, name: str, parent: Span | None = None, **attrs):
-        self.name = name
-        self.attrs = attrs
-        self._parent = parent
-        self._trace = _active.get()
+class PhaseLedger:
+    """What one request spent where, per span name: total ms, self ms (the
+    total less what child spans cover) and count; the request's counters
+    (wire bytes, segments and rows dispatched) and the static work of the
+    device programs it launched. One per request per role: the broker's is
+    set at `Broker.execute` entry, a server's at its request entry (HTTP
+    handler or in-process `execute_partials`); servers ship theirs back on
+    element 3 of the partials tuple and the broker folds them in with
+    `merge_servers`. Thread-safe: scatter legs and scheduler workers fold
+    concurrently."""
 
-    def __enter__(self) -> "InvocationScope":
-        if self._trace is not None:
-            self._t0 = time.perf_counter()
-            self._span = Span(self.name, self._trace.now_ms(), attrs=self.attrs)
-        return self
+    __slots__ = ("qid", "role", "_lock", "spans", "counters", "device_work")
 
-    def set_attr(self, key: str, value) -> None:
-        if self._trace is not None:
-            self._span.attrs[key] = value
+    def __init__(self, qid: str = "", role: str = "broker"):
+        self.qid = qid
+        self.role = role
+        self._lock = threading.Lock()
+        self.spans: dict[str, list] = {}  # name -> [total ms, self ms, count]
+        self.counters: dict[str, int] = {}
+        self.device_work: dict[str, dict] = {}
+
+    def fold(self, name: str, ms: float, self_ms: float, parent: "span | None" = None) -> None:
+        with self._lock:
+            ent = self.spans.get(name)
+            if ent is None:
+                self.spans[name] = [ms, self_ms, 1]
+            else:
+                ent[0] += ms
+                ent[1] += self_ms
+                ent[2] += 1
+            if parent is not None:
+                parent._child_ms += ms
+
+    def pass_up(self, parent: "span", child_ms: float) -> None:
+        with self._lock:
+            parent._child_ms += child_ms
+
+    def count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self.counters[name] = self.counters.get(name, 0) + int(n)
+
+    def add_device_work(self, program: str, rows: int, kernels: dict) -> None:
+        """One launch of a fused per-segment program: its rows and the static
+        work of the registered kernels traced into it (kernel_obs)."""
+        with self._lock:
+            _add_work(self.device_work, program, {"launches": 1, "rows": int(rows), "kernels": kernels})
+
+    def to_wire(self) -> dict:
+        with self._lock:
+            return {
+                "spans": {k: list(v) for k, v in self.spans.items()},
+                "counters": dict(self.counters),
+                "deviceWork": {
+                    p: {**w, "kernels": {k: dict(c) for k, c in w["kernels"].items()}}
+                    for p, w in self.device_work.items()
+                },
+            }
+
+    def merge_servers(self, docs: list) -> None:
+        """Fold the ledgers of the servers of one scatter into this one: the
+        servers ran side by side, so a span's time is the **max** over them
+        (the critical path); counters and device work add up."""
+        worst: dict[str, list] = {}
+        with self._lock:
+            for doc in docs:
+                for name, ent in doc.get("spans", {}).items():
+                    if name not in worst or ent[0] > worst[name][0]:
+                        worst[name] = ent
+                for name, n in doc.get("counters", {}).items():
+                    self.counters[name] = self.counters.get(name, 0) + int(n)
+                for program, work in doc.get("deviceWork", {}).items():
+                    _add_work(self.device_work, program, work)
+            for name, (ms, self_ms, n) in worst.items():
+                ent = self.spans.setdefault(name, [0.0, 0.0, 0])
+                ent[0] += ms
+                ent[1] += self_ms
+                ent[2] += n
+
+    def response_fields(self) -> dict:
+        """The four keys every v1 broker response carries."""
+        doc = self.to_wire()
+        work = doc["deviceWork"].values()
+        return {
+            "spanTimesMs": {k: round(v[0], 3) for k, v in doc["spans"].items()},
+            "spanSelfMs": {k: round(v[1], 3) for k, v in doc["spans"].items()},
+            "counters": {
+                "wireRequestBytes": 0,
+                "wireResponseBytes": 0,
+                **doc["counters"],
+                # what was dispatched is what `deviceWork` holds, program by program
+                "segmentsDispatched": sum(w["launches"] for w in work),
+                "rowsDispatched": sum(w["rows"] for w in work),
+            },
+            "deviceWork": doc["deviceWork"],
+        }
+
+
+def _add_work(into: dict, program: str, work: dict) -> None:
+    ent = into.setdefault(program, {"launches": 0, "rows": 0, "kernels": {}})
+    ent["launches"] += int(work.get("launches", 0))
+    ent["rows"] += int(work.get("rows", 0))
+    for kernel, c in work.get("kernels", {}).items():
+        k = ent["kernels"].setdefault(kernel, {"calls": 0, "bytes": 0.0, "flops": 0.0})
+        for key in ("calls", "bytes", "flops"):
+            k[key] += c.get(key, 0)
+
+
+_ledger: contextvars.ContextVar[PhaseLedger | None] = contextvars.ContextVar("pinot_ledger", default=None)
+# innermost open span of this execution context: what a new span nests under
+_open: contextvars.ContextVar["span | None"] = contextvars.ContextVar("pinot_open_span", default=None)
+
+
+def active_ledger() -> PhaseLedger | None:
+    return _ledger.get()
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add to a counter of the request's ledger; no-op outside a request."""
+    led = _ledger.get()
+    if led is not None:
+        led.count(name, n)
+
+
+class request_ledger:
+    """The dynamic extent of one request in one role. A role's outer entry
+    (the server's HTTP handler) and inner entry (`execute_partials`) share
+    one ledger; another role's (an in-process server under the broker's
+    thread) starts its own, as a remote server would."""
+
+    __slots__ = ("qid", "role", "_tokens")
+
+    def __init__(self, qid: str = "", role: str = "broker"):
+        self.qid = qid
+        self.role = role
+        self._tokens = None
+
+    def __enter__(self) -> PhaseLedger:
+        cur = _ledger.get()
+        if cur is not None and cur.role == self.role:
+            return cur
+        ledger = PhaseLedger(self.qid, self.role)
+        self._tokens = (_ledger.set(ledger), _open.set(None))
+        return ledger
 
     def __exit__(self, *exc):
-        if self._trace is not None:
-            self._span.duration_ms = (time.perf_counter() - self._t0) * 1e3
-            self._trace.add_span(self._span, self._parent)
+        if self._tokens is not None:
+            _ledger.reset(self._tokens[0])
+            _open.reset(self._tokens[1])
         return False
 
 
-class phase_timer:
-    """Times one ServerQueryPhase (TimerContext parity). Records into the
-    active trace's phaseTimesMs when tracing is on, and — when `role` is
-    given — unconditionally into that role's metrics registry as a
-    `<role>.phase.<phase>Ms` Timer, so `/metrics` answers "which phase ate
-    the budget" in aggregate even for untraced queries while `/debug/traces`
-    answers it per request."""
+class span:
+    """The one timing primitive: `with span("server.dispatch", segment=...)`.
 
-    def __init__(self, phase: ServerQueryPhase, role: str | None = None):
-        self.phase = phase
-        self.role = role
+    Always: perf_counter at entry and exit, folded into the request's phase
+    ledger (total, self, count; nesting from the context, so a span opened
+    in a scheduler worker lands under the span that submitted it), and —
+    under a profiler session — a `jax.profiler.TraceAnnotation(name,
+    qid=..., **attrs)`: the span lies in the `.xplane.pb`'s host plane, on
+    the device trace's clock, with the request's id in it.
+    When a RequestTrace is active: the `Span` joins its tree, under the
+    enclosing span's. With `phase=`: the duration also feeds that
+    ServerQueryPhase — the trace's `phaseTimesMs`, the `<role>.phase.*Ms`
+    timer of `/metrics` when `role` is given, and the HTTP timeline's
+    sub-phases. `ms` holds the duration after exit."""
 
-    def __enter__(self):
+    __slots__ = (
+        "name", "attrs", "ms", "_phase", "_role", "_late", "_t0", "_child_ms",
+        "_ledger", "_parent", "_token", "_ann", "_trace", "_span",
+    )  # fmt: skip
+
+    #: False: a dynamic name (`segment:<name>`) stays out of the ledger, whose names are a fixed set
+    _FOLD = True
+    #: False: no `Span` in the active trace's tree
+    _TREE = True
+
+    def __init__(self, name: str, *, phase: ServerQueryPhase | None = None, role: str | None = None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.ms = 0.0
+        self._phase = phase
+        self._role = role
+        self._late = None
+        self._span = None
+
+    def __enter__(self) -> "span":
+        led = self._ledger = _ledger.get()
+        self._parent = _open.get()
+        self._token = _open.set(self)
+        self._child_ms = 0.0
+        tr = self._trace = _active.get()
+        if tr is not None and self._TREE:
+            self._span = Span(self.name, tr.now_ms(), attrs=dict(self.attrs))
+        # no profiler session (one static check): no annotation object at all
+        ann = self._ann = (
+            TraceAnnotation(self.name, qid=led.qid if led is not None else "", **self.attrs)
+            if TraceAnnotation.is_enabled()
+            else None
+        )
+        if ann is not None:
+            ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def set_attr(self, key: str, value) -> None:
+        """An attribute known only inside the span (rows matched, whether
+        the dispatch compiled): reaches the profiler event and the tree."""
+        if self._late is None:
+            self._late = {}
+        self._late[key] = value
+        if self._span is not None:
+            self._span.attrs[key] = value
+
+    def _tree_parent(self) -> Span | None:
+        """The nearest enclosing span with a `Span` in this trace's tree."""
+        p = self._parent
+        while p is not None and p._trace is self._trace:
+            if p._span is not None:
+                return p._span
+            p = p._parent
+        return None
+
     def __exit__(self, *exc):
-        ms = (time.perf_counter() - self._t0) * 1e3
-        tr = _active.get()
-        if tr is not None:
-            tr.record_phase(self.phase, ms)
-        if self.role is not None:
-            from pinot_tpu.common.metrics import get_registry
-
-            get_registry(self.role).timer(f"{self.role}.phase.{self.phase.value}Ms").update_ms(ms)
-        # fold into the active HTTP wire timeline's sub-phase decomposition
-        # (no-op outside an instrumented HTTP request)
-        from pinot_tpu.common.frontend_obs import record_timeline_sub
-
-        record_timeline_sub(self.phase.value, ms)
+        ms = self.ms = (time.perf_counter() - self._t0) * 1e3
+        ann = self._ann
+        if ann is not None:
+            if self._late:
+                ann.set_metadata(**self._late)
+            ann.__exit__(*exc)
+        _open.reset(self._token)
+        led = self._ledger
+        if led is not None:
+            parent = self._parent
+            if parent is not None and parent._ledger is not led:
+                parent = None
+            if self._FOLD:
+                led.fold(self.name, ms, max(ms - self._child_ms, 0.0), parent)
+            elif parent is not None and self._child_ms:
+                led.pass_up(parent, self._child_ms)  # a span outside the ledger hides no child from its parent
+        tr = self._trace
+        if self._span is not None:
+            self._span.duration_ms = ms
+            tr.add_span(self._span, self._tree_parent())
+        if self._phase is not None:
+            _record_phase(self._phase, self._role, ms)
         return False
+
+
+def _record_phase(phase: ServerQueryPhase, role: str | None, ms: float) -> None:
+    """One ServerQueryPhase sample: the active trace's phaseTimesMs, the
+    role's `<role>.phase.<phase>Ms` timer of `/metrics`, and the active HTTP
+    wire timeline's sub-phase decomposition (no-op outside one)."""
+    tr = _active.get()
+    if tr is not None:
+        tr.record_phase(phase, ms)
+    if role is not None:
+        from pinot_tpu.common.metrics import get_registry
+
+        get_registry(role).timer(f"{role}.phase.{phase.value}Ms").update_ms(ms)
+    from pinot_tpu.common.frontend_obs import record_timeline_sub
+
+    record_timeline_sub(phase.value, ms)
+
+
+def record_span(name: str, ms: float, phase: ServerQueryPhase | None = None, role: str | None = None) -> None:
+    """Fold an interval that no one thread saw whole (a queue wait: submitted
+    here, started there) into the ledger, as a child of the open span, and
+    into `phase` as `span(phase=, role=)` would."""
+    led = _ledger.get()
+    if led is not None:
+        parent = _open.get()
+        led.fold(name, ms, ms, parent if parent is not None and parent._ledger is led else None)
+    if phase is not None:
+        _record_phase(phase, role, ms)
+
+
+class InvocationScope(span):
+    """Span around an operator invocation whose name is made at run time
+    (`segment:<name>`, `stage3:w1`): timed and annotated by `span`, joins the
+    active trace's tree under the root (or `parent`), stays out of the
+    ledger. (Tracing.java InvocationScope parity.)"""
+
+    __slots__ = ("_explicit_parent", "_off")
+    _FOLD = False
+
+    def __init__(self, name: str, parent: Span | None = None, **attrs):
+        super().__init__(name, **attrs)
+        self._explicit_parent = parent
+
+    def __enter__(self) -> "InvocationScope":
+        # outside the ledger, so with no trace to join and no profiler session
+        # nothing would read it (Tracing.java's default NoOpTracer)
+        self._off = _active.get() is None and not TraceAnnotation.is_enabled()
+        return self if self._off else super().__enter__()
+
+    def set_attr(self, key: str, value) -> None:
+        if not self._off:
+            super().set_attr(key, value)
+
+    def __exit__(self, *exc):
+        return False if self._off else super().__exit__(*exc)
+
+    def _tree_parent(self) -> Span | None:
+        return self._explicit_parent
+
+
+class phase_timer(span):
+    """Times one ServerQueryPhase (TimerContext parity) — `span` with
+    `phase=`, for sites that have no span name of their own: the trace's
+    phaseTimesMs when tracing is on, and — when `role` is given — that
+    role's `<role>.phase.<phase>Ms` Timer unconditionally, so `/metrics`
+    answers "which phase ate the budget" even for untraced queries. Stays
+    out of the ledger, whose names are the span sites' own."""
+
+    __slots__ = ()
+    _FOLD = False
+    _TREE = False
+
+    def __init__(self, phase: ServerQueryPhase, role: str | None = None):
+        super().__init__(f"phase.{phase.value}", phase=phase, role=role)
+
+
+def bind_request(fn):
+    """`fn` bound to the calling context's ledger, open span and trace — for
+    pool threads, which inherit no context (the query scheduler copies the
+    submitter's context by itself)."""
+    led, parent, tr = _ledger.get(), _open.get(), _active.get()
+
+    def bound(*args, **kwargs):
+        def inner():
+            _ledger.set(led)
+            _open.set(parent)
+            _active.set(tr)
+            return fn(*args, **kwargs)
+
+        return contextvars.copy_context().run(inner)
+
+    return bound
 
 
 def run_traced(trace: RequestTrace | None, fn, *args, **kwargs):

@@ -152,11 +152,13 @@ class QueryEngine:
 
         from pinot_tpu.common.accounting import default_accountant
         from pinot_tpu.common.faults import FAULTS, InjectedFault
-        from pinot_tpu.common.trace import trace_event
+        from pinot_tpu.common import runtime
+        from pinot_tpu.common.trace import record_span, span, trace_event
         from pinot_tpu.query import pruner, scan_stats
 
         pend: list = []
         pruned = 0
+        prune_ms = 0.0
         cm = (
             scan_stats.collect_probes(probe_sink)
             if probe_sink is not None
@@ -172,7 +174,9 @@ class QueryEngine:
                 except InjectedFault:
                     trace_event("fault.injected", point="segment.execute", segment=seg.name)
                     raise
+                t_prune = time.perf_counter()
                 reason = pruner.prune_reason(seg, ctx)
+                prune_ms += (time.perf_counter() - t_prune) * 1e3
                 if reason is not None:
                     # bloom/min-max/geo pruned: contribute a canonical empty
                     # partial; the reject reason rides along for the per-reason
@@ -180,7 +184,16 @@ class QueryEngine:
                     pend.append((seg, ("pruned", pruner.empty_partial(ctx), reason)))
                     pruned += 1
                 else:
-                    pend.append((seg, self._dispatch_segment(seg, ctx)))
+                    with span("server.dispatch", segment=seg.name) as sp:
+                        compiles = runtime.compile_requests()
+                        disp = self._dispatch_segment(seg, ctx)
+                        if disp[0] == "dev":
+                            sp.set_attr("program", disp[2].program)
+                            sp.set_attr("rows", disp[2].rows)
+                            sp.set_attr("compiled", runtime.compile_requests() != compiles)
+                    pend.append((seg, disp))
+        # pruning is microseconds a segment, between the dispatches: one ledger entry a query, no span each
+        record_span("server.prune", prune_ms)
         return pend, pruned
 
     def _resolve_partials(self, ctx: QueryContext, pend: list, pruned: int):
@@ -191,7 +204,7 @@ class QueryEngine:
         from pinot_tpu.common.accounting import default_accountant
         from pinot_tpu.common.metrics import ScanMeter, ServerMeter, server_metrics
         from pinot_tpu.common.segment_heat import HEAT
-        from pinot_tpu.common.trace import InvocationScope, trace_event
+        from pinot_tpu.common.trace import InvocationScope, span, trace_event
         from pinot_tpu.query import scan_stats
 
         obs = scan_stats.enabled() and getattr(self, "scan_obs_enabled", True)
@@ -213,7 +226,7 @@ class QueryEngine:
             # this thread spent descheduled or blocked
             t_cpu = time.thread_time_ns()
             t_wall = time.perf_counter()
-            with InvocationScope(f"segment:{seg.name}") as scope:
+            with InvocationScope(f"segment:{seg.name}") as scope, span("server.unpack", segment=seg.name):
                 if obs:
                     with scan_stats.collect_probes(summary["indexProbeEntries"]):
                         partial, matched = self._finish_segment(seg, ctx, disp)

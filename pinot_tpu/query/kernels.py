@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import threading
 import weakref
+import zlib
 from functools import lru_cache
 
 import jax
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pinot_tpu.common.kernel_obs import KERNELS, CacheObserver
+from pinot_tpu.common.trace import active_ledger, span
 
 _F = jnp.float64
 _I = jnp.int64
@@ -711,12 +713,50 @@ def build_masked_fn(spec: tuple):
     return run
 
 
+def _canonical(x) -> str:
+    """A spec as text that is the same in every process: no `hash()`, no set
+    order, no numpy scalar's repr."""
+    if isinstance(x, (tuple, list)):
+        return "(" + ",".join(_canonical(v) for v in x) + ")"
+    if isinstance(x, (set, frozenset)):
+        return "{" + ",".join(sorted(_canonical(v) for v in x)) + "}"
+    if isinstance(x, dict):
+        return "{" + ",".join(sorted(f"{_canonical(k)}:{_canonical(v)}" for k, v in x.items())) + "}"
+    if isinstance(x, np.generic):
+        x = x.item()
+    return repr(x)
+
+
+@lru_cache(maxsize=4096)
+def program_name(spec: tuple) -> str:
+    """`seg_<kind>_<crc32 of the canonical spec, 8 hex>`: what the fused
+    per-segment program of a plan shape is called — in the device trace
+    (module `jit_<name>`), in `server.dispatch` spans and in a response's
+    `deviceWork` — the same in every process and run of a checkout."""
+    kind = spec[0]
+    if kind == "agg":
+        kind = "agg" if spec[2] is None else ("groupby" if spec[3] else "distinct")
+    elif kind == "select_ob":
+        kind = "select"
+    return f"seg_{kind}_{zlib.crc32(_canonical(spec).encode()):08x}"
+
+
+def _named(fn, spec: tuple):
+    fn.__name__ = fn.__qualname__ = program_name(spec)
+    return fn
+
+
 @lru_cache(maxsize=1024)
 def get_kernel(spec: tuple):
     """Jitted program for a plan spec. One compile per (spec, input shapes).
     n_padded (the doc-pad length) is static: cols may contain MV flat arrays,
     so the doc shape cannot be inferred from an arbitrary entry."""
-    return jax.jit(build_fn(spec), static_argnums=3)
+    base = build_fn(spec)
+
+    def run(cols, ops, n_docs, n_padded):
+        return base(cols, ops, n_docs, n_padded)
+
+    return jax.jit(_named(run, spec), static_argnums=3)
 
 
 @lru_cache(maxsize=1024)
@@ -734,9 +774,13 @@ def get_packed_kernel(spec: tuple):
     with input shapes under one spec (select_ob's k is clipped to n_padded),
     so _packed_meta derives them per input-shape signature via eval_shape."""
     base = build_fn(spec)
+    name = program_name(spec)
 
     def run(cols, ops, n_docs, n_padded):
-        leaves, _ = jax.tree.flatten(base(cols, ops, n_docs, n_padded))
+        # runs when jax traces the program, once per input signature: the
+        # registered kernels reached inside leave their static work behind
+        with KERNELS.building(name, n_padded):
+            leaves, _ = jax.tree.flatten(base(cols, ops, n_docs, n_padded))
         chunks = []
         for l in leaves:
             flat = jnp.ravel(l)
@@ -749,7 +793,7 @@ def get_packed_kernel(spec: tuple):
             return jnp.zeros((0,), dtype=jnp.float64)
         return jnp.concatenate(chunks)
 
-    return jax.jit(run, static_argnums=3)
+    return jax.jit(_named(run, spec), static_argnums=3)
 
 
 #: compile-cache observability (engine.kernelCache.*{cache=} on /metrics) —
@@ -868,6 +912,10 @@ def dispatch_plan_packed(plan, device_segment):
     cols, ops = _plan_inputs(plan, device_segment)
     n_cols = len(cols)
     vec = kernel(cols, ops, np.int32(device_segment.n_docs), device_segment.padded)
+    name = kernel.__name__  # program_name(plan.spec), without hashing the spec again
+    ledger = active_ledger()
+    if ledger is not None:
+        ledger.add_device_work(name, device_segment.padded, KERNELS.program_work(name, device_segment.padded))
     treedef, leaf_meta = _packed_meta(
         plan.spec,
         tuple(sorted((k, tuple(v.shape), str(np.dtype(v.dtype))) for k, v in cols.items())),
@@ -876,16 +924,19 @@ def dispatch_plan_packed(plan, device_segment):
     )
 
     def unpack():
-        # THE device->host sync, fenced + attributed by kernel_obs (device
-        # time = wall minus the memoized link RTT, the bench.py split)
-        v = np.asarray(
-            KERNELS.timed_sync(
-                "query.fused_packed",
-                lambda: np.asarray(vec),
-                rows=device_segment.padded,
-                cols=n_cols,
+        # THE device->host sync: `server.device_wait` is the wait for the
+        # readback and nothing else — everything queued on the device ahead
+        # of this program plus the program. kernel_obs' deviceMs is the same
+        # wall less the memoized link RTT.
+        with span("server.device_wait"):
+            v = np.asarray(
+                KERNELS.timed_sync(
+                    "query.fused_packed",
+                    lambda: np.asarray(vec),
+                    rows=device_segment.padded,
+                    cols=n_cols,
+                )
             )
-        )
         out = []
         i = 0
         for shape, dtype in leaf_meta:
@@ -903,6 +954,8 @@ def dispatch_plan_packed(plan, device_segment):
             out.append(chunk.reshape(shape))
         return jax.tree.unflatten(treedef, out)
 
+    # what was launched, for the caller's `server.dispatch` span
+    unpack.program, unpack.rows = name, device_segment.padded
     return unpack
 
 

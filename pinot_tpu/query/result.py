@@ -59,6 +59,10 @@ class ResultTable:
     # broker result-cache verdict for THIS request (BrokerResponse metadata):
     # true = the response was served from cluster/result_cache.py
     cache_hit: bool = False
+    # the request's phase ledger as the broker answers it (common/trace.py
+    # PhaseLedger.response_fields): spanTimesMs, spanSelfMs, counters,
+    # deviceWork — on every v1 broker response, traced or not
+    span_stats: dict | None = None
 
     def __post_init__(self):
         self.rows = [[_plain(v) for v in row] for row in self.rows]
@@ -83,6 +87,8 @@ class ResultTable:
             "timeUsedMs": self.time_used_ms,
             "cacheHit": self.cache_hit,
         }
+        if self.span_stats is not None:
+            d.update(self.span_stats)
         if self.scan_profile is not None:
             d["scanProfile"] = self.scan_profile
         if self.trace is not None:

@@ -221,6 +221,8 @@ def test_record_emits_labelled_metric_families(zero_rtt):
     assert reg.timer("engine.kernel.deviceMs", kernel="unit.k", shape="2^10").count == 2
     assert reg.meter("engine.kernel.invocations", kernel="unit.k", shape="2^10").count == 2
     assert reg.meter("engine.kernel.bytesMoved", kernel="unit.k", shape="2^10").count == 2 * 1024 * 8
+    # the HBM gauges are set where they are read (/metrics, /debug/roofline), not per record
+    r.publish_hbm_gauges()
     assert reg.gauge("engine.hbm.peakBytes").value == 1024 * 8
 
 

@@ -1,0 +1,423 @@
+"""The one span primitive (common/trace.py `span`): nesting and self time,
+the phase ledger that leaves with every v1 broker response (`spanTimesMs`,
+`spanSelfMs`, `counters`, `deviceWork`), the stable names of the fused
+per-segment programs, and the spans' arrival in a profiler trace.
+
+No test asserts a duration or an overhead: only order relations between
+spans that enclose each other, and counts.
+"""
+
+import contextlib
+import json
+import os
+import re
+import signal
+import subprocess
+import sys
+import threading
+import urllib.request
+
+import numpy as np
+import pytest
+
+from pinot_tpu.cluster import Broker, Controller, PropertyStore, Server
+from pinot_tpu.cluster.http import BrokerHTTPService, RemoteServerClient, ServerHTTPService
+from pinot_tpu.common import CacheConfig, DataType, Schema, TableConfig
+from pinot_tpu.common.trace import (
+    PhaseLedger,
+    ServerQueryPhase,
+    active_ledger,
+    bind_request,
+    count,
+    phase_timer,
+    record_span,
+    request_ledger,
+    span,
+    start_trace,
+)
+from pinot_tpu.query.scheduler import make_scheduler
+from pinot_tpu.segment import SegmentBuilder
+
+AGG = "SELECT COUNT(*), SUM(v) FROM t WHERE v > 3"
+GROUP_BY = "SELECT d, SUM(v) FROM t GROUP BY d ORDER BY d LIMIT 10"
+
+BROKER_SPANS = {"broker.request", "broker.compile", "broker.route", "broker.scatter", "broker.reduce"}
+SERVER_SPANS = {
+    "server.execute", "server.plan", "server.prune", "server.dispatch", "server.device_wait", "server.unpack",
+}  # fmt: skip
+WIRE_SPANS = {"broker.wire.encode", "broker.wire.decode", "server.wire.decode"}
+RESPONSE_KEYS = ("spanTimesMs", "spanSelfMs", "counters", "deviceWork")
+
+
+# ---------------------------------------------------------------------------
+# the primitive
+# ---------------------------------------------------------------------------
+
+
+def test_span_outside_a_request_is_inert():
+    assert active_ledger() is None
+    with span("server.dispatch", segment="s0") as sp:
+        sp.set_attr("rows", 4)
+    record_span("server.queue", 1.0)
+    count("segmentsDispatched")
+    assert sp.ms >= 0.0 and active_ledger() is None
+
+
+def test_child_time_is_not_counted_twice():
+    with request_ledger("q-nest") as led:
+        with span("outer"):
+            with span("inner"):
+                with span("leaf"):
+                    pass
+            with span("inner"):
+                pass
+            record_span("queued", 2.0)
+    s = led.to_wire()["spans"]
+    assert s["inner"][2] == 2 and s["outer"][2] == 1 and s["queued"] == [2.0, 2.0, 1]
+    # self = total less what the children cover: the three levels add up to the outer total
+    assert s["leaf"][1] == s["leaf"][0]
+    assert s["inner"][1] == pytest.approx(s["inner"][0] - s["leaf"][0])
+    assert s["outer"][1] == pytest.approx(max(s["outer"][0] - s["inner"][0] - 2.0, 0.0))
+    fields = led.response_fields()
+    assert set(fields) == set(RESPONSE_KEYS)
+    assert fields["counters"] == {
+        "wireRequestBytes": 0, "wireResponseBytes": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
+    }  # fmt: skip
+
+
+def test_phase_timer_is_transparent_to_the_ledger():
+    """A phase timer has no span name of its own: it stays out of the
+    ledger and hides no child from the span around it."""
+    with request_ledger("q-phase") as led:
+        with span("outer"):
+            with phase_timer(ServerQueryPhase.QUERY_PLAN_EXECUTION):
+                with span("inner"):
+                    pass
+    s = led.to_wire()["spans"]
+    assert set(s) == {"outer", "inner"}
+    assert s["outer"][1] == pytest.approx(s["outer"][0] - s["inner"][0])
+
+
+def test_span_in_a_scheduler_worker_lands_under_its_request():
+    sched = make_scheduler("fcfs")
+    sched.start()
+    try:
+        seen = {}
+
+        def job():
+            seen["ledger"] = active_ledger()
+            with span("server.plan"):
+                pass
+            return threading.get_ident()
+
+        with request_ledger("q-sched", "server") as led:
+            with span("server.execute"):
+                worker = sched.submit(job, table="t").result()
+        assert worker != threading.get_ident() and seen["ledger"] is led
+        s = led.to_wire()["spans"]
+        # the worker's span is a child of the span that submitted it
+        assert s["server.execute"][1] == pytest.approx(s["server.execute"][0] - s["server.plan"][0])
+    finally:
+        sched.stop()
+    assert active_ledger() is None
+
+
+def test_bind_request_carries_the_request_into_a_pool_thread():
+    from concurrent.futures import ThreadPoolExecutor
+
+    def leg():
+        with span("broker.wire.encode"):
+            pass
+        return active_ledger()
+
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        with request_ledger("q-pool") as led:
+            with span("broker.scatter"):
+                assert pool.submit(bind_request(leg)).result() is led
+        # nothing of the request stays behind in the pool's thread
+        assert pool.submit(active_ledger).result() is None
+    assert "broker.wire.encode" in led.to_wire()["spans"]
+
+
+def test_a_role_gets_its_own_ledger():
+    with request_ledger("q-roles", "broker") as broker_led:
+        with span("broker.scatter"):
+            with request_ledger("q-roles", "server") as server_led:
+                assert server_led is not broker_led and server_led.qid == "q-roles"
+                with request_ledger("q-roles", "server") as again:  # HTTP handler, then execute_partials
+                    assert again is server_led
+                with span("server.execute"):
+                    pass
+            assert active_ledger() is broker_led
+    assert "server.execute" in server_led.to_wire()["spans"]
+    # the server's time is not a child of the broker's span: in-process and over HTTP read alike
+    b = broker_led.to_wire()["spans"]["broker.scatter"]
+    assert b[0] == b[1]
+
+
+def test_merge_of_servers_is_the_max_and_work_adds_up():
+    def doc(ms, rows):
+        return {
+            "spans": {"server.execute": [ms, 1.0, 1], "server.device_wait": [ms / 2, ms / 2, 3]},
+            "counters": {"wireRequestBytes": 7},
+            "deviceWork": {"seg_agg_00000001": {"launches": 3, "rows": rows, "kernels": {
+                "ops.grouped_planes": {"calls": 3, "bytes": 10.0, "flops": 20.0}}}},
+        }  # fmt: skip
+
+    led = PhaseLedger("q-merge")
+    led.merge_servers([doc(40.0, 300), doc(90.0, 600)])
+    out = led.response_fields()
+    assert out["spanTimesMs"]["server.execute"] == 90.0 and out["spanTimesMs"]["server.device_wait"] == 45.0
+    # what was dispatched is read off the merged device work
+    assert out["counters"]["segmentsDispatched"] == 6 and out["counters"]["rowsDispatched"] == 900
+    assert out["counters"]["wireRequestBytes"] == 14
+    work = out["deviceWork"]["seg_agg_00000001"]
+    assert work["launches"] == 6 and work["rows"] == 900
+    assert work["kernels"]["ops.grouped_planes"] == {"calls": 6, "bytes": 20.0, "flops": 40.0}
+    # a second leg (hybrid table: offline, then realtime) comes after the first: its time adds
+    led.merge_servers([doc(10.0, 100)])
+    assert led.response_fields()["spanTimesMs"]["server.execute"] == 100.0
+
+
+def test_span_joins_the_request_trace_tree_when_one_is_active():
+    with start_trace("q-tree") as tr:
+        with span("server.execute"):
+            with phase_timer(ServerQueryPhase.QUERY_PLAN_EXECUTION):
+                with span("server.dispatch", segment="s0") as sp:
+                    sp.set_attr("rows", 8)
+    d = tr.to_dict()
+    assert "queryPlanExecution" in d["phaseTimesMs"]
+    (root,) = d["spans"]
+    assert root["name"] == "server.execute"
+    (child,) = root["children"]  # the phase timer adds no node of its own
+    assert child["name"] == "server.dispatch" and child["attrs"] == {"segment": "s0", "rows": 8}
+
+
+# ---------------------------------------------------------------------------
+# every broker answer carries the ledger
+# ---------------------------------------------------------------------------
+
+
+def _load(controller, n_segments=4, rows=200):
+    schema = Schema.build("t", dimensions=[("d", DataType.INT)], metrics=[("v", DataType.LONG)])
+    controller.add_schema(schema)
+    controller.add_table(TableConfig("t"))
+    b = SegmentBuilder(schema)
+    for i in range(n_segments):
+        controller.upload_segment(
+            "t", b.build({"d": np.arange(rows, dtype=np.int32) % 5, "v": np.arange(rows, dtype=np.int64)}, f"t_{i}")
+        )
+
+
+@pytest.fixture(scope="module")
+def inproc(tmp_path_factory):
+    controller = Controller(PropertyStore(), tmp_path_factory.mktemp("spans_inproc"))
+    for i in range(2):
+        controller.register_server(f"server_{i}", Server(f"server_{i}"))
+    _load(controller)
+    return Broker(controller, cache_config=CacheConfig(enabled=False))
+
+
+@pytest.fixture(scope="module")
+def over_http(tmp_path_factory):
+    controller = Controller(PropertyStore(), tmp_path_factory.mktemp("spans_http"))
+    servers = {f"server_{i}": Server(f"server_{i}", scheduler="fcfs") for i in range(2)}
+    services = {sid: ServerHTTPService(s, port=0) for sid, s in servers.items()}
+    for sid, svc in services.items():
+        controller.register_server(sid, RemoteServerClient(f"http://127.0.0.1:{svc.port}"))
+    _load(controller)
+    broker = Broker(controller, cache_config=CacheConfig(enabled=False))
+    front = BrokerHTTPService(broker, port=0)
+    yield f"http://127.0.0.1:{front.port}"
+    front.stop()
+    for svc in services.values():
+        svc.stop()
+
+
+def _post(url, sql):
+    req = urllib.request.Request(
+        f"{url}/query/sql", json.dumps({"sql": sql}).encode(), {"Content-Type": "application/json"}
+    )
+    with urllib.request.urlopen(req, timeout=120) as rsp:
+        return json.loads(rsp.read())
+
+
+def _check_ledger(doc, names, n_segments, wire: bool):
+    for key in RESPONSE_KEYS:
+        assert key in doc, key
+    total, own = doc["spanTimesMs"], doc["spanSelfMs"]
+    assert set(total) == set(own)
+    assert names <= set(total), sorted(names - set(total))
+    assert total["broker.request"] >= total["broker.scatter"] >= total["server.execute"] >= total["server.device_wait"]
+    assert all(0.0 <= own[k] <= total[k] + 1e-6 for k in total)
+    c = doc["counters"]
+    assert c["segmentsDispatched"] == n_segments and c["rowsDispatched"] > 0
+    assert (c["wireRequestBytes"] > 0 and c["wireResponseBytes"] > 0) if wire else c["wireRequestBytes"] == 0
+    assert sum(w["launches"] for w in doc["deviceWork"].values()) == n_segments
+    assert sum(w["rows"] for w in doc["deviceWork"].values()) == c["rowsDispatched"]
+    assert all(re.match(r"^seg_[a-z]+_[0-9a-f]{8}$", name) for name in doc["deviceWork"])
+
+
+@pytest.mark.parametrize("sql,kind", [(AGG, "agg"), (GROUP_BY, "groupby")])
+def test_untraced_query_in_process_answers_with_the_ledger(inproc, sql, kind):
+    doc = inproc.execute(sql).to_dict()
+    assert "traceInfo" not in doc and "traceId" not in doc
+    _check_ledger(doc, BROKER_SPANS | SERVER_SPANS, n_segments=4, wire=False)
+    assert all(name.startswith(f"seg_{kind}_") for name in doc["deviceWork"])
+
+
+@pytest.mark.parametrize("sql,kind", [(AGG, "agg"), (GROUP_BY, "groupby")])
+def test_untraced_query_over_http_answers_with_the_ledger(over_http, sql, kind):
+    doc = _post(over_http, sql)
+    assert not doc.get("exceptions") and "traceInfo" not in doc
+    # the servers run a scheduler here, so the queue wait is a span too
+    _check_ledger(doc, BROKER_SPANS | SERVER_SPANS | WIRE_SPANS | {"server.queue"}, n_segments=4, wire=True)
+    assert all(name.startswith(f"seg_{kind}_") for name in doc["deviceWork"])
+
+
+def test_sampled_query_over_http_keeps_ledger_and_subtrees(over_http):
+    doc = _post(over_http, "SET trace=true; " + AGG)
+    _check_ledger(doc, BROKER_SPANS | SERVER_SPANS | WIRE_SPANS, n_segments=4, wire=True)
+    # the trace document's phase keys keep their names, beside the new top-level keys
+    assert {"requestCompilation", "brokerReduce"} <= set(doc["traceInfo"]["phaseTimesMs"])
+    subtrees = doc["traceInfo"]["processes"]
+    assert len(subtrees) == 2 and all("ledger" not in sub for sub in subtrees)
+
+    def names(spans):
+        for s in spans:
+            yield s["name"]
+            yield from names(s.get("children", ()))
+
+    assert {"server.plan", "server.dispatch", "server.device_wait"} <= set(names(subtrees[0]["spans"]))
+
+
+def test_streamed_selection_answers_with_the_broker_side_of_the_ledger(inproc):
+    doc = inproc.execute("SELECT d, v FROM t LIMIT 5").to_dict()
+    for key in RESPONSE_KEYS:
+        assert key in doc
+    assert {"broker.request", "broker.route"} <= set(doc["spanTimesMs"])
+
+
+# ---------------------------------------------------------------------------
+# stable program names, static device work
+# ---------------------------------------------------------------------------
+
+_NAME_SCRIPT = """
+import pinot_tpu
+from pinot_tpu.query.kernels import program_name
+spec = ("agg", ("and", (("cmp", "ge", ("raw", "v"), 0), ("in_lut", "d", 1))), ("groups", ("d", "e"), 35), (("sum", ("raw", "v")), ("count",)))
+print(program_name(spec), program_name(spec[:2] + (None,) + spec[3:]), program_name(("select", spec[1], (("raw", "v"),), frozenset({"b", "a", "c"}))))
+"""
+
+
+def test_program_name_is_the_same_in_every_process():
+    outs = []
+    for seed in ("1", "77"):
+        env = {**os.environ, "PYTHONHASHSEED": seed, "JAX_PLATFORMS": "cpu"}
+        out = subprocess.run(
+            [sys.executable, "-c", _NAME_SCRIPT], env=env, capture_output=True, text=True, timeout=300, check=True
+        )
+        outs.append(out.stdout.split())
+    assert outs[0] == outs[1]
+    groupby, agg, select = outs[0]
+    assert all(re.match(r"^seg_[a-z]+_[0-9a-f]{8}$", n) for n in outs[0])
+    assert (groupby[:12], agg[:8], select[:11]) == ("seg_groupby_", "seg_agg_", "seg_select_")
+    assert groupby[-8:] != agg[-8:]  # two specs, two names
+
+
+def test_jitted_program_carries_its_name():
+    from pinot_tpu.query.kernels import get_packed_kernel, program_name
+
+    spec = ("agg", ("const", True), None, (("count",),))
+    assert get_packed_kernel(spec).__name__ == program_name(spec)
+
+
+def test_device_work_of_a_group_by_is_the_kernels_cost_model(tmp_path, monkeypatch):
+    """The Pallas byte-plane kernel is traced into the fused program: the
+    response's `deviceWork` holds its registered cost model at the shapes the
+    trace saw, once per launch."""
+    from pinot_tpu.ops.groupby_pallas import PLANES_CHUNK, _planes_cost
+
+    monkeypatch.setenv("PINOT_TPU_PALLAS", "1")  # the chip's path, interpreted on the CPU
+    controller = Controller(PropertyStore(), tmp_path)
+    controller.register_server("server_0", Server("server_0"))
+    schema = Schema.build("w", dimensions=[("g", DataType.INT)], metrics=[("m", DataType.INT)])
+    controller.add_schema(schema)
+    controller.add_table(TableConfig("w"))
+    b = SegmentBuilder(schema)
+    n, groups = 3000, 7
+    for i in range(2):
+        cols = {"g": np.arange(n, dtype=np.int32) % groups, "m": (np.arange(n, dtype=np.int32) * 3) % 1000}
+        controller.upload_segment("w", b.build(cols, f"w_{i}"))
+    broker = Broker(controller, cache_config=CacheConfig(enabled=False))
+    res = broker.execute("SELECT g, SUM(m), COUNT(*) FROM w WHERE m < 990 GROUP BY g ORDER BY g LIMIT 20")
+    m = (np.arange(n) * 3) % 1000
+    want = [[g, 2 * int(m[(np.arange(n) % groups == g) & (m < 990)].sum())] for g in range(groups)]
+    assert [r[:2] for r in res.rows] == want
+    doc = res.to_dict()
+    ((name, work),) = doc["deviceWork"].items()
+    assert name.startswith("seg_groupby_") and work["launches"] == 2
+    seg_rows = work["rows"] // 2
+    assert seg_rows >= n
+    kernel = work["kernels"]["ops.grouped_planes"]
+    assert kernel["calls"] == 2  # one pallas_call a launch
+    # the kernel pads the segment's docs to its chunk; one int32 value column is four
+    # byte planes and the count, padded to the 8-row sublane tile; the planner rounds
+    # the dense group space (7 values of `g`) up to a step of 256 (plan.group_spec)
+    rows = -(-seg_rows // PLANES_CHUNK) * PLANES_CHUNK
+    nbytes, flops = _planes_cost({"rows": rows, "groups": 256, "planes": 8})
+    assert flops == rows * 256 * 17.0
+    assert kernel["bytes"] == pytest.approx(2 * nbytes)
+    assert kernel["flops"] == pytest.approx(2 * flops)
+
+
+# ---------------------------------------------------------------------------
+# on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def time_limit(seconds: int):
+    """This test's own limit: a profiler that hangs fails one test, not the run."""
+
+    def expired(signum, frame):
+        raise TimeoutError(f"no end after {seconds} s")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+def test_spans_reach_the_profiler_trace_with_the_query_id(tmp_path, inproc):
+    import jax
+    from jax.profiler import ProfileData
+
+    inproc.execute(AGG)  # compiled before the trace starts
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1  # what perfbench/server_main.py sets
+    with time_limit(120):
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            doc = inproc.execute(AGG).to_dict()
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = list(tmp_path.rglob("*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("server.dispatch", "server.device_wait", "broker.request"):
+                    found.setdefault(ev.name, []).append(dict(ev.stats))
+    assert len(found["server.dispatch"]) == 4 and len(found["server.device_wait"]) == 4
+    qids = {str(st["qid"]) for evs in found.values() for st in evs}
+    assert len(qids) == 1 and re.match(r"^q\d+$", qids.pop())
+    programs = {str(st["program"]) for st in found["server.dispatch"]}
+    assert programs == set(doc["deviceWork"])
+    assert all(int(st["rows"]) > 0 and str(st["segment"]).startswith("t_") for st in found["server.dispatch"])
