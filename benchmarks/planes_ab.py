@@ -1,61 +1,91 @@
-"""On-chip A/B of the flat vs two-level byte-plane group-by kernels.
+"""On-chip shape sweep of the byte-plane group-by kernel: ms per launch by group
+count, plane rows and grid, each point checked against np.bincount.
 
-Run where there is a TPU (the parent never imports jax, so each child can own
-the chip in turn):
-    python -m benchmarks.planes_ab
-Flip the default in ops/groupby_pallas.py (planes_v2_enabled) if v2 wins —
-theory says the (r*G2 x chunk) @ (chunk x G1) form lifts MXU row
-utilization from r/128 to full, for identical total MACs."""
+    python -m benchmarks.planes_ab                       # the rule's grid at every shape
+    python -m benchmarks.planes_ab --ng 7000 --planes 5 --g2 rule 1 32 64 128 --g1-tile 128 256 --chunk 4096 8192
 
-import json
-import os
-import subprocess
-import sys
-
-_CHILD = r"""
-import json, os, sys, time
-import numpy as np
-import pinot_tpu
-import jax, jax.numpy as jnp
-from pinot_tpu.ops import groupby_pallas as gp
-
-n, ng = int(sys.argv[1]), int(sys.argv[2])
-rng = np.random.default_rng(0)
-gid = jnp.asarray(rng.integers(0, ng, n).astype(np.int32))
-vals = jnp.asarray(rng.integers(-100000, 100000, n).astype(np.int32))
-mask = jnp.asarray(rng.random(n) < 0.9)
-jax.block_until_ready((gid, vals, mask))
-
-@jax.jit
-def run(g, v, m):
-    s, c = gp.pallas_grouped_multi_sum_blocked([v], g, m, ng)
-    return s[0], c
-
-out = jax.block_until_ready(run(gid, vals, mask))
-t0 = time.perf_counter()
-outs = [run(gid, vals, mask) for _ in range(20)]
-jax.block_until_ready(outs)
-dt = (time.perf_counter() - t0) / 20 * 1e3
-want = np.bincount(np.asarray(gid)[np.asarray(mask)],
-                   weights=np.asarray(vals)[np.asarray(mask)].astype(np.float64), minlength=ng)
-ok = bool(np.array_equal(np.asarray(out[0]), want))
-print(json.dumps({"v2": os.environ.get("PINOT_TPU_PALLAS_V2", "0"), "n": n, "ng": ng,
-                  "ms": round(dt, 2), "exact": ok}))
+`--g2 rule` is what `groupby_pallas.grid_for` picks for the shape; `--g2 1` is
+the flat one-hot (the same kernel with one lo row, `--g1-tile` then being the
+group tile). The grid is an argument here and a function of the shape in the
+package: no environment variable selects it. One process, which holds the
+chip; lines go to stdout and to --out. PERF.md §6 (PR 25) has the v5e's table.
 """
+
+from __future__ import annotations
+
+import argparse
+import itertools
+import json
+import time
+from pathlib import Path
+
+import numpy as np
+
+
+def _time_ms(fn, args) -> tuple[object, float, int]:
+    import jax
+
+    out = jax.block_until_ready(fn(*args))  # compiles
+    t0 = time.perf_counter()
+    jax.block_until_ready(fn(*args))
+    once = (time.perf_counter() - t0) * 1e3
+    iters = int(max(2, min(20, 300.0 / max(once, 0.1))))
+    t0 = time.perf_counter()
+    jax.block_until_ready([fn(*args) for _ in range(iters)])
+    return out, (time.perf_counter() - t0) * 1e3 / iters, iters
 
 
 def main() -> None:
-    for n, ng in [(16_000_000, 3125), (60_000_000, 3125), (16_000_000, 40_000)]:
-        for v2 in ("0", "1"):
-            env = dict(os.environ)
-            env["PINOT_TPU_PALLAS_V2"] = v2
-            p = subprocess.run(
-                [sys.executable, "-c", _CHILD, str(n), str(ng)],
-                capture_output=True, text=True, env=env, timeout=900,
-            )
-            line = p.stdout.strip().splitlines()[-1] if p.stdout.strip() else ""
-            print(line if line.startswith("{") else json.dumps(
-                {"v2": v2, "n": n, "ng": ng, "error": p.stderr.strip()[-200:]}), flush=True)
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--rows", type=int, default=4096 * 1024, help="docs a launch (a served segment)")
+    ap.add_argument("--ng", type=int, nargs="+", default=[175, 256, 1024, 4375, 7000, 40_000, 437_500])
+    ap.add_argument("--planes", type=int, nargs="+", default=[1, 5, 9, 13], help="plane rows r = 4 x SUMs + 1")
+    ap.add_argument("--g2", nargs="+", default=["rule"], help="lo widths: 'rule', 1 (flat) or a multiple of 8")
+    ap.add_argument("--g1-tile", type=int, nargs="+", default=[128])
+    ap.add_argument("--chunk", type=int, nargs="+", default=[4096])
+    ap.add_argument("--seed", type=int, default=25)
+    ap.add_argument("--out", default="chiprun_out/planes_ab/sweep.jsonl")
+    cfg = ap.parse_args()
+
+    import pinot_tpu  # noqa: F401  (x64, compile cache)
+    import jax
+    import jax.numpy as jnp
+
+    from pinot_tpu.ops import groupby_pallas as gp
+
+    dev = jax.devices()[0]
+    Path(cfg.out).parent.mkdir(parents=True, exist_ok=True)
+    log = open(cfg.out, "a")
+
+    def emit(rec: dict) -> None:
+        line = json.dumps(rec)
+        log.write(line + "\n")
+        log.flush()
+        print(line, flush=True)
+
+    for chunk in cfg.chunk:
+        gp._check_chunk(chunk)
+    emit({"device": dev.device_kind, "platform": dev.platform, "rows": cfg.rows})
+    rng = np.random.default_rng(cfg.seed)
+    for ng, r in itertools.product(cfg.ng, cfg.planes):
+        gid_h = rng.integers(0, ng, cfg.rows).astype(np.int32)
+        planes_h = rng.integers(-128, 256, (r, cfg.rows)).astype(np.float32)
+        planes_h[-1] = rng.random(cfg.rows) < 0.6  # the mask's row
+        want = np.stack([np.bincount(gid_h, weights=p, minlength=ng) for p in planes_h]).astype(np.int64)
+        gid, planes = jnp.asarray(gid_h), jnp.asarray(planes_h)
+        seen = set()
+        for g2, g1_tile, chunk in itertools.product(cfg.g2, cfg.g1_tile, cfg.chunk):
+            grid = gp.grid_for(ng, r, chunk) if g2 == "rule" else gp.PlanesGrid(int(g2), g1_tile, chunk)
+            if grid in seen or cfg.rows % chunk:
+                continue
+            seen.add(grid)
+            rec = {"ng": ng, "planes": r, **grid._asdict(), "hi_tiles": gp._hi_tiles(ng, grid), "rule": g2 == "rule"}
+            try:
+                out, ms, iters = _time_ms(lambda g, p: gp._planes2_impl(g, p, ng, grid), (gid, planes))
+                rec.update(ms=round(ms, 3), iters=iters, exact=bool(np.array_equal(np.asarray(out), want)))
+            except Exception as e:  # a grid the compiler refuses is a result of the sweep
+                rec["refused"] = str(e).strip().splitlines()[-1][:200]
+            emit(rec)
 
 
 if __name__ == "__main__":

@@ -20,8 +20,9 @@ bound). What ran where is asked of the roles, not assumed: platform, device
 kind and ids, HBM in use, kernels called, compile-cache hits, native library.
 
 Order of processes that touch a device (one at a time per chip):
-  1. probe + kernel leg child (counts the chips, compiles the Pallas kernels
-     at the served shapes, checks them against numpy; exits)
+  1. probe + kernel leg child (counts the chips, compiles the Pallas kernel
+     at served shapes on both sides of its grid rule, checks it against
+     numpy; exits)
   2. the server(s) of the cluster leg(s); on a host with >= 4 chips a second
      leg runs four servers, each pinned to its own chip
   3. on >= 4 chips, after the servers have exited: parallel/mesh.py
@@ -63,6 +64,7 @@ SEG_ROWS = 4_000_000
 MESH_ROWS = 16_000_000  # the mesh leg runs bench.py's default table size
 REHEARSAL_ROWS, REHEARSAL_SEG_ROWS = 24_000, 4_000
 N_GROUPS = 25 * 25 * 7  # Q4's dense group space: c_nation x p_category x d_year
+N_GROUPS_SMALL = 5 * 5 * 7  # SSB Q4.1's: c_region x s_region x d_year
 TABLE = "lineorder"
 WARM_RUNS = 3
 #: relative error allowed on DISTINCTCOUNTHLL: 3 x the published standard
@@ -341,8 +343,8 @@ def run_child(env: dict, mode: str, *argv: str, timeout: float = 900.0) -> dict:
 
 def child_kernels(args) -> dict:
     """Probe + kernel leg. Owns the device until it exits: reports what JAX
-    sees, then compiles every Pallas kernel of ops/groupby_pallas.py at the
-    served shape (one segment x Q4's group space) and checks it is exact."""
+    sees, then compiles the Pallas kernel of ops/groupby_pallas.py at served
+    shapes (one segment x Q4.1's and Q4's group spaces) and checks it is exact."""
     from pinot_tpu.common import runtime
 
     rt = runtime.require_device()
@@ -356,23 +358,28 @@ def child_kernels(args) -> dict:
 
     n = args.seg_rows
     rng = np.random.default_rng([args.seed, 999])
-    gid = rng.integers(0, N_GROUPS, n).astype(np.int32)
     vals = rng.integers(-99_900, 599_950, n).astype(np.int32)  # lo_revenue - lo_supplycost
     mask = rng.random(n) < 0.6
-    want_sum = np.bincount(gid[mask], weights=vals[mask].astype(np.float64), minlength=N_GROUPS)
-    want_cnt = np.bincount(gid[mask], minlength=N_GROUPS)
-    d_gid, d_vals, d_mask = jnp.asarray(gid), jnp.asarray(vals), jnp.asarray(mask)
-    kernels = {}
-    for name, v2 in (("ops.grouped_planes", "0"), ("ops.grouped_planes2", "1")):
-        os.environ["PINOT_TPU_PALLAS_V2"] = v2  # the package's own kernel switch
+    d_vals, d_mask = jnp.asarray(vals), jnp.asarray(mask)
+    shapes = []
+    # one shape on each side of gp.grid_for's rule: a group space so small that
+    # the per-step cost binds (the narrowest lo width), and Q4's, where the MXU does
+    for groups in (N_GROUPS_SMALL, N_GROUPS):
+        gid = rng.integers(0, groups, n).astype(np.int32)
         t0 = time.perf_counter()
-        sums, counts = gp.pallas_grouped_multi_sum([d_vals], d_gid, d_mask, N_GROUPS)
+        sums, counts = gp.pallas_grouped_multi_sum([d_vals], jnp.asarray(gid), d_mask, groups)
         got_sum, got_cnt = np.asarray(sums[0]), np.asarray(counts)
         first_s = time.perf_counter() - t0
-        exact = bool(np.array_equal(got_sum, want_sum) and np.array_equal(got_cnt, want_cnt))
+        exact = bool(
+            np.array_equal(got_sum, np.bincount(gid[mask], weights=vals[mask].astype(np.float64), minlength=groups))
+            and np.array_equal(got_cnt, np.bincount(gid[mask], minlength=groups))
+        )
         if not exact:
-            raise SystemExit(f"kernel {name} is not exact at rows={n} groups={N_GROUPS}")
-        kernels[name] = {"rows": n, "groups": N_GROUPS, "exact": exact, "first_call_s": round(first_s, 2)}
+            raise SystemExit(f"kernel ops.grouped_planes2 is not exact at rows={n} groups={groups}")
+        grid = gp.grid_for(groups, 5)
+        shapes.append({"rows": n, "groups": groups, "g2": grid.g2, "hiTiles": gp._hi_tiles(groups, grid),
+                       "exact": exact, "first_call_s": round(first_s, 2)})  # fmt: skip
+    kernels = {"ops.grouped_planes2": shapes}
     from pinot_tpu.common.kernel_obs import KERNELS
 
     called = {k["kernel"] for k in KERNELS.roofline()["kernels"]}
@@ -634,7 +641,7 @@ def cluster_leg(
                     f"{sid}: {roof['hbm']['liveBytes']} B in use on device < table's {resident} B",
                 )
             require(
-                roof["inlined"].get("ops.grouped_planes", 0) > 0,
+                roof["inlined"].get("ops.grouped_planes2", 0) > 0,
                 f"{sid}: the Pallas byte-plane kernel was never traced into a fused program",
             )
             report["servers"][sid] = {

@@ -1,70 +1,51 @@
-"""Pallas TPU kernels for the group-by hot path: segment aggregation as
-one-hot matmul on the MXU.
+"""Pallas TPU kernel for the group-by hot path: segment aggregation as a
+two-level one-hot matmul on the MXU.
 
 Reference parity: the inner loops of DefaultGroupByExecutor +
 DictionaryBasedGroupKeyGenerator (pinot-core/.../query/aggregation/groupby/
 DefaultGroupByExecutor.java:191, DictionaryBasedGroupKeyGenerator.java:119-130)
 and the count/sum result holders. On TPU the dense-group-id reduction maps to
-the systolic array: for a doc chunk of C docs and a group tile of G groups,
-the one-hot matrix onehot[c, g] = (gid[c] == g) turns
+the systolic array: with onehot[c, g] = (gid[c] == g),
 
     out[g] += sum_c masked_values[c] * onehot[c, g]
 
-into a (planes, C) x (C, G) matmul — the MXU does the scatter-add. The grid
-walks (group_tile, chunk) with the chunk axis innermost so each output tile
-stays resident in VMEM while all chunks accumulate into it.
+is a matmul over the doc chunk, and the MXU does the scatter-add. The dense
+id is factored gid = hi*G2 + lo so that both operands fill the array (see
+"the contraction" below); the grid walks (hi tile, chunk) with the chunk axis
+innermost, so each output tile stays resident in VMEM while all chunks
+accumulate into it.
 
-The kernels here are exact (integer byte planes, see below) and are what the
-engine's fused programs call on TPU (kernels._grouped_all via pallas_auto);
-MIN/MAX and float aggregates stay on XLA segment reductions.
+The kernel is exact (integer byte planes, see below) and is what the engine's
+fused programs call on TPU (kernels._grouped_all via pallas_auto); MIN/MAX and
+float aggregates stay on XLA segment reductions.
 """
 
 from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from pinot_tpu.common.kernel_obs import KERNELS
 
-# Tile geometry. Each grid step carries a fixed dispatch overhead on TPU, so
-# for a (chunks x group-tiles) grid the step count — not the MACs — dominates
-# at bench shapes (4M docs x 4.4k groups). The one-hot tile is bf16 (plane
-# values <=255 are exact in bf16's 8 mantissa bits), so a 4096-doc chunk
-# against a 1024-group tile is an 8MB operand. CHUNK*255 < 2^24 keeps the
-# per-chunk plane dot exact. Overridable for hardware sweeps
-# (benchmarks/pallas_sweep.py).
+# Docs a grid step. Overridable for hardware sweeps; benchmarks/planes_ab.py
+# takes the chunk as an argument instead.
 PLANES_CHUNK = int(os.environ.get("PINOT_TPU_PALLAS_CHUNK_PLANES", "4096"))
-_GTILE_ENV = os.environ.get("PINOT_TPU_PALLAS_GTILE", "")
 
 
-def gtile_for(ng: int) -> int:
-    """Group-tile width for a given group count. Wide tiles win at high
-    cardinality (per-step overhead amortized over more MXU columns) but a
-    small GROUP BY padded to a 1024-wide tile would do 4x the one-hot cell
-    work for nothing, so the tile tracks ng."""
-    if _GTILE_ENV:
-        return int(_GTILE_ENV)
-    for t in (256, 512, 1024):
-        if ng <= t:
-            return t
-    return 1024
+def _check_chunk(chunk: int) -> None:
+    """Exactness invariant of the byte-plane SUM: one chunk's plane dot must
+    stay below the f32 exact-integer bound. The chunk axis is the lane axis."""
+    if chunk * 255 >= 2**24:
+        raise ValueError(f"plane chunk {chunk}: CHUNK*255 must stay < 2^24 for lossless sums")
+    if chunk % 128:
+        raise ValueError(f"plane chunk {chunk}: must be a multiple of 128 (lane tiling)")
 
 
-# exactness invariant of the byte-plane SUM: one chunk's plane dot must stay
-# below the f32 exact-integer bound. Fail loudly on bad sweep overrides.
-if PLANES_CHUNK * 255 >= 2**24:
-    raise ValueError(
-        f"PINOT_TPU_PALLAS_CHUNK_PLANES={PLANES_CHUNK}: CHUNK*255 must stay < 2^24 for lossless sums"
-    )
-if PLANES_CHUNK % 128:
-    raise ValueError(
-        f"PINOT_TPU_PALLAS_CHUNK_PLANES={PLANES_CHUNK}: must be a multiple of 128 (lane tiling)"
-    )
-if _GTILE_ENV and int(_GTILE_ENV) % 128:
-    raise ValueError("PINOT_TPU_PALLAS_GTILE must be a multiple of 128 (lane tiling)")
+_check_chunk(PLANES_CHUNK)  # fail loudly on a bad PINOT_TPU_PALLAS_CHUNK_PLANES
 
 
 def pallas_auto() -> bool:
@@ -86,75 +67,140 @@ def interpret_mode() -> bool:
     return jax.config.jax_platforms == "cpu"
 
 
-def _grids(n_padded: int, ng: int, chunk: int):
-    gtile = gtile_for(ng)
-    ng_pad = max(gtile, ((ng + gtile - 1) // gtile) * gtile)
-    return n_padded // chunk, ng_pad // gtile, ng_pad, gtile
-
-
-# -- exact integer sum+count: byte-plane one-hot matmul ----------------------
+# -- exact integer sum+count: byte planes ------------------------------------
 #
 # f32 MXU accumulation is inexact past 2^24, so a lossless integer SUM splits
 # each int32 value into four signed byte planes (v = b3*2^24 + b2*2^16 +
 # b1*2^8 + b0, arithmetic shifts keep the sign in b3). Each chunk's per-plane
-# dot product is <= CHUNK*255 < 2^24 (enforced at module load); the cross-chunk
+# dot product is <= CHUNK*255 < 2^24 (_check_chunk); the cross-chunk
 # accumulator is int32 (exact to 2^31 — plane totals stay under it for
-# segment sets below ~8M docs). One (8, CHUNK) x (CHUNK, GROUP_TILE) matmul
-# yields byte-plane sums AND the group count (mask rides as a 5th plane);
-# the tiny (5, ng) recombination runs in f64 outside the kernel.
+# segment sets below ~8M docs, SAFE_DOCS). One pass yields the byte-plane sums
+# AND the group count (the mask rides as the last plane); the tiny (r, ng)
+# recombination runs in f64 outside the kernel.
+#
+# -- the contraction: gid = hi*G2 + lo ---------------------------------------
+#
+# A flat one-hot, (r, chunk) @ (chunk, groups), pushes r <= 13 plane rows
+# through a 128-row systolic array and builds a chunk x groups one-hot on the
+# VPU for every step: 25 ms a 4M-row launch at 7000 groups on the v5e, a
+# tenth of the MXU's peak. The two-level form scales G2 lo-one-hot rows by
+# every plane row, L[p*G2 + l, c] = plane_p[c] * (lo[c] == l), and contracts
+# with the hi one-hot over the chunk: (r*G2, chunk) x (G1_TILE, chunk)^T — the
+# same useful MACs on full rows, an L build that does not grow with the group
+# count, and both one-hots compared along the lane axis as the ids arrive (no
+# relayout of the id vector). Measured cost is the padded MACs, r*G2 x G1
+# tiles of 128, at 80-90 % of the MXU's peak, plus ~0.45 us a grid step: 1.9 ms
+# at 7000 groups (sweep table: PERF.md §6, PR 25; benchmarks/planes_ab.py).
+# Flat is the G2 = 1 corner of the same kernel, and what the sweep calls so.
+# Same exactness invariant: products <= 255, per-chunk dots < 2^24 in f32,
+# int32 cross-chunk accumulation.
+
+G1_TILE = 128  # hi one-hot rows a step: one MXU tile. 256 and 512 measured no faster
+# budget of the (r*G2, chunk) bf16 left operand a step builds in VMEM; above
+# it the hi axis is tiled over the grid and L is rebuilt per tile (a few % of
+# a step). 20 MB compiled and ran on the v5e (128 MiB of VMEM)
+LEFT_BYTES_MAX = 16 << 20
+
+
+class PlanesGrid(NamedTuple):
+    g2: int  # lo width: rows of the left operand per plane row
+    g1_tile: int  # hi one-hot rows a grid step contracts with
+    chunk: int  # docs a grid step
+
+
+def grid_for(ng: int, r: int, chunk: int = PLANES_CHUNK) -> PlanesGrid:
+    """The grid for `ng` dense groups and `r` plane rows, from the shape alone.
+    The kernel's time follows the padded product r*G2 x tiles*G1_TILE, so: one
+    hi tile and the smallest G2 (a multiple of the 8-row sublane tile) that
+    covers `ng`, while the left operand fits LEFT_BYTES_MAX; past that the hi
+    axis is tiled with the widest G2 that fits, a multiple of 128 (XLA takes
+    tens of seconds to compile the untangling of a wide output whose G2 is
+    not). Below 1024 groups the per-step cost binds and G2 = 8 is as fast as
+    any (PERF.md §6, PR 25)."""
+    fit = LEFT_BYTES_MAX // (2 * chunk * r)
+    g2 = -(-ng // (G1_TILE * 8)) * 8
+    if g2 > fit:
+        g2 = fit // 128 * 128 or max(fit // 8 * 8, 8)
+    return PlanesGrid(g2, G1_TILE, chunk)
+
+
+def _hi_tiles(ng: int, grid: PlanesGrid) -> int:
+    """Grid steps along the hi axis: ceil(ng / G2) hi values in tiles of g1_tile."""
+    g1 = -(-ng // grid.g2)
+    return -(-g1 // grid.g1_tile)
+
 
 @functools.lru_cache(maxsize=None)
-def _make_planes_kernel(r: int, gtile: int, chunk: int):
+def _make_planes2_kernel(r: int, grid: PlanesGrid):
     from jax.experimental import pallas as pl
 
-    def kernel(gid_ref, planes_ref, out_ref):
-        ci = pl.program_id(1)
-        gi = pl.program_id(0)
+    g2, g1_tile, chunk = grid
 
-        @pl.when(ci == 0)
+    def kernel(hilo_ref, planes_ref, out_ref, left_ref):
+        @pl.when(pl.program_id(1) == 0)
         def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
+            out_ref[...] = jnp.zeros_like(out_ref)
 
-        gid = gid_ref[0, :]
-        # bf16 is exact here: plane bytes are integers in [-128, 255] and the
-        # one-hot is 0/1 — both inside bf16's 2^8 exact-integer range. The
-        # one-hot tile is half the size it would be in f32, and the MXU runs
-        # bf16 at twice the f32 rate.
-        planes = planes_ref[:].astype(jnp.bfloat16)  # (r, chunk), pre-masked
-        base = gi * gtile
-        onehot = (
-            gid[:, None] == (base + jax.lax.broadcasted_iota(jnp.int32, (chunk, gtile), 1))
+        # ids stay on the lane axis, one-hot rows on the sublane axis
+        lo_hit = jax.lax.broadcasted_iota(jnp.int32, (g2, chunk), 0) == hilo_ref[1:2, :]
+        for p in range(r):
+            # bf16 is exact here: plane bytes are integers in [-128, 255],
+            # inside bf16's 2^8 exact-integer range, and the MXU runs bf16 at
+            # full rate. The select runs in f32: the v5e's VPU has no bf16
+            left_ref[p * g2 : (p + 1) * g2, :] = jnp.where(
+                lo_hit, planes_ref[p : p + 1, :], 0.0
+            ).astype(jnp.bfloat16)
+        base = pl.program_id(0) * g1_tile
+        hi_hit = (
+            base + jax.lax.broadcasted_iota(jnp.int32, (g1_tile, chunk), 0) == hilo_ref[0:1, :]
         ).astype(jnp.bfloat16)
         # f32 accumulation keeps each chunk's plane dot exact (< 2^24)
-        acc = jnp.dot(planes, onehot, preferred_element_type=jnp.float32)
-        out_ref[:] = out_ref[:] + acc.astype(jnp.int32)
+        acc = jax.lax.dot_general(
+            left_ref[...], hi_hit, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        out_ref[...] += acc.astype(jnp.int32)
 
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("ng", "r"))
-def _planes_impl(gid, planes, ng: int, r: int):
+@functools.partial(jax.jit, static_argnames=("ng", "grid"))
+def _planes2_impl(gid, planes, ng: int, grid: PlanesGrid):
+    """(r, n) pre-masked f32 byte planes, (n,) int32 dense ids -> (r, ng)
+    int32 plane sums per group; n a multiple of grid.chunk. Rows whose id is
+    outside [0, ng) add to no group."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_padded = gid.shape[0]
-    n_chunks, n_gtiles, ng_pad, gtile = _grids(n_padded, ng, PLANES_CHUNK)
+    g2, g1_tile, chunk = grid
+    r, n_padded = planes.shape
+    g1_pad = _hi_tiles(ng, grid) * g1_tile
+    hi = gid // g2
+    hilo = jnp.stack([hi, gid - hi * g2])
+    left_bytes = 2 * r * g2 * chunk
     # the scope names the op's metadata path and `name=` the Mosaic kernel, so
     # a device trace finds the kernel whatever wraps this function
-    # (`_planes_impl` stays a substring of both: perfbench's groupby_kernel_share)
-    with jax.named_scope("ops.grouped_planes"):
-        return pl.pallas_call(
-            _make_planes_kernel(r, gtile, PLANES_CHUNK),
-            name="ops_grouped_planes_impl",
-            grid=(n_gtiles, n_chunks),
+    # (`_planes2_impl` stays a substring of it: perfbench's groupby_kernel_share)
+    with jax.named_scope("ops.grouped_planes2"):
+        out = pl.pallas_call(
+            _make_planes2_kernel(r, grid),
+            name="ops_grouped_planes2_impl",
+            grid=(g1_pad // g1_tile, n_padded // chunk),
             in_specs=[
-                pl.BlockSpec((1, PLANES_CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-                pl.BlockSpec((r, PLANES_CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
+                pl.BlockSpec((2, chunk), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
+                pl.BlockSpec((r, chunk), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((r, gtile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((r, ng_pad), jnp.int32),
+            out_specs=pl.BlockSpec(
+                (r * g2, g1_tile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM
+            ),
+            out_shape=jax.ShapeDtypeStruct((r * g2, g1_pad), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((r * g2, chunk), jnp.bfloat16)],
+            # L, its f32 select and the one-hots of a step, beside the
+            # double-buffered blocks: above the 16 MiB default from G2 ~ 64 on
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=3 * left_bytes + (16 << 20)),
             interpret=interpret_mode(),
-        )(gid.reshape(1, n_padded), planes)
+        )(hilo, planes)
+    # out[p*G2 + l, h] holds group h*G2 + l: -> (r, G2, g1_pad) -> (r, ng)
+    return jnp.transpose(out.reshape(r, g2, g1_pad), (0, 2, 1)).reshape(r, g1_pad * g2)[:, :ng]
 
 
 # Byte-plane totals accumulate in int32: a group holding n masked docs can
@@ -163,91 +209,6 @@ def _planes_impl(gid, planes, ng: int, r: int):
 # (kernels._exact_int_grouped_sum) beyond this; build_masked_fn flattens ALL
 # local segments into one doc vector, so the bound is easy to exceed.
 SAFE_DOCS = (2**31 - 2**24) // 255
-
-
-# -- two-level byte-plane kernel: gid = hi*G2 + lo ---------------------------
-#
-# The flat one-hot kernel's dot is (r x chunk) @ (chunk x gtile): M = r = 8
-# plane rows against the MXU's 128-row tile (~6% row utilization). The
-# two-level form scales each of G2=128 lo-one-hot rows by every plane row,
-# giving L[(p*G2+l), c] = plane_p[c] * (lo[c]==l), then contracts against
-# the hi-one-hot: (r*G2 x chunk) @ (chunk x G1) with G1 = ng_pad/G2 — a full
-# 1024-row M dimension doing IDENTICAL total MACs. The elementwise build of
-# L costs only r*G2*chunk VPU ops per step (no G1 factor), so it does not
-# cancel the MXU win. Same exactness invariant: products <= 255, per-chunk
-# dots < 2^24 in f32, int32 cross-chunk accumulation.
-
-G2 = 128  # lo-width: one MXU/VPU lane tile
-
-
-@functools.lru_cache(maxsize=None)
-def _make_planes2_kernel(r: int, g1tile: int, chunk: int):
-    from jax.experimental import pallas as pl
-
-    def kernel(gid_ref, planes_ref, out_ref):
-        ci = pl.program_id(1)
-        gi = pl.program_id(0)
-
-        @pl.when(ci == 0)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        gid = gid_ref[0, :]
-        lo = gid & (G2 - 1)
-        hi = gid >> (G2.bit_length() - 1)
-        planes = planes_ref[:].astype(jnp.bfloat16)  # (r, chunk)
-        onehot_lo = (
-            jax.lax.broadcasted_iota(jnp.int32, (G2, chunk), 0) == lo[None, :]
-        ).astype(jnp.bfloat16)
-        left = (planes[:, None, :] * onehot_lo[None, :, :]).reshape(r * G2, chunk)
-        base = gi * g1tile
-        onehot_hi = (
-            hi[:, None] == (base + jax.lax.broadcasted_iota(jnp.int32, (chunk, g1tile), 1))
-        ).astype(jnp.bfloat16)
-        acc = jnp.dot(left, onehot_hi, preferred_element_type=jnp.float32)
-        out_ref[:] = out_ref[:] + acc.astype(jnp.int32)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("ng", "r"))
-def _planes2_impl(gid, planes, ng: int, r: int):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_padded = gid.shape[0]
-    g1 = -(-ng // G2)
-    # lane-tile floor: the MXU N dimension is 128-wide — a narrower block
-    # pads internally and wastes columns (same constraint the module-load
-    # guards enforce on PLANES_CHUNK/GTILE)
-    g1tile = min(256, max(128, -(-g1 // 128) * 128))
-    g1_pad = -(-g1 // g1tile) * g1tile
-    with jax.named_scope("ops.grouped_planes2"):
-        out = pl.pallas_call(
-            _make_planes2_kernel(r, g1tile, PLANES_CHUNK),
-            name="ops_grouped_planes2_impl",
-            grid=(g1_pad // g1tile, n_padded // PLANES_CHUNK),
-            in_specs=[
-                pl.BlockSpec((1, PLANES_CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-                pl.BlockSpec((r, PLANES_CHUNK), lambda g, c: (jnp.int32(0), c), memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec(
-                (r * G2, g1tile), lambda g, c: (jnp.int32(0), g), memory_space=pltpu.VMEM
-            ),
-            out_shape=jax.ShapeDtypeStruct((r * G2, g1_pad), jnp.int32),
-            interpret=interpret_mode(),
-        )(gid.reshape(1, n_padded), planes)
-    # out[(p*G2 + l), h] holds group h*G2+l: -> (r, G2, g1_pad) -> (r, ng)
-    cube = out.reshape(r, G2, g1_pad)
-    flat = jnp.transpose(cube, (0, 2, 1)).reshape(r, g1_pad * G2)
-    return flat[:, :ng]
-
-
-def planes_v2_enabled() -> bool:
-    """Two-level kernel opt-in/out: PINOT_TPU_PALLAS_V2=1 forces on, =0 off.
-    Default OFF until an on-chip A/B flips it (the flat kernel is the
-    measured-on-hardware baseline)."""
-    return os.environ.get("PINOT_TPU_PALLAS_V2", "0") == "1"
 
 
 def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
@@ -263,7 +224,9 @@ def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
             "use the XLA two-level path for larger inputs"
         )
     k = len(values_list)
-    pad = (-gid.shape[0]) % PLANES_CHUNK
+    r = 4 * k + 1  # four byte planes a value, and the mask: no pad rows, L's rows are r*G2
+    grid = grid_for(ng, r)
+    pad = (-gid.shape[0]) % grid.chunk
     n_padded = gid.shape[0] + pad
     gid = jnp.pad(gid.astype(jnp.int32), (0, pad))
     mask = jnp.pad(mask, (0, pad))
@@ -280,23 +243,19 @@ def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
             ]
         )
     rows.append(mask.astype(jnp.float32))
-    r = -(-len(rows) // 8) * 8  # pad plane rows to the f32 sublane tile
-    while len(rows) < r:
-        rows.append(jnp.zeros((n_padded,), jnp.float32))
     planes = jnp.stack(rows)
-    name, impl = (
-        ("ops.grouped_planes2", _planes2_impl)
-        if planes_v2_enabled()
-        else ("ops.grouped_planes", _planes_impl)
-    )
     out = KERNELS.timed_sync(
-        name, lambda: impl(gid, planes, ng, r), rows=n_padded, groups=ng, planes=r
+        "ops.grouped_planes2",
+        lambda: _planes2_impl(gid, planes, ng, grid),
+        rows=n_padded,
+        groups=ng,
+        planes=r,
     )
     sums = []
     for i in range(k):
-        p = out[4 * i : 4 * i + 4, :ng].astype(jnp.float64)
+        p = out[4 * i : 4 * i + 4].astype(jnp.float64)
         sums.append(p[0] + p[1] * 256.0 + p[2] * 65536.0 + p[3] * 16777216.0)
-    counts = out[4 * k, :ng].astype(jnp.int64)
+    counts = out[4 * k].astype(jnp.int64)
     return sums, counts
 
 
@@ -330,32 +289,29 @@ def pallas_grouped_sum_count_exact(values_i32, gid, mask, ng: int):
     return sums[0], counts
 
 
-# -- kernel registry: cost models for the roofline report --------------------
+# -- kernel registry: the cost model for the roofline report -----------------
 #
-# Bytes model what each grid actually streams through VMEM: every doc chunk
-# is re-read once per group tile (the chunk axis is innermost), so traffic
-# scales with rows x group-tiles, not rows alone. FLOPs count the one-hot
-# build (1 compare) + MXU MAC (2) per (doc, group) pair.
+# Bytes model what the launched grid streams through VMEM: every doc chunk
+# (its hi/lo ids and plane rows) is read once per hi tile (the chunk axis is
+# innermost). FLOPs are the useful MACs of the reduction, one (2 flops) per
+# (doc, group, plane row) — never the padded MACs of the grid, so a share of
+# the MXU's peak built on them cannot be raised by padding, nor pass 100 %.
+# The flat one-hot's rows x groups compares are not counted: this form makes
+# rows x (G2 + G1_TILE x tiles) of them, on the VPU, and with them a COUNT
+# alone over 40,000 groups would read 126 % of the v5e's peak (PERF.md §6).
 
 
 def _planes_cost(shape: dict) -> tuple[float, float]:
     rows = max(float(shape.get("rows", 0)), 0.0)
-    groups = max(float(shape.get("groups", 1)), 1.0)
-    planes = max(float(shape.get("planes", 8)), 1.0)
-    gtile = float(gtile_for(int(groups)))
-    n_gtiles = max(-(-groups // gtile), 1.0)
-    return rows * (planes + 1.0) * 4.0 * n_gtiles, rows * groups * (2.0 * planes + 1.0)
+    groups = max(int(shape.get("groups", 1)), 1)
+    planes = max(int(shape.get("planes", 5)), 1)
+    tiles = _hi_tiles(groups, grid_for(groups, planes))
+    return rows * (planes + 2.0) * 4.0 * tiles, rows * groups * 2.0 * planes
 
 
-KERNELS.register(
-    "ops.grouped_planes",
-    _planes_impl,
-    cost_model=_planes_cost,
-    description="byte-plane exact SUM+COUNT, flat grid",
-)
 KERNELS.register(
     "ops.grouped_planes2",
     _planes2_impl,
     cost_model=_planes_cost,
-    description="byte-plane exact SUM+COUNT, two-level grid (PINOT_TPU_PALLAS_V2)",
+    description="byte-plane exact SUM+COUNT, two-level (hi/lo) one-hot contraction",
 )

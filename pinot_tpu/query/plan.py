@@ -966,12 +966,12 @@ class _Lowering:
             self._group_ng = u
             return ("groups_sparse", tuple(cols), u, self.op_idx(strides64))
         strides = group_strides(cards, np.int32)
-        # round ng to 256 steps — the smallest rung of the pallas adaptive
-        # group-tile ladder (groupby_pallas.gtile_for: 256/512/1024), so
-        # bucket edges land on tile edges. A pow2 bucket would nearly double
-        # the one-hot work at e.g. 4375 groups, while 256-step buckets still
-        # keep the kernel compile cache warm across near-alike queries (the
-        # Pinot plan-cache normalization tradeoff)
+        # round ng to 256 steps: the pallas kernel's lo width steps by 8 per
+        # 1024 groups (groupby_pallas.grid_for), so a finer bucket buys no
+        # MXU work. A pow2 bucket would nearly double the contraction at e.g.
+        # 4375 groups, while 256-step buckets still keep the kernel compile
+        # cache warm across near-alike queries (the Pinot plan-cache
+        # normalization tradeoff)
         ng = ((max(num_groups, 1) + 255) // 256) * 256
         self._group_ng = ng
         if len(mv_cols) == 2:

@@ -70,11 +70,12 @@ def test_rehearsal_runs_end_to_end_and_says_so(rehearsal):
     assert server["platform"] == "cpu" and server["deviceCount"] == 1
     assert server["deviceFallbacks"] == 0
     # Q4 rode the byte-plane kernel inside its fused program (interpreted here)
-    assert server["kernelsInlined"]["ops.grouped_planes"] > 0
+    assert server["kernelsInlined"]["ops.grouped_planes2"] > 0
     assert server["kernelsCalled"]["query.fused_packed"] > 0
     assert server["native"] == "built" or server["native"].startswith("fallback:")
-    # both Pallas kernels of the package went through the kernel leg
-    assert set(report["kernelLeg"]["kernels"]) == {"ops.grouped_planes", "ops.grouped_planes2"}
+    # the package's Pallas kernel went through the kernel leg on both sides of its grid rule
+    (shapes,) = report["kernelLeg"]["kernels"].values()
+    assert [(s["groups"], s["g2"], s["exact"]) for s in shapes] == [(175, 8, True), (4375, 40, True)]
 
 
 def test_broker_and_controller_report_cpu_backend(rehearsal):

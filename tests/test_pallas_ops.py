@@ -1,8 +1,8 @@
-"""Pallas byte-plane group-by kernels (one-hot MXU matmul) vs numpy.
+"""Pallas byte-plane group-by kernel (two-level one-hot MXU matmul) vs numpy.
 
 Runs in interpret mode on CPU (tests/conftest.py forces the CPU backend);
-the same kernels compile natively on TPU, where `python chip_smoke.py` runs
-them at real shapes. Reference semantics: DefaultGroupByExecutor result
+the same kernel compiles natively on TPU, where `python chip_smoke.py` runs
+it at real shapes. Reference semantics: DefaultGroupByExecutor result
 holders (SURVEY.md §2.2).
 """
 
@@ -17,7 +17,7 @@ from pinot_tpu.ops import groupby_pallas as gp
 @pytest.fixture(scope="module")
 def data():
     rng = np.random.default_rng(42)
-    n, ng = 5000, 37  # deliberately not multiples of PLANES_CHUNK / the group tile
+    n, ng = 5000, 37  # deliberately not multiples of PLANES_CHUNK / the hi and lo widths
     gid = rng.integers(0, ng, n).astype(np.int32)
     vals = rng.integers(-500_000, 500_000, n).astype(np.int32)
     mask = rng.random(n) < 0.7
@@ -42,10 +42,11 @@ def test_grouped_sum_and_count_match_numpy_exactly(data):
 
 
 def test_empty_mask_and_group_tile_boundary():
-    # ng exactly at every rung of the adaptive tile ladder (gtile_for);
-    # all docs masked out — exercises the tile-edge base+iota compare
-    for ng in (256, 512, 1024):
-        assert gp.gtile_for(ng) == ng  # ng IS the tile boundary
+    # ng exactly at, and one past, the edge of a lo width (G2 steps by 8 per
+    # 1024 groups); all docs masked out — exercises the tile-edge compares
+    for ng in (1024, 1025, 7168):
+        grid = gp.grid_for(ng, 5)
+        assert (grid.g2 - 8) * grid.g1_tile < ng <= grid.g2 * grid.g1_tile
         gid = jnp.arange(2048, dtype=jnp.int32) % ng
         vals = jnp.ones(2048, dtype=jnp.int32)
         mask = jnp.zeros(2048, dtype=bool)
@@ -54,9 +55,14 @@ def test_empty_mask_and_group_tile_boundary():
         assert np.asarray(counts).sum() == 0
 
 
-def test_large_ng_multiple_tiles():
+def test_large_ng_multiple_tiles(monkeypatch):
+    """Past the left operand's VMEM budget the hi axis is tiled over the grid
+    (437,500 groups at the real budget; made cheap here)."""
+    monkeypatch.setattr(gp, "LEFT_BYTES_MAX", 2 * gp.PLANES_CHUNK * 16)
     rng = np.random.default_rng(0)
-    n, ng = 3000, 2500  # 3 group tiles of 1024
+    n, ng = 3000, 2500
+    grid = gp.grid_for(ng, 1)
+    assert grid.g2 == 16 and gp._hi_tiles(ng, grid) == 2
     gid = jnp.asarray(rng.integers(0, ng, n).astype(np.int32))
     mask = jnp.ones(n, dtype=bool)
     _, counts = gp.pallas_grouped_multi_sum([], gid, mask, ng)
@@ -150,43 +156,133 @@ def test_blocked_multi_sum_past_safe_docs(monkeypatch):
     assert np.array_equal(np.asarray(counts), tc)
 
 
-def test_two_level_planes_kernel_matches_flat(monkeypatch):
-    """PINOT_TPU_PALLAS_V2 two-level (hi/lo) byte-plane kernel is exact and
-    identical to the flat kernel across group counts that do / don't divide
-    G2, including multi-value fusion."""
-    import os
-
-    import jax.numpy as jnp
-
-    from pinot_tpu.ops import groupby_pallas as gp
-
-    rng = np.random.default_rng(8)
-    for n, ng, k in [(8192, 130, 2), (12288, 3125, 1), (4096, 64, 1)]:
-        gid = jnp.asarray(rng.integers(0, ng, n).astype(np.int32))
-        vals = [jnp.asarray(rng.integers(-50000, 50000, n).astype(np.int32)) for _ in range(k)]
-        mask = jnp.asarray(rng.random(n) < 0.8)
-        monkeypatch.setenv("PINOT_TPU_PALLAS_V2", "0")
-        s1, c1 = gp.pallas_grouped_multi_sum(vals, gid, mask, ng)
-        monkeypatch.setenv("PINOT_TPU_PALLAS_V2", "1")
-        s2, c2 = gp.pallas_grouped_multi_sum(vals, gid, mask, ng)
-        hm, hg = np.asarray(mask), np.asarray(gid)
-        for i in range(k):
-            want = np.bincount(hg[hm], weights=np.asarray(vals[i])[hm].astype(np.float64), minlength=ng)
-            assert np.array_equal(np.asarray(s1[i]), want)
-            assert np.array_equal(np.asarray(s2[i]), want)
-        assert np.array_equal(np.asarray(c2), np.bincount(hg[hm], minlength=ng))
+@pytest.mark.parametrize("k", [0, 1, 2, 3])  # plane rows r = 4k + 1: 1, 5, 9, 13
+@pytest.mark.parametrize("ng", [64, 130, 256, 3125, 7000])
+def test_exact_on_both_sides_of_the_rule(ng, k):
+    """The public entry is exact against np.bincount where the per-step cost
+    binds (G2 = 8, up to 1024 groups) and where the MXU does (G2 = 32 and 56),
+    at group counts that do and do not divide G2, with no, one and several
+    fused value arrays, negative values included."""
+    rng = np.random.default_rng([ng, k])
+    n = 9000  # three chunks, the last one padded
+    gid = jnp.asarray(rng.integers(0, ng, n).astype(np.int32))
+    vals = [jnp.asarray(rng.integers(-(2**31), 2**31 - 1, n).astype(np.int32)) for _ in range(k)]
+    mask = jnp.asarray(rng.random(n) < 0.8)
+    sums, counts = gp.pallas_grouped_multi_sum(vals, gid, mask, ng)
+    hm, hg = np.asarray(mask), np.asarray(gid)
+    assert len(sums) == k
+    for i in range(k):
+        want = np.bincount(hg[hm], weights=np.asarray(vals[i])[hm].astype(np.float64), minlength=ng)
+        assert np.array_equal(np.asarray(sums[i]), want)
+    assert np.array_equal(np.asarray(counts), np.bincount(hg[hm], minlength=ng))
 
 
-def test_v2_kernel_failure_propagates(monkeypatch):
-    """A kernel the compiler refuses fails the call: nothing substitutes the
-    flat kernel behind the caller's back."""
+def test_all_false_mask_gives_zeros_at_a_wide_grid():
+    n, ng = 8192, 7000
+    gid = jnp.asarray(np.arange(n, dtype=np.int32) % ng)
+    v = jnp.asarray(np.full(n, -7, np.int32))
+    sums, counts = gp.pallas_grouped_multi_sum([v, v], gid, jnp.zeros(n, bool), ng)
+    assert not np.asarray(sums[0]).any() and not np.asarray(sums[1]).any() and not np.asarray(counts).any()
+
+
+def test_ids_outside_the_group_space_add_to_no_group():
+    """What the flat one-hot did by construction: a doc whose dense id is not
+    in [0, ng) matches no column, also where hi*G2 + lo would land in the
+    grid's padding or lo would wrap."""
+    ng = 130
+    gid = jnp.asarray(np.array([0, 129, 130, 1023, 1024, 5000, -1, -8, 7], np.int32))
+    v = jnp.asarray(np.full(9, 3, np.int32))
+    sums, counts = gp.pallas_grouped_multi_sum([v], gid, jnp.ones(9, bool), ng)
+    want = np.zeros(ng)
+    want[[0, 129, 7]] = 1
+    assert np.array_equal(np.asarray(counts), want) and np.array_equal(np.asarray(sums[0]), 3 * want)
+
+
+@pytest.mark.parametrize(
+    "ng, r, g2, tiles",
+    [
+        (64, 1, 8, 1),  # below 1024 groups: the narrowest lo width, the per-step cost binds
+        (256, 5, 8, 1),  # SSB Q4.1, TPC-H Q1
+        (1024, 13, 8, 1),
+        (4608, 5, 40, 1),  # SSB Q3.1, Q4.2: 4375 groups as the planner rounds them
+        (7168, 5, 56, 1),  # SSB Q2.x: 7000 groups, no padding at all
+        (7168, 13, 56, 1),
+        (40192, 5, 320, 1),  # one tile while r*G2*chunk bf16 fits LEFT_BYTES_MAX
+        (40192, 13, 128, 3),  # past it: the widest multiple of 128 that fits, hi tiled
+        (437504, 5, 384, 9),  # SSB Q3.2-Q3.4
+        (1750016, 5, 384, 36),  # SSB Q4.3
+    ],
+)
+def test_grid_for_picks_the_grid_from_the_shape(ng, r, g2, tiles):
+    grid = gp.grid_for(ng, r)
+    assert grid == gp.PlanesGrid(g2, gp.G1_TILE, gp.PLANES_CHUNK)
+    assert gp._hi_tiles(ng, grid) == tiles
+    assert grid.g2 * grid.g1_tile * tiles >= ng  # the grid covers every group
+    assert 2 * r * grid.g2 * grid.chunk <= gp.LEFT_BYTES_MAX
+
+
+def test_cost_model_follows_the_launched_grid():
+    """bytes: ids and plane rows once per hi tile of the rule's grid; flops:
+    the useful MACs, whatever the grid pads (4608 groups run as 5120)."""
+    rows = 4 * gp.PLANES_CHUNK
+    assert gp._planes_cost({"rows": rows, "groups": 4608, "planes": 5}) == (rows * 7 * 4.0, rows * 4608 * 10.0)
+    assert gp._planes_cost({"rows": rows, "groups": 437504, "planes": 5}) == (rows * 7 * 4.0 * 9, rows * 437504 * 10.0)
+
+
+def test_refused_kernel_raises(monkeypatch):
+    """A kernel the compiler refuses fails the call: there is no other kernel
+    to put in its place, and nothing catches the error on the way out."""
     def boom(*a, **k):
         raise RuntimeError("mosaic says no")
 
-    monkeypatch.setenv("PINOT_TPU_PALLAS_V2", "1")
     monkeypatch.setattr(gp, "_planes2_impl", boom)
     n, ng = 8192, 50
     gid = jnp.asarray(np.arange(n, dtype=np.int32) % ng)
     v = jnp.asarray(np.ones(n, np.int32))
     with pytest.raises(RuntimeError, match="mosaic says no"):
         gp.pallas_grouped_multi_sum([v], gid, jnp.ones(n, bool), ng)
+
+
+# -- the chip's compiler, without the chip -----------------------------------
+#
+# Interpret mode cannot see what Mosaic refuses (a misaligned slice, too much
+# VMEM). The TPU compiler is installed wherever jax[tpu] is and compiles for a
+# described v5e; nothing runs. The topology is described inside a fixture (one
+# process at a time may load libtpu: never at import), in this file only.
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize(
+    "ng, r",
+    [
+        (256, 1),  # TPC-H Q1's count plane: the narrowest left operand, 8 bf16 rows
+        (256, 5),  # SSB Q4.1
+        (7168, 5),  # SSB Q2.x: G2 = 56, not a multiple of the bf16 sublane tile
+        (7168, 13),
+        (437504, 5),  # SSB Q3.2-Q3.4: nine hi tiles, the widest left operand (15 MB)
+    ],
+)
+def test_mosaic_compiles_the_rules_grid_for_the_v5e(one_v5e_chip, monkeypatch, ng, r):
+    import jax
+
+    n = 8 * gp.PLANES_CHUNK
+    gid = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_v5e_chip)
+    planes = jax.ShapeDtypeStruct((r, n), jnp.float32, sharding=one_v5e_chip)
+    grid = gp.grid_for(ng, r)
+    # this process is on the CPU, where the package would interpret the kernel;
+    # the jitted body is traced afresh under a jit of the test's own
+    monkeypatch.setattr(gp, "interpret_mode", lambda: False)
+    fn = jax.jit(lambda g, p: gp._planes2_impl.__wrapped__(g, p, ng, grid))
+    text = fn.lower(gid, planes).compile().as_text()
+    assert "ops_grouped_planes2_impl" in text and "tpu_custom_call" in text
