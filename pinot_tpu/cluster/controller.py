@@ -13,9 +13,14 @@ once those calls return.
 
 from __future__ import annotations
 
+import contextlib
+import threading
+import time
+import zlib
 from pathlib import Path
 
 from pinot_tpu.common.config import TableConfig
+from pinot_tpu.common.trace import ServerQueryPhase
 from pinot_tpu.common.types import Schema
 from pinot_tpu.cluster.metadata import PropertyStore
 from pinot_tpu.segment.builder import write_segment
@@ -42,6 +47,11 @@ class Controller:
         self._servers: dict[str, object] = {}  # server_id -> Server handle
         self._election = None
         self._transitions = None
+        #: held from the choice of a new segment's servers until its entry stands in the ideal state
+        self._assign_lock = threading.Lock()
+        #: (table, segment, server) of the transitions an upload is waiting for right now: drift the
+        #: reconciler must not take for loss (a 181 MB segment loads for longer than its grace)
+        self._loading: set[tuple[str, str, str]] = set()
 
     def readiness(self) -> "tuple[bool, dict]":
         """(ready, per-component detail) for GET /health/ready — the broker/
@@ -303,80 +313,242 @@ class Controller:
         verification. A failed or short write (ENOSPC, crash, disk fault)
         surfaces as a typed SegmentUploadError and removes the partial dir,
         so later downloads can never reference half a segment."""
-        config = self.get_table(table)
-        if config is None:
-            raise KeyError(f"no such table: {table}")
-        from pinot_tpu.common.errors import SegmentCorruptedError, SegmentUploadError
+        config = self._table_for_upload(table)
         from pinot_tpu.segment.store import SEGMENT_FILE, verify_segment_file
 
         table_dir = self.deep_store / table
         seg_dir = table_dir / segment.name
-        existed = seg_dir.exists()
-        table_dir_existed = table_dir.exists()
-        try:
+        created = [] if seg_dir.exists() else [seg_dir, *([] if table_dir.exists() else [table_dir])]
+        with self._landing(table, segment.name, created):
             seg_dir = write_segment(segment, table_dir)
-            file_crc = (
-                verify_segment_file(seg_dir) if (seg_dir / SEGMENT_FILE).exists() else None
-            )
-        except (OSError, SegmentCorruptedError) as e:
-            if not existed:
-                import shutil
-
-                shutil.rmtree(seg_dir, ignore_errors=True)
-                if not table_dir_existed:
-                    # first segment of the table: drop the dir the failed
-                    # write created so the deep store is exactly as before
-                    import contextlib
-
-                    with contextlib.suppress(OSError):
-                        table_dir.rmdir()
-            raise SegmentUploadError(
-                getattr(e, "errno", None) or 0,
-                f"segment upload {table}/{segment.name} failed, no partial dir left: {e}",
-            ) from e
+            file_crc = verify_segment_file(seg_dir) if (seg_dir / SEGMENT_FILE).exists() else None
         stats = {
-            col: {
-                "min": ci.stats.to_dict()["min"],
-                "max": ci.stats.to_dict()["max"],
-                "cardinality": ci.cardinality,
-            }
+            col: {"min": ci.stats.to_dict()["min"], "max": ci.stats.to_dict()["max"], "cardinality": ci.cardinality}
             for col, ci in segment.columns.items()
         }
-        assigned = self._assign(table, segment.name, config.replication)
-        import time as _time
+        return self._publish(
+            table, config, segment.name, seg_dir, segment.n_docs, stats, file_crc, self._compute_partitions(segment, config)
+        )
 
-        seg_meta = {
-            "numDocs": segment.n_docs,
-            "location": str(seg_dir),
-            "stats": stats,
-            "servers": assigned,
-            "uploadedAt": _time.time(),
-        }
+    def upload_segment_archive(self, table: str, archive: bytes) -> tuple[str, list[str]]:
+        """The HTTP entry: a gzipped tar of a segment directory. The directory
+        lands in the deep store **as uploaded** — the client already sent the
+        deep store's own format, so nothing is decoded or encoded again:
+        untar into a temporary directory inside `<deep store>/<table>/`, fsync,
+        verify the landed file's whole-file CRC, read name, numDocs and the
+        columns' stats from the file's own index map, rename into place, then
+        the same assign-and-publish step as `upload_segment`, whose ordering
+        contract holds here too. Only a table that declares partitioning or is
+        a dimension table has the segment decoded. Returns (segment name,
+        assigned server ids)."""
+        import os
+        import shutil
+        import tempfile
+
+        from pinot_tpu.common.durability import fsync_dir
+        from pinot_tpu.common.trace import span
+        from pinot_tpu.segment.store import SEGMENT_FILE, SegmentFileReader, dictionary_cardinality
+
+        config = self._table_for_upload(table)
+        table_dir = self.deep_store / table
+        with span("controller.upload", phase=ServerQueryPhase.SEGMENT_UPLOAD, role="controller", bytes=len(archive)) as up:
+            table_dir_existed = table_dir.exists()
+            table_dir.mkdir(parents=True, exist_ok=True)
+            tmp = Path(tempfile.mkdtemp(prefix=".upload-", dir=table_dir))
+            with self._landing(table, "<archive>", [tmp] if table_dir_existed else [tmp, table_dir]):
+                with span("controller.upload.untar", phase=ServerQueryPhase.SEGMENT_UPLOAD_UNTAR, role="controller"):
+                    self._untar(archive, tmp)
+                    entries = list(tmp.iterdir())
+                    seg_root = entries[0] if len(entries) == 1 and entries[0].is_dir() else tmp
+                    if not (seg_root / SEGMENT_FILE).exists():
+                        # the v1 layout (metadata.json + columns.npz): decoded, and written as the deep store writes
+                        from pinot_tpu.segment.loader import load_segment
+
+                        segment = load_segment(seg_root)
+                        shutil.rmtree(tmp)
+                        if not table_dir_existed:
+                            table_dir.rmdir()  # `upload_segment` makes it, and takes it away again if it fails
+                        up.set_attr("segment", segment.name)
+                        return segment.name, self.upload_segment(table, segment)
+                with span("controller.upload.verify", phase=ServerQueryPhase.SEGMENT_UPLOAD_VERIFY, role="controller"):
+                    reader = SegmentFileReader(seg_root / SEGMENT_FILE)  # verifies the whole-file CRC, decodes no entry
+                    file_crc, meta = reader.file_crc, reader.meta
+                    del reader  # and with it the file's memory map, before the file changes place
+                    name = meta["segmentName"]
+                    up.set_attr("segment", name)
+                    if Path(name).name != name or name.startswith("."):
+                        raise OSError(f"segment name {name!r} is no directory name")
+                seg_dir = table_dir / name
+                if seg_dir.exists():  # a refresh: the files change place one by one, each atomically
+                    for f in seg_root.iterdir():
+                        os.replace(f, seg_dir / f.name)
+                else:
+                    os.rename(seg_root, seg_dir)
+                fsync_dir(seg_dir)
+                fsync_dir(table_dir)
+                shutil.rmtree(tmp, ignore_errors=True)
+            stats = {}
+            for cm in meta["columns"]:
+                card = dictionary_cardinality(meta, cm["name"])
+                stats[cm["name"]] = {
+                    "min": cm["stats"]["min"],
+                    "max": cm["stats"]["max"],
+                    "cardinality": cm["stats"]["cardinality"] if card is None else card,
+                }
+            partitions = {}
+            if (config.extra or {}).get("segmentPartitionConfig"):
+                from pinot_tpu.segment.loader import load_segment
+
+                partitions = self._compute_partitions(load_segment(seg_dir), config)
+            return name, self._publish(table, config, name, seg_dir, int(meta["numDocs"]), stats, file_crc, partitions)
+
+    #: the load path moves a segment in pieces of this size
+    _PIECE = 4 << 20
+
+    @classmethod
+    def _untar(cls, archive: bytes, dest: Path) -> None:
+        """The archive's directories and regular files under `dest`, each file
+        fsynced (durable before any metadata names it, as `atomic_write_bytes`
+        makes it). Two passes, both in pieces of 4 MB: the gzip stream is
+        inflated into a temporary tar file, then each member is copied out of
+        it. Five uploads at once took 5.3 s a 181 MB segment for 1.7 s alone
+        (four chips' host; PERF.md, PR 27): tarfile's own loop moves 16 kB at
+        a time and the uploads took turns at the interpreter lock, and
+        inflating a whole archive into memory made them take turns at the
+        process's page tables instead (5.2 s each for 1.9 s alone on the
+        CPU). Members pass tarfile's `data` filter, so none leaves `dest`;
+        anything but a directory or a regular file is refused."""
+        import os
+        import tarfile
+
+        tar_path = dest / ".archive.tar"
+        try:
+            view = memoryview(archive)
+            with open(tar_path, "wb") as out:
+                inflater = zlib.decompressobj(wbits=31)
+                for i in range(0, len(view), cls._PIECE):
+                    data = view[i : i + cls._PIECE]
+                    while True:
+                        out.write(inflater.decompress(data))
+                        data = inflater.unused_data if inflater.eof else b""
+                        if not data:
+                            break
+                        inflater = zlib.decompressobj(wbits=31)  # a gzip file may hold several members
+                if not inflater.eof:
+                    raise EOFError("the gzip stream ends early")
+            with open(tar_path, "rb") as src, tarfile.open(fileobj=src, mode="r:") as tf:
+                for member in tf:
+                    member = tarfile.data_filter(member, str(dest))
+                    target = dest / member.name
+                    if member.isdir():
+                        target.mkdir(parents=True, exist_ok=True)
+                    elif member.isreg():
+                        target.parent.mkdir(parents=True, exist_ok=True)
+                        back = src.tell()
+                        src.seek(member.offset_data)
+                        with open(target, "wb") as f:
+                            left = member.size
+                            while left:
+                                piece = src.read(min(left, cls._PIECE))
+                                if not piece:
+                                    raise EOFError("the archive ends inside a member")
+                                f.write(piece)
+                                left -= len(piece)
+                            f.flush()
+                            os.fsync(f.fileno())
+                        src.seek(back)
+                    else:
+                        raise tarfile.TarError(f"{member.name!r} is neither a directory nor a regular file")
+        except (tarfile.TarError, EOFError, zlib.error) as e:
+            raise OSError(f"unreadable archive: {e}") from e
+        finally:
+            tar_path.unlink(missing_ok=True)
+
+    def _table_for_upload(self, table: str) -> TableConfig:
+        config = self.get_table(table)
+        if config is None:
+            raise KeyError(f"no such table: {table}")
+        return config
+
+    @contextlib.contextmanager
+    def _landing(self, table: str, what: str, created: list[Path]):
+        """The write-and-verify half of an upload: a disk fault or a failed
+        verification inside it removes `created` (directories this upload
+        made, innermost first) and leaves as a typed SegmentUploadError."""
+        from pinot_tpu.common.errors import SegmentCorruptedError, SegmentUploadError
+
+        try:
+            yield
+        except SegmentUploadError:
+            raise  # an inner landing's, which has tidied up after itself
+        except (OSError, SegmentCorruptedError) as e:
+            import shutil
+
+            for d in created[:1]:
+                shutil.rmtree(d, ignore_errors=True)
+            for d in created[1:]:
+                # the table's first segment: drop the dir the failed write
+                # made, so that the deep store is exactly as before
+                with contextlib.suppress(OSError):
+                    d.rmdir()
+            raise SegmentUploadError(
+                getattr(e, "errno", None) or 0,
+                f"segment upload {table}/{what} failed, no partial dir left: {e}",
+            ) from e
+
+    def _publish(
+        self, table: str, config: TableConfig, name: str, seg_dir: Path, n_docs: int, stats: dict,
+        file_crc: int | None, partitions: dict,
+    ) -> list[str]:  # fmt: skip
+        """The assign half, shared by both entries: the segment's servers
+        chosen, its metadata written and its entry put into the ideal state
+        as one step (`_assign_lock`), the routing version, then the servers'
+        state transitions."""
+        from pinot_tpu.common.trace import span
+
+        seg_meta = {"numDocs": n_docs, "location": str(seg_dir), "stats": stats}
         if file_crc is not None:
             # cluster truth for downloaders/scrubbers: a copy whose bytes
             # don't hash to this is corrupt no matter what its footer says
             seg_meta["fileCrc"] = file_crc
-        partitions = self._compute_partitions(segment, config)
         if partitions:
             seg_meta["partitions"] = partitions
-        self.store.set(f"/tables/{table}/segments/{segment.name}", seg_meta, fence=self.lease_fence())
-        ideal = self.store.get(f"/tables/{table}/idealstate") or {}
-        ideal[segment.name] = {s: "ONLINE" for s in assigned}
-        self.store.set(f"/tables/{table}/idealstate", ideal, fence=self.lease_fence())
-        self.bump_routing_version(table)
-        # state transition: servers load the segment from the deep store.
-        # With HA enabled, a failing server falls back to the durable retry
-        # queue instead of failing the upload (Helix async transition analog).
-        handles = self.servers()
-        for sid in assigned:
-            if self._transitions is not None:
-                try:
-                    handles[sid].add_segment(table, segment.name, str(seg_dir))
-                    self._transitions.record_external_view(table, segment.name, sid, "ONLINE")
-                except Exception:  # pinotlint: disable=deadline-swallow — segment-add control plane; failure enqueues a retryable helix transition
-                    self._transitions.enqueue(table, segment.name, sid, "add", str(seg_dir))
-            else:
-                handles[sid].add_segment(table, segment.name, str(seg_dir))
+        loading: list[tuple[str, str, str]] = []
+        try:
+            with span("controller.upload.publish", phase=ServerQueryPhase.SEGMENT_UPLOAD_PUBLISH, role="controller"):
+                # Choosing the servers and entering them are one step for the uploads of this controller
+                # (the leader is the only writer): of two uploads that end together the second counts the
+                # first's entry, so a table comes out even however its uploads interleave, and neither
+                # entry is lost (seed 3260000704 lost one; PERF.md, PR 26). The metadata is written before
+                # the ideal state names the segment, as the reconciler and the brokers expect.
+                with self._assign_lock:
+                    assigned = self._assign(table, config.replication)
+                    seg_meta.update(servers=assigned, uploadedAt=time.time())
+                    self.store.set(f"/tables/{table}/segments/{name}", seg_meta, fence=self.lease_fence())
+                    loading = [(table, name, sid) for sid in assigned]
+                    self._loading.update(loading)  # before the ideal state shows them: the reconciler leaves them be
+
+                    def enter(ideal: dict | None) -> dict:
+                        return {**(ideal or {}), name: dict.fromkeys(assigned, "ONLINE")}
+
+                    self.store.update(f"/tables/{table}/idealstate", enter, fence=self.lease_fence())
+                self.bump_routing_version(table)
+            # state transition: servers load the segment from the deep store.
+            # With HA enabled, a failing server falls back to the durable retry
+            # queue instead of failing the upload (Helix async transition analog).
+            handles = self.servers()
+            for sid in assigned:
+                with span("controller.upload.transition", phase=ServerQueryPhase.SEGMENT_UPLOAD_TRANSITION, role="controller", server=sid):
+                    if self._transitions is not None:
+                        try:
+                            handles[sid].add_segment(table, name, str(seg_dir))
+                            self._transitions.record_external_view(table, name, sid, "ONLINE")
+                        except Exception:  # pinotlint: disable=deadline-swallow — segment-add control plane; failure enqueues a retryable helix transition
+                            self._transitions.enqueue(table, name, sid, "add", str(seg_dir))
+                    else:
+                        handles[sid].add_segment(table, name, str(seg_dir))
+        finally:
+            self._loading.difference_update(loading)
         self._refresh_dim_table(table, config)
         return assigned
 
@@ -425,10 +597,11 @@ class Controller:
             out[col] = {"numPartitions": int(n_parts), "partitionIds": ids}
         return out
 
-    def _assign(self, table: str, segment_name: str, replication: int) -> list[str]:
+    def _assign(self, table: str, replication: int) -> list[str]:
         """Balanced assignment restricted to the table's server-tenant pool:
         pick the `replication` eligible servers hosting the fewest segments
-        of this table (OfflineSegmentAssignment + tenant tags)."""
+        of this table (OfflineSegmentAssignment + tenant tags). The caller
+        holds `_assign_lock` until the choice stands in the ideal state."""
         from pinot_tpu.cluster.tenancy import candidate_servers
 
         handles = self.servers()
@@ -457,9 +630,14 @@ class Controller:
         # order matters: drop the ideal-state intent FIRST so the reconciler
         # and the delivery worker's obsolete-message guard both stop wanting
         # the segment, THEN cancel queued messages, then unload
-        ideal = self.store.get(f"/tables/{table}/idealstate") or {}
-        replicas = ideal.pop(segment_name, {})
-        self.store.set(f"/tables/{table}/idealstate", ideal, fence=self.lease_fence())
+        replicas: dict = {}
+
+        def drop(ideal: dict | None) -> dict:
+            ideal = ideal or {}
+            replicas.update(ideal.pop(segment_name, {}))
+            return ideal
+
+        self.store.update(f"/tables/{table}/idealstate", drop, fence=self.lease_fence())
         self.bump_routing_version(table)
         if self._transitions is not None:
             self._transitions.cancel(table, segment_name)
@@ -535,17 +713,21 @@ class Controller:
     def set_segment_state(self, table: str, segment: str, server_id: str, state: str | None) -> None:
         """Set/remove one (segment, server) ideal-state entry; state=None
         removes the segment entry entirely when its replica map empties."""
-        ideal = self.store.get(f"/tables/{table}/idealstate") or {}
-        entry = ideal.get(segment, {})
-        if state is None:
-            entry.pop(server_id, None)
-        else:
-            entry[server_id] = state
-        if entry:
-            ideal[segment] = entry
-        else:
-            ideal.pop(segment, None)
-        self.store.set(f"/tables/{table}/idealstate", ideal, fence=self.lease_fence())
+
+        def change(ideal: dict | None) -> dict:
+            ideal = ideal or {}
+            entry = ideal.get(segment, {})
+            if state is None:
+                entry.pop(server_id, None)
+            else:
+                entry[server_id] = state
+            if entry:
+                ideal[segment] = entry
+            else:
+                ideal.pop(segment, None)
+            return ideal
+
+        self.store.update(f"/tables/{table}/idealstate", change, fence=self.lease_fence())
         self.bump_routing_version(table)
 
     # -- views ---------------------------------------------------------------

@@ -153,15 +153,16 @@ class LeaderElection:
                 # incarnation with our identity (process restarted inside the
                 # TTL — the ZK-session analog: a new session, not a renewal).
                 # In every case the predecessor's in-flight writes must fence.
+                # Our own copy of the epoch changes HERE, inside the store's
+                # update: set after it returned, a write of this controller's
+                # that took the store's lock in between still carried the old
+                # epoch and was fenced by its own controller's re-claim
+                self._epoch = cur_epoch + 1  # pinotlint: disable=race-discipline — single-writer int: only the renew thread (and pre-start start()) assigns it; readers snapshot a monotonically-increasing fence
                 return {"owner": cid, "expires": now + self.ttl, "epoch": cur_epoch + 1}
             return None
 
         got = self.store.update(LEASE_PATH, claim)
-        if got is not None and got.get("owner") == cid:
-            self._epoch = int(got.get("epoch", 0))  # pinotlint: disable=race-discipline — single-writer int: only the renew thread (and pre-start start()) assigns it; readers snapshot a monotonically-increasing fence, and a one-tick-stale epoch only makes fencing MORE conservative
-            self._set_leader(True)
-        else:
-            self._set_leader(False)
+        self._set_leader(got is not None and got.get("owner") == cid)
 
     def _run(self) -> None:
         while not self._stop.wait(self.renew_every):
@@ -353,7 +354,9 @@ class TransitionManager:
 
     #: drift younger than this is presumed an in-flight upload, not loss —
     #: prevents racing upload_segment between its idealstate write and its
-    #: synchronous add_segment/record_external_view
+    #: synchronous add_segment/record_external_view. This controller's own
+    #: uploads say what they wait for (`Controller._loading`), however long;
+    #: the grace covers another controller's, left behind by a failover
     RECONCILE_GRACE_S = 5.0
 
     def reconcile(self) -> int:
@@ -377,7 +380,7 @@ class TransitionManager:
                         continue  # CONSUMING segments converge via ingestion
                     if ev.get(segment, {}).get(sid) == "ONLINE":
                         continue
-                    if (table, segment, sid) in pending:
+                    if (table, segment, sid) in pending or (table, segment, sid) in self.controller._loading:
                         continue
                     meta = self.store.get(f"/tables/{table}/segments/{segment}") or {}
                     if now - meta.get("uploadedAt", 0.0) < self.RECONCILE_GRACE_S:

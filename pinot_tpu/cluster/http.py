@@ -1451,20 +1451,8 @@ class ControllerHTTPService:
                         self._json({"status": "ok", "reloaded": names})
                     elif len(parts) == 2 and parts[0] == "segments":
                         # segment upload: tarball of the segment directory
-                        import io as _io
-                        import tarfile
-                        import tempfile
-
-                        from pinot_tpu.segment.loader import load_segment
-
-                        with tempfile.TemporaryDirectory() as tmp:
-                            with tarfile.open(fileobj=_io.BytesIO(raw), mode="r:gz") as tf:
-                                tf.extractall(tmp, filter="data")
-                            entries = list(Path(tmp).iterdir())
-                            seg_root = entries[0] if len(entries) == 1 and entries[0].is_dir() else Path(tmp)
-                            seg = load_segment(seg_root)
-                            assigned = c.upload_segment(parts[1], seg)
-                        self._json({"status": "ok", "segment": seg.name, "servers": assigned})
+                        name, assigned = c.upload_segment_archive(parts[1], raw)
+                        self._json({"status": "ok", "segment": name, "servers": assigned})
                     elif self.path == "/tasks/schedule" and svc.task_manager is not None:
                         body = json.loads(raw or b"{}")
                         tasks = svc.task_manager.schedule_tasks(body.get("taskType"))

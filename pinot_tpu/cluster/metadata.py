@@ -21,6 +21,13 @@ Multi-process contract (the ZK-versioned-write analog):
     document records a NEWER epoch, the write raises `FencedWriteError` —
     a paused/partitioned ex-leader cannot corrupt ideal state after a
     standby takes over (the classic stale-leader split-brain hole).
+  * The lease document has a section of its own (`<root>/.lease.lock`): a
+    renewal, which leaves the epoch as it is and so can change no fence
+    check's outcome, is written under that section alone and never queues
+    behind the store's writes and their fsyncs (five 181 MB uploads at a
+    time held renewals past a 2 s lease). A claim that raises the epoch takes
+    the store's section as well, so a fence check and its write stay one
+    atomic step against it.
 
 Layout:
   /schemas/{name}                      -> Schema json
@@ -73,10 +80,12 @@ class PropertyStore:
         self._mem: dict[str, dict] = {}
         self._mem_ver: dict[str, int] = {}
         self._lock = threading.RLock()
-        self._lock_fd: int | None = None
+        self._lease_lock = threading.RLock()
+        self._lock_fds: dict[str, int] = {}
 
     _SUFFIX = ".doc.json"
     _LOCKFILE = ".store.lock"
+    _LEASE_LOCKFILE = ".lease.lock"
 
     def _file(self, path: str) -> Path:
         # real nested directories: no separator encoding, so names containing
@@ -87,32 +96,42 @@ class PropertyStore:
 
     # -- cross-process exclusion ----------------------------------------------
 
-    def _flock_fd(self) -> int:
-        # one cached fd per store instance; in-process threads are already
-        # serialized by self._lock, so sharing the fd is safe (flock excludes
-        # per open-file-description, i.e. per process here)
-        if self._lock_fd is None:
+    def _flock_fd(self, lockfile: str) -> int:
+        # one cached fd per lock file and store instance; in-process threads
+        # are already serialized by the section's thread lock, so sharing the
+        # fd is safe (flock excludes per open-file-description, i.e. per
+        # process here)
+        fd = self._lock_fds.get(lockfile)
+        if fd is None:
             assert self.root is not None
             self.root.mkdir(parents=True, exist_ok=True)
-            self._lock_fd = os.open(str(self.root / self._LOCKFILE), os.O_RDWR | os.O_CREAT, 0o644)
-        return self._lock_fd
+            fd = self._lock_fds[lockfile] = os.open(str(self.root / lockfile), os.O_RDWR | os.O_CREAT, 0o644)
+        return fd
 
     @contextlib.contextmanager
-    def _exclusive(self):
-        """Mutation critical section: the store thread lock, plus (file-backed)
-        an advisory flock on the per-store lockfile so read-modify-write is
-        atomic across PROCESSES — two controllers sharing one store contend
-        correctly on the lease instead of silently losing updates."""
-        with self._lock:
+    def _section(self, lock, lockfile: str):
+        with lock:
             if self.root is None or fcntl is None:
                 yield
                 return
-            fd = self._flock_fd()
+            fd = self._flock_fd(lockfile)
             fcntl.flock(fd, fcntl.LOCK_EX)
             try:
                 yield
             finally:
                 fcntl.flock(fd, fcntl.LOCK_UN)
+
+    @contextlib.contextmanager
+    def _exclusive(self, path: str = ""):
+        """Mutation critical section: the store thread lock, plus (file-backed)
+        an advisory flock on the per-store lockfile so read-modify-write is
+        atomic across PROCESSES — two controllers sharing one store contend
+        correctly on the lease instead of silently losing updates. A mutation
+        of the lease document holds the lease's section around it (always in
+        that order)."""
+        with self._section(self._lease_lock, self._LEASE_LOCKFILE) if path == LEASE_PATH else contextlib.nullcontext():
+            with self._section(self._lock, self._LOCKFILE):
+                yield
 
     # -- versioned read/write internals ----------------------------------------
 
@@ -172,7 +191,7 @@ class PropertyStore:
     def set(self, path: str, doc: dict, fence: int | None = None) -> int:
         """Write `doc`, stamping version = current + 1. Returns the version
         written. `fence` (a lease epoch) rejects stale ex-leader writes."""
-        with self._exclusive():
+        with self._exclusive(path):
             self._check_fence(path, fence)
             _, ver = self._read_versioned(path)
             self._write(path, doc, ver + 1)
@@ -199,12 +218,28 @@ class PropertyStore:
         except InjectedFault:
             trace_event("fault.injected", point="store.cas", path=path)
             raise
+        if path == LEASE_PATH:
+            return self._update_lease(fn)
         with self._exclusive():
             cur, ver = self._read_versioned(path)
             new = fn(cur)
             if new is not None:
                 self._check_fence(path, fence)
                 self._write(path, new, ver + 1)
+            return new
+
+    def _update_lease(self, fn) -> dict | None:
+        """`update` of the lease document, under the lease's own section. A
+        renewal (the epoch stays) is written there and then; whatever changes
+        the epoch, which fence checks read, waits for the store's section."""
+        with self._section(self._lease_lock, self._LEASE_LOCKFILE):
+            cur, ver = self._read_versioned(LEASE_PATH)
+            new = fn(cur)
+            if new is None:
+                return None
+            renewal = cur is not None and new.get("epoch") == cur.get("epoch")
+            with contextlib.nullcontext() if renewal else self._section(self._lock, self._LOCKFILE):
+                self._write(LEASE_PATH, new, ver + 1)
             return new
 
     def cas(self, path: str, expected_version: int, doc: dict, fence: int | None = None) -> bool:
@@ -217,7 +252,7 @@ class PropertyStore:
         except InjectedFault:
             trace_event("fault.injected", point="store.cas", path=path)
             raise
-        with self._exclusive():
+        with self._exclusive(path):
             cur, ver = self._read_versioned(path)
             if ver != expected_version or (cur is None and expected_version != 0):
                 return False
@@ -226,7 +261,7 @@ class PropertyStore:
             return True
 
     def delete(self, path: str, fence: int | None = None) -> None:
-        with self._exclusive():
+        with self._exclusive(path):
             self._check_fence(path, fence)
             if self.root is None:
                 self._mem.pop(path, None)

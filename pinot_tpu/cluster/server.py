@@ -157,13 +157,20 @@ class Server:
                 self._pending_transitions -= 1
 
     def _add_segment_inner(self, table: str, segment_name: str, seg_dir: str | Path) -> None:
+        from pinot_tpu.common.trace import ServerQueryPhase, span
         from pinot_tpu.segment.store import SEGMENT_FILE
 
         seg_dir = Path(seg_dir)
-        if self.data_dir is not None and (seg_dir / SEGMENT_FILE).exists():
-            seg = self._load_with_healing(table, segment_name, seg_dir)
-        else:
-            seg = load_segment(seg_dir)
+        seg_file = seg_dir / SEGMENT_FILE
+        size = seg_file.stat().st_size if seg_file.exists() else None  # None: the v1 layout, no `.ptseg`
+        # download, verification and decode run outside the server's lock: two
+        # segments load side by side (two of 181 MB were both loaded after
+        # 1.6-2.5 s, one after the other took 4.6-7.3 s; CPU, PERF.md PR 27)
+        with span("server.load", phase=ServerQueryPhase.SEGMENT_LOAD, role="server", segment=segment_name, bytes=size or 0):
+            if self.data_dir is not None and size is not None:
+                seg = self._load_with_healing(table, segment_name, seg_dir)
+            else:
+                seg = load_segment(seg_dir)
         with self._lock:
             rt = self._realtime.get(table)
             if rt is not None and hasattr(rt, "on_segment_loaded"):
@@ -212,7 +219,7 @@ class Server:
             raise
 
     def _register_local(self, table: str, name: str, local_dir: Path, source_dir: Path):
-        seg = load_segment(local_dir)
+        seg = load_segment(local_dir, verify=False)  # every caller has just verified the whole file
         with self._lock:
             self._local_segs[(table, name)] = {
                 "local": str(local_dir),

@@ -69,6 +69,14 @@ class ServerQueryPhase(Enum):
     REQUEST_COMPILATION = "requestCompilation"
     BROKER_REDUCE = "brokerReduce"
     MAILBOX_RECEIVE_WAIT = "mailboxReceiveWait"
+    # the segment load path: the controller's upload (spans `controller.upload*`)
+    # and the server's load of an assigned segment (`server.load`)
+    SEGMENT_UPLOAD = "segmentUpload"
+    SEGMENT_UPLOAD_UNTAR = "segmentUploadUntar"
+    SEGMENT_UPLOAD_VERIFY = "segmentUploadVerify"
+    SEGMENT_UPLOAD_PUBLISH = "segmentUploadPublish"
+    SEGMENT_UPLOAD_TRANSITION = "segmentUploadTransition"
+    SEGMENT_LOAD = "segmentLoad"
 
 
 @dataclass
@@ -394,19 +402,30 @@ class PhaseLedger:
 
     def merge_servers(self, docs: list) -> None:
         """Fold the ledgers of the servers of one scatter into this one: the
-        servers ran side by side, so a span's time is the **max** over them
-        (the critical path); counters and device work add up."""
-        worst: dict[str, list] = {}
+        servers ran side by side, so the spans are those of the server with
+        the longest `server.execute`, **taken whole** (the critical path: a
+        span's max and another span's max could come from two servers, and
+        `server.execute` less `server.device_wait` then described neither);
+        counters and device work add up over all of them. Counters
+        `serversMerged` and `scatterSkewMs` (the slowest `server.execute`
+        less the fastest, in ms) say how far apart they ran."""
+        if not docs:
+            return
+
+        def execute_ms(doc: dict) -> float:
+            return doc.get("spans", {}).get("server.execute", (0.0,))[0]
+
+        slowest = max(docs, key=execute_ms)
         with self._lock:
             for doc in docs:
-                for name, ent in doc.get("spans", {}).items():
-                    if name not in worst or ent[0] > worst[name][0]:
-                        worst[name] = ent
                 for name, n in doc.get("counters", {}).items():
                     self.counters[name] = self.counters.get(name, 0) + int(n)
                 for program, work in doc.get("deviceWork", {}).items():
                     _add_work(self.device_work, program, work)
-            for name, (ms, self_ms, n) in worst.items():
+            self.counters["serversMerged"] = self.counters.get("serversMerged", 0) + len(docs)
+            skew = execute_ms(slowest) - min(execute_ms(d) for d in docs)
+            self.counters["scatterSkewMs"] = round(self.counters.get("scatterSkewMs", 0) + skew, 3)
+            for name, (ms, self_ms, n) in slowest.get("spans", {}).items():
                 ent = self.spans.setdefault(name, [0.0, 0.0, 0])
                 ent[0] += ms
                 ent[1] += self_ms
@@ -422,6 +441,8 @@ class PhaseLedger:
             "counters": {
                 "wireRequestBytes": 0,
                 "wireResponseBytes": 0,
+                "serversMerged": 0,
+                "scatterSkewMs": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),

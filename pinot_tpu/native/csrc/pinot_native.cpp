@@ -442,29 +442,6 @@ PT_EXPORT int64_t pt_hash_group_ids(const uint64_t* keys, int64_t n,
 }
 
 // ---------------------------------------------------------------------------
-// crc32 (reflected, poly 0xEDB88320) for segment-file / wire integrity
-// ---------------------------------------------------------------------------
-
-static uint32_t crc_table[256];
-static bool crc_init_done = false;
-
-static void crc_init() {
-  for (uint32_t i = 0; i < 256; i++) {
-    uint32_t c = i;
-    for (int k = 0; k < 8; k++) c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
-    crc_table[i] = c;
-  }
-  crc_init_done = true;
-}
-
-PT_EXPORT uint32_t pt_crc32(const uint8_t* p, int64_t n, uint32_t seed) {
-  if (!crc_init_done) crc_init();
-  uint32_t c = seed ^ 0xFFFFFFFFu;
-  for (int64_t i = 0; i < n; i++) c = crc_table[(c ^ p[i]) & 0xFF] ^ (c >> 8);
-  return c ^ 0xFFFFFFFFu;
-}
-
-// ---------------------------------------------------------------------------
 // var-length string blob: encode offsets during dictionary/file IO
 // (takes utf-8 blob + int32 lengths, writes int64 offsets prefix-sum)
 // ---------------------------------------------------------------------------

@@ -23,10 +23,12 @@ from pinot_tpu.segment.stats import ColumnStats
 from pinot_tpu.segment.store import SEGMENT_FILE, SegmentFileReader
 
 
-def load_segment(seg_dir: str | Path) -> ImmutableSegment:
+def load_segment(seg_dir: str | Path, verify: bool = True) -> ImmutableSegment:
+    """`verify=False` skips the whole-file CRC for a caller that has just
+    checked these bytes; every entry's own CRC is checked as it is decoded."""
     seg_dir = Path(seg_dir)
     if (seg_dir / SEGMENT_FILE).exists():
-        r = SegmentFileReader(seg_dir / SEGMENT_FILE)
+        r = SegmentFileReader(seg_dir / SEGMENT_FILE, verify=verify)
         return _reconstruct(r.meta, r.read, strings_decoded=True)
     meta = json.loads((seg_dir / "metadata.json").read_text())
     version = meta.get("formatVersion")

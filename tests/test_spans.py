@@ -81,7 +81,8 @@ def test_child_time_is_not_counted_twice():
     fields = led.response_fields()
     assert set(fields) == set(RESPONSE_KEYS)
     assert fields["counters"] == {
-        "wireRequestBytes": 0, "wireResponseBytes": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
+        "wireRequestBytes": 0, "wireResponseBytes": 0, "serversMerged": 0, "scatterSkewMs": 0,
+        "segmentsDispatched": 0, "rowsDispatched": 0,
     }  # fmt: skip
 
 
@@ -155,28 +156,43 @@ def test_a_role_gets_its_own_ledger():
     assert b[0] == b[1]
 
 
-def test_merge_of_servers_is_the_max_and_work_adds_up():
+@pytest.mark.parametrize("executes", [[40.0], [40.0, 90.0], [70.0, 90.0, 40.0, 55.5]], ids=["1-server", "2-servers", "4-servers"])
+def test_merge_of_servers_is_the_max_and_work_adds_up(executes):
+    """The spans are the slowest server's, taken whole; counters and device
+    work add up over all of them; the skew is the slowest less the fastest."""
+
     def doc(ms, rows):
+        # a server's wait is the longer, the shorter its execution: a span-by-span max would pair
+        # the slowest server's `server.execute` with the fastest's `server.device_wait`
         return {
-            "spans": {"server.execute": [ms, 1.0, 1], "server.device_wait": [ms / 2, ms / 2, 3]},
+            "spans": {"server.execute": [ms, 1.0, 1], "server.device_wait": [100.0 - ms, 100.0 - ms, 3]},
             "counters": {"wireRequestBytes": 7},
             "deviceWork": {"seg_agg_00000001": {"launches": 3, "rows": rows, "kernels": {
                 "ops.grouped_planes": {"calls": 3, "bytes": 10.0, "flops": 20.0}}}},
         }  # fmt: skip
 
+    n = len(executes)
     led = PhaseLedger("q-merge")
-    led.merge_servers([doc(40.0, 300), doc(90.0, 600)])
+    led.merge_servers([doc(ms, 300 * (i + 1)) for i, ms in enumerate(executes)])
     out = led.response_fields()
-    assert out["spanTimesMs"]["server.execute"] == 90.0 and out["spanTimesMs"]["server.device_wait"] == 45.0
+    assert out["spanTimesMs"] == {"server.execute": max(executes), "server.device_wait": 100.0 - max(executes)}
+    assert out["spanSelfMs"] == {"server.execute": 1.0, "server.device_wait": 100.0 - max(executes)}
+    rows = 300 * n * (n + 1) // 2
     # what was dispatched is read off the merged device work
-    assert out["counters"]["segmentsDispatched"] == 6 and out["counters"]["rowsDispatched"] == 900
-    assert out["counters"]["wireRequestBytes"] == 14
+    assert out["counters"]["segmentsDispatched"] == 3 * n and out["counters"]["rowsDispatched"] == rows
+    assert out["counters"]["wireRequestBytes"] == 7 * n
+    assert out["counters"]["serversMerged"] == n
+    assert out["counters"]["scatterSkewMs"] == max(executes) - min(executes)
     work = out["deviceWork"]["seg_agg_00000001"]
-    assert work["launches"] == 6 and work["rows"] == 900
-    assert work["kernels"]["ops.grouped_planes"] == {"calls": 6, "bytes": 20.0, "flops": 40.0}
+    assert work["launches"] == 3 * n and work["rows"] == rows
+    assert work["kernels"]["ops.grouped_planes"] == {"calls": 3 * n, "bytes": 10.0 * n, "flops": 20.0 * n}
     # a second leg (hybrid table: offline, then realtime) comes after the first: its time adds
     led.merge_servers([doc(10.0, 100)])
-    assert led.response_fields()["spanTimesMs"]["server.execute"] == 100.0
+    out = led.response_fields()
+    assert out["spanTimesMs"]["server.execute"] == max(executes) + 10.0
+    assert out["counters"]["serversMerged"] == n + 1 and out["counters"]["scatterSkewMs"] == max(executes) - min(executes)
+    led.merge_servers([])  # a leg whose segments were all pruned
+    assert led.response_fields()["counters"]["serversMerged"] == n + 1
 
 
 def test_span_joins_the_request_trace_tree_when_one_is_active():
@@ -191,6 +207,52 @@ def test_span_joins_the_request_trace_tree_when_one_is_active():
     assert root["name"] == "server.execute"
     (child,) = root["children"]  # the phase timer adds no node of its own
     assert child["name"] == "server.dispatch" and child["attrs"] == {"segment": "s0", "rows": 8}
+
+
+# ---------------------------------------------------------------------------
+# the load path's spans, outside any request
+# ---------------------------------------------------------------------------
+
+
+def test_the_load_paths_spans_feed_metrics_without_a_ledger(tmp_path):
+    """`controller.upload` and its four children, and the server's
+    `server.load`, are `span`s with a phase: no request's ledger is open
+    around an upload, so what is left of them is the role's `/metrics` timer
+    (and, under a profiler session, the annotation)."""
+    import io
+    import tarfile
+
+    from pinot_tpu.common.metrics import get_registry
+    from pinot_tpu.segment.builder import write_segment
+
+    schema = Schema.build("u", dimensions=[("d", DataType.INT)], metrics=[("v", DataType.LONG)])
+    controller = Controller(PropertyStore(), tmp_path / "deep")
+    controller.register_server("server_0", Server("server_0", data_dir=tmp_path / "data"))
+    controller.add_schema(schema)
+    controller.add_table(TableConfig("u"))
+    seg = SegmentBuilder(schema).build({"d": np.arange(50, dtype=np.int32), "v": np.arange(50, dtype=np.int64)}, "u_0")
+    seg_dir = write_segment(seg, tmp_path / "built")
+    buf = io.BytesIO()
+    with tarfile.open(fileobj=buf, mode="w:gz") as tf:
+        tf.add(seg_dir, arcname=seg_dir.name)
+    names = {
+        "controller": ["segmentUpload", "segmentUploadUntar", "segmentUploadVerify", "segmentUploadPublish", "segmentUploadTransition"],
+        "server": ["segmentLoad"],
+    }
+    before = {(role, n): get_registry(role).timer(f"{role}.phase.{n}Ms").count for role, ns in names.items() for n in ns}
+    assert active_ledger() is None
+    assert controller.upload_segment_archive("u", buf.getvalue()) == ("u_0", ["server_0"])
+    for (role, n), count in before.items():
+        timer = get_registry(role).timer(f"{role}.phase.{n}Ms")
+        assert timer.count == count + 1, (role, n)
+    whole = get_registry("controller").timer("controller.phase.segmentUploadMs")
+    parts = [get_registry("controller").timer(f"controller.phase.{n}Ms") for n in names["controller"][1:]]
+    assert whole.max_ms >= max(t.min_ms for t in parts)
+    # the in-process entry shares the publish and transition steps, and the server's load
+    controller.upload_segment("u", SegmentBuilder(schema).build({"d": np.arange(5, dtype=np.int32), "v": np.arange(5, dtype=np.int64)}, "u_1"))
+    assert get_registry("controller").timer("controller.phase.segmentUploadPublishMs").count == before[("controller", "segmentUploadPublish")] + 2
+    assert get_registry("server").timer("server.phase.segmentLoadMs").count == before[("server", "segmentLoad")] + 2
+    assert get_registry("controller").timer("controller.phase.segmentUploadMs").count == before[("controller", "segmentUpload")] + 1
 
 
 # ---------------------------------------------------------------------------
