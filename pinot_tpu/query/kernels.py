@@ -226,11 +226,24 @@ def _filter(fspec, cols, ops, n_padded):
 # aggregation partials
 # ---------------------------------------------------------------------------
 
-# Exact integer summation without 64-bit arithmetic on the hot path: TPU
-# emulates f64/i64, so a 4M-doc f64 segment_sum costs ~8x its i32 twin. For
-# int32 values we split docs into blocks and each value into 16-bit halves;
-# per-block per-group i32 partial sums are exact (|half| * BLOCK < 2^31), and
-# only the tiny (n_blocks, ng) second-level reduction runs in f64.
+# Three forms of a grouped SUM/AVG/MIN/MAX, chosen from what the program can
+# see — the value's dtype and the group space — and from nothing else:
+#   * int32 values: exact integer summation without 64-bit arithmetic on the
+#     hot path. Where the Pallas kernel is on (`_grouped_all`), COUNT and every
+#     int32 SUM/AVG ride one byte-plane pass on the MXU; without it docs split
+#     into blocks and each value into 16-bit halves, per-block per-group i32
+#     partial sums are exact (|half| * BLOCK < 2^31), and only the tiny
+#     (n_blocks, ng) second-level reduction runs in f64.
+#   * any other value (DOUBLE columns and expressions, LONG past int32) over a
+#     dense group space whose real group count the plan states in its group
+#     spec, which it does up to plan.DENSE_REDUCE_MAX_GROUPS: a dense masked
+#     reduction
+#     (`_dense_grouped`), one compare-select-add a (row, slot) pair in the
+#     program's emulated f64, fused by XLA into one pass over the rows.
+#   * any other value otherwise (more groups, MV keys' value space, the
+#     sort-compaction path's slot budget): a scatter, jax.ops.segment_*.
+#     On the v5e 4M f64 rows scattered into 256 slots take 0.16-0.28 s, a
+#     thousand times a masked f64 sum of the same rows (PERF.md §6, PR 28).
 _BLOCK = 8192
 
 
@@ -263,8 +276,54 @@ def _exact_int_sum(v, mask):
     return jnp.sum(lo.astype(_F)) + jnp.sum(hi.astype(_F)) * 65536.0
 
 
-def _count_grouped(mask, gid, ng):
+_REDUCTIONS = {
+    # kind: (what a row outside the group contributes, dense reduce, scatter);
+    # the sum keeps its dtype: jnp.sum alone widens a count's int32 to the emulated int64
+    "sum": (0, lambda x, axis: jnp.sum(x, axis=axis, dtype=x.dtype), jax.ops.segment_sum),
+    "min": (jnp.inf, jnp.min, jax.ops.segment_min),
+    "max": (-jnp.inf, jnp.max, jax.ops.segment_max),
+}
+
+
+def _dense_grouped(kind, v, gid, mask, g):
+    """(g,) `kind` of `v` over the masked rows of each group id below `g`:
+    every row is compared against the g slots and reduced within blocks of
+    rows, then across the blocks — a tree in place of a scatter's order, in
+    the same arithmetic (emulated f64 for a DOUBLE, int32 for a count). XLA
+    fuses compare, select and reduce: the (g, rows) one-hot never reaches
+    HBM."""
+    fill, reduce, _ = _REDUCTIONS[kind]
+    v2, g2, m2 = _blocked(v), _blocked(gid), _blocked(mask)
+    slots = jnp.arange(g, dtype=jnp.int32)[:, None, None]
+    hit = m2[None] & (g2[None] == slots)
+    return reduce(reduce(jnp.where(hit, v2[None], jnp.asarray(fill, v.dtype)), axis=2), axis=1)
+
+
+def _grouped_reduce(kind, v, gid, mask, ng, dense_g):
+    """Grouped sum / min / max of `v` (f64, or the int32 ones of a count)
+    into the (ng,) partial: dense where the plan stated a real group count
+    `dense_g` (plan.with_real_groups: a dense group space of few groups), the
+    scatter otherwise. Both leave the reduction's identity (0, +/-inf) in the
+    slots no row reached."""
+    fill, _, scatter = _REDUCTIONS[kind]
+    fill = jnp.asarray(fill, v.dtype)
+    if dense_g is None:
+        return scatter(jnp.where(mask, v, fill), gid, num_segments=ng)
+    r = KERNELS.timed_sync(
+        "query.grouped_dense",
+        lambda: _dense_grouped(kind, v, gid, mask, dense_g),
+        rows=v.shape[0],
+        groups=dense_g,
+        width=v.dtype.itemsize,
+    )
+    return jnp.concatenate([r, jnp.full((ng - dense_g,), fill, dtype=v.dtype)])
+
+
+def _count_grouped(mask, gid, ng, dense_g=None):
     # counts fit i32 (segment docs < 2^31); widen after the reduction
+    if dense_g is not None:
+        ones = jnp.ones(mask.shape, dtype=jnp.int32)
+        return _grouped_reduce("sum", ones, gid, mask, ng, dense_g).astype(_I)
     return jax.ops.segment_sum(mask.astype(jnp.int32), gid, num_segments=ng).astype(_I)
 
 
@@ -401,9 +460,11 @@ def _int_scalar_extreme(v, mask, is_min):
     return jnp.where(jnp.any(mask), r.astype(_F), empty)
 
 
-def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None):
+def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None, dense_g=None):
     """gather/doc_pad: MV GROUP BY evaluates in VALUE space — doc-space
-    value/filter vectors gather through the owning-doc ids first."""
+    value/filter vectors gather through the owning-doc ids first. dense_g:
+    the real group count of a dense group space, where the plan stated one
+    (`_grouped_reduce` chooses the form of a non-int32 reduction from it)."""
     kind = aspec[0]
     if kind == "masked_nan_empty":
         # null-handling SUM: the per-group empty check must see the FULL
@@ -417,16 +478,16 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None):
                 fm = fm[gather]
             m2 = m2 & fm
             node = node[2]
-        r = _agg_grouped(node, cols, ops, m2, gid, ng, gather, doc_pad)
-        cnt = _count_grouped(m2, gid, ng)
+        r = _agg_grouped(node, cols, ops, m2, gid, ng, gather, doc_pad, dense_g)
+        cnt = _count_grouped(m2, gid, ng, dense_g)
         return jnp.where(cnt == 0, jnp.nan, r.astype(_F))
     if kind == "masked":
         fm = _filter(aspec[1], cols, ops, doc_pad if gather is not None else mask.shape[0])
         if gather is not None:
             fm = fm[gather]
-        return _agg_grouped(aspec[2], cols, ops, mask & fm, gid, ng, gather, doc_pad)
+        return _agg_grouped(aspec[2], cols, ops, mask & fm, gid, ng, gather, doc_pad, dense_g)
     if kind == "count":
-        return _count_grouped(mask, gid, ng)
+        return _count_grouped(mask, gid, ng, dense_g)
     if kind == "distinct_ids":
         # grouped DISTINCTCOUNT: per-group presence matrix via 2-D
         # scatter-or; the plan gates ng*pad under the device budget
@@ -468,20 +529,21 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None):
     if kind == "sum":
         if is_i32:
             return _exact_int_grouped_sum(v_raw, gid, mask, ng)
-        return jax.ops.segment_sum(jnp.where(mask, v, 0.0), gid, num_segments=ng)
+        return _grouped_reduce("sum", v, gid, mask, ng, dense_g)
     if kind == "min":
         if is_i32:
             return _int_grouped_extreme(v_raw, gid, mask, ng, True)
-        return jax.ops.segment_min(jnp.where(mask, v, jnp.inf), gid, num_segments=ng)
+        return _grouped_reduce("min", v, gid, mask, ng, dense_g)
     if kind == "max":
         if is_i32:
             return _int_grouped_extreme(v_raw, gid, mask, ng, False)
-        return jax.ops.segment_max(jnp.where(mask, v, -jnp.inf), gid, num_segments=ng)
+        return _grouped_reduce("max", v, gid, mask, ng, dense_g)
     if kind == "avg":
-        s = _exact_int_grouped_sum(v_raw, gid, mask, ng) if is_i32 else jax.ops.segment_sum(
-            jnp.where(mask, v, 0.0), gid, num_segments=ng
-        )
-        return (s, _count_grouped(mask, gid, ng))
+        if is_i32:
+            s = _exact_int_grouped_sum(v_raw, gid, mask, ng)
+        else:
+            s = _grouped_reduce("sum", v, gid, mask, ng, dense_g)
+        return (s, _count_grouped(mask, gid, ng, dense_g))
     if kind == "minmaxrange":
         if is_i32:
             return (
@@ -489,18 +551,27 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None):
                 _int_grouped_extreme(v_raw, gid, mask, ng, False),
             )
         return (
-            jax.ops.segment_min(jnp.where(mask, v, jnp.inf), gid, num_segments=ng),
-            jax.ops.segment_max(jnp.where(mask, v, -jnp.inf), gid, num_segments=ng),
+            _grouped_reduce("min", v, gid, mask, ng, dense_g),
+            _grouped_reduce("max", v, gid, mask, ng, dense_g),
         )
     raise AssertionError(aspec)
 
 
-def _grouped_all(aggs, cols, ops, mask, gid, ng, gather=None, doc_pad=None):
+def _grouped_all(aggs, cols, ops, mask, gid, ng, gather=None, doc_pad=None, dense_g=None):
     """Group counts + every agg partial. On TPU the count and ALL int32
-    SUM/AVG aggs fuse into ONE pallas byte-plane matmul pass; remaining aggs
-    (min/max/f64/hll/...) use their per-agg reductions. gather/doc_pad: MV
-    GROUP BY (value-space gids) gathers doc-space values first."""
+    SUM/AVG aggs fuse into ONE pallas byte-plane matmul pass on the MXU.
+    Every other agg takes its own reduction in `_agg_grouped`: SUM / AVG / MIN
+    / MAX / MINMAXRANGE of a value that is not int32 (DOUBLE, LONG past
+    int32) the dense masked reduction where the plan stated `dense_g`, the
+    real group count (it does up to plan.DENSE_REDUCE_MAX_GROUPS), and the
+    scatter otherwise; int32
+    MIN/MAX, HLL, histograms and presence matrices their scatters.
+    gather/doc_pad: MV GROUP BY (value-space gids) gathers doc-space values
+    first."""
     from pinot_tpu.ops import groupby_pallas as gp
+
+    def rest(a):
+        return _agg_grouped(a, cols, ops, mask, gid, ng, gather, doc_pad, dense_g)
 
     if gp.pallas_auto():
         vals, owner = [], {}
@@ -521,10 +592,10 @@ def _grouped_all(aggs, cols, ops, mask, gid, ng, gather=None, doc_pad=None):
             elif i in owner:
                 parts.append(sums[owner[i]] if a[0] == "sum" else (sums[owner[i]], counts))
             else:
-                parts.append(_agg_grouped(a, cols, ops, mask, gid, ng, gather, doc_pad))
+                parts.append(rest(a))
         return counts, tuple(parts)
-    counts = _count_grouped(mask, gid, ng)
-    return counts, tuple(_agg_grouped(a, cols, ops, mask, gid, ng, gather, doc_pad) for a in aggs)
+    counts = _count_grouped(mask, gid, ng, dense_g)
+    return counts, tuple(rest(a) for a in aggs)
 
 
 # ---------------------------------------------------------------------------
@@ -619,12 +690,14 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
         cid = jnp.clip(jnp.searchsorted(uniq, gid64), 0, u_slots - 1).astype(jnp.int32)
         counts, parts = _grouped_all(aggs, cols, ops, mask, cid, u_slots)
         return matched, counts, parts, uniq, n_unique
-    _, gcols, ng, strides_idx = gspec
+    # ("groups", cols, ng, strides[, real groups]): the plan appends the real
+    # group count where a non-int32 reduction can use it (plan.group_spec)
+    _, gcols, ng, strides_idx, *real = gspec
     strides = ops[strides_idx]
     gid = jnp.zeros((n_padded,), dtype=jnp.int32)
     for i, c in enumerate(gcols):
         gid = gid + cols[c] * strides[i]
-    counts, parts = _grouped_all(aggs, cols, ops, mask, gid, ng)
+    counts, parts = _grouped_all(aggs, cols, ops, mask, gid, ng, dense_g=real[0] if real else None)
     return matched, counts, parts
 
 
@@ -812,6 +885,22 @@ def _fused_cost(shape: dict) -> tuple[float, float]:
     return rows * (cols * 8.0 + 1.0), rows * cols * 4.0
 
 
+def _dense_cost(shape: dict) -> tuple[float, float]:
+    """One dense grouped reduction: every row's value (8 B a DOUBLE, 4 B a
+    count's ones), group id (4 B) and mask (1 B) stream once; a
+    compare-select and an add a (row, slot) pair, so flops / (2 x rows) is the
+    number of slots reduced over."""
+    rows = max(float(shape.get("rows", 0)), 0.0)
+    groups = max(float(shape.get("groups", 1)), 1.0)
+    return rows * (float(shape.get("width", 8)) + 5.0), rows * groups * 2.0
+
+
+KERNELS.register(
+    "query.grouped_dense",
+    _dense_grouped,
+    cost_model=_dense_cost,
+    description="grouped f64 SUM/MIN/MAX over few real groups as a dense masked reduction; one call a reduction traced",
+)
 KERNELS.register(
     "query.fused",
     get_kernel,

@@ -36,9 +36,18 @@ from pinot_tpu.common.types import DataType
 from pinot_tpu.query import ast
 from pinot_tpu.query.ast import CompareOp, Expr, FilterExpr
 from pinot_tpu.query.context import AggregationInfo, QueryContext, QueryType
-from pinot_tpu.segment.segment import ImmutableSegment
+from pinot_tpu.segment.segment import ImmutableSegment, narrows_to_int32
 
 MAX_DENSE_GROUPS = 1 << 20
+
+#: most real groups for which a group-by states its real group count, which
+#: turns its non-int32 SUM / AVG / MIN / MAX into dense masked reductions
+#: (kernels._grouped_reduce). From the v5e's sweep at 4M rows
+#: (benchmarks/grouped_dense_ab.py; PERF.md §6, PR 28): the f64 scatter is
+#: 285-340 ms whatever the group count, the dense form 0.025 ms a slot — 0.3 ms
+#: at 8, 6.5 at 256, 102 at 4096, the largest count measured and still 3.3x
+#: ahead; the two would meet near 12,000, where nothing was measured.
+DENSE_REDUCE_MAX_GROUPS = 4096
 
 # Virtual columns provided at query time (VirtualColumnProvider parity,
 # pinot-segment-local/.../segment/virtualcolumn/VirtualColumnProvider.java).
@@ -88,6 +97,7 @@ class _Lowering:
         self.operands: list[Any] = []
         self.columns: list[str] = []
         self._group_ng = 1  # set by group_spec; agg budget checks consult it
+        self._group_real = None  # set by group_spec: real groups of a dense group space
         # null docmask operand index per frozenset of columns: one decode +
         # one device transfer however many Kleene leaves reference them
         self._null_mask_ops: dict[frozenset, int] = {}
@@ -668,10 +678,7 @@ class _Lowering:
             # match to_device's lossless int64->int32 narrowing: the operand
             # dtype must equal the DEVICE dtype or the kernel-side cast wraps
             # out-of-range literals (and can even de-sort the probe array)
-            if col_dt == np.int64 and (
-                np.iinfo(np.int32).min <= ci_in.stats.min_value
-                and ci_in.stats.max_value <= np.iinfo(np.int32).max
-            ):
+            if narrows_to_int32(ci_in):
                 col_dt = np.dtype(np.int32)
         if int_ok and col_dt is not None and np.issubdtype(col_dt, np.integer):
             info = np.iinfo(col_dt)
@@ -974,6 +981,7 @@ class _Lowering:
         # normalization tradeoff)
         ng = ((max(num_groups, 1) + 255) // 256) * 256
         self._group_ng = ng
+        self._group_real = max(num_groups, 1)
         if len(mv_cols) == 2:
             return self._group_spec_mv2(cols, ng, strides, mv_cols)
         if mv_cols:
@@ -982,6 +990,49 @@ class _Lowering:
             nv = self.op_idx(np.int32(len(self.seg.columns[mv_cols[0]].forward)))
             return ("groups_mv", tuple(cols), ng, self.op_idx(strides), mv_cols[0], nv)
         return ("groups", tuple(cols), ng, self.op_idx(strides))
+
+    def with_real_groups(self, gspec: tuple, aggs: tuple) -> tuple:
+        """A dense single-value group spec with the real group count appended
+        where the program can use it: some SUM / AVG / MIN / MAX / MINMAXRANGE
+        is over a value that is not int32 on the device, and the count is
+        small enough for the dense form of that reduction
+        (kernels._grouped_reduce). The count is rounded up to eighths of its
+        next power of two, at least 8, so near-alike tables share a compile
+        (6 -> 8, 175 -> 192, 4000 -> 4096). Every other group-by — int32
+        metrics and COUNT only, as all of SSB; more groups; MV keys; the
+        sort-compaction path — keeps the spec, and with it the program name
+        and compile-cache key, it has always had."""
+        if gspec[0] != "groups":
+            return gspec
+        step = max(8, _pow2(self._group_real) // 8)
+        real = min(-(-self._group_real // step) * step, gspec[2])
+        if real > DENSE_REDUCE_MAX_GROUPS:
+            return gspec
+
+        def inner(a):
+            while a[0] in ("masked", "masked_nan_empty"):
+                a = a[2]
+            return a
+
+        wide = any(
+            a[0] in ("sum", "avg", "min", "max", "minmaxrange") and not self._is_i32(a[1])
+            for a in map(inner, aggs)
+        )
+        return gspec + (real,) if wide else gspec
+
+    def _is_i32(self, vspec: tuple) -> bool:
+        """True only where a value spec is certainly int32 on the device
+        (kernels._value): an INT column, a LONG one that to_device narrows, an
+        int32 dictionary's values, and +, -, *, % of such."""
+        kind = vspec[0]
+        if kind == "raw":
+            ci = self.seg.columns[vspec[1]]
+            return ci.forward.dtype == np.int32 or narrows_to_int32(ci)
+        if kind == "dictval":
+            return self.operands[vspec[2]].dtype == np.int32
+        if kind == "bin" and vspec[1] in "+-*%":
+            return self._is_i32(vspec[2]) and self._is_i32(vspec[3])
+        return False
 
     def _group_spec_mv2(self, cols, ng, strides, mv_cols) -> tuple:
         """Two MV keys: per-doc cartesian pairs in a dense (base flat values x
@@ -1170,6 +1221,8 @@ def plan_segment(seg: ImmutableSegment, ctx: QueryContext, valid_mask=None) -> S
 
             if any(_has_mv(a) for a in aggs):
                 raise DeviceFallback("MV aggregations under an MV GROUP BY run host-side")
+        if gspec is not None:
+            gspec = lo.with_real_groups(gspec, aggs)
         spec = ("agg", fspec, gspec, aggs)
         plan = SegmentPlan(
             spec=spec,

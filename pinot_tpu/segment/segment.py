@@ -192,10 +192,7 @@ class ImmutableSegment:
                 )
                 if len(fwd) < vpad:
                     fwd = np.concatenate([fwd, np.zeros(vpad - len(fwd), dtype=fwd.dtype)])
-                if fwd.dtype == np.int64 and (
-                    np.iinfo(np.int32).min <= ci.stats.min_value
-                    and ci.stats.max_value <= np.iinfo(np.int32).max
-                ):
+                if narrows_to_int32(ci):
                     fwd = fwd.astype(np.int32)
                 arrays[name] = jnp.asarray(fwd)
                 arrays[f"{name}!docs"] = jnp.asarray(docids)
@@ -203,10 +200,9 @@ class ImmutableSegment:
             if len(fwd) < pad:
                 fwd = np.concatenate([fwd, np.zeros(pad - len(fwd), dtype=fwd.dtype)])
             dt = fwd.dtype
-            if dt == np.int64:
+            if narrows_to_int32(ci):
                 # dict ids are already int32; this is the raw-column path
-                if np.iinfo(np.int32).min <= ci.stats.min_value and ci.stats.max_value <= np.iinfo(np.int32).max:
-                    fwd = fwd.astype(np.int32)
+                fwd = fwd.astype(np.int32)
             elif dt == np.float64 and fast32:
                 fwd = fwd.astype(np.float32)
             arrays[name] = jnp.asarray(fwd)
@@ -215,6 +211,14 @@ class ImmutableSegment:
 
         staging_tracker.track(ds)  # HBM staging leak detection (test harness)
         return ds
+
+
+def narrows_to_int32(ci: ColumnIndex) -> bool:
+    """to_device's lossless narrowing: an int64 forward array whose min and
+    max fit int32 is staged as int32. The plan asks the same question where
+    an operand's dtype has to equal the device's (plan.py)."""
+    i32 = np.iinfo(np.int32)
+    return ci.forward.dtype == np.int64 and i32.min <= ci.stats.min_value and ci.stats.max_value <= i32.max
 
 
 @dataclass

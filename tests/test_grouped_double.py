@@ -1,0 +1,344 @@
+"""Grouped SUM / AVG / MIN / MAX / MINMAXRANGE of values that are not int32
+(DOUBLE columns and expressions, LONG past int32): the dense masked reduction
+that a small real group count selects, against the scatter it replaces,
+against the host executor on the CPU's true f64.
+
+The form is chosen from the value's dtype and the plan's real group count
+alone (`kernels._grouped_reduce`, `plan.with_real_groups`): the scatter leg
+here is the same query planned with `DENSE_REDUCE_MAX_GROUPS` patched to 0, which is
+the spec — and so the program — the parent commit gave it.
+"""
+
+import functools
+import math
+
+import numpy as np
+import pytest
+
+import pinot_tpu  # noqa: F401  (x64 before jax.numpy is touched)
+import jax
+import jax.numpy as jnp
+
+from pinot_tpu.common import DataType, FieldSpec, Schema
+from pinot_tpu.common.kernel_obs import KERNELS
+from pinot_tpu.common.trace import request_ledger
+from pinot_tpu.query import QueryEngine, kernels
+from pinot_tpu.query import engine as engine_mod
+from pinot_tpu.query import plan as plan_mod
+from pinot_tpu.query.plan import DeviceFallback, plan_segment
+from pinot_tpu.segment import SegmentBuilder
+
+T = plan_mod.DENSE_REDUCE_MAX_GROUPS
+KINDS = ("sum", "avg", "min", "max", "minmaxrange")
+GROUPS = (1, 6, 8, T, T + 1, 300)
+MASKS = ("all", "none", "one_empty", "nan_empty")
+VALUES = {"column": "x", "product": "x * (1 - d)", "long": "w"}
+DENSE = "query.grouped_dense"
+
+
+def bucket(groups: int) -> int:
+    """The real group count as the plan states it (plan.with_real_groups)."""
+    step = max(8, (1 << (groups - 1).bit_length() if groups > 1 else 1) // 8)
+    return min(-(-groups // step) * step, -(-groups // 256) * 256)
+
+
+def takes_dense(groups: int) -> bool:
+    return bucket(groups) <= T
+
+
+# ---------------------------------------------------------------------------
+# through the engine: dense against scatter against the host executor
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def table(groups: int):
+    """One segment: key `k` with `groups` values (6 is Q1's 3 x 2 over two
+    keys), two DOUBLE metrics, a LONG past int32 and an INT for the filters."""
+    rng = np.random.default_rng(groups)
+    n = max(2400, 2 * groups)
+    k = rng.permutation(np.arange(n, dtype=np.int32) % groups)
+    schema = Schema.build(
+        "t",
+        dimensions=[("k", DataType.INT), ("a", DataType.INT), ("b", DataType.INT)],
+        metrics=[("x", DataType.DOUBLE), ("d", DataType.DOUBLE), ("w", DataType.LONG), ("q", DataType.INT)],
+    )
+    data = {
+        "k": k, "a": k // 2, "b": k % 2,
+        "x": np.round(rng.random(n) * 1e5, 2) - 2e4,
+        "d": rng.integers(0, 11, n) / 100.0,
+        "w": rng.integers(1 << 33, 1 << 40, n).astype(np.int64),
+        "q": rng.integers(0, 50, n).astype(np.int32),
+    }  # fmt: skip
+    return SegmentBuilder(schema).build(data, f"t{groups}")
+
+
+def sql(groups: int, mask: str) -> str:
+    keys = "a, b" if groups == 6 else "k"
+    # the per-aggregate FILTER empties group 0 and leaves it in the answer: its
+    # aggregates show what a reduction leaves where no row arrived
+    filt = " FILTER (WHERE k <> 0)" if mask in ("one_empty", "nan_empty") else ""
+    aggs = ", ".join(
+        f"{fn}({expr}){filt}"
+        for expr in VALUES.values()
+        for fn in ("SUM", "AVG", "MIN", "MAX", "MINMAXRANGE")
+    )
+    return (
+        ("SET enableNullHandling = true; " if mask == "nan_empty" else "")
+        + f"SELECT {keys}, {aggs}, COUNT(*) FROM t"
+        + (" WHERE q < 0" if mask == "none" else "")
+        + f" GROUP BY {keys} ORDER BY {keys} LIMIT 100000"
+    )
+
+
+def _fallback(*_a, **_k):
+    raise DeviceFallback("the host executor is the reference here")
+
+
+@functools.lru_cache(maxsize=None)
+def answers(groups: int, mask: str):
+    """(dense rows, scatter rows, host rows, dense deviceWork, scatter
+    deviceWork) of one query; an engine a leg, so nothing is shared but the
+    segment."""
+    seg, q = table(groups), sql(groups, mask)
+    with request_ledger("dense") as led:
+        dense = QueryEngine([seg]).execute(q).rows
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(plan_mod, "DENSE_REDUCE_MAX_GROUPS", 0)
+        with request_ledger("scatter") as led0:
+            scatter = QueryEngine([seg]).execute(q).rows
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "plan_segment", _fallback)
+        host = QueryEngine([seg]).execute(q).rows
+    return dense, scatter, host, led.to_wire()["deviceWork"], led0.to_wire()["deviceWork"]
+
+
+def same(got, want) -> bool:
+    if got is None or want is None:
+        return got is None and want is None
+    got, want = float(got), float(want)
+    if math.isnan(want) or math.isinf(want):
+        return (math.isnan(got) and math.isnan(want)) or got == want
+    return abs(got - want) <= 1e-12 * max(abs(want), 1e-300)
+
+
+@pytest.mark.parametrize("value", list(VALUES))
+@pytest.mark.parametrize("mask", MASKS)
+@pytest.mark.parametrize("groups", GROUPS)
+@pytest.mark.parametrize("kind", KINDS)
+def test_dense_scatter_and_host_agree(kind, groups, mask, value):
+    dense, scatter, host, _, _ = answers(groups, mask)
+    n_keys = 2 if groups == 6 else 1
+    col = n_keys + list(VALUES).index(value) * len(KINDS) + KINDS.index(kind)
+    assert len(dense) == len(scatter) == len(host) == (0 if mask == "none" else groups)
+    for d, s, h in zip(dense, scatter, host):
+        assert d[:n_keys] == s[:n_keys] == h[:n_keys]
+        assert same(d[col], h[col]) and same(s[col], h[col]), (d[:n_keys], d[col], s[col], h[col])
+        assert d[-1] == s[-1] == h[-1]  # COUNT(*)
+
+
+@pytest.mark.parametrize("mask", ("one_empty", "nan_empty"))
+def test_an_emptied_group_reads_what_the_scatter_leaves(mask):
+    """0.0 for a sum, +/-inf for the extremes (NULL for all under null
+    handling, where the wrapper turns the empty sum into NaN)."""
+    dense, scatter, host, _, _ = answers(8, mask)
+    first, then = dense[0], dense[1]
+    assert first[0] == 0 and first == scatter[0]
+    s, _avg, mn, mx, _rng = first[1:6]
+    if mask == "nan_empty":
+        assert s is None and host[0][1] is None
+    else:
+        assert s == 0.0 and mn == math.inf and mx == -math.inf
+    assert all(v is not None and math.isfinite(v) for v in then[1:6])
+
+
+@pytest.mark.parametrize("groups", GROUPS)
+def test_device_work_names_the_dense_kernel_exactly_when_it_ran(groups):
+    _, _, _, work, work0 = answers(groups, "all")
+    ((name, w),) = work.items()
+    ((name0, w0),) = work0.items()
+    assert name.startswith("seg_groupby_") and w["launches"] == w0["launches"] == 1
+    assert DENSE not in w0["kernels"]  # the parent's program never names it
+    if not takes_dense(groups):
+        assert name == name0 and DENSE not in w["kernels"]
+        return
+    assert name != name0
+    k = w["kernels"][DENSE]
+    # one call a reduction traced: SUM, AVG's sum and count, MIN, MAX and MINMAXRANGE's
+    # two, for each of the three values; COUNT(*) and the group counts, which the Pallas
+    # kernel has on the chip (XLA folds the duplicates; the trace counts them)
+    assert k["calls"] == 3 * 7 + 2
+    rows = w["rows"]
+    assert k["flops"] == k["calls"] * rows * bucket(groups) * 2.0
+    assert k["bytes"] == rows * (3 * 6 * 13.0 + 5 * 9.0)
+
+
+# ---------------------------------------------------------------------------
+# the helper alone
+# ---------------------------------------------------------------------------
+
+_NP = {"sum": (np.add, 0.0), "min": (np.minimum, np.inf), "max": (np.maximum, -np.inf)}
+
+
+@pytest.mark.parametrize("mask_kind", ("all", "none", "one_empty"))
+@pytest.mark.parametrize("groups", GROUPS)
+@pytest.mark.parametrize("kind", sorted(_NP))
+def test_grouped_reduce_fills_every_slot_as_the_scatter_does(kind, groups, mask_kind):
+    rng = np.random.default_rng(groups)
+    n = 20_000
+    v = rng.normal(0, 1e6, n)
+    gid = rng.integers(0, groups, n).astype(np.int32)
+    mask = {"all": np.ones(n, bool), "none": np.zeros(n, bool), "one_empty": gid != groups - 1}[mask_kind]
+    ng = -(-groups // 256) * 256
+    ufunc, fill = _NP[kind]
+    want = np.full(ng, fill)
+    ufunc.at(want, gid[mask], v[mask])
+    args = (jnp.asarray(v), jnp.asarray(gid), jnp.asarray(mask))
+    with KERNELS.building("test_grouped_reduce", n):
+        dense = np.asarray(jax.jit(lambda *a: kernels._grouped_reduce(kind, *a, ng, bucket(groups)))(*args))
+    work = KERNELS.program_work("test_grouped_reduce", n)
+    scatter = np.asarray(jax.jit(lambda *a: kernels._grouped_reduce(kind, *a, ng, None))(*args))
+    assert DENSE in work  # a stated count is what selects the dense form; the plan states none past T
+    assert dense.shape == scatter.shape == (ng,) and dense.dtype == np.float64
+    np.testing.assert_allclose(dense, want, rtol=1e-12, atol=0)
+    np.testing.assert_allclose(scatter, want, rtol=1e-12, atol=0)
+    if mask_kind == "one_empty" and groups > 1:
+        assert dense[groups - 1] == fill == scatter[groups - 1]
+    assert (dense[groups:] == fill).all()
+
+
+def test_count_goes_with_the_reduction():
+    """AVG's own count and the NaN-empty wrapper's are int64 either way."""
+    rng = np.random.default_rng(5)
+    gid = jnp.asarray(rng.integers(0, 6, 9000).astype(np.int32))
+    mask = jnp.asarray(rng.random(9000) < 0.7)
+    dense = np.asarray(kernels._count_grouped(mask, gid, 256, 8))
+    scatter = np.asarray(kernels._count_grouped(mask, gid, 256))
+    assert dense.dtype == scatter.dtype == np.int64 and (dense == scatter).all() and dense.sum() == int(mask.sum())
+
+
+# ---------------------------------------------------------------------------
+# the plan's rule
+# ---------------------------------------------------------------------------
+
+
+def gspec_of(seg, q: str):
+    return plan_segment(seg, QueryEngine([seg]).make_context(q)).spec[2]
+
+
+@pytest.mark.parametrize(
+    "groups, aggs, real",
+    [
+        (6, "SUM(x)", 8),
+        (6, "SUM(q), AVG(q), COUNT(*)", None),  # int32 metrics and COUNT: the spec of always
+        (6, "SUM(q * q - q)", None),  # +, -, * of int32 stay int32
+        (6, "SUM(q * 2)", 8),  # a literal is a DOUBLE
+        (6, "MIN(w)", 8),  # a LONG the device keeps as int64
+        (6, "MAX(q), MINMAXRANGE(q)", None),
+        (6, "COUNT(*) FILTER (WHERE q > 3), SUM(d) FILTER (WHERE q > 3)", 8),
+        (300, "AVG(x)", 320),
+        (T, "SUM(x)", T),
+        (T + 1, "SUM(x)", None),  # past T: the scatter's spec
+    ],
+)
+def test_the_plan_states_the_real_group_count_where_a_wide_reduction_can_use_it(groups, aggs, real):
+    keys = "a, b" if groups == 6 else "k"
+    gspec = gspec_of(table(groups), f"SELECT {keys}, {aggs} FROM t GROUP BY {keys} LIMIT 10")
+    assert gspec[0] == "groups" and gspec[2] == -(-groups // 256) * 256
+    assert gspec[4:] == (() if real is None else (real,))
+    assert real is None or real == bucket(groups)
+
+
+def test_a_long_that_fits_int32_is_an_int32_metric():
+    schema = Schema.build("n", dimensions=[("k", DataType.INT)], metrics=[("v", DataType.LONG)])
+    seg = SegmentBuilder(schema).build(
+        {"k": np.arange(100, dtype=np.int32) % 5, "v": np.arange(100, dtype=np.int64)}, "n0"
+    )
+    assert len(gspec_of(seg, "SELECT k, SUM(v) FROM n GROUP BY k LIMIT 10")) == 4
+
+
+def test_mv_keys_and_the_sort_compaction_path_keep_their_specs():
+    schema = Schema.build(
+        "m", dimensions=[("k", DataType.INT), ("hi", DataType.INT), ("hj", DataType.INT)], metrics=[("x", DataType.DOUBLE)]
+    )
+    schema.add(FieldSpec("tags", DataType.INT, single_value=False))
+    n = 3000
+    rng = np.random.default_rng(9)
+    tags = np.empty(n, dtype=object)
+    for i in range(n):
+        tags[i] = rng.integers(0, 5, int(rng.integers(1, 4))).astype(np.int32).tolist()
+    seg = SegmentBuilder(schema).build(
+        {
+            "k": np.arange(n, dtype=np.int32) % 4,
+            "tags": tags,
+            "hi": np.arange(n, dtype=np.int32),
+            "hj": (np.arange(n, dtype=np.int32) * 7) % n,
+            "x": rng.random(n),
+        },
+        "m0",
+    )
+    mv = gspec_of(seg, "SELECT tags, SUM(x) FROM m GROUP BY tags LIMIT 10")
+    sparse = gspec_of(seg, "SELECT hi, hj, SUM(x) FROM m GROUP BY hi, hj LIMIT 10")
+    assert mv[0] == "groups_mv" and len(mv) == 6
+    assert sparse[0] == "groups_sparse" and len(sparse) == 4
+
+
+# ---------------------------------------------------------------------------
+# SSB-shaped group-bys are the parent's programs, name for name
+# ---------------------------------------------------------------------------
+
+SSB_PROGRAMS = {
+    # flights 2 to 4 over an SSB-shaped table: integer metrics only. The names are
+    # program_name() of the parent commit's plans (88b50b1) over this same table.
+    "SELECT d_year, p_brand1, SUM(lo_revenue) FROM lineorder WHERE p_category = 'MFGR#12' AND s_region = 'AMERICA' "
+    "GROUP BY d_year, p_brand1 ORDER BY d_year, p_brand1 LIMIT 10": "seg_groupby_838d2a87",
+    "SELECT c_nation, s_nation, d_year, SUM(lo_revenue) FROM lineorder WHERE c_region = 'ASIA' AND s_region = 'ASIA' "
+    "AND d_year >= 1992 AND d_year <= 1997 GROUP BY c_nation, s_nation, d_year ORDER BY d_year LIMIT 10": "seg_groupby_5ec0973e",
+    "SELECT d_year, c_nation, SUM(lo_revenue - lo_supplycost), COUNT(*) FROM lineorder WHERE c_region = 'AMERICA' "
+    "GROUP BY d_year, c_nation ORDER BY d_year, c_nation LIMIT 10": "seg_groupby_6728849d",
+    "SELECT d_year, SUM(lo_extendedprice * lo_discount), AVG(lo_quantity) FROM lineorder "
+    "GROUP BY d_year ORDER BY d_year LIMIT 10": "seg_groupby_5d951c51",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def ssb_segment():
+    rng = np.random.default_rng(28)
+    n = 4000
+    names = lambda p, k: np.asarray([f"{p}{i:02d}" for i in range(k)], dtype=object)  # noqa: E731
+    schema = Schema.build(
+        "lineorder",
+        dimensions=[
+            ("d_year", DataType.INT), ("p_brand1", DataType.STRING), ("p_category", DataType.STRING),
+            ("s_region", DataType.STRING), ("c_region", DataType.STRING), ("c_nation", DataType.STRING),
+            ("s_nation", DataType.STRING),
+        ],
+        metrics=[
+            ("lo_revenue", DataType.INT), ("lo_supplycost", DataType.INT), ("lo_extendedprice", DataType.INT),
+            ("lo_discount", DataType.INT), ("lo_quantity", DataType.INT),
+        ],
+    )  # fmt: skip
+    regions = np.asarray(["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"], dtype=object)
+    data = {
+        "d_year": (1992 + np.arange(n) % 7).astype(np.int32),
+        "p_brand1": names("MFGR#12", 40)[np.arange(n) % 40],
+        "p_category": names("MFGR#", 25)[np.arange(n) % 25],
+        "s_region": regions[np.arange(n) % 5],
+        "c_region": regions[(np.arange(n) // 5) % 5],
+        "c_nation": names("N", 25)[np.arange(n) % 25],
+        "s_nation": names("N", 25)[(np.arange(n) // 3) % 25],
+        "lo_revenue": rng.integers(1, 1 << 22, n).astype(np.int32),
+        "lo_supplycost": rng.integers(1, 1 << 16, n).astype(np.int32),
+        "lo_extendedprice": rng.integers(1, 1 << 15, n).astype(np.int32),
+        "lo_discount": rng.integers(0, 11, n).astype(np.int32),
+        "lo_quantity": rng.integers(1, 51, n).astype(np.int32),
+    }
+    return SegmentBuilder(schema).build(data, "lo0")
+
+
+@pytest.mark.parametrize("q", list(SSB_PROGRAMS))
+def test_an_int32_only_group_by_is_the_parents_program(q):
+    seg = ssb_segment()
+    plan = plan_segment(seg, QueryEngine([seg]).make_context(q))
+    assert len(plan.spec[2]) == 4  # ("groups", cols, ng, strides): no real count
+    assert kernels.program_name(plan.spec) == SSB_PROGRAMS[q]

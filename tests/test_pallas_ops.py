@@ -286,3 +286,26 @@ def test_mosaic_compiles_the_rules_grid_for_the_v5e(one_v5e_chip, monkeypatch, n
     fn = jax.jit(lambda g, p: gp._planes2_impl.__wrapped__(g, p, ng, grid))
     text = fn.lower(gid, planes).compile().as_text()
     assert "ops_grouped_planes2_impl" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("g", [8, 4096])  # TPC-H Q1's six groups in their bucket; plan.DENSE_REDUCE_MAX_GROUPS
+def test_dense_grouped_reduction_is_one_fused_pass_on_the_v5e(one_v5e_chip, g):
+    """A grouped DOUBLE SUM over few real groups (query/kernels.py
+    `_dense_grouped`) has to fuse: the (g, rows) one-hot of a 4M-row segment
+    would be 256 MB at 8 groups. The v5e's compiler gives one reduce fusion of
+    emulated-f64 pairs, no scatter, and next to no temporary memory."""
+    import jax
+
+    from pinot_tpu.query import kernels, plan
+
+    n = 4096 * 1024
+    assert g <= plan.DENSE_REDUCE_MAX_GROUPS
+
+    def arg(dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_v5e_chip)
+
+    fn = jax.jit(lambda v, gid, mask: kernels._dense_grouped("sum", v, gid, mask, g))
+    compiled = fn.lower(arg(jnp.float64), arg(jnp.int32), arg(jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "scatter" not in text and f"f32[{g},{n // kernels._BLOCK}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < n  # the one-hot alone: n * g * 8
