@@ -152,8 +152,8 @@ class AdmissionController:
         # with no queue pressure the rejection rests entirely on the service
         # EWMA, which only updates when a query completes — shedding 100%
         # would freeze a poisoned estimate forever (a JIT-cold warmup is
-        # enough to push it past the deadline, observed as a permanent
-        # 503 storm in bench.py cluster). The first estimate-only shed
+        # enough to push it past the deadline: a permanent 503 storm).
+        # The first estimate-only shed
         # starts the probe clock; one query per interval is then admitted
         # as a probe so the estimate can recover. Real backlog
         # (wait_ms > 0) still sheds unconditionally.

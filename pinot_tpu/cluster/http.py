@@ -72,7 +72,7 @@ def _serve(
 ) -> tuple[ThreadingHTTPServer, int, threading.Thread]:
     class _Server(ThreadingHTTPServer):
         # socketserver's default accept backlog of 5 refuses connections the
-        # moment 100s of clients connect at once (bench.py qps drives 128+);
+        # moment 100s of clients connect at once;
         # a deep backlog lets the thread-per-request model absorb the burst
         request_queue_size = 256
 

@@ -1,9 +1,8 @@
 """Frontend & transport request-lifecycle observability.
 
 The cluster is instrumented down to per-kernel HBM bandwidth (kernel_obs)
-yet the dominant latency at high client counts sits *outside* all of it:
-BENCH_qps_r15 measured a 0.9 ms broker p99 against a 276 ms client p99,
-and the only evidence was a one-off flamegraph. This module builds the
+yet at high client counts most of a request's latency can sit *outside*
+all of it, between the client's clock and the broker's. This module is the
 instrument for that tier — the socket-level request lifecycle — so
 "client minus broker" decomposes into named milliseconds:
 
@@ -27,8 +26,8 @@ instrument for that tier — the socket-level request lifecycle — so
   and mirror into the role registry for /metrics exposition.
 
 * **SchedLagProbe** — a heartbeat thread measuring wakeup delay
-  (`runtime.schedLagMs`): the direct GIL/thread-starvation signal the
-  r15 flamegraph only implied. One probe per process, recording into
+  (`runtime.schedLagMs`): the direct GIL/thread-starvation signal.
+  One probe per process, recording into
   every role registry that registered interest.
 
 * **frontend_snapshot()** — the `GET /debug/frontend` document: live
@@ -36,10 +35,10 @@ instrument for that tier — the socket-level request lifecycle — so
   scheduling lag, merged per-node into `/debug/cluster` by the
   ClusterMetricsAggregator.
 
-* **attribute_client_gap()** — the bench-side cross-check math: given
+* **attribute_client_gap()** — the client-side cross-check math: given
   per-request client phase splits (connect/send/TTFB/read) and the
   broker-reported time, attribute the client-minus-broker gap to named
-  phases (BENCH_qps_r16 acceptance: >=90% attributed).
+  phases.
 """
 
 from __future__ import annotations
@@ -539,9 +538,8 @@ def attribute_client_gap(samples: list[dict]) -> dict:
 
     anything left (client-side bookkeeping between the stamps) is
     `otherMs`. `coverage` is the named share of the total gap across all
-    samples — the BENCH_qps_r16 acceptance requires >= 0.9. `tail` runs
-    the same math over the top 1% of requests by wall time (the p99 the
-    asyncio rewrite must attack)."""
+    samples. `tail` runs the same math over the top 1% of requests by
+    wall time."""
 
     def fold(rows: list[dict]) -> dict:
         gap = conn = send = ttfb_net = read = broker = wall = 0.0

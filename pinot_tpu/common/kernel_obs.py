@@ -1,4 +1,4 @@
-"""Kernel & memory observability plane: per-kernel device-time attribution,
+"""Kernel & memory observability plane: per-kernel readback-wait attribution,
 HBM accounting, and roofline analytics.
 
 The rest of the observability stack (traces, the 31 Hz profiler, the cluster
@@ -7,18 +7,21 @@ device-side counterpart — the TPU-native equivalent of the reference's
 per-operator `Tracing` SPI / `ExecutionStatistics` accounting:
 
 - `KernelRegistry`: every jitted / pallas root registers under a stable name
-  with a bytes-moved / FLOPs cost model. Invocations are timed device-side
-  (`block_until_ready` fencing with the memoized `devlink.link_profile()`
-  RTT subtracted, the same split `bench.py` computes) and folded into
-  labelled `engine.kernel.*{kernel=,shape=}` Timer/Meter families, per-query
+  with a bytes-moved / FLOPs cost model. An invocation is timed on the host:
+  the wall from the call to its result being ready (`block_until_ready`),
+  as it is. For the served path that is the readback's wait — the transfer
+  and whatever PJRT still had queued in front of the launch — not the
+  device's time, which only a profiler trace tells (PERF.md). The number is
+  called `deviceMs` and folded into labelled
+  `engine.kernel.*{kernel=,shape=}` Timer/Meter families, per-query
   device-ms + peak-HBM totals in the accountant, and `kernel.execute` span
   events on the active trace. A kernel traced into an outer jit has nothing
   concrete to time; it is counted as inlined instead.
 - HBM accounting: live/peak bytes from `device.memory_stats()` on an
   accelerator; on the CPU backend, which reports none, a deterministic
   host-side estimator so CPU tier-1 sees the same math the TPU path uses.
-- `roofline()`: per-(kernel, shape-bucket) achieved GB/s vs. the HBM peak of
-  the device the process runs on (`DEVICE_PEAKS`, keyed by `device_kind`;
+- `roofline()`: per-(kernel, shape-bucket) bytes moved over that wait vs. the
+  HBM peak of the device the process runs on (`DEVICE_PEAKS`, keyed by `device_kind`;
   a device without an entry gets no percentage), arithmetic intensity, and
   the top roofline-gap offenders — served as `GET /debug/roofline` and
   merged into the controller's `/debug/cluster`.
@@ -65,34 +68,6 @@ def shape_bucket(n) -> str:
     if n <= 0:
         return "0"
     return f"2^{n.bit_length() - 1}"
-
-
-# -- link RTT (memoized; mirrors bench.py's device/link split) --------------
-
-_UNSET = object()
-_link_rtt_ms_cached = _UNSET
-_link_lock = threading.Lock()
-
-
-def _link_rtt_ms() -> float:
-    """Memoized host<->device link RTT in ms from `devlink.link_profile()`.
-    A probe that fails raises: a device that cannot round-trip eight bytes
-    is not one to report timings from."""
-    global _link_rtt_ms_cached
-    if _link_rtt_ms_cached is _UNSET:
-        with _link_lock:
-            if _link_rtt_ms_cached is _UNSET:
-                from pinot_tpu.common import devlink
-
-                rtt_s, _ = devlink.link_profile()
-                _link_rtt_ms_cached = max(float(rtt_s) * 1e3, 0.0)
-    return _link_rtt_ms_cached
-
-
-def _reset_link_rtt() -> None:
-    """Test hook."""
-    global _link_rtt_ms_cached
-    _link_rtt_ms_cached = _UNSET
 
 
 def _has_tracer(out) -> bool:
@@ -295,10 +270,10 @@ class KernelRegistry:
 
     def timed_sync(self, name: str, fn: Callable[[], object], **shape):
         """Run `fn` (a device dispatch whose result the caller is about to
-        consume), fence with `block_until_ready`, and record wall-minus-RTT
-        as device time — the same split `bench.py` computes. Disabled
-        registries and calls made under an outer jax trace pass straight
-        through."""
+        consume), fence with `block_until_ready`, and record the fenced wall
+        as it is: the wait for the result, under the name `deviceMs`.
+        Disabled registries and calls made under an outer jax trace pass
+        straight through."""
         if not self._enabled:
             return fn()
         import jax
@@ -319,8 +294,7 @@ class KernelRegistry:
                 ent["flops"] += flops
             return out
         out = jax.block_until_ready(out)
-        wall_ms = (time.perf_counter() - t0) * 1e3
-        self.record(name, max(wall_ms - _link_rtt_ms(), 0.0), **shape)
+        self.record(name, (time.perf_counter() - t0) * 1e3, **shape)
         return out
 
     # -- static work of named outer programs ----------------------------------
@@ -430,7 +404,6 @@ class KernelRegistry:
             "hbmPeakGBps": peak,
             "hbmPeakSource": peak_source,
             "enabled": self._enabled,
-            "linkRttMs": round(_link_rtt_ms(), 4) if self._stats else 0.0,
             "kernels": rows,
             "offenders": offenders,
             "inlined": inlined,

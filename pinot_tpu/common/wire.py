@@ -434,7 +434,7 @@ class ConnectionPool:
 
 #: process-global pool shared by the v1 scatter client, the v2 mailbox
 #: sender, and the controller proxy. Sized so a saturating client fleet
-#: (bench.py qps runs 128 threads) never queues on checkout by default.
+#: (128 threads and more) never queues on checkout by default.
 POOL = ConnectionPool()
 
 

@@ -31,9 +31,8 @@ import jax.numpy as jnp
 
 from pinot_tpu.common.kernel_obs import KERNELS
 
-# Docs a grid step. Overridable for hardware sweeps; benchmarks/planes_ab.py
-# takes the chunk as an argument instead.
-PLANES_CHUNK = int(os.environ.get("PINOT_TPU_PALLAS_CHUNK_PLANES", "4096"))
+# Docs a grid step; benchmarks/planes_ab.py sweeps it through `grid_for(..., chunk=)`.
+PLANES_CHUNK = 4096
 
 
 def _check_chunk(chunk: int) -> None:
@@ -43,9 +42,6 @@ def _check_chunk(chunk: int) -> None:
         raise ValueError(f"plane chunk {chunk}: CHUNK*255 must stay < 2^24 for lossless sums")
     if chunk % 128:
         raise ValueError(f"plane chunk {chunk}: must be a multiple of 128 (lane tiling)")
-
-
-_check_chunk(PLANES_CHUNK)  # fail loudly on a bad PINOT_TPU_PALLAS_CHUNK_PLANES
 
 
 def pallas_auto() -> bool:

@@ -1016,7 +1016,7 @@ def dispatch_plan_packed(plan, device_segment):
         # THE device->host sync: `server.device_wait` is the wait for the
         # readback and nothing else — everything queued on the device ahead
         # of this program plus the program. kernel_obs' deviceMs is the same
-        # wall less the memoized link RTT.
+        # wait, taken by its own clock inside this span.
         with span("server.device_wait"):
             v = np.asarray(
                 KERNELS.timed_sync(

@@ -1,9 +1,10 @@
 """Pallas byte-plane group-by kernel (two-level one-hot MXU matmul) vs numpy.
 
 Runs in interpret mode on CPU (tests/conftest.py forces the CPU backend);
-the same kernel compiles natively on TPU, where `python chip_smoke.py` runs
-it at real shapes. Reference semantics: DefaultGroupByExecutor result
-holders (SURVEY.md §2.2).
+the same kernel compiles natively on TPU, where the benchmark's group-by
+cells (`python3 -m perfbench.run --workload ssb-groupby-closed`) run it at
+real shapes. Reference semantics: DefaultGroupByExecutor result holders
+(SURVEY.md §2.2).
 """
 
 import numpy as np
