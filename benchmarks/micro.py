@@ -213,9 +213,11 @@ def bench_cache_overhead(n=120_000):
             stmt, normalized = broker_on._compile(q())
             probe = broker_on.execute(q())
             ops = 20_000
+            # the snapshot's one validating call is made with the caches off too
+            snaps = {"t": broker_on._route_snapshot("t")}
             t0 = time.perf_counter()
             for i in range(ops):
-                key, versions, twins = broker_on._cache_key(stmt, "t", normalized)
+                key, versions, _ = broker_on._cache_key(stmt, snaps, normalized)
                 miss_key = (f"{normalized}#{i}", key[1])
                 broker_on.caches.result_get(miss_key, versions)
                 broker_on.caches.result_put(

@@ -25,7 +25,7 @@ from pinot_tpu.query.result import ResultTable
 from pinot_tpu.query.scheduler import SchedulerRejectedError
 from pinot_tpu.query.sql import parse_sql
 from pinot_tpu.cluster.controller import Controller
-from pinot_tpu.cluster.routing import BalancedInstanceSelector, segment_can_match
+from pinot_tpu.cluster.routing import BalancedInstanceSelector, RouteSnapshot, segment_can_match
 
 
 def _collect_tables(stmt) -> list[str]:
@@ -147,6 +147,8 @@ class Broker:
             if enable_quota
             else None
         )
+        #: queried table -> the route snapshot held for it (`_route_snapshot`)
+        self._snapshots: dict[str, RouteSnapshot] = {}
         self.cache_config = cache_config if cache_config is not None else CacheConfig()
         #: QueryCaches (result/parse/plan tiers + single-flight), or None
         #: when CacheConfig.enabled is False — every cache branch in the
@@ -440,13 +442,20 @@ class Broker:
                         "startMs": time.time() * 1e3,
                     }
                 table = getattr(stmt, "from_table", None) or ""
+                tables = _collect_tables(stmt)
+                if table and table not in tables:
+                    tables.append(table)
                 if self.access_control is not None:
                     from pinot_tpu.cluster.access import READ
 
-                    for t in _collect_tables(stmt) or ([table] if table else []):
+                    for t in tables:
                         self.access_control.check(identity, t, READ)
+                # routing state, confirmed by the controller now that the
+                # query is here: one snapshot a table, before its first reader
+                with span("broker.route"):
+                    snaps = {t: self._route_snapshot(t) for t in tables}
                 if self.quota is not None and table:
-                    self.quota.acquire(table)
+                    self.quota.acquire(table, snapshot=snaps[table])
                 # admission decision BEFORE any work is enqueued: shed
                 # (SchedulerRejectedError -> HTTP 503 + Retry-After) when the
                 # projected completion cannot fit the remaining deadline
@@ -477,7 +486,7 @@ class Broker:
                     if wire_tl is not None:
                         wire_tl.record_sub("queueWait", wait_ms)
                     return self._execute(
-                        stmt, sql, deadline=deadline, qid=qid, partial=partial,
+                        stmt, sql, snaps, deadline=deadline, qid=qid, partial=partial,
                         normalized=normalized,
                     )
 
@@ -489,8 +498,7 @@ class Broker:
                 # result-cache tier, AFTER quota + admission by design: hits
                 # still count against quotas and shed/degrade verdicts, but a
                 # hit bypasses the scheduler enqueue and the whole scatter
-                with span("broker.route"):
-                    cache_state = self._cache_key(stmt, table, normalized)
+                cache_state = self._cache_key(stmt, snaps, normalized)
                 hit_box = {"hit": False}
 
                 def run_cached():
@@ -608,7 +616,8 @@ class Broker:
         immutable (plan mode deep-copies before star expansion).
 
         Plan mode (stmt given): -> (expanded statement, QueryContext), cached
-        per (normalized sql, table, routing epoch); the cached prototype is
+        per (normalized sql, table, epoch: the route snapshot's token, which
+        config, schema and segment-set writes all move); the cached prototype is
         cloned per query with fresh hints/options dicts so per-request state
         (deadline, tenant, trace context) never leaks between queries."""
         import copy
@@ -624,10 +633,7 @@ class Broker:
                     return parse_sql(sql), None
             return self.caches.get_or_parse(sql, on_compile=timer)
 
-        if self.caches is None or normalized is None or epoch is None:
-            # epoch None with caches on = routing versions unavailable
-            # (controller failover): plan uncached rather than risk keying
-            # a plan to an unknown routing state
+        if self.caches is None or normalized is None:
             with timer():
                 self._expand_star(stmt, schema)
                 return stmt, QueryContext.from_statement(stmt)
@@ -649,33 +655,44 @@ class Broker:
         ctx.deadline = None
         return cached_stmt, ctx
 
-    def _cache_key(self, stmt, table: str, normalized: str | None):
+    def _route_snapshot(self, table: str) -> RouteSnapshot:
+        """The one way the broker learns routing state, and the one request a
+        query makes of the controller: the snapshot held for `table` where
+        the controller says its token still stands, else the one it sends in
+        its place. Asked on every query: the query routes on state that the
+        controller confirmed after the query arrived. Counted in the
+        request's ledger and on `/metrics` as `controllerCalls` and
+        `routeSnapshotFetches`."""
+        from pinot_tpu.common.metrics import BrokerMeter, broker_metrics
+        from pinot_tpu.common.trace import count
+
+        held = self._snapshots.get(table)
+        fresh = self.controller.route_snapshot(table, have=held.token if held is not None else None)
+        bm = broker_metrics()
+        bm.meter(BrokerMeter.CONTROLLER_CALLS).mark()
+        count("controllerCalls")
+        count("routeSnapshotFetches", 0 if fresh is None else 1)
+        if fresh is None:
+            return held
+        bm.meter(BrokerMeter.ROUTE_SNAPSHOT_FETCHES).mark()
+        if fresh.exists:
+            self._snapshots[table] = fresh
+        else:
+            self._snapshots.pop(table, None)  # names nobody made are not kept
+        return fresh
+
+    def _cache_key(self, stmt, snaps: dict[str, RouteSnapshot], normalized: str | None):
         """Result-tier key material: ((normalized sql, option fingerprint),
-        version vector, twin table list) or None when caching is off. The
-        vector covers every referenced table AND its `_REALTIME` twin — hybrid
-        queries route through both halves, so a mutation on either must change
-        the key."""
-        if self.caches is None or normalized is None:
+        the token of every referenced table's route snapshot, the snapshots)
+        or None when caching is off. A token covers the table AND its
+        `_REALTIME` twin — hybrid queries route through both halves, so a
+        mutation on either changes the key."""
+        if self.caches is None or normalized is None or not snaps:
             return None
         from pinot_tpu.cluster.result_cache import options_fingerprint
 
-        tables = _collect_tables(stmt) or ([table] if table else [])
-        if not tables:
-            return None
-        twins: list[str] = []
-        for t in tables:
-            twins.append(t)
-            if not t.endswith("_REALTIME"):
-                twins.append(f"{t}_REALTIME")
-        try:
-            vv = self.controller.routing_versions(twins)
-        except ConnectionError:
-            # every controller candidate down (HA failover in progress):
-            # degrade to uncached execution — routing state can't be keyed
-            # safely, but the query itself only needs servers, not metadata
-            return None
-        versions = tuple(sorted((t, int(v)) for t, v in vv.items()))
-        return (normalized, options_fingerprint(stmt.options)), versions, twins
+        versions = tuple(sorted((t, s.token) for t, s in snaps.items()))
+        return (normalized, options_fingerprint(stmt.options)), versions, snaps
 
     def _run_cached(self, cache_state, run_admitted, partial, deadline, hit_box):
         """Result-tier lookup around the admitted execution. Hit: clone the
@@ -685,7 +702,7 @@ class Broker:
         it is complete (partial/degraded/error responses are never cached)."""
         from pinot_tpu.common.trace import trace_event
 
-        key, versions, twins = cache_state
+        key, versions, snaps = cache_state
         caches = self.caches
 
         def hit(value):
@@ -704,7 +721,9 @@ class Broker:
                     key,
                     self._clone_result(result),
                     versions,
-                    realtime=self._has_consuming(twins),
+                    # consuming rows advance with no write a token sees: such
+                    # entries get the realtimeTtlMs cap, not life until the next bump
+                    realtime=any(s.has_consuming() for s in snaps.values()),
                 )
             return result
 
@@ -736,20 +755,6 @@ class Broker:
         out.trace = None
         out.trace_id = ""
         return out
-
-    def _has_consuming(self, tables) -> bool:
-        """Any listed table with an ideal-state segment lacking committed
-        metadata (= actively consuming). Those rows advance with no metadata
-        write, so cached entries get the realtimeTtlMs freshness cap instead
-        of living until the next version bump."""
-        for t in tables:
-            ideal = self.controller.ideal_state(t)
-            if not ideal:
-                continue
-            meta = self.controller.all_segment_metadata(t)
-            if any(s not in meta for s in ideal):
-                return True
-        return False
 
     def cache_snapshot(self) -> dict:
         """The GET /debug/cache document."""
@@ -958,7 +963,18 @@ class Broker:
         )
         return kept
 
-    def _execute(self, stmt, sql: str, deadline=None, qid=None, partial=None, normalized=None) -> ResultTable:
+    def _execute(
+        self,
+        stmt,
+        sql: str,
+        snaps: dict[str, RouteSnapshot],
+        deadline=None,
+        qid=None,
+        partial=None,
+        normalized=None,
+    ) -> ResultTable:
+        """Run an admitted query. `snaps`: the route snapshot of every table
+        it names, as `_route_snapshot` confirmed them when it arrived."""
         t0 = time.perf_counter()
         if getattr(stmt, "explain", False) or getattr(stmt, "explain_analyze", False):
             # failing loudly beats silently executing the query and returning
@@ -978,15 +994,13 @@ class Broker:
                 import copy
 
                 stmt = copy.deepcopy(stmt)
-            return self._execute_multistage(stmt, sql, deadline=deadline, qid=qid)
+            return self._execute_multistage(stmt, sql, snaps, deadline=deadline, qid=qid)
         from pinot_tpu.common.trace import ServerQueryPhase, active_trace, span
 
         table = stmt.from_table
-        rt_name = f"{table}_REALTIME"
-        with span("broker.route"):
-            offline_cfg = self.controller.get_table(table)
-            rt_cfg = self.controller.get_table(rt_name) if not table.endswith("_REALTIME") else None
-        if offline_cfg is None and rt_cfg is None:
+        snap = snaps[table]
+        offline_cfg, rt_cfg = snap.offline_cfg, snap.rt_cfg
+        if not snap.exists:
             raise KeyError(f"no such table: {table}")  # BrokerResponse TableDoesNotExist parity
         # broker-tenant gate: a tagged broker serves only tables whose broker
         # tenant it belongs to (BrokerResourceManager routing-table parity)
@@ -1002,19 +1016,10 @@ class Broker:
                         f"table {cfg.table_name!r} belongs to broker tenant tag {want!r}; "
                         f"this broker serves {self.tenant_tags}"
                     )
-        with span("broker.route"):
-            schema = self.controller.get_schema(table) or self.controller.get_schema(rt_name)
-            # plan epoch: the (offline, realtime) routing versions — schema and
-            # segment-set changes both land as bumps, re-keying the cached plan
-            epoch = None
-            if self.caches is not None and normalized is not None:
-                try:
-                    epoch = tuple(sorted(self.controller.routing_versions([table, rt_name]).items()))
-                except ConnectionError:
-                    # controller failover in progress: plan uncached this round
-                    epoch = None
+        # plan epoch: the snapshot's token — a write to either twin's config,
+        # schema, segments or ideal state moves it and re-keys the cached plan
         stmt, ctx = self._compile(
-            sql, stmt=stmt, schema=schema, table=table, normalized=normalized, epoch=epoch
+            sql, stmt=stmt, schema=snap.schema, table=table, normalized=normalized, epoch=snap.token
         )
         ctx.deadline = deadline
         # workload attribution: the table's server tenant rides the hints to
@@ -1040,33 +1045,17 @@ class Broker:
             ctx.hints["__traceCtx__"] = tr.context.to_dict()
 
         with span("broker.route"):
-            # legs: (physical table, sql text). Hybrid tables split on the time
-            # boundary (TimeBoundaryManager parity): offline <= boundary < realtime
-            if offline_cfg is not None and rt_cfg is not None and offline_cfg.time_column:
-                from pinot_tpu.cluster.routing import TimeBoundary
-
-                offline_meta = self.controller.all_segment_metadata(table)
-                tb = TimeBoundary.compute(offline_meta, offline_cfg.time_column)
-                if tb is None:
-                    legs = [(rt_name, sql)]
-                else:
-                    legs = [(table, tb.offline_sql(sql)), (rt_name, tb.realtime_sql(sql))]
-            elif offline_cfg is not None:
-                legs = [(table, sql)]
-            else:
-                legs = [(rt_name, sql)]
-
-            all_meta: dict[str, dict] = {}
-            for leg_table, _ in legs:
-                all_meta.update(self.controller.all_segment_metadata(leg_table))
-            self._compute_hints(ctx, all_meta)
+            # legs: (physical table, sql text); a hybrid table splits on the
+            # snapshot's time boundary
+            legs = snap.legs(sql)
+            self._compute_hints(ctx, snap.all_meta)
 
         if ctx.query_type == QueryType.SELECTION and ctx.gapfill is None:
             # plain SELECT: framed streaming with incremental reduce — broker
             # memory stays bounded by (needed rows + one frame), and servers
             # stop producing once the LIMIT is satisfied
             # (StreamingReduceService parity)
-            return self._execute_streaming(ctx, legs, all_meta, t0, partial=partial)
+            return self._execute_streaming(ctx, snap, legs, t0, partial=partial)
 
         from pinot_tpu.query import scan_stats
 
@@ -1075,7 +1064,7 @@ class Broker:
         for leg_table, leg_sql in legs:
             if deadline is not None:
                 deadline.check(f"scatter {leg_table}")
-            p, s, q, pr, leg_scan = self._scatter_leg(ctx, leg_table, leg_sql, partial=partial)
+            p, s, q, pr, leg_scan = self._scatter_leg(ctx, snap, leg_table, leg_sql, partial=partial)
             partials.extend(p)
             scanned += s
             queried += q
@@ -1093,7 +1082,7 @@ class Broker:
             ctx,
             rows,
             num_docs_scanned=int(scanned),
-            total_docs=sum(m.get("numDocs", 0) for m in all_meta.values()),
+            total_docs=snap.total_docs,
             num_segments_queried=queried,
             num_segments_pruned=sum(by_reason.values()),
             num_segments_pruned_by_value=by_reason.get("value", 0),
@@ -1105,7 +1094,7 @@ class Broker:
             time_used_ms=(time.perf_counter() - t0) * 1e3,
         )
 
-    def _execute_streaming(self, ctx: QueryContext, legs, all_meta, t0, partial=None) -> ResultTable:
+    def _execute_streaming(self, ctx: QueryContext, snap: RouteSnapshot, legs, t0, partial=None) -> ResultTable:
         """Selection-only streaming scatter/gather: all servers stream in
         parallel into one bounded frame queue (memory stays bounded by
         queue depth x frame size); the incremental reduce appends rows and
@@ -1123,7 +1112,7 @@ class Broker:
         for leg_table, leg_sql in legs:
             if ctx.deadline is not None:
                 ctx.deadline.check(f"stream scatter {leg_table}")
-            plan, servers, ideal, n_candidates, leg_pruned = self._route_leg(ctx, leg_table)
+            plan, servers, ideal, n_candidates, leg_pruned = self._route_leg(ctx, snap, leg_table)
             plan = self._degrade_plan(plan, partial, leg_table)
             queried += n_candidates
             pruned += leg_pruned
@@ -1182,7 +1171,7 @@ class Broker:
             ctx,
             rows,
             num_docs_scanned=int(state["scanned"]),
-            total_docs=sum(m.get("numDocs", 0) for m in all_meta.values()),
+            total_docs=snap.total_docs,
             num_segments_queried=queried,
             num_segments_pruned=sum(by_reason.values()),
             num_segments_pruned_by_value=by_reason.get("value", 0),
@@ -1289,13 +1278,14 @@ class Broker:
             raise error
         return failed
 
-    def _route_leg(self, ctx: QueryContext, table: str):
-        """Prune on stats/partitions and pick replicas. Returns
-        (plan {server: [segments]}, servers, ideal, n_candidates, pruned)."""
+    def _route_leg(self, ctx: QueryContext, snap: RouteSnapshot, table: str):
+        """Prune on stats/partitions and pick replicas, all from the
+        snapshot. Returns (plan {server: [segments]}, servers, ideal,
+        n_candidates, pruned)."""
         from pinot_tpu.cluster.routing import segment_partitions_match
 
-        meta = self.controller.all_segment_metadata(table)
-        ideal = self.controller.ideal_state(table)
+        meta = snap.meta.get(table, {})
+        ideal = snap.ideal.get(table, {})
 
         candidates, pruned = [], 0
         for seg_name, m in meta.items():
@@ -1316,28 +1306,38 @@ class Broker:
         plan, unroutable = self.selector.select(routable_ideal, candidates)
         if unroutable:
             raise RuntimeError(f"no ONLINE replica for segments: {unroutable}")
-        return plan, self.controller.servers(), ideal, len(candidates), pruned
+        return plan, snap.servers, ideal, len(candidates), pruned
 
-    def _scatter_leg(self, ctx: QueryContext, table: str, sql: str, partial=None):
+    def _scatter_leg(self, ctx: QueryContext, snap: RouteSnapshot, table: str, sql: str, partial=None):
         """Route + scatter one physical table, re-routing briefly when a
         query lands exactly in a segment-rollover commit window (the routed
         CONSUMING name is transiently unresolvable on a single replica —
-        SegmentCompletionManager's commit interval). Connection failures
-        fail over to other replicas inside the single attempt."""
+        SegmentCompletionManager's commit interval) or behind a rebalance
+        move's drain (the replica left the server after the route was
+        confirmed; a streamed leg's server refuses in words, an aggregation's
+        returns one partial too few and the scatter's own guard says it).
+        Each further attempt asks the controller again: what the
+        server no longer hosts, the next snapshot no longer routes there.
+        Connection failures fail over to other replicas inside the single
+        attempt."""
+        from pinot_tpu.common.trace import span
+
         last: RuntimeError | None = None
         for attempt in range(4):
             if ctx.deadline is not None:
                 ctx.deadline.check(f"scatter {table}")
             try:
-                return self._scatter_leg_once(ctx, table, sql, partial=partial)
+                return self._scatter_leg_once(ctx, snap, table, sql, partial=partial)
             except RuntimeError as e:
                 if "does not host segments" not in str(e):
                     raise
                 last = e
                 time.sleep(0.05 * (attempt + 1))  # commit windows are short
+                with span("broker.route"):
+                    snap = self._route_snapshot(snap.table)
         raise last
 
-    def _scatter_leg_once(self, ctx: QueryContext, table: str, sql: str, partial=None):
+    def _scatter_leg_once(self, ctx: QueryContext, snap: RouteSnapshot, table: str, sql: str, partial=None):
         """One route + scatter pass: prune on stats/partitions, select
         replicas (excluding failure-detected servers), fan out, retry
         connection failures on other replicas once. Returns
@@ -1348,7 +1348,7 @@ class Broker:
         from pinot_tpu.common.trace import span
 
         with span("broker.route"):
-            plan, servers, ideal, n_candidates, pruned = self._route_leg(ctx, table)
+            plan, servers, ideal, n_candidates, pruned = self._route_leg(ctx, snap, table)
             plan = self._degrade_plan(plan, partial, table)
         with span("broker.scatter", table=table, servers=len(plan)):
             return self._scatter_routed(ctx, table, sql, partial, plan, servers, ideal, n_candidates, pruned)
@@ -1390,9 +1390,13 @@ class Broker:
             self._hedge_record(sid, table, elapsed_ms)
             if len(out[0]) != len(segs):
                 # a server silently skipping unhosted segments would mean
-                # missing rows; fail loudly instead (partial-response guard)
+                # missing rows; fail loudly instead (partial-response guard).
+                # It is what a streamed leg's server says itself: the route is
+                # older than the server's segment set (`_scatter_leg` asks the
+                # controller again and routes anew)
                 raise RuntimeError(
-                    f"server {sid} executed {len(out[0])}/{len(segs)} requested segments"
+                    f"server {sid} executed {len(out[0])}/{len(segs)} requested segments: "
+                    f"it does not host segments of {table!r} that it was routed"
                 )
             return out
 
@@ -1468,8 +1472,11 @@ class Broker:
             ledger.merge_servers(server_ledgers)
         return partials, scanned, n_candidates, pruned, scan
 
-    def _execute_multistage(self, stmt, sql: str, deadline=None, qid=None) -> ResultTable:
-        """Dispatch the v2 engine over one replica of each segment.
+    def _execute_multistage(
+        self, stmt, sql: str, snaps: dict[str, RouteSnapshot], deadline=None, qid=None
+    ) -> ResultTable:
+        """Dispatch the v2 engine over one replica of each segment, each
+        table read off its route snapshot.
 
         Reference parity: QueryDispatcher.submitAndReduce
         (pinot-query-runtime/.../QueryDispatcher.java:128). Two modes:
@@ -1483,7 +1490,9 @@ class Broker:
 
         import zlib
 
-        servers = self.controller.servers()
+        servers: dict[str, object] = {}
+        for snap in snaps.values():
+            servers.update(snap.servers)
         schemas: dict[str, list[str]] = {}
         # table -> server -> [(segment name, deep-store location)]
         seg_assign: dict[str, dict[str, list]] = {}
@@ -1493,12 +1502,12 @@ class Broker:
         total_docs = 0
         table_docs: dict[str, int] = {}  # cost-model row counts per table
         for table in _collect_tables(stmt):
-            if self.controller.get_table(table) is None:
+            snap = snaps[table]
+            if snap.offline_cfg is None:
                 raise KeyError(f"no such table: {table}")
-            schema = self.controller.get_schema(table)
-            if schema is not None:
-                schemas[table] = list(schema.columns)
-            ideal = self.controller.ideal_state(table)
+            if snap.schema is not None:
+                schemas[table] = list(snap.schema.columns)
+            ideal = snap.ideal.get(table, {})
             assign: dict[str, list] = {}
             info: list = []
             for seg_name, replicas in sorted(ideal.items()):
@@ -1507,7 +1516,7 @@ class Broker:
                 )
                 if not online:
                     continue
-                meta = self.controller.segment_metadata(table, seg_name)
+                meta = snap.meta.get(table, {}).get(seg_name)
                 location = (meta or {}).get("location")
                 info.append((seg_name, online, location))
                 # replica spread must be stable across processes/restarts:

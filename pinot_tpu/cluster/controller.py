@@ -23,8 +23,17 @@ from pinot_tpu.common.config import TableConfig
 from pinot_tpu.common.trace import ServerQueryPhase
 from pinot_tpu.common.types import Schema
 from pinot_tpu.cluster.metadata import PropertyStore
+from pinot_tpu.cluster.routing import RouteSnapshot
 from pinot_tpu.segment.builder import write_segment
 from pinot_tpu.segment.segment import ImmutableSegment
+
+#: the counter that every write to an `/instances/{server}` document moves
+INSTANCES_VERSION_PATH = "/instancesversion"
+
+
+def routing_version_path(table: str) -> str:
+    """The counter document that every write to `table`'s routing state moves."""
+    return f"/tables/{table}/routingversion"
 
 
 class Controller:
@@ -183,25 +192,28 @@ class Controller:
             f"/instances/{server_id}",
             {"host": host, "port": port, "alive": True, "tags": eff_tags},
             fence=self.lease_fence(),
+            bump=INSTANCES_VERSION_PATH,
         )
 
     def update_server_tags(self, server_id: str, tags: list[str]) -> None:
         """Re-tag a server (updateInstanceTags REST parity)."""
         doc = self.store.get(f"/instances/{server_id}") or {}
         doc["tags"] = list(tags)
-        self.store.set(f"/instances/{server_id}", doc, fence=self.lease_fence())
+        self.store.set(f"/instances/{server_id}", doc, fence=self.lease_fence(), bump=INSTANCES_VERSION_PATH)
 
-    def servers(self) -> dict[str, object]:
+    def instances(self) -> dict[str, dict]:
+        """server id -> instance document."""
+        return {p.split("/")[-1]: self.store.get(p) or {} for p in self.store.list("/instances/")}
+
+    def servers(self, instances: dict[str, dict] | None = None) -> dict[str, object]:
+        """server id -> handle: the registered in-process objects, and a
+        RemoteServerClient (kept) for every other instance that listens on a port."""
         out = dict(self._servers)
-        for path in self.store.list("/instances/"):
-            sid = path.split("/")[-1]
-            if sid in out:
-                continue
-            doc = self.store.get(path) or {}
-            if doc.get("port"):
+        for sid, doc in (self.instances() if instances is None else instances).items():
+            if sid not in out and doc.get("port"):
                 from pinot_tpu.cluster.http import RemoteServerClient
 
-                out[sid] = self._servers[sid] = RemoteServerClient(f"http://{doc['host']}:{doc['port']}")
+                out[sid] = self._servers[sid] = RemoteServerClient.of_instance(doc)
         return out
 
     # -- brokers (DynamicBrokerSelector's ZK external-view analog) -----------
@@ -222,7 +234,14 @@ class Controller:
     def add_schema(self, schema: Schema) -> None:
         # fenced: config mutations from a stale ex-leader (lease lost while
         # it was paused/partitioned) must bounce like any other lead write
-        self.store.set(f"/schemas/{schema.name}", {"json": schema.to_json()}, fence=self.lease_fence())
+        # a schema is routing state of the table of its name (star expansion,
+        # the cached plan): the write moves that table's routing version
+        self.store.set(
+            f"/schemas/{schema.name}",
+            {"json": schema.to_json()},
+            fence=self.lease_fence(),
+            bump=routing_version_path(schema.name),
+        )
 
     def get_schema(self, name: str) -> Schema | None:
         doc = self.store.get(f"/schemas/{name}")
@@ -230,39 +249,71 @@ class Controller:
 
     def add_table(self, config: TableConfig) -> None:
         fence = self.lease_fence()
-        self.store.set(f"/tables/{config.table_name}/config", {"json": config.to_json()}, fence=fence)
-        if self.store.get(f"/tables/{config.table_name}/idealstate") is None:
-            self.store.set(f"/tables/{config.table_name}/idealstate", {}, fence=fence)
         # config (re)writes can change plans/pruning: treat as a routing change
-        self.bump_routing_version(config.table_name)
+        bump = routing_version_path(config.table_name)
+        self.store.set(f"/tables/{config.table_name}/config", {"json": config.to_json()}, fence=fence, bump=bump)
+        self.store.update(
+            f"/tables/{config.table_name}/idealstate", lambda cur: {} if cur is None else None, fence=fence, bump=bump
+        )
 
-    # -- routing version vector ----------------------------------------------
-    # One monotonic counter per table, bumped by EVERY code path that mutates
-    # the table's segment set or its routing-relevant metadata (upload,
-    # delete, refresh, rebalance move, realtime state change, deep-store
-    # repair). The broker's result/plan caches key on these versions, so a
-    # bump implicitly invalidates every cached result computed against the
-    # old segment set — no explicit flush protocol exists or is needed. The
-    # pinotlint `cache-invalidation` checker enforces that mutation sites
-    # keep calling this.
+    # -- routing versions and the route snapshot ------------------------------
+    # One monotonic counter per table, moved by EVERY write to what a broker
+    # routes that table's queries on: its config, the schema of its name, its
+    # segments' metadata and its ideal state (upload, delete, refresh,
+    # rebalance move, realtime state change, deep-store repair). A second
+    # counter, `INSTANCES_VERSION_PATH`, moves with every write to an instance
+    # document (a server registering, a re-tag). Each such write names its
+    # counter as `bump=` of the store call itself, so the write and the count
+    # are one step of the store: no reader is told "unchanged" about a
+    # document that has changed. The counters of a logical table and of its
+    # `_REALTIME` twin and the instances' counter are the *token* of the
+    # table's route snapshot (`route_snapshot`): the broker holds the
+    # snapshot, asks once a query whether the token still stands, and keys
+    # its plan and result caches on it — no flush protocol exists or is
+    # needed. A counter outlives its table (`delete_table` leaves it and
+    # moves it), so a table dropped and made again never repeats a token.
+    # The pinotlint `cache-invalidation` checker holds every such write to
+    # its `bump=`.
 
     def bump_routing_version(self, table: str) -> int:
-        """Increment and return the table's routing version."""
-        doc = self.store.update(
-            f"/tables/{table}/routingversion",
-            lambda cur: {"v": int((cur or {}).get("v", 0)) + 1},
-            fence=self.lease_fence(),
-        )
-        return int(doc["v"])
+        """Increment and return the table's routing version: for what changes
+        a table's answers without a write to the documents above."""
+        return self.store.bump(routing_version_path(table), fence=self.lease_fence())
 
     def routing_version(self, table: str) -> int:
         """The table's current routing version (0 = never mutated/unknown)."""
-        doc = self.store.get(f"/tables/{table}/routingversion")
-        return int((doc or {}).get("v", 0))
+        return self.store.counter(routing_version_path(table))
 
-    def routing_versions(self, tables: list[str]) -> dict[str, int]:
-        """Batched `routing_version` (one round trip for HTTP deployments)."""
-        return {t: self.routing_version(t) for t in tables}
+    def _route_token(self, table: str, rt_name: str | None) -> str:
+        twin = self.routing_version(rt_name) if rt_name else 0
+        return f"{self.routing_version(table)}.{twin}.{self.store.counter(INSTANCES_VERSION_PATH)}"
+
+    def route_snapshot(self, table: str, have: str | None = None) -> RouteSnapshot | None:
+        """Everything a broker routes a query on `table` from, as one
+        document under one token (`routing.RouteSnapshot`): the configs of
+        the table and of its `_REALTIME` twin (None where there is none), the
+        schema, every segment's metadata and the ideal state of both, and the
+        server handles. None where `have` is the current token: the caller's
+        document still stands. The document is read in one section of the
+        store that no write lands in, and every write that it holds moves the
+        token in the store call that makes it, so token and content agree."""
+        rt_name = None if table.endswith("_REALTIME") else f"{table}_REALTIME"
+        if have is not None and have == self._route_token(table, rt_name):
+            return None
+        with self.store.consistent_read():
+            physical = [table] + ([rt_name] if rt_name else [])
+            instances = self.instances()
+            return RouteSnapshot(
+                table,
+                self._route_token(table, rt_name),
+                self.get_table(table),
+                self.get_table(rt_name) if rt_name else None,
+                self.get_schema(table) or (self.get_schema(rt_name) if rt_name else None),
+                {t: self.all_segment_metadata(t) for t in physical},
+                {t: self.ideal_state(t) for t in physical},
+                self.servers(instances),
+                instances,
+            )
 
     def get_table(self, name: str) -> TableConfig | None:
         doc = self.store.get(f"/tables/{name}/config")
@@ -289,8 +340,10 @@ class Controller:
             from pinot_tpu.cluster.dimension import unregister_dim_table
 
             unregister_dim_table(name)
+        counter = routing_version_path(name)
         for p in list(self.store.list(f"/tables/{name}/")):
-            self.store.delete(p, fence=self.lease_fence())
+            if p != counter:  # it outlives the table: a table made again repeats no token
+                self.store.delete(p, fence=self.lease_fence(), bump=counter)
         return len(segs)
 
     def delete_schema(self, name: str) -> None:
@@ -298,7 +351,7 @@ class Controller:
         still uses it — the reference's referential guard."""
         if name in self.tables():
             raise ValueError(f"schema {name!r} is still used by table {name!r}; delete the table first")
-        self.store.delete(f"/schemas/{name}", fence=self.lease_fence())
+        self.store.delete(f"/schemas/{name}", fence=self.lease_fence(), bump=routing_version_path(name))
 
     # -- segment upload & assignment ----------------------------------------
 
@@ -502,8 +555,8 @@ class Controller:
     ) -> list[str]:  # fmt: skip
         """The assign half, shared by both entries: the segment's servers
         chosen, its metadata written and its entry put into the ideal state
-        as one step (`_assign_lock`), the routing version, then the servers'
-        state transitions."""
+        as one step (`_assign_lock`), each write moving the routing version
+        with it, then the servers' state transitions."""
         from pinot_tpu.common.trace import span
 
         seg_meta = {"numDocs": n_docs, "location": str(seg_dir), "stats": stats}
@@ -524,15 +577,14 @@ class Controller:
                 with self._assign_lock:
                     assigned = self._assign(table, config.replication)
                     seg_meta.update(servers=assigned, uploadedAt=time.time())
-                    self.store.set(f"/tables/{table}/segments/{name}", seg_meta, fence=self.lease_fence())
+                    self.write_segment_metadata(table, name, seg_meta)
                     loading = [(table, name, sid) for sid in assigned]
                     self._loading.update(loading)  # before the ideal state shows them: the reconciler leaves them be
 
                     def enter(ideal: dict | None) -> dict:
                         return {**(ideal or {}), name: dict.fromkeys(assigned, "ONLINE")}
 
-                    self.store.update(f"/tables/{table}/idealstate", enter, fence=self.lease_fence())
-                self.bump_routing_version(table)
+                    self._update_ideal_state(table, enter)
             # state transition: servers load the segment from the deep store.
             # With HA enabled, a failing server falls back to the durable retry
             # queue instead of failing the upload (Helix async transition analog).
@@ -637,8 +689,7 @@ class Controller:
             replicas.update(ideal.pop(segment_name, {}))
             return ideal
 
-        self.store.update(f"/tables/{table}/idealstate", drop, fence=self.lease_fence())
-        self.bump_routing_version(table)
+        self._update_ideal_state(table, drop)
         if self._transitions is not None:
             self._transitions.cancel(table, segment_name)
         handles = self.servers()
@@ -647,7 +698,9 @@ class Controller:
             if srv is not None:
                 srv.remove_segment(table, segment_name)
         meta = self.store.get(f"/tables/{table}/segments/{segment_name}")
-        self.store.delete(f"/tables/{table}/segments/{segment_name}", fence=self.lease_fence())
+        self.store.delete(
+            f"/tables/{table}/segments/{segment_name}", fence=self.lease_fence(), bump=routing_version_path(table)
+        )
         if remove_from_deep_store and meta and meta.get("location"):
             import shutil
 
@@ -683,8 +736,7 @@ class Controller:
             if keep:
                 new_meta = self.segment_metadata(table, name) or {}
                 new_meta.update(keep)
-                self.store.set(f"/tables/{table}/segments/{name}", new_meta, fence=self.lease_fence())
-                self.bump_routing_version(table)
+                self.write_segment_metadata(table, name, new_meta)
             reloaded.append(name)
         return reloaded
 
@@ -727,8 +779,24 @@ class Controller:
                 ideal.pop(segment, None)
             return ideal
 
-        self.store.update(f"/tables/{table}/idealstate", change, fence=self.lease_fence())
-        self.bump_routing_version(table)
+        self._update_ideal_state(table, change)
+
+    def _update_ideal_state(self, table: str, fn) -> None:
+        """The one way an ideal state changes: the write and the routing
+        version's move are one step of the store (with the move as a call of
+        its own after the write, a broker asking in between is told
+        "unchanged" about an ideal state that has changed)."""
+        self.store.update(
+            f"/tables/{table}/idealstate", fn, fence=self.lease_fence(), bump=routing_version_path(table)
+        )
+
+    def write_segment_metadata(self, table: str, segment: str, meta: dict) -> None:
+        """Write one segment's metadata document; the routing version moves
+        with it (fenced: a writer outliving this controller's lease must not
+        overwrite what the new lead has since written)."""
+        self.store.set(
+            f"/tables/{table}/segments/{segment}", meta, fence=self.lease_fence(), bump=routing_version_path(table)
+        )
 
     # -- views ---------------------------------------------------------------
 

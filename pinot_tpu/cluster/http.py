@@ -973,6 +973,11 @@ class RemoteServerClient:
         self.timeout = timeout
         self._host, self._port = _host_port(self.base_url)
 
+    @classmethod
+    def of_instance(cls, doc: dict) -> "RemoteServerClient":
+        """The client of the server an instance document (`/instances/{id}`) names."""
+        return cls(f"http://{doc['host']}:{doc['port']}")
+
     def _hop_timeout(self, hints: dict | None) -> float:
         """Per-call socket timeout: the query deadline riding in the hints
         markers (+0.5s grace so the server-side deadline error wins the race
@@ -1188,6 +1193,7 @@ class ControllerHTTPService:
 
       GET  /health | /health/ready | /tables | /tables/{t} | /tables/{t}/schema
            /tables/{t}/idealstate | /tables/{t}/segments | /brokers | /instances
+           /tables/{t}/route?have=<token>   (the broker's route snapshot)
            /tasks?state=... | /debug/cluster | /debug/alerts
       POST /schemas            {schema json}
       POST /tables             {table config json}
@@ -1311,18 +1317,19 @@ class ControllerHTTPService:
                     elif len(parts) == 3 and parts[0] == "tables" and parts[2] == "schema":
                         sch = c.get_schema(parts[1])
                         self._json(json.loads(sch.to_json()) if sch else {"error": "not found"}, 200 if sch else 404)
+                    elif len(parts) == 3 and parts[0] == "tables" and parts[2] == "route":
+                        # the broker's one call a query: {"unchanged": true}
+                        # where `have` is the current token, else the table's
+                        # whole route snapshot
+                        from urllib.parse import parse_qs
+
+                        have = parse_qs(self.path.partition("?")[2]).get("have", [None])[0]
+                        snap = c.route_snapshot(parts[1], have=have)
+                        self._json({"unchanged": True} if snap is None else snap.to_doc())
                     elif len(parts) == 3 and parts[0] == "tables" and parts[2] == "idealstate":
                         self._json(c.ideal_state(parts[1]))
                     elif len(parts) == 3 and parts[0] == "tables" and parts[2] == "segments":
                         self._json(c.all_segment_metadata(parts[1]))
-                    elif self.path.partition("?")[0] == "/routingversions":
-                        # batched version-vector read for broker cache keys:
-                        # one RTT regardless of how many tables a query touches
-                        from urllib.parse import parse_qs
-
-                        qs = parse_qs(self.path.partition("?")[2])
-                        names = [t for t in (qs.get("tables", [""])[0]).split(",") if t]
-                        self._json(c.routing_versions(names))
                     elif len(parts) == 3 and parts[0] == "tables" and parts[2] == "consumingSegmentsInfo":
                         info = {}
                         for sid, srv in c.servers().items():
@@ -1334,7 +1341,7 @@ class ControllerHTTPService:
                     elif self.path == "/brokers":
                         self._json(c.brokers())
                     elif self.path == "/instances":
-                        self._json({p.split("/")[-1]: c.store.get(p) for p in c.store.list("/instances/")})
+                        self._json(c.instances())
                     elif parts and parts[0] == "tasks" and svc.task_manager is not None:
                         self._json(
                             [
@@ -1615,13 +1622,16 @@ class RemoteControllerClient:
     def segment_metadata(self, table: str, segment: str) -> dict | None:
         return self.all_segment_metadata(table).get(segment)
 
-    def routing_versions(self, tables: list[str]) -> dict[str, int]:
-        if not tables:
-            return {}
-        return {t: int(v) for t, v in self._get(f"/routingversions?tables={','.join(tables)}").items()}
+    def route_snapshot(self, table: str, have: str | None = None):
+        """`Controller.route_snapshot` over REST: None where `have` still
+        stands, else the snapshot rebuilt from its document, a
+        RemoteServerClient a server."""
+        from pinot_tpu.cluster.routing import RouteSnapshot
 
-    def routing_version(self, table: str) -> int:
-        return self.routing_versions([table]).get(table, 0)
+        doc = self._get(f"/tables/{table}/route" + (f"?have={have}" if have else ""))
+        if doc.get("unchanged"):
+            return None
+        return RouteSnapshot.from_doc(doc, RemoteServerClient.of_instance)
 
     def get_table(self, name: str):
         from pinot_tpu.common.config import TableConfig
@@ -1645,7 +1655,7 @@ class RemoteControllerClient:
         out = {}
         for sid, doc in self._get("/instances").items():
             if doc and doc.get("port"):
-                out[sid] = RemoteServerClient(f"http://{doc['host']}:{doc['port']}")
+                out[sid] = RemoteServerClient.of_instance(doc)
         return out
 
     def add_schema(self, schema) -> None:

@@ -34,6 +34,7 @@ Layout:
   /tables/{name}/config                -> TableConfig json
   /tables/{name}/idealstate            -> {segment: {server: "ONLINE"|"CONSUMING"}}
   /tables/{name}/segments/{segment}    -> segment zk metadata (docs, stats, location)
+  /tables/{name}/routingversion       -> {"v": n}, a counter that a write to any of the four above moves
   /instances/{server}                  -> instance config (host, port, alive)
   /controllers/{cid}                   -> controller endpoint (host, port)
   /controllers/lease                   -> {owner, expires, epoch} lead lease
@@ -186,16 +187,47 @@ class PropertyStore:
                 current_epoch=current,
             )
 
+    def _bump(self, counter: str | None) -> int:
+        """Move the counter document `counter` ({"v": n}) by one and return
+        n. The caller holds the exclusive section and has written what the
+        counter stands for just before: to the readers of this process, and
+        to whoever reads inside `consistent_read`, the write and the count
+        are one step."""
+        if counter is None:
+            return 0
+        cur, ver = self._read_versioned(counter)
+        n = int((cur or {}).get("v", 0)) + 1
+        self._write(counter, {"v": n}, ver + 1)
+        return n
+
     # -- public surface ---------------------------------------------------------
 
-    def set(self, path: str, doc: dict, fence: int | None = None) -> int:
+    def set(self, path: str, doc: dict, fence: int | None = None, bump: str | None = None) -> int:
         """Write `doc`, stamping version = current + 1. Returns the version
-        written. `fence` (a lease epoch) rejects stale ex-leader writes."""
+        written. `fence` (a lease epoch) rejects stale ex-leader writes.
+        `bump` names a counter document moved in the same section (`_bump`)."""
         with self._exclusive(path):
             self._check_fence(path, fence)
             _, ver = self._read_versioned(path)
             self._write(path, doc, ver + 1)
+            self._bump(bump)
             return ver + 1
+
+    def bump(self, counter: str, fence: int | None = None) -> int:
+        """Move a counter document by one with no other write; its new value."""
+        with self._exclusive(counter):
+            self._check_fence(counter, fence)
+            return self._bump(counter)
+
+    def counter(self, counter: str) -> int:
+        """A counter document's value (0 where it was never moved)."""
+        return int((self.get(counter) or {}).get("v", 0))
+
+    def consistent_read(self):
+        """A section in which no write of any process lands: what is read
+        inside it is one state of the store. For a reader of several
+        documents that must agree with a counter (`Controller.route_snapshot`)."""
+        return self._exclusive()
 
     def get(self, path: str) -> dict | None:
         with self._lock:
@@ -207,12 +239,13 @@ class PropertyStore:
         with self._lock:
             return self._read_versioned(path)
 
-    def update(self, path: str, fn, fence: int | None = None) -> dict | None:
+    def update(self, path: str, fn, fence: int | None = None, bump: str | None = None) -> dict | None:
         """Atomic read-modify-write under the store's exclusive section
         (thread lock + cross-process flock): fn(current_doc) -> new doc to
         write, or None to leave unchanged. Returns what was written (or
         None). This is the CAS primitive leader leases and external-view
-        updates build on (ZK versioned-write analog)."""
+        updates build on (ZK versioned-write analog). `bump` as in `set`:
+        moved only where something was written."""
         try:
             FAULTS.maybe_fail("store.cas")
         except InjectedFault:
@@ -226,6 +259,7 @@ class PropertyStore:
             if new is not None:
                 self._check_fence(path, fence)
                 self._write(path, new, ver + 1)
+                self._bump(bump)
             return new
 
     def _update_lease(self, fn) -> dict | None:
@@ -260,7 +294,7 @@ class PropertyStore:
             self._write(path, doc, ver + 1)
             return True
 
-    def delete(self, path: str, fence: int | None = None) -> None:
+    def delete(self, path: str, fence: int | None = None, bump: str | None = None) -> None:
         with self._exclusive(path):
             self._check_fence(path, fence)
             if self.root is None:
@@ -270,6 +304,7 @@ class PropertyStore:
                 f = self._file(path)
                 if f.exists():
                     f.unlink()
+            self._bump(bump)
 
     def list(self, prefix: str) -> list[str]:
         with self._lock:

@@ -275,10 +275,7 @@ class IntegrityScrubber(ControllerPeriodicTask):
             meta["fileCrc"] = crc
             # fenced: a scrubber sweep outliving this controller's lease
             # must not overwrite metadata the new lead has since rewritten
-            self.controller.store.set(
-                f"/tables/{table}/segments/{name}", meta, fence=self.controller.lease_fence()
-            )
-            self.controller.bump_routing_version(table)
+            self.controller.write_segment_metadata(table, name, meta)
             logging.getLogger("pinot_tpu.storage").warning(
                 "re-replicated corrupt deep-store copy of %s/%s from %s", table, name, sid
             )

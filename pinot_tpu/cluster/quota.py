@@ -44,8 +44,9 @@ class QueryQuotaManager:
         self._lock = threading.Lock()
         self.rejected = 0  # lifetime rejections (debug/admission snapshot)
 
-    def _qps_limit(self, table: str) -> float | None:
-        config = self._controller.get_table(table)
+    @staticmethod
+    def _qps_limit(snapshot) -> float | None:
+        config = snapshot.offline_cfg
         if config is None:
             return None
         q = (config.extra or {}).get("queryQuotaQps")
@@ -66,24 +67,26 @@ class QueryQuotaManager:
         ).mark()
         raise QuotaExceededError(message, retry_after_s=1.0)
 
-    def _tenant_of(self, table: str) -> str:
+    @staticmethod
+    def _tenant_of(snapshot) -> str:
         from pinot_tpu.cluster.tenancy import table_tenants
 
-        config = self._controller.get_table(table) or self._controller.get_table(
-            f"{table}_REALTIME"
-        )
+        config = snapshot.offline_cfg or snapshot.rt_cfg
         return table_tenants(config)[1] if config is not None else ""
 
-    def acquire(self, table: str, tenant: str | None = None) -> None:
+    def acquire(self, table: str, tenant: str | None = None, snapshot=None) -> None:
         """Admit or reject one query against the table's QPS quota and (when
-        configured) the owning tenant's aggregate QPS quota. The tenant is
-        resolved from the table config when not supplied — and only when
-        tenant quotas exist, so the common no-quota path stays one lookup."""
-        limit = self._qps_limit(table)
+        configured) the owning tenant's aggregate QPS quota. The configs are
+        those of `snapshot`, the table's route snapshot (the broker passes the
+        one it confirmed for this query; without one the controller is asked).
+        The tenant is resolved from them when not supplied."""
+        if snapshot is None:
+            snapshot = self._controller.route_snapshot(table)
+        limit = self._qps_limit(snapshot)
         tenant_limit = None
         if self._tenant_qps:
             if tenant is None:
-                tenant = self._tenant_of(table)
+                tenant = self._tenant_of(snapshot)
             tenant_limit = self._tenant_qps.get(tenant)
         tenant = tenant or ""
         if limit is None and tenant_limit is None:

@@ -233,10 +233,7 @@ def rebalance_table(
                 meta["servers"] = sorted(target[seg])
                 # fenced: a rebalance surviving on a stale ex-leader (lease
                 # lost mid-move) must not clobber the new lead's placement
-                controller.store.set(
-                    f"/tables/{table}/segments/{seg}", meta, fence=controller.lease_fence()
-                )
-                controller.bump_routing_version(table)
+                controller.write_segment_metadata(table, seg, meta)
         _progress_update(
             table,
             status="DONE",

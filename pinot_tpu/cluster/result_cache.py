@@ -9,8 +9,9 @@ CacheConfig and one labelled meter family
 - **Result cache** — bounded LRU of reduced responses, keyed on
   (normalized SQL, option fingerprint, per-table routing version vector).
   Invalidation is implicit: every segment-set mutation (upload, refresh,
-  delete, rebalance move, realtime commit) bumps the owning table's routing
-  version (Controller.bump_routing_version), which changes the key; the
+  delete, rebalance move, realtime commit) moves the owning table's routing
+  version with the write itself (`bump=` of the store call; the version is
+  part of the token of the table's route snapshot), which changes the key; the
   superseded entry is detected on the next lookup, counted as an
   invalidation, and dropped. Entries are byte-bounded (`maxBytes`) and a
   result touching a table with an active consuming segment carries a TTL cap

@@ -300,3 +300,103 @@ def _with_time_predicate(sql: str, predicate: str) -> str:
     tail = _search_outside_quotes(_TAIL, sql)
     pos = tail.start() if tail else len(sql)
     return sql[:pos].rstrip() + f" WHERE {predicate} " + sql[pos:]
+
+
+# -- the route snapshot --------------------------------------------------------
+
+
+class RouteSnapshot:
+    """Everything the broker reads of the controller to route a query on one
+    logical table, as of one `token`: the configs of the table and of its
+    `_REALTIME` twin (None where there is none), the schema, every segment's
+    metadata and the ideal state of each physical table, and the server
+    handles. `Controller.route_snapshot` builds it and
+    `RemoteControllerClient.route_snapshot` rebuilds it from `to_doc`; the
+    broker holds one a table and asks once a query whether its token still
+    stands (upstream's broker likewise routes from a routing table kept in
+    memory and rebuilt when the external view changes). Read-only once built:
+    queries on other threads route from the same object."""
+
+    def __init__(self, table, token, offline_cfg, rt_cfg, schema, meta, ideal, servers, instances):
+        self.table = table
+        self.token = token
+        self.offline_cfg = offline_cfg
+        self.rt_cfg = rt_cfg
+        self.schema = schema
+        #: physical table -> {segment: metadata}; physical table -> ideal state
+        self.meta: dict[str, dict[str, dict]] = meta
+        self.ideal: dict[str, dict[str, dict[str, str]]] = ideal
+        #: server id -> handle (in-process object or RemoteServerClient)
+        self.servers: dict[str, object] = servers
+        #: server id -> instance document (what `to_doc` ships of the servers)
+        self.instances: dict[str, dict] = instances
+        rt_name = f"{table}_REALTIME"
+        #: hybrid split, TimeBoundaryManager parity: offline <= boundary < realtime
+        self.time_boundary: TimeBoundary | None = None
+        if offline_cfg is not None and rt_cfg is not None and offline_cfg.time_column:
+            self.time_boundary = TimeBoundary.compute(meta.get(table, {}), offline_cfg.time_column)
+            self.leg_tables = [rt_name] if self.time_boundary is None else [table, rt_name]
+        elif offline_cfg is not None:
+            self.leg_tables = [table]
+        else:
+            self.leg_tables = [rt_name]
+        #: the metadata of every segment a query's legs can touch, and its rows
+        self.all_meta: dict[str, dict] = {}
+        for t in self.leg_tables:
+            self.all_meta.update(meta.get(t, {}))
+        self.total_docs = sum(m.get("numDocs", 0) for m in self.all_meta.values())
+
+    @property
+    def exists(self) -> bool:
+        return self.offline_cfg is not None or self.rt_cfg is not None
+
+    def legs(self, sql: str) -> list[tuple[str, str]]:
+        """(physical table, sql text) of each leg of a query."""
+        if len(self.leg_tables) == 2:
+            return [
+                (self.leg_tables[0], self.time_boundary.offline_sql(sql)),
+                (self.leg_tables[1], self.time_boundary.realtime_sql(sql)),
+            ]
+        return [(self.leg_tables[0], sql)]
+
+    def has_consuming(self) -> bool:
+        """Some segment of an ideal state has no committed metadata yet: it
+        is consuming, and its rows advance with no write the token sees."""
+        return any(s not in self.meta.get(t, {}) for t, ideal in self.ideal.items() for s in ideal)
+
+    def to_doc(self) -> dict:
+        """The JSON form (`GET /tables/{t}/route`): handles stay behind, the
+        instance documents say where the servers listen."""
+        return {
+            "table": self.table,
+            "token": self.token,
+            "offlineConfig": self.offline_cfg.to_json() if self.offline_cfg is not None else None,
+            "realtimeConfig": self.rt_cfg.to_json() if self.rt_cfg is not None else None,
+            "schema": self.schema.to_json() if self.schema is not None else None,
+            "segments": self.meta,
+            "idealStates": self.ideal,
+            "instances": self.instances,
+        }
+
+    @classmethod
+    def from_doc(cls, doc: dict, make_handle) -> "RouteSnapshot":
+        """Rebuild from `to_doc`; `make_handle(instance document)` gives the
+        handle of each server that listens on a port."""
+        from pinot_tpu.common.config import TableConfig
+        from pinot_tpu.common.types import Schema
+
+        def config(key):
+            return TableConfig.from_json(doc[key]) if doc.get(key) else None
+
+        instances = doc.get("instances") or {}
+        return cls(
+            doc["table"],
+            doc["token"],
+            config("offlineConfig"),
+            config("realtimeConfig"),
+            Schema.from_json(doc["schema"]) if doc.get("schema") else None,
+            doc.get("segments") or {},
+            doc.get("idealStates") or {},
+            {sid: make_handle(d) for sid, d in instances.items() if d and d.get("port")},
+            instances,
+        )

@@ -583,6 +583,10 @@ class BrokerMeter(Enum):
     HEDGE_ISSUED = "broker.hedge.issued"
     HEDGE_WON = "broker.hedge.won"
     HEDGE_WASTED = "broker.hedge.wasted"
+    # routing state: requests made of the controller (one a query where the
+    # held route snapshot stands), and the snapshots fetched among them
+    CONTROLLER_CALLS = "broker.controllerCalls"
+    ROUTE_SNAPSHOT_FETCHES = "broker.routeSnapshotFetches"
 
 
 class BrokerGauge(Enum):

@@ -1,23 +1,29 @@
-"""cache-invalidation: segment-set mutations must bump the routing version.
+"""cache-invalidation: a write to a table's routing state moves its token.
 
-The broker's result and plan caches (cluster/result_cache.py) key on each
-table's routing version vector instead of an explicit flush protocol: any
-code path that mutates a table's segment set — upload, delete, refresh,
-rebalance move, realtime commit, deep-store repair — must call
-`bump_routing_version(table)` or a cached response computed against the old
-segment set keeps being served forever. That is a silent-staleness bug: no
-error, no metric, just wrong rows.
+The broker routes every query from a *route snapshot* of the table that it
+holds (`cluster/routing.py` `RouteSnapshot`: configs, schema, segment
+metadata, ideal states, server instances) and asks the controller once a
+query whether the snapshot's token still stands; its result and plan caches
+(cluster/result_cache.py) key on the same token. The token is made of counter
+documents: a table's routing version and the instances' version. A write to
+anything the snapshot holds that leaves its counter where it was is a
+silent-staleness bug: the broker is told "unchanged", routes on the old
+state and serves cached rows of it forever — no error, no metric, just wrong
+rows.
 
-Rule: a function whose body issues a PropertyStore segment-set write — a
-`*.store.set(...)` / `*.store.update(...)` call whose argument tree carries a
-string constant containing `idealstate` or `/segments/` — must also contain a
-`bump_routing_version(...)` call (any receiver). Detection is syntactic, in
-the atomic-write mold: path strings assembled in a separate statement escape
-the net, and a bump behind a helper called from the same function must be
-suppressed with a reasoned `# pinotlint: disable=cache-invalidation — <why>`.
+Rule: a PropertyStore write — a `*.store.set(...)` / `.update(...)` /
+`.delete(...)` call, receiver named `store` or `*_store` — whose argument
+tree carries a string constant containing `idealstate`, `/segments/`,
+`/config`, `/schemas/` or `/instances/` must name the counter it moves as
+`bump=` **of that call**: the store then writes and counts in one section, so
+no reader sees the one without the other. A `bump_routing_version(...)` call
+somewhere else in the function does not do: it leaves a window between the
+write and the count. Detection is syntactic, in the
+atomic-write mold: path strings assembled in a separate statement escape the
+net, and a write that needs no count carries a reasoned
+`# pinotlint: disable=cache-invalidation — <why>`.
 
-Exempt: cluster/metadata.py (the store itself) and the function that IS the
-bump (writes the `/routingversion` doc through the same store API).
+Exempt: cluster/metadata.py (the store itself).
 """
 
 from __future__ import annotations
@@ -26,8 +32,8 @@ import ast
 
 from pinot_tpu.devtools.lint.core import Checker, Finding, ModuleInfo
 
-#: path substrings that mark a store write as a segment-set mutation
-_MUTATION_MARKERS = ("idealstate", "/segments/")
+#: path substrings that mark a store write as one to what a route snapshot holds
+_MUTATION_MARKERS = ("idealstate", "/segments/", "/config", "/schemas/", "/instances/")
 
 
 def _mutation_marker_in(node: ast.AST) -> str | None:
@@ -40,11 +46,11 @@ def _mutation_marker_in(node: ast.AST) -> str | None:
 
 
 def _is_store_write(node: ast.Call) -> bool:
-    """`<expr>.store.set(...)`/`.update(...)` or a bare `store.set(...)` —
-    receiver must END in `store` so e.g. `self.caches.result.set` never
-    matches."""
+    """`<expr>.store.set(...)`/`.update(...)`/`.delete(...)` or a bare
+    `store.set(...)` — receiver must END in `store` so e.g.
+    `self.caches.result.set` never matches."""
     f = node.func
-    if not (isinstance(f, ast.Attribute) and f.attr in ("set", "update")):
+    if not (isinstance(f, ast.Attribute) and f.attr in ("set", "update", "delete")):
         return False
     recv = f.value
     if isinstance(recv, ast.Attribute):
@@ -54,16 +60,8 @@ def _is_store_write(node: ast.Call) -> bool:
     return False
 
 
-def _calls_bump(fn: ast.AST) -> bool:
-    for c in ast.walk(fn):
-        if isinstance(c, ast.Call):
-            f = c.func
-            name = f.attr if isinstance(f, ast.Attribute) else (
-                f.id if isinstance(f, ast.Name) else None
-            )
-            if name == "bump_routing_version":
-                return True
-    return False
+def _names_its_counter(node: ast.Call) -> bool:
+    return any(kw.arg == "bump" for kw in node.keywords)
 
 
 class CacheInvalidationChecker(Checker):
@@ -73,29 +71,21 @@ class CacheInvalidationChecker(Checker):
         p = module.path.replace("\\", "/")
         if p.endswith("cluster/metadata.py"):
             return []  # the PropertyStore itself
-        out: list[Finding] = []
+        found: dict[int, Finding] = {}  # by line: a nested function's write is named for the innermost
         for fn in ast.walk(module.tree):
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
-            if fn.name == "bump_routing_version":
-                continue  # the sanctioned version writer
-            writes = []
             for node in ast.walk(fn):
-                if isinstance(node, ast.Call) and _is_store_write(node):
-                    marker = _mutation_marker_in(node)
-                    if marker:
-                        writes.append((node, marker))
-            if writes and not _calls_bump(fn):
-                for node, marker in writes:
-                    out.append(
-                        Finding(
-                            self.name,
-                            module.path,
-                            node.lineno,
-                            f"segment-set mutation ({marker!r} store write) in "
-                            f"{fn.name}() without a bump_routing_version() call: "
-                            "the broker result/plan caches key on the routing "
-                            "version and will serve stale responses forever",
-                        )
+                if not (isinstance(node, ast.Call) and _is_store_write(node)) or _names_its_counter(node):
+                    continue
+                marker = _mutation_marker_in(node)
+                if marker:
+                    found[node.lineno] = Finding(
+                        self.name,
+                        module.path,
+                        node.lineno,
+                        f"routing-state write ({marker!r} store write) in {fn.name}() without "
+                        "`bump=`: the broker's route snapshot and its result/plan caches key on "
+                        "the counter and will route and serve on stale state forever",
                     )
-        return out
+        return [found[line] for line in sorted(found)]

@@ -230,8 +230,7 @@ class RefreshSegmentTaskExecutor(PinotTaskExecutor):
         controller.upload_segment(table, rebuilt)
         meta = controller.segment_metadata(table, name)
         meta["refreshEpoch"] = task.configs["epoch"]
-        controller.store.set(f"/tables/{table}/segments/{name}", meta)
-        controller.bump_routing_version(table)
+        controller.write_segment_metadata(table, name, meta)
         return {"refreshed": name}
 
 

@@ -628,8 +628,7 @@ class RealtimeTableManager:
             meta["startOffset"] = start_off
             meta["endOffset"] = end_off
             meta["partition"] = partition
-            self.controller.store.set(f"/tables/{self.table}/segments/{segment.name}", meta)
-            self.controller.bump_routing_version(self.table)
+            self.controller.write_segment_metadata(self.table, segment.name, meta)
             self._record_stats_history(segment)
 
         return commit
@@ -650,8 +649,7 @@ class RealtimeTableManager:
                 "servers": [self.server.server_id],
                 "peerDownload": self.server.server_id,
             }
-            self.controller.store.set(f"/tables/{self.table}/segments/{segment.name}", meta)
-            self.controller.bump_routing_version(self.table)
+            self.controller.write_segment_metadata(self.table, segment.name, meta)
             self._record_stats_history(segment)
 
         return peer_commit

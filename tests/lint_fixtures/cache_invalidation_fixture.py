@@ -1,7 +1,5 @@
-"""Golden fixture for the cache-invalidation checker: segment-set store
-writes (idealstate / deep-store segment metadata paths) with and without the
-required `bump_routing_version()` call that invalidates the broker's
-result/plan caches."""
+"""Golden fixture for the cache-invalidation checker: writes to what a route
+snapshot holds, with and without the `bump=` that moves its token with them."""
 
 
 class FakeController:
@@ -12,15 +10,15 @@ class FakeController:
     def upload_without_bump(self, table, seg):
         ideal = self.store.get(f"/tables/{table}/idealstate") or {}
         ideal[seg] = ["s1"]
-        self.store.set(f"/tables/{table}/idealstate", ideal)  # line 15: VIOLATION
+        self.store.set(f"/tables/{table}/idealstate", ideal)  # line 13: VIOLATION
 
     def refresh_without_bump(self, table, seg, meta):
-        self.meta_store.update(  # line 18: VIOLATION
+        self.meta_store.update(  # line 16: VIOLATION
             f"/tables/{table}/segments/{seg}", lambda cur: meta
         )
 
     def upload_with_bump(self, table, seg):
-        self.store.set(f"/tables/{table}/idealstate", {seg: ["s1"]})  # CLEAN
+        self.store.set(f"/tables/{table}/idealstate", {seg: ["s1"]})  # line 21: VIOLATION, the count is a step of its own
         self.bump_routing_version(table)
 
     def bump_routing_version(self, table):
@@ -37,3 +35,15 @@ class FakeController:
 
     def suppressed_write(self, table):
         self.store.set(f"/tables/{table}/idealstate", {})  # pinotlint: disable=cache-invalidation — fixture: bump lives in the caller
+
+    def write_that_counts(self, table, seg, meta):
+        counter = f"/tables/{table}/routingversion"
+        self.store.set(f"/tables/{table}/segments/{seg}", meta, bump=counter)  # CLEAN
+        self.store.update(f"/tables/{table}/idealstate", lambda cur: cur, bump=counter)  # CLEAN
+        self.store.delete(f"/tables/{table}/segments/{seg}", bump=counter)  # CLEAN
+
+    def other_routing_state(self, table, sid, doc):
+        self.store.set(f"/schemas/{table}", doc)  # line 46: VIOLATION
+        self.store.set(f"/instances/{sid}", doc)  # line 47: VIOLATION
+        self.store.set(f"/tables/{table}/config", doc)  # line 48: VIOLATION
+        self.store.delete(f"/tables/{table}/segments/s")  # line 49: VIOLATION

@@ -133,14 +133,18 @@ def test_kernel_registry_ignores_off_kernel_path():
 
 def test_cache_invalidation_fixture_findings():
     fs = findings_for("cache_invalidation_fixture.py", checks=["cache-invalidation"])
-    assert lines_of(fs, "cache-invalidation") == [15, 18]
-    assert all("bump_routing_version" in f.message for f in fs)
+    assert lines_of(fs, "cache-invalidation") == [13, 16, 21, 46, 47, 48, 49]
+    assert all("`bump=`" in f.message for f in fs)
     by_line = {f.line: f.message for f in fs}
-    assert "'idealstate'" in by_line[15]  # idealstate replace without a bump
-    assert "'/segments/'" in by_line[18]  # segment-metadata update without a bump
-    # upload_with_bump, the bump itself, reads, non-segment paths, non-store
-    # receivers, and the suppressed write must all stay quiet
-    for clean in ("upload_with_bump", "bump_routing_version", "read_only_paths",
+    assert "'idealstate'" in by_line[13]  # idealstate replace without a count
+    assert "'/segments/'" in by_line[16]  # segment-metadata update without a count
+    assert "in upload_with_bump()" in by_line[21]  # the count as a step of its own leaves a window
+    # schema, instance, config and a delete are routing state too (PR 30)
+    for line, marker in ((46, "'/schemas/'"), (47, "'/instances/'"), (48, "'/config'"), (49, "'/segments/'")):
+        assert marker in by_line[line]
+    # writes that name their counter, the bump itself, reads, paths no
+    # snapshot holds, non-store receivers, and the suppressed write stay quiet
+    for clean in ("write_that_counts", "bump_routing_version", "read_only_paths",
                   "suppressed_write"):
         assert not any(f"in {clean}()" in f.message for f in fs)
 
@@ -337,14 +341,15 @@ def test_fence_mutation_is_caught(tmp_path):
     tree = tmp_path / "pinot_tpu"
     shutil.copytree(PACKAGE, tree)
     assert lint_paths([str(tree)], checks=["fence-discipline"]) == []
-    target = tree / "cluster" / "rebalance.py"
+    target = tree / "cluster" / "controller.py"
     src = target.read_text()
-    mutated = src.replace(", fence=controller.lease_fence()", "")
-    assert mutated != src  # the mutation actually landed
+    fenced = '{"host": host, "port": port}, fence=self.lease_fence())'  # register_broker's write
+    assert src.count(fenced) == 1
+    mutated = src.replace(fenced, '{"host": host, "port": port})')
     target.write_text(mutated)
     fs = lint_paths([str(tree)], checks=["fence-discipline"])
     assert len(fs) == 1, "\n".join(str(f) for f in fs)
-    assert fs[0].path.endswith("rebalance.py")
+    assert fs[0].path.endswith("controller.py")
     assert "omits fence=" in fs[0].message
 
 
