@@ -72,10 +72,36 @@ class _PartialState:
         self.exceptions: list[dict] = []
         self.servers_queried = 0
         self.servers_responded = 0
+        #: ids of the servers whose answers the result holds
+        self.responded: set[str] = set()
+        #: legs sent again to another replica inside the query: the first choice was unreachable or short
+        self.legs_failed_over = 0
+        #: re-routes on a newer snapshot after a server said it does not host what it was routed
+        self.stale_route_retries = 0
+
+    def heard(self, plan: dict, failed_servers) -> None:
+        """One round of a scatter: every server of `plan` was asked, those not in `failed_servers` answered."""
+        bad = set(failed_servers)
+        self.servers_queried += len(plan)
+        self.servers_responded += len(plan) - len(bad)
+        self.responded.update(sid for sid in plan if sid not in bad)
+
+    def failed_over(self, legs: int) -> None:
+        from pinot_tpu.common.metrics import BrokerMeter, broker_metrics
+
+        self.legs_failed_over += legs
+        broker_metrics().meter(BrokerMeter.LEGS_FAILED_OVER).mark(legs)
 
     def record(self, message: str, error_code: int = QueryErrorCode.QUERY_EXECUTION) -> None:
         self.partial = True
         self.exceptions.append({"errorCode": error_code, "message": message})
+
+
+class _ShortAnswerError(RuntimeError):
+    """A server answered fewer segments than it was routed: the route is older
+    than the server's segment set. The scatter sends the leg to another
+    replica inside the query; where there is none this error stands, and
+    `_scatter_leg` routes anew on the controller's next snapshot."""
 
 
 class Broker:
@@ -149,6 +175,8 @@ class Broker:
         )
         #: queried table -> the route snapshot held for it (`_route_snapshot`)
         self._snapshots: dict[str, RouteSnapshot] = {}
+        #: server id -> the session (registration count) of its instance document, as last seen
+        self._sessions: dict[str, int] = {}
         self.cache_config = cache_config if cache_config is not None else CacheConfig()
         #: QueryCaches (result/parse/plan tiers + single-flight), or None
         #: when CacheConfig.enabled is False — every cache branch in the
@@ -250,6 +278,14 @@ class Broker:
     @staticmethod
     def _is_failed_marker(r) -> bool:
         return isinstance(r, tuple) and bool(r) and r[0] == "__failed__"
+
+    @staticmethod
+    def _raise_short(failed: list) -> None:
+        """Of legs no replica is left for: the partial-response guard's own
+        error where one of them was a short answer (`_scatter_leg` routes it anew)."""
+        for f in failed:
+            if isinstance(f[3], _ShortAnswerError):
+                raise f[3]
 
     def _scatter_plan(self, scatter, plan: dict, ideal, table: str) -> list:
         """Fan the scatter closure over the plan. With hedging disabled this
@@ -554,6 +590,9 @@ class Broker:
             if partial.servers_queried:
                 result.num_servers_queried = partial.servers_queried
                 result.num_servers_responded = partial.servers_responded
+                result.servers_responded = sorted(partial.responded)
+                result.num_legs_failed_over = partial.legs_failed_over
+                result.num_stale_route_retries = partial.stale_route_retries
             if self.query_logger is not None:
                 self.query_logger.log(sql, table, result.time_used_ms, result.num_docs_scanned)
             if table:
@@ -675,6 +714,15 @@ class Broker:
         if fresh is None:
             return held
         bm.meter(BrokerMeter.ROUTE_SNAPSHOT_FETCHES).mark()
+        for sid, doc in fresh.instances.items():
+            session = (doc or {}).get("session")
+            if self._sessions.get(sid) != session:
+                # a server that registered again is another process: what the
+                # detector held against the last one does not bind it, and the
+                # external view alone says which segments it may be asked for
+                if sid in self._sessions and self.failure_detector is not None:
+                    self.failure_detector.mark_success(sid)
+                self._sessions[sid] = session
         if fresh.exists:
             self._snapshots[table] = fresh
         else:
@@ -1122,8 +1170,7 @@ class Broker:
                 deadline=ctx.deadline,
             )
             if partial is not None:
-                partial.servers_queried += len(plan)
-                partial.servers_responded += len(plan) - len(failed)
+                partial.heard(plan, (sid for sid, _, _ in failed))
             if failed and len(rows) < need:
                 # one failover round on surviving replicas (connection-failure
                 # parity with _scatter_leg)
@@ -1148,8 +1195,9 @@ class Broker:
                     deadline=ctx.deadline,
                 ) if plan2 else []
                 if partial is not None:
-                    partial.servers_queried += len(plan2)
-                    partial.servers_responded += len(plan2) - len(still)
+                    partial.heard(plan2, (sid for sid, _, _ in still))
+                    if plan2:
+                        partial.failed_over(len(failed))
                 if still:
                     if partial is None or not partial.allow:
                         raise RuntimeError(
@@ -1280,12 +1328,16 @@ class Broker:
 
     def _route_leg(self, ctx: QueryContext, snap: RouteSnapshot, table: str):
         """Prune on stats/partitions and pick replicas, all from the
-        snapshot. Returns (plan {server: [segments]}, servers, ideal,
-        n_candidates, pruned)."""
+        snapshot. Returns (plan {server: [segments]}, servers, routable,
+        n_candidates, pruned): `routable` is the ideal state less the replicas
+        the external view does not confirm, so a server is asked for a
+        segment only once it has it — the first choice, a retry round and a
+        hedge alike."""
         from pinot_tpu.cluster.routing import segment_partitions_match
 
         meta = snap.meta.get(table, {})
         ideal = snap.ideal.get(table, {})
+        routable = snap.routable.get(table, {})
 
         candidates, pruned = [], 0
         for seg_name, m in meta.items():
@@ -1300,13 +1352,11 @@ class Broker:
         # consuming segments have no committed metadata yet: always routed
         candidates.extend(s for s in ideal if s not in meta)
 
-        routable_ideal = (
-            self.failure_detector.filter_ideal_state(ideal) if self.failure_detector else ideal
-        )
-        plan, unroutable = self.selector.select(routable_ideal, candidates)
+        healthy = self.failure_detector.filter_ideal_state(routable) if self.failure_detector else routable
+        plan, unroutable = self.selector.select(healthy, candidates)
         if unroutable:
             raise RuntimeError(f"no ONLINE replica for segments: {unroutable}")
-        return plan, snap.servers, ideal, len(candidates), pruned
+        return plan, snap.servers, routable, len(candidates), pruned
 
     def _scatter_leg(self, ctx: QueryContext, snap: RouteSnapshot, table: str, sql: str, partial=None):
         """Route + scatter one physical table, re-routing briefly when a
@@ -1315,11 +1365,13 @@ class Broker:
         SegmentCompletionManager's commit interval) or behind a rebalance
         move's drain (the replica left the server after the route was
         confirmed; a streamed leg's server refuses in words, an aggregation's
-        returns one partial too few and the scatter's own guard says it).
+        returns one partial too few; the scatter sends such a leg to another
+        replica first, and its guard says it where there is none).
         Each further attempt asks the controller again: what the
         server no longer hosts, the next snapshot no longer routes there.
         Connection failures fail over to other replicas inside the single
         attempt."""
+        from pinot_tpu.common.metrics import BrokerMeter, broker_metrics
         from pinot_tpu.common.trace import span
 
         last: RuntimeError | None = None
@@ -1332,6 +1384,9 @@ class Broker:
                 if "does not host segments" not in str(e):
                     raise
                 last = e
+                if partial is not None:
+                    partial.stale_route_retries += 1
+                broker_metrics().meter(BrokerMeter.STALE_ROUTE_RETRIES).mark()
                 time.sleep(0.05 * (attempt + 1))  # commit windows are short
                 with span("broker.route"):
                     snap = self._route_snapshot(snap.table)
@@ -1361,8 +1416,6 @@ class Broker:
 
         trace = active_trace()
         hints = dict(ctx.hints)
-        if partial is not None:
-            partial.servers_queried += len(plan)
         adaptive = self.selector if isinstance(self.selector, AdaptiveServerSelector) else None
 
         def scatter(item):
@@ -1390,28 +1443,33 @@ class Broker:
             self._hedge_record(sid, table, elapsed_ms)
             if len(out[0]) != len(segs):
                 # a server silently skipping unhosted segments would mean
-                # missing rows; fail loudly instead (partial-response guard).
-                # It is what a streamed leg's server says itself: the route is
-                # older than the server's segment set (`_scatter_leg` asks the
-                # controller again and routes anew)
-                raise RuntimeError(
-                    f"server {sid} executed {len(out[0])}/{len(segs)} requested segments: "
-                    f"it does not host segments of {table!r} that it was routed"
-                )
+                # missing rows (partial-response guard): the whole leg goes to
+                # another replica in the retry round below, and where there is
+                # none this error is raised. It is what a streamed leg's server
+                # says itself: the route is older than the server's segment set
+                # (`_scatter_leg` asks the controller again and routes anew)
+                return (
+                    "__failed__", sid, segs,
+                    _ShortAnswerError(
+                        f"server {sid} executed {len(out[0])}/{len(segs)} requested segments: "
+                        f"it does not host segments of {table!r} that it was routed"
+                    ),
+                )  # fmt: skip
             return out
 
         # pool threads inherit no context: the legs run under this request's
         # trace, ledger and open span (`broker.scatter`)
         scatter = bind_request(scatter)
         results = self._scatter_plan(scatter, plan, ideal, table)
-        failed = [r for r in results if isinstance(r, tuple) and r and r[0] == "__failed__"]
-        results = [r for r in results if not (isinstance(r, tuple) and r and r[0] == "__failed__")]
+        failed = [r for r in results if self._is_failed_marker(r)]
+        results = [r for r in results if not self._is_failed_marker(r)]
         if partial is not None:
-            partial.servers_responded += len(plan) - len(failed)
+            partial.heard(plan, (f[1] for f in failed))
         if failed:
-            # one retry round on surviving replicas (connection-failure
-            # failover; a second failure is a hard error — or, under
-            # allowPartialResults, a recorded loss)
+            # one retry round on surviving replicas (a leg whose server was
+            # unreachable, or answered short of what it was routed; a second
+            # failure is a hard error — or, under allowPartialResults, a
+            # recorded loss)
             bad_servers = {f[1] for f in failed}
             retry_segs = [s for f in failed for s in f[2]]
             retry_ideal = {
@@ -1421,6 +1479,7 @@ class Broker:
             plan2, unroutable2 = self.selector.select(retry_ideal, retry_segs)
             if unroutable2:
                 if partial is None or not partial.allow:
+                    self._raise_short(failed)
                     raise RuntimeError(
                         f"servers {sorted(bad_servers)} unreachable and no surviving replica for {unroutable2}"
                     ) from failed[0][3]
@@ -1429,15 +1488,15 @@ class Broker:
                     f"replica for {sorted(unroutable2)}: {failed[0][3]}"
                 )
             retry_results = list(self._pool.map(scatter, plan2.items())) if plan2 else []
-            still = [r for r in retry_results if isinstance(r, tuple) and r and r[0] == "__failed__"]
-            retry_results = [
-                r for r in retry_results if not (isinstance(r, tuple) and r and r[0] == "__failed__")
-            ]
+            still = [r for r in retry_results if self._is_failed_marker(r)]
+            retry_results = [r for r in retry_results if not self._is_failed_marker(r)]
             if partial is not None:
-                partial.servers_queried += len(plan2)
-                partial.servers_responded += len(plan2) - len(still)
+                partial.heard(plan2, (f[1] for f in still))
+                if plan2:
+                    partial.failed_over(len(failed))
             if still:
                 if partial is None or not partial.allow:
+                    self._raise_short(still)
                     raise RuntimeError(
                         f"retry failed for servers {[f[1] for f in still]}"
                     ) from still[0][3]
@@ -1507,7 +1566,7 @@ class Broker:
                 raise KeyError(f"no such table: {table}")
             if snap.schema is not None:
                 schemas[table] = list(snap.schema.columns)
-            ideal = snap.ideal.get(table, {})
+            ideal = snap.routable.get(table, {})
             assign: dict[str, list] = {}
             info: list = []
             for seg_name, replicas in sorted(ideal.items()):

@@ -61,6 +61,9 @@ class Controller:
         #: (table, segment, server) of the transitions an upload is waiting for right now: drift the
         #: reconciler must not take for loss (a 181 MB segment loads for longer than its grace)
         self._loading: set[tuple[str, str, str]] = set()
+        #: server id -> the instant (perf_counter) its session was reset, until its last replica is ONLINE again
+        self._restoring: dict[str, float] = {}
+        self._session_lock = threading.Lock()
 
     def readiness(self) -> "tuple[bool, dict]":
         """(ready, per-component detail) for GET /health/ready — the broker/
@@ -175,25 +178,82 @@ class Controller:
         lazily from the instance doc. `tags` carry tenant/tier membership
         ("<tenant>_OFFLINE", "hot_tier", ...); untagged servers belong to
         the DefaultTenant."""
-        if handle is not None:
-            self._servers[server_id] = handle
-        else:
-            # HTTP re-registration (server restart): the endpoint may have
-            # moved ports — drop any cached remote handle built from the old
-            # instance doc so deliveries go to the live process
-            self._servers.pop(server_id, None)
-        prev = self.store.get(f"/instances/{server_id}") or {}
+        known = self.store.get(f"/instances/{server_id}")
+        prev = known or {}
         # a re-registration without tags (server restart) must not wipe
         # operator-assigned tenant/tier tags
         eff_tags = list(tags) if tags is not None else prev.get("tags", [])
         # fenced: instance registration is a leader-only mutation (HTTP gates
-        # standbys already); a deposed lead must not resurrect stale liveness
+        # standbys already); a deposed lead must not resurrect stale liveness.
+        # `session` counts the registrations: a broker that had marked the
+        # server down lets its next session in (`Broker._route_snapshot`)
         self.store.set(
             f"/instances/{server_id}",
-            {"host": host, "port": port, "alive": True, "tags": eff_tags},
+            {"host": host, "port": port, "alive": True, "tags": eff_tags, "session": int(prev.get("session", 0)) + 1},
             fence=self.lease_fence(),
             bump=INSTANCES_VERSION_PATH,
         )
+        if handle is not None:
+            self._servers[server_id] = handle
+        else:
+            # a remote handle follows the instance document (`servers`); a
+            # handle registered in process gives way to the endpoint
+            self._servers.pop(server_id, None)
+            if known is not None:
+                self._reset_server_session(server_id)
+
+    def _reset_server_session(self, server_id: str) -> None:
+        """A server that registers again over HTTP is a new session (the Helix
+        participant's ZK session analog): what its last one held is gone with
+        the process, so its entries leave every table's external view. The
+        reconciler then sees ideal state and view apart and enqueues its
+        replicas again, and no broker routes to it for a segment before the
+        view says ONLINE again. Left standing, the entries kept a restarted
+        server ready and empty for ever (PERF.md, PR 31)."""
+        from pinot_tpu.common.metrics import ControllerMeter, controller_metrics
+        from pinot_tpu.common.trace import span
+
+        dropped = 0
+
+        def forget(view: dict | None) -> dict | None:
+            nonlocal dropped
+            held = [seg for seg, replicas in (view or {}).items() if server_id in replicas]
+            if not held:
+                return None
+            dropped += len(held)
+            kept = {seg: {s: st for s, st in replicas.items() if s != server_id} for seg, replicas in view.items()}
+            return {seg: replicas for seg, replicas in kept.items() if replicas}
+
+        with span("controller.serverSessionReset", server=server_id) as reset:
+            for table in self.tables():
+                self._update_external_view(table, forget)
+            reset.set_attr("replicas", dropped)
+        controller_metrics().meter(ControllerMeter.SERVER_SESSION_RESETS).mark()
+        with self._session_lock:
+            if dropped:
+                self._restoring[server_id] = time.perf_counter()
+            else:
+                self._restoring.pop(server_id, None)
+
+    def _replica_online(self, server_id: str) -> None:
+        """A replica of `server_id` was confirmed ONLINE: where that was the
+        last one a session reset took from it, the time from its
+        re-registration goes to `controller.serverSessionRestoreMs`."""
+        with self._session_lock:
+            since = self._restoring.get(server_id)
+        if since is None:
+            return
+        for table in self.tables():
+            view = self.store.get(f"/tables/{table}/externalview") or {}
+            for seg, replicas in self.ideal_state(table).items():
+                if replicas.get(server_id) == "ONLINE" and view.get(seg, {}).get(server_id) != "ONLINE":
+                    return
+        with self._session_lock:
+            if self._restoring.pop(server_id, None) is None:
+                return
+        from pinot_tpu.common.metrics import ControllerTimer, controller_metrics
+
+        controller_metrics().timer(ControllerTimer.SERVER_SESSION_RESTORE).update_ms((time.perf_counter() - since) * 1e3)
 
     def update_server_tags(self, server_id: str, tags: list[str]) -> None:
         """Re-tag a server (updateInstanceTags REST parity)."""
@@ -207,12 +267,21 @@ class Controller:
 
     def servers(self, instances: dict[str, dict] | None = None) -> dict[str, object]:
         """server id -> handle: the registered in-process objects, and a
-        RemoteServerClient (kept) for every other instance that listens on a port."""
+        RemoteServerClient (kept) for every other instance that listens on a
+        port. A kept client follows the instance document: one built for an
+        endpoint the server has since left (a restart on another port, seen
+        by another controller, or read between a registration's two steps)
+        would take every delivery to a dead port for good."""
+        from pinot_tpu.cluster.http import RemoteServerClient
+
         out = dict(self._servers)
         for sid, doc in (self.instances() if instances is None else instances).items():
-            if sid not in out and doc.get("port"):
-                from pinot_tpu.cluster.http import RemoteServerClient
-
+            if not doc.get("port"):
+                continue
+            held = out.get(sid)
+            if held is None or (
+                isinstance(held, RemoteServerClient) and held.base_url != RemoteServerClient.url_of(doc)
+            ):
                 out[sid] = self._servers[sid] = RemoteServerClient.of_instance(doc)
         return out
 
@@ -259,8 +328,10 @@ class Controller:
     # -- routing versions and the route snapshot ------------------------------
     # One monotonic counter per table, moved by EVERY write to what a broker
     # routes that table's queries on: its config, the schema of its name, its
-    # segments' metadata and its ideal state (upload, delete, refresh,
-    # rebalance move, realtime state change, deep-store repair). A second
+    # segments' metadata, its ideal state (upload, delete, refresh,
+    # rebalance move, realtime state change, deep-store repair) and its
+    # external view (a replica confirmed or lost; still in a cluster where
+    # nothing loads or dies, so the steady state stays one call a query). A second
     # counter, `INSTANCES_VERSION_PATH`, moves with every write to an instance
     # document (a server registering, a re-tag). Each such write names its
     # counter as `bump=` of the store call itself, so the write and the count
@@ -292,8 +363,8 @@ class Controller:
         """Everything a broker routes a query on `table` from, as one
         document under one token (`routing.RouteSnapshot`): the configs of
         the table and of its `_REALTIME` twin (None where there is none), the
-        schema, every segment's metadata and the ideal state of both, and the
-        server handles. None where `have` is the current token: the caller's
+        schema, every segment's metadata, the ideal state and the external
+        view of both, and the server handles. None where `have` is the current token: the caller's
         document still stands. The document is read in one section of the
         store that no write lands in, and every write that it holds moves the
         token in the store call that makes it, so token and content agree."""
@@ -313,6 +384,7 @@ class Controller:
                 {t: self.ideal_state(t) for t in physical},
                 self.servers(instances),
                 instances,
+                {t: self.external_view(t) for t in physical},
             )
 
     def get_table(self, name: str) -> TableConfig | None:
@@ -780,6 +852,26 @@ class Controller:
             return ideal
 
         self._update_ideal_state(table, change)
+        if self._transitions is not None:
+            # both callers speak for a server that holds the replica already (a rebalance move after
+            # its `add_segment`, a consuming segment as it opens): the view follows in the same call
+            self._transitions.record_external_view(table, segment, server_id, state)
+
+    def _update_external_view(self, table: str, fn) -> dict | None:
+        """The one way an external view changes; brokers route by it, so the
+        routing version moves with the write, as with the ideal state's."""
+        return self.store.update(
+            f"/tables/{table}/externalview", fn, fence=self.lease_fence(), bump=routing_version_path(table)
+        )
+
+    def external_view(self, table: str) -> dict | None:
+        """What the servers have confirmed of `table`: {segment: {server:
+        state}}. None where no view is kept: without HA a state transition is
+        a synchronous call onto the server, and the view equals the ideal
+        state once it returns (module docstring)."""
+        if self._transitions is None:
+            return None
+        return self.store.get(f"/tables/{table}/externalview") or {}
 
     def _update_ideal_state(self, table: str, fn) -> None:
         """The one way an ideal state changes: the write and the routing
@@ -811,7 +903,7 @@ class Controller:
         n = 0
         for t in self.tables():
             if self.store.get(f"/tables/{t}/externalview") is not None:
-                self.store.delete(f"/tables/{t}/externalview", fence=self.lease_fence())
+                self.store.delete(f"/tables/{t}/externalview", fence=self.lease_fence(), bump=routing_version_path(t))
                 n += 1
         return n
 

@@ -250,10 +250,8 @@ class TransitionManager:
             if msg is not None and msg["table"] == table and msg["segment"] == segment:
                 self.store.delete(path, fence=self._fence())
                 n += 1
-        self.store.update(
-            f"/tables/{table}/externalview",
-            lambda doc: ({k: v for k, v in (doc or {}).items() if k != segment}),
-            fence=self._fence(),
+        self.controller._update_external_view(
+            table, lambda doc: {k: v for k, v in doc.items() if k != segment} if doc and segment in doc else None
         )
         return n
 
@@ -337,8 +335,14 @@ class TransitionManager:
         return True
 
     def record_external_view(self, table: str, segment: str, server_id: str, state: str | None) -> None:
+        """What a server confirmed of one replica (None: it holds it no
+        longer). Written only where it changes the view: brokers route by the
+        view, and a write to it moves the table's routing version."""
+
         def upd(doc):
             doc = doc or {}
+            if doc.get(segment, {}).get(server_id) == state:
+                return None
             entry = doc.setdefault(segment, {})
             if state is None:
                 entry.pop(server_id, None)
@@ -348,7 +352,9 @@ class TransitionManager:
                 entry[server_id] = state
             return doc
 
-        self.store.update(f"/tables/{table}/externalview", upd, fence=self._fence())
+        self.controller._update_external_view(table, upd)
+        if state == "ONLINE":
+            self.controller._replica_online(server_id)
 
     # -- reconciliation --------------------------------------------------------
 

@@ -973,10 +973,14 @@ class RemoteServerClient:
         self.timeout = timeout
         self._host, self._port = _host_port(self.base_url)
 
+    @staticmethod
+    def url_of(doc: dict) -> str:
+        return f"http://{doc['host']}:{doc['port']}"
+
     @classmethod
     def of_instance(cls, doc: dict) -> "RemoteServerClient":
         """The client of the server an instance document (`/instances/{id}`) names."""
-        return cls(f"http://{doc['host']}:{doc['port']}")
+        return cls(cls.url_of(doc))
 
     def _hop_timeout(self, hints: dict | None) -> float:
         """Per-call socket timeout: the query deadline riding in the hints

@@ -36,8 +36,10 @@ class QueryQuotaManager:
     across every table the tenant serves — the HelixExternalViewBased
     database/application rate-limiter analog)."""
 
-    def __init__(self, controller, tenant_qps: dict[str, float] | None = None):
+    def __init__(self, controller, tenant_qps: dict[str, float] | None = None, clock=time.monotonic):
+        """clock: the window's clock, in seconds (a test holds it still)."""
         self._controller = controller
+        self._clock = clock
         self._hits: dict[str, collections.deque] = {}
         self._tenant_hits: dict[str, collections.deque] = {}
         self._tenant_qps = dict(tenant_qps or {})
@@ -91,7 +93,7 @@ class QueryQuotaManager:
         tenant = tenant or ""
         if limit is None and tenant_limit is None:
             return
-        now = time.monotonic()
+        now = self._clock()
         with self._lock:
             if limit is not None:
                 dq = self._hits.setdefault(table, collections.deque())

@@ -100,9 +100,22 @@ def segment_can_match(f: ast.FilterExpr | None, stats: dict) -> bool:
     return True
 
 
+#: the states in which a replica answers queries
+SERVING_STATES = ("ONLINE", "CONSUMING")
+
+
+def _serving_replicas(ideal_state: dict[str, dict[str, str]], seg: str) -> list[str]:
+    """The servers that may be asked for `seg`, in a fixed order."""
+    return sorted(s for s, st in ideal_state.get(seg, {}).items() if st in SERVING_STATES)
+
+
 class BalancedInstanceSelector:
-    """Round-robin replica choice per segment (BalancedInstanceSelector
-    parity; the adaptive latency-aware variant plugs in here later)."""
+    """Round-robin replica choice (BalancedInstanceSelector parity; the
+    adaptive latency-aware variant plugs in here later): a request id that
+    steps once a query, plus the segment's index in the query, picks the
+    replica. One counter stepped once a *segment* picked the same replica of
+    every segment in every query where replicas lie in regular pairs and the
+    segments are even in number: half of the servers never served."""
 
     def __init__(self):
         self._rr = itertools.count()
@@ -113,17 +126,15 @@ class BalancedInstanceSelector:
         """segment list -> ({server_id: [segments]}, unroutable_segments),
         picking one ONLINE replica per segment. Callers must surface
         unroutable segments as an error, never as silently-missing rows."""
+        request_id = next(self._rr)
         plan: dict[str, list[str]] = {}
         unroutable: list[str] = []
-        for seg in segments:
-            replicas = sorted(
-                s for s, st in ideal_state.get(seg, {}).items() if st in ("ONLINE", "CONSUMING")
-            )
+        for i, seg in enumerate(segments):
+            replicas = _serving_replicas(ideal_state, seg)
             if not replicas:
                 unroutable.append(seg)
                 continue
-            pick = replicas[next(self._rr) % len(replicas)]
-            plan.setdefault(pick, []).append(seg)
+            plan.setdefault(replicas[(request_id + i) % len(replicas)], []).append(seg)
         return plan, unroutable
 
 
@@ -142,9 +153,7 @@ class ReplicaGroupInstanceSelector:
         plan: dict[str, list[str]] = {}
         unroutable: list[str] = []
         for seg in segments:
-            replicas = sorted(
-                s for s, st in ideal_state.get(seg, {}).items() if st in ("ONLINE", "CONSUMING")
-            )
+            replicas = _serving_replicas(ideal_state, seg)
             if not replicas:
                 unroutable.append(seg)
                 continue
@@ -184,9 +193,7 @@ class AdaptiveServerSelector:
         plan: dict[str, list[str]] = {}
         unroutable: list[str] = []
         for seg in segments:
-            replicas = sorted(
-                s for s, st in ideal_state.get(seg, {}).items() if st in ("ONLINE", "CONSUMING")
-            )
+            replicas = _serving_replicas(ideal_state, seg)
             if not replicas:
                 unroutable.append(seg)
                 continue
@@ -305,19 +312,33 @@ def _with_time_predicate(sql: str, predicate: str) -> str:
 # -- the route snapshot --------------------------------------------------------
 
 
+def _routable(ideal: dict[str, dict[str, str]], view: dict[str, dict[str, str]] | None) -> dict[str, dict[str, str]]:
+    """The ideal state less the replicas the external view does not confirm."""
+    if view is None:
+        return ideal
+    return {
+        seg: {s: st for s, st in replicas.items() if view.get(seg, {}).get(s) in SERVING_STATES}
+        for seg, replicas in ideal.items()
+    }
+
+
 class RouteSnapshot:
     """Everything the broker reads of the controller to route a query on one
     logical table, as of one `token`: the configs of the table and of its
     `_REALTIME` twin (None where there is none), the schema, every segment's
-    metadata and the ideal state of each physical table, and the server
-    handles. `Controller.route_snapshot` builds it and
+    metadata, the ideal state and the external view of each physical table,
+    and the server handles. A replica is routed to where both say it serves:
+    the ideal state is what the controller wants, the external view what the
+    servers have confirmed (`routable`; a table whose transitions are
+    synchronous calls has no view of its own, and the ideal state stands for
+    it). `Controller.route_snapshot` builds it and
     `RemoteControllerClient.route_snapshot` rebuilds it from `to_doc`; the
     broker holds one a table and asks once a query whether its token still
     stands (upstream's broker likewise routes from a routing table kept in
     memory and rebuilt when the external view changes). Read-only once built:
     queries on other threads route from the same object."""
 
-    def __init__(self, table, token, offline_cfg, rt_cfg, schema, meta, ideal, servers, instances):
+    def __init__(self, table, token, offline_cfg, rt_cfg, schema, meta, ideal, servers, instances, external=None):
         self.table = table
         self.token = token
         self.offline_cfg = offline_cfg
@@ -326,6 +347,10 @@ class RouteSnapshot:
         #: physical table -> {segment: metadata}; physical table -> ideal state
         self.meta: dict[str, dict[str, dict]] = meta
         self.ideal: dict[str, dict[str, dict[str, str]]] = ideal
+        #: physical table -> external view; None where none is kept for the table
+        self.external: dict[str, dict[str, dict[str, str]] | None] = external or {}
+        #: physical table -> the replicas a query may be sent to, in the ideal state's shape
+        self.routable = {t: _routable(i, self.external.get(t)) for t, i in ideal.items()}
         #: server id -> handle (in-process object or RemoteServerClient)
         self.servers: dict[str, object] = servers
         #: server id -> instance document (what `to_doc` ships of the servers)
@@ -375,6 +400,7 @@ class RouteSnapshot:
             "schema": self.schema.to_json() if self.schema is not None else None,
             "segments": self.meta,
             "idealStates": self.ideal,
+            "externalViews": self.external,
             "instances": self.instances,
         }
 
@@ -399,4 +425,5 @@ class RouteSnapshot:
             doc.get("idealStates") or {},
             {sid: make_handle(d) for sid, d in instances.items() if d and d.get("port")},
             instances,
+            doc.get("externalViews"),
         )

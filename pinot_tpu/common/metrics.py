@@ -587,6 +587,11 @@ class BrokerMeter(Enum):
     # held route snapshot stands), and the snapshots fetched among them
     CONTROLLER_CALLS = "broker.controllerCalls"
     ROUTE_SNAPSHOT_FETCHES = "broker.routeSnapshotFetches"
+    # replica fail-over: legs sent again to another replica inside the query
+    # (first choice unreachable or short), and re-routes on a newer snapshot
+    # after a server said it does not host what it was routed
+    LEGS_FAILED_OVER = "broker.legsFailedOver"
+    STALE_ROUTE_RETRIES = "broker.staleRouteRetries"
 
 
 class BrokerGauge(Enum):
@@ -604,6 +609,13 @@ class BrokerTimer(Enum):
 class ControllerMeter(Enum):
     SEGMENT_UPLOADS = "controller.segmentUploads"
     TABLE_ADDS = "controller.tableAdds"
+    # a server registered again over HTTP: its last session's entries left the external views
+    SERVER_SESSION_RESETS = "controller.serverSessionReset"
+
+
+class ControllerTimer(Enum):
+    #: a server's re-registration to its last replica ONLINE in the external view again
+    SERVER_SESSION_RESTORE = "controller.serverSessionRestoreMs"
 
 
 class MinionMeter(Enum):

@@ -2,7 +2,7 @@
 
 The broker routes every query from a *route snapshot* of the table that it
 holds (`cluster/routing.py` `RouteSnapshot`: configs, schema, segment
-metadata, ideal states, server instances) and asks the controller once a
+metadata, ideal states, external views, server instances) and asks the controller once a
 query whether the snapshot's token still stands; its result and plan caches
 (cluster/result_cache.py) key on the same token. The token is made of counter
 documents: a table's routing version and the instances' version. A write to
@@ -13,7 +13,7 @@ rows.
 
 Rule: a PropertyStore write — a `*.store.set(...)` / `.update(...)` /
 `.delete(...)` call, receiver named `store` or `*_store` — whose argument
-tree carries a string constant containing `idealstate`, `/segments/`,
+tree carries a string constant containing `idealstate`, `externalview`, `/segments/`,
 `/config`, `/schemas/` or `/instances/` must name the counter it moves as
 `bump=` **of that call**: the store then writes and counts in one section, so
 no reader sees the one without the other. A `bump_routing_version(...)` call
@@ -33,7 +33,7 @@ import ast
 from pinot_tpu.devtools.lint.core import Checker, Finding, ModuleInfo
 
 #: path substrings that mark a store write as one to what a route snapshot holds
-_MUTATION_MARKERS = ("idealstate", "/segments/", "/config", "/schemas/", "/instances/")
+_MUTATION_MARKERS = ("idealstate", "externalview", "/segments/", "/config", "/schemas/", "/instances/")
 
 
 def _mutation_marker_in(node: ast.AST) -> str | None:

@@ -56,6 +56,13 @@ class ResultTable:
     exceptions: list = field(default_factory=list)
     num_servers_queried: int = 0
     num_servers_responded: int = 0
+    # which servers those were, and what the scatter had to do to hear them:
+    # legs sent again to another replica inside the query (the first choice
+    # unreachable or short), re-routes on a newer snapshot after a server said
+    # it does not host what it was routed; both 0 in a healthy answer
+    servers_responded: list = field(default_factory=list)
+    num_legs_failed_over: int = 0
+    num_stale_route_retries: int = 0
     # broker result-cache verdict for THIS request (BrokerResponse metadata):
     # true = the response was served from cluster/result_cache.py
     cache_hit: bool = False
@@ -105,6 +112,9 @@ class ResultTable:
         if self.num_servers_queried:
             d["numServersQueried"] = self.num_servers_queried
             d["numServersResponded"] = self.num_servers_responded
+            d["serversResponded"] = list(self.servers_responded)
+            d["numLegsFailedOver"] = self.num_legs_failed_over
+            d["numStaleRouteRetries"] = self.num_stale_route_retries
         return d
 
     def __repr__(self) -> str:  # human-friendly table

@@ -11,6 +11,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from pinot_tpu.parallel import shuffle
 
 
+@pytest.fixture(autouse=True)
+def _colocated_link(monkeypatch):
+    """The device link's profile is measured once a process, on first use: on
+    a loaded box the probe's round trip reads long, the economic gate then
+    keeps the joins below off the device, and they fail for the box's sake.
+    Pinned to a co-located chip's, as `tests/test_multistage_device_ops.py` does."""
+    from pinot_tpu.common import devlink
+
+    monkeypatch.setattr(devlink, "_profile", (1e-4, 5e9))
+
+
 @pytest.fixture(scope="module")
 def mesh():
     devs = jax.devices()
