@@ -58,11 +58,12 @@ class ServerQueryPhase(Enum):
     QUERY_PLAN_EXECUTION = "queryPlanExecution"
     RESPONSE_SERIALIZATION = "responseSerialization"
     SCHEDULER_WAIT = "schedulerWait"
-    #: kernel_obs' `deviceMs`: host wall of the blocking readback less a
-    #: memoized link RTT — everything queued on the device ahead of the
-    #: program plus the program, not the program's device time. The span
-    #: `server.device_wait` is the same wait, unadjusted; device time proper
-    #: is in the profiler trace (perfbench `device_busy_ms_per_query`)
+    #: kernel_obs' `deviceMs`: a launch's share of its query's one wait for
+    #: the result vectors (kernels.wait_packed) — the first launch's holds
+    #: everything queued on the device ahead of it, the later ones what was
+    #: still to arrive: not the program's device time. The span
+    #: `server.device_wait` is that wait whole, once a query; device time
+    #: proper is in the profiler trace (perfbench `device_busy_ms_per_query`)
     DEVICE_EXECUTION = "deviceExecution"
     # broker/transport phases (BrokerQueryPhase parity) — one enum keeps the
     # phaseTimesMs namespace flat across roles
@@ -443,6 +444,10 @@ class PhaseLedger:
                 "wireResponseBytes": 0,
                 "serversMerged": 0,
                 "scatterSkewMs": 0,
+                # link crossings of the query (query/kernels.py): transfer calls host -> device (one a launch: its
+                # operands go with it; one more when a stable operand is first staged) and waits device -> host
+                "hostToDeviceTransfers": 0,
+                "deviceReadbackWaits": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),

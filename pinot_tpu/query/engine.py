@@ -19,7 +19,7 @@ import pandas as pd
 
 from pinot_tpu.query import ast, host_exec, reduce as reduce_mod
 from pinot_tpu.query.context import QueryContext, QueryType
-from pinot_tpu.query.kernels import dispatch_plan_packed
+from pinot_tpu.query.kernels import dispatch_plan_packed, wait_packed
 from pinot_tpu.query.plan import DeviceFallback, SegmentPlan, plan_segment
 from pinot_tpu.query.result import ResultTable
 from pinot_tpu.query.sql import parse_sql
@@ -197,9 +197,11 @@ class QueryEngine:
         return pend, pruned
 
     def _resolve_partials(self, ctx: QueryContext, pend: list, pruned: int):
-        """Sync + convert every pending dispatch; per-segment accounting
-        checkpoint (the QueryKilledError enforcement point), tracing scope,
-        byte sampling, segment meters, and the scan-path/heat fold — the ONE
+        """Wait once for the query's result vectors (their copies to the host
+        started at enqueue), then convert every pending dispatch; per-segment
+        accounting checkpoint (the QueryKilledError enforcement point) and
+        deadline check in the wait as in the conversions, tracing scope, byte
+        sampling, segment meters, and the scan-path/heat fold — the ONE
         resolve loop.  Returns (partials, matched_docs, scan_summary)."""
         from pinot_tpu.common.accounting import default_accountant
         from pinot_tpu.common.metrics import ScanMeter, ServerMeter, server_metrics
@@ -212,6 +214,14 @@ class QueryEngine:
         n_post = len(ctx.post_filter_columns) if obs else 0
         out = []
         scanned = 0
+        launched = [(seg, disp[2]) for seg, disp in pend if disp[0] == "dev"]
+
+        def checkpoint(i: int) -> None:
+            default_accountant.checkpoint()
+            if ctx.deadline is not None:
+                ctx.deadline.check(f"segment {launched[i][0].name}")
+
+        wait_packed([res for _, res in launched], checkpoint)
         for seg, disp in pend:
             if disp[0] == "pruned":
                 out.append(disp[1])  # no scan, no sample
@@ -247,7 +257,8 @@ class QueryEngine:
                     seg.name,
                     docs_scanned=int(matched),
                     bytes_touched=seg.size_bytes,
-                    device_ms=(time.perf_counter() - t_wall) * 1e3,
+                    device_ms=(time.perf_counter() - t_wall) * 1e3
+                    + (disp[2].wait_ms if disp[0] == "dev" else 0.0),
                 )
                 if seg_stats["fullScanFallbacks"]:
                     # offender hop for the roofline runbook: which predicate
@@ -444,13 +455,13 @@ class QueryEngine:
         """Asynchronous submit (QueryScheduler.submit ListenableFuture
         parity, core/query/scheduler/QueryScheduler.java): plans the query
         and ENQUEUES every per-segment device program without the
-        device->host sync (jax dispatch is non-blocking; see
+        device->host sync (jax dispatch is non-blocking; each result
+        vector's copy to the host starts behind its program, see
         kernels.dispatch_plan_packed), returning a zero-argument resolve()
-        that performs the syncs, broker reduce, and ResultTable build.
-        Dispatching several queries before resolving any overlaps their
-        device round trips — on a high-RTT link N in-flight queries share
-        the link instead of paying N serial syncs. execute() is exactly
-        submit()() — one path, same instrumentation."""
+        that performs the query's one wait, broker reduce, and ResultTable
+        build. Dispatching several queries before resolving any overlaps
+        their device round trips. execute() is exactly submit()() — one
+        path, same instrumentation."""
         t0 = time.perf_counter()
         ctx = self.make_context(sql)
         if getattr(ctx.statement, "explain", False):
@@ -522,9 +533,11 @@ class QueryEngine:
         """Async half of segment execution: plan + ENQUEUE the fused device
         program without any device->host sync. Returns ("ready", partial,
         matched) when the segment resolved host-side (star-tree swap, host
-        fallback), else ("dev", plan, out) with `out` still in flight —
-        _finish_segment performs the sync. Splitting here is what lets
-        submit() overlap the device round trips of multiple queries."""
+        fallback), else ("dev", plan, result, vmask) with `result` (a
+        kernels.PackedResult) still in flight and on its way to the host —
+        _resolve_partials waits for a query's results together,
+        _finish_segment for its own where none did. Splitting here is what
+        lets a query enqueue every segment before it waits for any."""
         valid = seg.extras.get("valid_docs")
         from pinot_tpu.query.context import null_handling_enabled
 
@@ -562,7 +575,7 @@ class QueryEngine:
         if disp[0] == "ready":
             return disp[1], disp[2]
         _, plan, unpack, vmask = disp
-        out = unpack()  # the one device->host sync for this segment
+        out = unpack()  # waits only where the caller has not waited for the query's vectors already
         qt = ctx.query_type
         if qt == QueryType.AGGREGATION:
             matched, parts = out

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pinot_tpu.common.kernel_obs import KERNELS, CacheObserver
-from pinot_tpu.common.trace import active_ledger, span
+from pinot_tpu.common.trace import active_ledger, count, span
 
 _F = jnp.float64
 _I = jnp.int64
@@ -836,9 +836,9 @@ def get_kernel(spec: tuple):
 def get_packed_kernel(spec: tuple):
     """Jitted program whose outputs ride back in ONE float64 vector.
 
-    Blocking on a pytree of N output arrays costs N device->host syncs;
-    packing collapses a query's outputs to one transfer (the same trick the
-    sharded executor uses, parallel/mesh.py:_sharded_kernel). int64 leaves
+    A pytree of N output arrays is N device->host copies; packing makes a
+    launch's outputs one vector, copied from the enqueue on
+    (`dispatch_plan_packed`; a query waits once for its vectors). int64 leaves
     split into hi/lo 32-bit halves (two f64 chunks) so values past 2^53 —
     sparse group gids, raw LONG columns — survive exactly; everything else
     casts to f64 losslessly.
@@ -918,10 +918,12 @@ KERNELS.register(
 @lru_cache(maxsize=4096)
 def _packed_meta(spec: tuple, col_sig: tuple, op_sig: tuple, n_padded: int):
     """(treedef, [(shape, dtype)]) of a spec's output tree for one input
-    shape signature — abstract evaluation only, no compile."""
+    shape signature — abstract evaluation only, no compile. The signatures
+    are (name, shape, dtype) a column and (shape, dtype) an operand, read
+    off the launch's own arguments: shape tuples and dtype objects."""
     base = build_fn(spec)
-    cols = {k: jax.ShapeDtypeStruct(s, np.dtype(d)) for k, s, d in col_sig}
-    ops = tuple(jax.ShapeDtypeStruct(s, np.dtype(d)) for s, d in op_sig)
+    cols = {k: jax.ShapeDtypeStruct(s, d) for k, s, d in col_sig}
+    ops = tuple(jax.ShapeDtypeStruct(s, d) for s, d in op_sig)
     out = jax.eval_shape(
         lambda c, o, nd: base(c, o, nd, n_padded),
         cols,
@@ -959,21 +961,28 @@ def mark_stable_operand(o: np.ndarray) -> np.ndarray:
 
 
 def stage_operand(o):
-    """jnp.asarray, with the staged copy cached for marked-stable arrays."""
-    if isinstance(o, np.ndarray):
-        key = id(o)
-        with _OP_CACHE_LOCK:
-            ref = _STABLE_OPS.get(key)
-            stable = ref is not None and ref() is o
-            ent = _OP_DEVICE_CACHE.get(key) if stable else None
-        if ent is not None and ent[0]() is o:
-            return ent[1]
-        if stable:
-            dev = jnp.asarray(o)
-            with _OP_CACHE_LOCK:
-                _OP_DEVICE_CACHE[key] = (weakref.ref(o), dev)
-            return dev
-    return jnp.asarray(o)
+    """What a jitted program is handed for one plan operand. A per-query
+    operand goes in as the numpy value the plan holds (plan.py `op_idx`
+    made it an array of its final dtype): jit's own argument path moves it
+    to the device with the launch, so it costs no transfer call of its own.
+    Only an array its owner marked stable is staged — once, counted under
+    `hostToDeviceTransfers` — and handed in as that device copy: a large
+    long-lived array must not cross the link again with every query."""
+    if not isinstance(o, np.ndarray):
+        return o
+    key = id(o)
+    with _OP_CACHE_LOCK:
+        ref = _STABLE_OPS.get(key)
+        if ref is None or ref() is not o:
+            return o
+        ent = _OP_DEVICE_CACHE.get(key)
+    if ent is not None and ent[0]() is o:
+        return ent[1]
+    dev = jnp.asarray(o)
+    count("hostToDeviceTransfers")
+    with _OP_CACHE_LOCK:
+        _OP_DEVICE_CACHE[key] = (weakref.ref(o), dev)
+    return dev
 
 
 def _plan_inputs(plan, device_segment):
@@ -989,46 +998,28 @@ def _plan_inputs(plan, device_segment):
     return cols, ops
 
 
-def dispatch_plan_packed(plan, device_segment):
-    """Async half of run_plan_packed: ENQUEUE the packed kernel (jax
-    dispatch is non-blocking) and return a zero-arg unpack() that performs
-    the single device->host transfer and re-inflates the output tree. A
-    caller overlapping several queries dispatches all of them first, then
-    unpacks — N in-flight programs share the link instead of syncing N
-    times."""
-    kernel = get_packed_kernel(plan.spec)
-    _packed_cache_obs.observe()
-    cols, ops = _plan_inputs(plan, device_segment)
-    n_cols = len(cols)
-    vec = kernel(cols, ops, np.int32(device_segment.n_docs), device_segment.padded)
-    name = kernel.__name__  # program_name(plan.spec), without hashing the spec again
-    ledger = active_ledger()
-    if ledger is not None:
-        ledger.add_device_work(name, device_segment.padded, KERNELS.program_work(name, device_segment.padded))
-    treedef, leaf_meta = _packed_meta(
-        plan.spec,
-        tuple(sorted((k, tuple(v.shape), str(np.dtype(v.dtype))) for k, v in cols.items())),
-        tuple((tuple(np.shape(o)), str(np.dtype(o.dtype))) for o in ops),
-        device_segment.padded,
-    )
+class PackedResult:
+    """One enqueued launch of a packed program. Its result vector has been on
+    its way to the host since the enqueue; calling the object re-inflates the
+    output tree, after `wait_packed` — the caller's, for all launches of a
+    query at once, or its own if none was made."""
 
-    def unpack():
-        # THE device->host sync: `server.device_wait` is the wait for the
-        # readback and nothing else — everything queued on the device ahead
-        # of this program plus the program. kernel_obs' deviceMs is the same
-        # wait, taken by its own clock inside this span.
-        with span("server.device_wait"):
-            v = np.asarray(
-                KERNELS.timed_sync(
-                    "query.fused_packed",
-                    lambda: np.asarray(vec),
-                    rows=device_segment.padded,
-                    cols=n_cols,
-                )
-            )
+    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_n_cols", "_treedef", "_leaf_meta")
+
+    def __init__(self, program: str, rows: int, vec, n_cols: int, treedef, leaf_meta):
+        # what was launched, for the caller's `server.dispatch` span
+        self.program, self.rows = program, rows
+        self.wait_ms = 0.0
+        self._vec, self._host = vec, None
+        self._n_cols, self._treedef, self._leaf_meta = n_cols, treedef, leaf_meta
+
+    def __call__(self):
+        if self._host is None:
+            wait_packed((self,))
+        v = self._host
         out = []
         i = 0
-        for shape, dtype in leaf_meta:
+        for shape, dtype in self._leaf_meta:
             size = int(np.prod(shape, dtype=np.int64)) if shape else 1
             if dtype == np.int64:
                 hi = v[i : i + size]
@@ -1041,11 +1032,69 @@ def dispatch_plan_packed(plan, device_segment):
                 if dtype != np.float64:
                     chunk = chunk.astype(dtype)
             out.append(chunk.reshape(shape))
-        return jax.tree.unflatten(treedef, out)
+        return jax.tree.unflatten(self._treedef, out)
 
-    # what was launched, for the caller's `server.dispatch` span
-    unpack.program, unpack.rows = name, device_segment.padded
-    return unpack
+
+def wait_packed(results, checkpoint=None) -> None:
+    """THE device->host wait of a query: one `server.device_wait` span and
+    one `deviceReadbackWaits` around the arrival of every result vector not
+    yet on the host. The copies were started when the programs were
+    enqueued, so the first wait covers what is queued on the device and the
+    others find their vector there or on its way; nothing else runs inside
+    the span. `checkpoint(i)`, where given, runs before the i-th vector is
+    waited for (the caller's deadline and kill checks: a raise leaves the
+    rest to arrive unread). Each launch then enters kernel_obs' ledger as
+    one call of `query.fused_packed` with its own share of the wait."""
+    # imported here: a line more at the top of the file moves the call-site
+    # lines in the traced programs' Mosaic payloads, and their cache keys
+    import time
+
+    pending = [(i, r) for i, r in enumerate(results) if r._host is None]
+    if not pending:
+        return
+    with span("server.device_wait"):
+        t = time.perf_counter()
+        for i, r in pending:
+            if checkpoint is not None:
+                checkpoint(i)
+            r._host = np.asarray(r._vec)
+            r._vec = None
+            now = time.perf_counter()
+            r.wait_ms, t = (now - t) * 1e3, now
+    count("deviceReadbackWaits")
+    if KERNELS.enabled:
+        for _, r in pending:
+            KERNELS.record("query.fused_packed", r.wait_ms, rows=r.rows, cols=r._n_cols)
+
+
+def dispatch_plan_packed(plan, device_segment) -> PackedResult:
+    """Async half of run_plan_packed: ENQUEUE the packed kernel (jax
+    dispatch is non-blocking) with its operands as arguments of the call,
+    start the result vector's copy to the host behind it, and return the
+    launch's `PackedResult`. One `hostToDeviceTransfers` a launch: columns
+    are resident, the operands and the doc count cross with the call. A
+    caller dispatches every segment of a query first and waits once
+    (`wait_packed`), then unpacks each."""
+    cols, ops = _plan_inputs(plan, device_segment)
+    kernel = get_packed_kernel(plan.spec)
+    _packed_cache_obs.observe()
+    rows = device_segment.padded
+    vec = kernel(cols, ops, np.int32(device_segment.n_docs), rows)
+    vec.copy_to_host_async()
+    count("hostToDeviceTransfers")
+    name = kernel.__name__  # program_name(plan.spec), without hashing the spec again
+    ledger = active_ledger()
+    if ledger is not None:
+        ledger.add_device_work(name, rows, KERNELS.program_work(name, rows))
+    # the key as the arrays give it, nothing sorted and no string built: segments of one table
+    # plan their columns in one order and share the entry; another order would only add one
+    treedef, leaf_meta = _packed_meta(
+        plan.spec,
+        tuple((k, v.shape, v.dtype) for k, v in cols.items()),
+        tuple((o.shape, o.dtype) for o in ops),
+        rows,
+    )
+    return PackedResult(name, rows, vec, len(cols), treedef, leaf_meta)
 
 
 def run_plan_packed(plan, device_segment):

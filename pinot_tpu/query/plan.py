@@ -105,7 +105,11 @@ class _Lowering:
     # -- operand / column registration --------------------------------------
 
     def op_idx(self, value) -> int:
-        self.operands.append(value)
+        # a numpy array of its final dtype, whatever kind of value came in:
+        # the jitted program is handed the plan's operands as they are
+        # (kernels.stage_operand), and a bare Python number there would be
+        # weakly typed, trace another program and promote differently
+        self.operands.append(np.asarray(value))
         return len(self.operands) - 1
 
     def use_col(self, col: str) -> str:

@@ -82,7 +82,7 @@ def test_child_time_is_not_counted_twice():
     assert set(fields) == set(RESPONSE_KEYS)
     assert fields["counters"] == {
         "wireRequestBytes": 0, "wireResponseBytes": 0, "serversMerged": 0, "scatterSkewMs": 0,
-        "segmentsDispatched": 0, "rowsDispatched": 0,
+        "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
     }  # fmt: skip
 
 
@@ -477,7 +477,8 @@ def test_spans_reach_the_profiler_trace_with_the_query_id(tmp_path, inproc):
             for ev in line.events:
                 if ev.name in ("server.dispatch", "server.device_wait", "broker.request"):
                     found.setdefault(ev.name, []).append(dict(ev.stats))
-    assert len(found["server.dispatch"]) == 4 and len(found["server.device_wait"]) == 4
+    # a launch a segment; one wait a server for its two segments' vectors
+    assert len(found["server.dispatch"]) == 4 and len(found["server.device_wait"]) == 2
     qids = {str(st["qid"]) for evs in found.values() for st in evs}
     assert len(qids) == 1 and re.match(r"^q\d+$", qids.pop())
     programs = {str(st["program"]) for st in found["server.dispatch"]}
