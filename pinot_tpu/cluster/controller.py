@@ -14,18 +14,22 @@ once those calls return.
 from __future__ import annotations
 
 import contextlib
+import logging
 import threading
 import time
 import zlib
 from pathlib import Path
 
 from pinot_tpu.common.config import TableConfig
+from pinot_tpu.common.errors import ServerTimedOut
 from pinot_tpu.common.trace import ServerQueryPhase
 from pinot_tpu.common.types import Schema
 from pinot_tpu.cluster.metadata import PropertyStore
 from pinot_tpu.cluster.routing import RouteSnapshot
 from pinot_tpu.segment.builder import write_segment
 from pinot_tpu.segment.segment import ImmutableSegment
+
+_LOG = logging.getLogger("pinot_tpu.controller")
 
 #: the counter that every write to an `/instances/{server}` document moves
 INSTANCES_VERSION_PATH = "/instancesversion"
@@ -621,6 +625,10 @@ class Controller:
                 f"segment upload {table}/{what} failed, no partial dir left: {e}",
             ) from e
 
+    #: how long an upload's acknowledgement waits for the view to confirm a replica whose server took the
+    #: transition and did not answer in time (`_publish`): a transition's own time once more
+    TRANSITION_CONFIRM_S = 60.0
+
     def _publish(
         self, table: str, config: TableConfig, name: str, seg_dir: Path, n_docs: int, stats: dict,
         file_crc: int | None, partitions: dict,
@@ -661,16 +669,30 @@ class Controller:
             # With HA enabled, a failing server falls back to the durable retry
             # queue instead of failing the upload (Helix async transition analog).
             handles = self.servers()
+            at_work: list[str] = []
             for sid in assigned:
                 with span("controller.upload.transition", phase=ServerQueryPhase.SEGMENT_UPLOAD_TRANSITION, role="controller", server=sid):
                     if self._transitions is not None:
                         try:
                             handles[sid].add_segment(table, name, str(seg_dir))
                             self._transitions.record_external_view(table, name, sid, "ONLINE")
-                        except Exception:  # pinotlint: disable=deadline-swallow — segment-add control plane; failure enqueues a retryable helix transition
+                        except Exception as e:  # pinotlint: disable=deadline-swallow — segment-add control plane; failure enqueues a retryable helix transition
+                            _LOG.warning("%s of %s not confirmed by %s (%s): queued for redelivery", name, table, sid, e)
                             self._transitions.enqueue(table, name, sid, "add", str(seg_dir))
+                            if isinstance(e, ServerTimedOut):
+                                at_work.append(sid)
                     else:
                         handles[sid].add_segment(table, name, str(seg_dir))
+            # A server that is down is the queue's to bring back, and the upload is acknowledged without it. One that
+            # took the call and was slow is still loading: it would host the segment while the view, which brokers
+            # route by, lacked it, and whoever took the acknowledgement for "loaded" was refused its first query
+            # (PERF.md, PR 35). So the acknowledgement waits, a transition's time at most, for the queue's delivery.
+            deadline = time.time() + self.TRANSITION_CONFIRM_S
+            while at_work and time.time() < deadline:
+                view = (self.external_view(table) or {}).get(name, {})
+                at_work = [sid for sid in at_work if view.get(sid) != "ONLINE"]
+                if at_work:
+                    time.sleep(0.05)
         finally:
             self._loading.difference_update(loading)
         self._refresh_dim_table(table, config)

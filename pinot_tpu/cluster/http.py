@@ -28,7 +28,7 @@ from pinot_tpu.cluster.broker import Broker
 from pinot_tpu.cluster.controller import Controller
 from pinot_tpu.cluster.server import Server
 from pinot_tpu.common import datatable
-from pinot_tpu.common.errors import QueryErrorCode, code_of, http_status_of, retry_after_of
+from pinot_tpu.common.errors import QueryErrorCode, ServerTimedOut, code_of, http_status_of, retry_after_of
 from pinot_tpu.common.frontend_obs import (
     ConnTracker,
     CountingReader,
@@ -1128,7 +1128,7 @@ class RemoteServerClient:
         finally:
             resp.close()
 
-    def _post_json(self, path: str, doc: dict) -> dict:
+    def _post_json(self, path: str, doc: dict, timeout_s: float | None = None) -> dict:
         body = json.dumps(doc).encode()
         try:
             with get_pool().request(
@@ -1138,19 +1138,32 @@ class RemoteServerClient:
                 path,
                 body=body,
                 headers={"Content-Type": "application/json"},
-                timeout_s=self.timeout,
+                timeout_s=self.timeout if timeout_s is None else timeout_s,
             ) as resp:
                 payload = resp.read()
                 status = resp.status
-        except (TimeoutError, OSError) as e:
+        except TimeoutError as e:
+            raise ServerTimedOut(f"server {self.base_url} unreachable: {e}") from None
+        except OSError as e:
             raise RuntimeError(f"server {self.base_url} unreachable: {e}") from None
         if status >= 400:
             detail = bytes(payload).decode(errors="replace")
             raise RuntimeError(f"server error from {self.base_url}: {detail}") from None
         return json.loads(payload)
 
+    #: what a state transition may take: the server answers once it has loaded
+    #: the segment, which lasts as long as the segment is big (a 346 MB segment
+    #: of 21 columns 4.6 s alone; with five in flight on a busy disk a load
+    #: outlasted the 10 s of a query's hop, PERF.md, PR 35). Past it the call
+    #: raises ServerTimedOut, and the controller waits for the view instead
+    LOAD_TIMEOUT_S = 60.0
+
     def add_segment(self, table: str, segment_name: str, seg_dir) -> None:
-        self._post_json("/segments/add", {"table": table, "segment": segment_name, "dir": str(seg_dir)})
+        self._post_json(
+            "/segments/add",
+            {"table": table, "segment": segment_name, "dir": str(seg_dir)},
+            timeout_s=max(self.timeout, self.LOAD_TIMEOUT_S),
+        )
 
     def remove_segment(self, table: str, segment_name: str) -> None:
         self._post_json("/segments/remove", {"table": table, "segment": segment_name})

@@ -106,6 +106,12 @@ class ControllerUnavailableError(ConnectionError):
         self.retry_after_s = retry_after_s
 
 
+class ServerTimedOut(RuntimeError):
+    """A server took a call and did not answer inside the call's time: unlike
+    one that refuses the connection, it may still be doing what it was asked
+    (a state transition is a segment's load)."""
+
+
 class SegmentUploadError(OSError):
     """A segment upload failed before any cluster metadata referenced it
     (ENOSPC, crash, or the written bytes failing verification). The errno

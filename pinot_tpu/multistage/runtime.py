@@ -205,12 +205,13 @@ def eval_expr(expr: ast.Expr, fields: list[L.Field], df: pd.DataFrame) -> pd.Ser
         from pinot_tpu.query.transforms import (
             DEVICE_FUNCS,
             STRING_FUNCS,
+            TIME_REWRITES,
             apply_string_func,
             rewrite_time_convert,
         )
 
         name = expr.name
-        if name in ("timeconvert", "datetimeconvert"):
+        if name in TIME_REWRITES:
             rw = rewrite_time_convert(expr)
             if rw is not None:
                 return eval_expr(rw, fields, df)
@@ -790,7 +791,7 @@ def _leaf_filter_mask(seg, filt, null_on: bool = False, stats=None, node=None) -
     also attributed to the owning Scan operator's stats."""
     from pinot_tpu.common.metrics import ServerMeter, server_metrics
     from pinot_tpu.query.kernels import run_plan
-    from pinot_tpu.query.plan import DeviceFallback, PlanError, plan_filter_mask
+    from pinot_tpu.query.plan import DeviceFallback, PlanError, mark_device_fallback, plan_filter_mask
 
     t0 = _time.perf_counter() if stats is not None else 0.0
     try:
@@ -798,8 +799,8 @@ def _leaf_filter_mask(seg, filt, null_on: bool = False, stats=None, node=None) -
         # (true, unknown) pair tree — same semantics as the v1 where_spec
         plan = plan_filter_mask(seg, filt, kleene=null_on)
         mask = np.asarray(run_plan(plan, seg.to_device_cached()))[: seg.n_docs]
-    except (DeviceFallback, PlanError):
-        server_metrics().meter(ServerMeter.DEVICE_FALLBACKS).mark()
+    except (DeviceFallback, PlanError) as e:
+        mark_device_fallback(e, f"the leaf filter of segment {seg.name}")
         if stats is not None:
             stats.add_fallback(node)
         return (

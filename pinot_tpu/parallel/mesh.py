@@ -32,7 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from pinot_tpu.common.kernel_obs import KERNELS
 from pinot_tpu.common.types import Schema
 from pinot_tpu.query.context import QueryContext, QueryType
-from pinot_tpu.query.kernels import build_fn
+from pinot_tpu.query.kernels import build_fn, plan_columns
 from pinot_tpu.query.plan import SegmentPlan, plan_segment
 from pinot_tpu.segment.builder import SegmentBuilder
 from pinot_tpu.segment.segment import ImmutableSegment, padded_len
@@ -380,7 +380,7 @@ def execute_sharded(table: ShardedTable, sql: str):
         # which the sharded flat layout doesn't have — run on the proto
         raise ProtoFallback("two-MV-key cartesian GROUP BY runs on the proto segment")
     kernel, _unpack = _sharded_kernel(plan.spec, table.mesh, table.mesh.axis_names[0], table.padded)
-    cols = {c: table.arrays[c] for c in plan.columns}
+    cols = plan_columns(plan, table.arrays)
     if not cols:
         cols = {"__shape__": next(iter(table.arrays.values()))}
     operands = list(plan.operands)

@@ -20,7 +20,7 @@ import pandas as pd
 from pinot_tpu.query import ast, host_exec, reduce as reduce_mod
 from pinot_tpu.query.context import QueryContext, QueryType
 from pinot_tpu.query.kernels import dispatch_plan_packed, wait_packed
-from pinot_tpu.query.plan import DeviceFallback, SegmentPlan, plan_segment
+from pinot_tpu.query.plan import DeviceFallback, SegmentPlan, mark_device_fallback, plan_segment
 from pinot_tpu.query.result import ResultTable
 from pinot_tpu.query.sql import parse_sql
 from pinot_tpu.segment.segment import DeviceSegment, ImmutableSegment
@@ -561,12 +561,10 @@ class QueryEngine:
             # plan_segment threads valid_docs into the kernel as a docmask
             # operand, so upsert tables run the fused device path too
             plan = plan_segment(seg, ctx, valid_mask=vmask)
-        except DeviceFallback:
-            from pinot_tpu.common.metrics import ServerMeter, server_metrics
-
+        except DeviceFallback as e:
             # the same meter the multistage leaf marks: a segment that left
             # the device path is counted, whichever engine it ran under
-            server_metrics().meter(ServerMeter.DEVICE_FALLBACKS).mark()
+            mark_device_fallback(e, f"segment {seg.name}")
             return ("ready",) + self._host_segment(seg, ctx, extra_mask=vmask) + ("host",)
         return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(seg)), vmask)
 

@@ -40,19 +40,6 @@ def eval_value(seg: ImmutableSegment, expr: ast.Expr) -> np.ndarray:
         return ci.materialize()
     if isinstance(expr, ast.Literal):
         return np.full(seg.n_docs, expr.value)
-    if isinstance(expr, ast.BinaryOp):
-        l = eval_value(seg, expr.left)
-        r = eval_value(seg, expr.right)
-        if expr.op == "+":
-            return l + r
-        if expr.op == "-":
-            return l - r
-        if expr.op == "*":
-            return l * r
-        if expr.op == "/":
-            return l.astype(np.float64) / r.astype(np.float64)
-        if expr.op == "%":
-            return np.mod(l, r)
     if isinstance(expr, ast.CaseWhen):
         conds = [filter_mask(seg, c) for c, _ in expr.whens]
         vals = [np.asarray(eval_value(seg, v)) for _, v in expr.whens]
@@ -71,18 +58,7 @@ def eval_value(seg: ImmutableSegment, expr: ast.Expr) -> np.ndarray:
             default = default.astype(object)
         return np.select(conds, vals, default=default)
     if isinstance(expr, ast.FunctionCall):
-        from pinot_tpu.query.transforms import (
-            DEVICE_FUNCS,
-            STRING_FUNCS,
-            apply_string_func,
-            rewrite_time_convert,
-        )
-
         name = expr.name
-        if name in ("timeconvert", "datetimeconvert"):
-            rw = rewrite_time_convert(expr)
-            if rw is not None:
-                return eval_value(seg, rw)
         if name == "map_value":
             # map_value(col, 'key'): dense per-key column via the map index
             # when present, else per-row document parse (StandardIndexes map
@@ -134,16 +110,6 @@ def eval_value(seg: ImmutableSegment, expr: ast.Expr) -> np.ndarray:
                 )
             keys = list(zip(*[a.tolist() for a in key_arrays]))
             return dim.lookup_column(dest, keys)
-        if name == "cast":
-            v = eval_value(seg, expr.args[0])
-            target = str(expr.args[1].value).upper()
-            if target in ("INT", "LONG", "TIMESTAMP", "BOOLEAN"):
-                return np.trunc(v.astype(np.float64)).astype(np.int64) if np.issubdtype(v.dtype, np.floating) else v
-            if target in ("FLOAT", "DOUBLE"):
-                return v.astype(np.float64)
-            if target == "STRING":
-                return np.asarray([str(x) for x in v], dtype=object)
-            raise PlanError(f"unsupported CAST target {target}")
         if name == "coalesce":
             # first non-null argument per row (CoalesceTransformFunction):
             # null = the column null-vector OR a NaN/None cell. Accumulate in
@@ -200,15 +166,18 @@ def eval_value(seg: ImmutableSegment, expr: ast.Expr) -> np.ndarray:
                     # both sides literal: constant result per doc
                     res = np.full(seg.n_docs, float(res[0]))
                 return res
-        if name in DEVICE_FUNCS:
-            _, fn = DEVICE_FUNCS[name]
-            # the device lambdas take the array module first — numpy works too
-            args = [eval_value(seg, a) for a in expr.args]
-            return np.asarray(fn(np, *args))
-        if name in STRING_FUNCS:
+    if isinstance(expr, (ast.BinaryOp, ast.FunctionCall)):
+        from pinot_tpu.query.transforms import STRING_FUNCS, apply_scalar, apply_string_func
+
+        # arithmetic, CAST, the time rewrites and the device functions: the
+        # step the planner's expression GROUP BY key takes over a dictionary
+        out = apply_scalar(expr, lambda e: eval_value(seg, e))
+        if out is not NotImplemented:
+            return out
+        if isinstance(expr, ast.FunctionCall) and expr.name in STRING_FUNCS:
             base = eval_value(seg, expr.args[0])
             lit_args = tuple(a.value for a in expr.args[1:] if isinstance(a, ast.Literal))
-            derived, _ = apply_string_func(name, base, lit_args)
+            derived, _ = apply_string_func(expr.name, base, lit_args)
             return derived
     raise PlanError(f"unsupported value expression in host executor: {expr}")
 

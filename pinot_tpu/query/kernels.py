@@ -308,7 +308,7 @@ def _grouped_reduce(kind, v, gid, mask, ng, dense_g):
     fill, _, scatter = _REDUCTIONS[kind]
     fill = jnp.asarray(fill, v.dtype)
     if dense_g is None:
-        return scatter(jnp.where(mask, v, fill), gid, num_segments=ng)
+        return _scatter_grouped(scatter, jnp.where(mask, v, fill), gid, ng)
     r = KERNELS.timed_sync(
         "query.grouped_dense",
         lambda: _dense_grouped(kind, v, gid, mask, dense_g),
@@ -626,7 +626,7 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
         strides = ops[strides_idx]
         gid = jnp.zeros((cols[mv_col].shape[0],), dtype=jnp.int32)
         for i, c in enumerate(gcols):
-            ids = cols[c] if c == mv_col else cols[c][docids]
+            ids = cols[c] if c == mv_col else _key_ids(c, cols, ops)[docids]
             gid = gid + ids * strides[i]
         counts, parts = _grouped_all(
             aggs, cols, ops, vmask, gid, ng, gather=docids, doc_pad=n_padded
@@ -655,7 +655,7 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
             elif c == mv_b:
                 idc = ids_b
             else:
-                idc = cols[c][docids][:, None]
+                idc = _key_ids(c, cols, ops)[docids][:, None]
             gid2 = gid2 + idc * strides[i]
         pair_docids = jnp.broadcast_to(docids[:, None], (va, lb)).reshape(-1)
         counts, parts = _grouped_all(
@@ -679,7 +679,7 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
         strides = ops[strides_idx]
         gid64 = jnp.zeros((n_padded,), dtype=jnp.int64)
         for i, c in enumerate(gcols):
-            gid64 = gid64 + cols[c].astype(jnp.int64) * strides[i]
+            gid64 = gid64 + _key_ids(c, cols, ops).astype(jnp.int64) * strides[i]
         sent = jnp.int64(1) << jnp.int64(62)
         gm = jnp.where(mask, gid64, sent)
         sg = jnp.sort(gm)
@@ -696,7 +696,7 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
     strides = ops[strides_idx]
     gid = jnp.zeros((n_padded,), dtype=jnp.int32)
     for i, c in enumerate(gcols):
-        gid = gid + cols[c] * strides[i]
+        gid = gid + _key_ids(c, cols, ops) * strides[i]
     counts, parts = _grouped_all(aggs, cols, ops, mask, gid, ng, dense_g=real[0] if real else None)
     return matched, counts, parts
 
@@ -901,6 +901,43 @@ KERNELS.register(
     cost_model=_dense_cost,
     description="grouped f64 SUM/MIN/MAX over few real groups as a dense masked reduction; one call a reduction traced",
 )
+
+
+def _key_ids(key, cols, ops):
+    """The ids of one GROUP BY key: a dictionary-coded column's own codes, or,
+    for an expression key ("remap", column, operand: plan.expr_key), the
+    codes gathered through the plan's code -> bucket operand."""
+    return cols[key] if isinstance(key, str) else ops[key[2]][cols[key[1]]]
+
+
+def _scatter_cost(shape: dict) -> tuple[float, float]:
+    """One grouped scatter, by what it must move at least: every row's value
+    and group id once, the group table once; an add (or compare) a row."""
+    rows = max(float(shape.get("rows", 0)), 0.0)
+    width = float(shape.get("width", 8))
+    return rows * (width + 4.0) + float(shape.get("groups", 0)) * width, rows
+
+
+def _scatter_grouped(scatter, v, gid, ng):
+    """The scatter side of `_grouped_reduce`: jax.ops.segment_{sum,min,max}
+    into `ng` slots, one call a reduction traced under the registry name
+    `query.grouped_scatter` so that a launch's `deviceWork` says how many
+    scatters it holds, over how many rows and slots."""
+    return KERNELS.timed_sync(
+        "query.grouped_scatter",
+        lambda: scatter(v, gid, num_segments=ng),
+        rows=v.shape[0],
+        groups=ng,
+        width=v.dtype.itemsize,
+    )
+
+
+KERNELS.register(
+    "query.grouped_scatter",
+    _scatter_grouped,
+    cost_model=_scatter_cost,
+    description="grouped SUM/MIN/MAX of a value that is not int32 as a scatter (no real group count stated, or more than plan.DENSE_REDUCE_MAX_GROUPS); one call a reduction traced",
+)
 KERNELS.register(
     "query.fused",
     get_kernel,
@@ -985,10 +1022,19 @@ def stage_operand(o):
     return dev
 
 
+def plan_columns(plan, arrays) -> dict:
+    """A program's column arguments out of a staged table's arrays: the plan's
+    columns under their names, its raw value columns under their places
+    ("@0", "@1", ...: plan._Lowering.raw_value)."""
+    cols = {c: arrays[c] for c in plan.columns}
+    cols.update((f"@{i}", arrays[c]) for i, c in enumerate(plan.value_columns))
+    return cols
+
+
 def _plan_inputs(plan, device_segment):
     """Device column dict + operand tuple for a plan (shared by run_plan and
     run_plan_packed; owns the no-columns '__shape__' dummy convention)."""
-    cols = {c: device_segment.arrays[c] for c in plan.columns}
+    cols = plan_columns(plan, device_segment.arrays)
     if not cols:
         # query touches no columns (e.g. SELECT COUNT(*) FROM t): feed a
         # dummy array for shape discovery

@@ -55,7 +55,29 @@ VIRTUAL_COLUMNS = ("$docId", "$segmentName", "$hostName")
 
 
 class DeviceFallback(Exception):
-    """Query shape has no device lowering yet; use the host executor."""
+    """Query shape has no device lowering yet; use the host executor.
+    `reason` is the class of the message, the `reason` label of
+    `server.deviceFallbacks`: given where the message names a column or a
+    function, else the message's own words."""
+
+    def __init__(self, message: str, reason: str | None = None):
+        super().__init__(message)
+        self.reason = reason or "_".join(re.findall(r"[A-Za-z][A-Za-z0-9+-]*", message)[:8]).lower()
+
+
+def mark_device_fallback(e: Exception, where: str) -> None:
+    """Count a segment that left the device path under the reason of what it
+    raised (`server.deviceFallbacks{reason=}`; a PlanError caught in a
+    fallback's place counts as `plan_error`), and say the first of each reason
+    in the server's log: `where` and the message."""
+    import logging
+
+    from pinot_tpu.common.metrics import ServerMeter, server_metrics
+
+    meter = server_metrics().meter(ServerMeter.DEVICE_FALLBACKS, reason=getattr(e, "reason", "plan_error"))
+    if meter.count == 0:
+        logging.getLogger("pinot_tpu.query").warning("%s runs on the host executor: %s (the first of its reason)", where, e)
+    meter.mark()
 
 
 class PlanError(ValueError):
@@ -76,12 +98,28 @@ def group_strides(cards: list, dtype=np.int64) -> np.ndarray:
 
 
 @dataclass
+class KeyBuckets:
+    """What an expression GROUP BY key's ids decode through: the distinct
+    values the expression takes over its column's dictionary, ascending, as
+    a dictionary of their own. A bare column's ColumnIndex has the same two
+    attributes."""
+
+    dictionary: Any
+
+    @property
+    def cardinality(self) -> int:
+        return self.dictionary.cardinality
+
+
+@dataclass
 class SegmentPlan:
     spec: tuple  # static, hashable — keys the kernel compile cache
     operands: tuple  # numpy arrays/scalars fed as dynamic inputs
     columns: tuple[str, ...]  # device arrays the kernel reads, in order
+    # raw columns read as values, which the spec names by position ("@0", "@1", ...: _Lowering.raw_value)
+    value_columns: tuple[str, ...] = ()
     # host-side decode info
-    group_cols: list[tuple[str, Any]] = field(default_factory=list)  # (col, ColumnIndex)
+    group_cols: list[tuple[str, Any]] = field(default_factory=list)  # (key, ColumnIndex | KeyBuckets)
     select_decode: list[tuple] = field(default_factory=list)
     aggs: list[AggregationInfo] = field(default_factory=list)
     # multi-key ORDER BY composite: [(col, card, desc, kind, offset)], most
@@ -96,8 +134,10 @@ class _Lowering:
         self.ctx = ctx
         self.operands: list[Any] = []
         self.columns: list[str] = []
+        self.value_columns: list[str] = []  # see raw_value
         self._group_ng = 1  # set by group_spec; agg budget checks consult it
         self._group_real = None  # set by group_spec: real groups of a dense group space
+        self.group_cols: list[tuple[str, Any]] = []  # set by group_spec: (key, what its ids decode through)
         # null docmask operand index per frozenset of columns: one decode +
         # one device transfer however many Kleene leaves reference them
         self._null_mask_ops: dict[frozenset, int] = {}
@@ -121,6 +161,22 @@ class _Lowering:
                 # flattened MV: kernels also need the owning-doc-id vector
                 self.columns.append(f"{col}!docs")
         return col
+
+    def raw_value(self, col: str) -> tuple:
+        """A raw single-value column read as a value: ("raw", "@<i>"), the
+        column named by its place among the plan's `value_columns` and not by
+        its name, so that queries that differ only in which raw column they
+        read (AVG(usage_user), AVG(usage_irq)) share one program."""
+        if col not in self.seg.columns:
+            raise PlanError(f"unknown column {col!r} in table {self.ctx.table}")
+        if col not in self.value_columns:
+            self.value_columns.append(col)
+        return ("raw", f"@{self.value_columns.index(col)}")
+
+    def _raw_column(self, vspec: tuple):
+        """The segment's column behind a ("raw", ...) value spec, named or placed."""
+        name = vspec[1]
+        return self.seg.columns[self.value_columns[int(name[1:])] if name.startswith("@") else name]
 
     def _mv_wrap(self, col: str, spec: tuple) -> tuple:
         """Wrap a flat (per-value) predicate spec into MV any-match doc
@@ -177,27 +233,28 @@ class _Lowering:
             if expr.name == "$docId":
                 return ("docid",)
             if expr.name in VIRTUAL_COLUMNS:
-                raise DeviceFallback(f"virtual column {expr.name} in value context runs host-side")
+                raise DeviceFallback(f"virtual column {expr.name} in value context runs host-side", reason="virtual_column_value")
             ci = self.seg.columns.get(expr.name)
             if ci is None:
                 raise PlanError(f"unknown column {expr.name!r}")
             if ci.is_mv:
                 raise DeviceFallback(
-                    f"MV column {expr.name!r} in value context runs host-side (use the *MV aggregations)"
+                    f"MV column {expr.name!r} in value context runs host-side (use the *MV aggregations)",
+                    reason="mv_column_value",
                 )
             if ci.data_type in (DataType.STRING, DataType.BYTES, DataType.JSON):
                 raise PlanError(f"column {expr.name!r} is not numeric")
+            if not ci.is_dict_encoded:
+                return self.raw_value(expr.name)
             self.use_col(expr.name)
-            if ci.is_dict_encoded:
-                # operand: dictionary values padded to pow2 (repeat last value)
-                dv = np.asarray(ci.dictionary.values)
-                pad = _pow2(max(len(dv), 1))
-                if len(dv) == 0:
-                    dv = np.zeros(1, dtype=ci.data_type.np_dtype)
-                if len(dv) < pad:
-                    dv = np.concatenate([dv, np.full(pad - len(dv), dv[-1], dtype=dv.dtype)])
-                return ("dictval", expr.name, self.op_idx(dv))
-            return ("raw", expr.name)
+            # operand: dictionary values padded to pow2 (repeat last value)
+            dv = np.asarray(ci.dictionary.values)
+            pad = _pow2(max(len(dv), 1))
+            if len(dv) == 0:
+                dv = np.zeros(1, dtype=ci.data_type.np_dtype)
+            if len(dv) < pad:
+                dv = np.concatenate([dv, np.full(pad - len(dv), dv[-1], dtype=dv.dtype)])
+            return ("dictval", expr.name, self.op_idx(dv))
         if isinstance(expr, ast.Literal):
             if not isinstance(expr.value, (int, float, bool)):
                 raise PlanError(f"non-numeric literal in value expression: {expr}")
@@ -240,12 +297,13 @@ class _Lowering:
         from pinot_tpu.query.transforms import (
             DEVICE_FUNCS,
             STRING_FUNCS,
+            TIME_REWRITES,
             apply_string_func,
             rewrite_time_convert,
         )
 
         name = expr.name
-        if name in ("timeconvert", "datetimeconvert"):
+        if name in TIME_REWRITES:
             rw = rewrite_time_convert(expr)
             if rw is not None:
                 return self.value_spec(rw)
@@ -260,7 +318,7 @@ class _Lowering:
                 return ("cast_int", self.value_spec(expr.args[0]))
             if target in ("FLOAT", "DOUBLE"):
                 return ("cast_float", self.value_spec(expr.args[0]))
-            raise DeviceFallback(f"CAST to {target} runs host-side")
+            raise DeviceFallback(f"CAST to {target} runs host-side", reason="cast_target")
         if name in DEVICE_FUNCS:
             arity, _ = DEVICE_FUNCS[name]
             if len(expr.args) != arity:
@@ -274,7 +332,7 @@ class _Lowering:
             if is_str:
                 # string-valued projection: the host executor evaluates it
                 # (device selections return numeric/id columns only)
-                raise DeviceFallback(f"string-valued {name}(...) runs host-side")
+                raise DeviceFallback(f"string-valued {name}(...) runs host-side", reason="string_valued_function")
             self.use_col(col)
             pad = _pow2(max(len(derived), 1))
             dv = derived
@@ -283,7 +341,7 @@ class _Lowering:
             if len(dv) < pad:
                 dv = np.concatenate([dv, np.full(pad - len(dv), dv[-1])])
             return ("dictval", col, self.op_idx(dv))
-        raise DeviceFallback(f"transform function {name} has no device lowering yet")
+        raise DeviceFallback(f"transform function {name} has no device lowering yet", reason="transform_function")
 
     def _derived_string_values(self, expr: ast.FunctionCall):
         """Evaluate a string function over a dict column's VALUES host-side.
@@ -291,17 +349,17 @@ class _Lowering:
         from pinot_tpu.query.transforms import apply_string_func
 
         if not expr.args or not isinstance(expr.args[0], ast.Identifier):
-            raise DeviceFallback(f"{expr.name} over non-column args runs host-side")
+            raise DeviceFallback(f"{expr.name} over non-column args runs host-side", reason="string_function_args")
         col = expr.args[0].name
         ci = self.seg.columns.get(col)
         if ci is None:
             raise PlanError(f"unknown column {col!r}")
         if not ci.is_dict_encoded:
-            raise DeviceFallback(f"{expr.name} over raw column runs host-side")
+            raise DeviceFallback(f"{expr.name} over raw column runs host-side", reason="string_function_raw_column")
         lit_args = []
         for a in expr.args[1:]:
             if not isinstance(a, ast.Literal):
-                raise DeviceFallback(f"{expr.name} with non-literal args runs host-side")
+                raise DeviceFallback(f"{expr.name} with non-literal args runs host-side", reason="string_function_args")
             lit_args.append(a.value)
         derived, is_str = apply_string_func(expr.name, ci.dictionary.values, tuple(lit_args))
         return derived, is_str, col
@@ -677,7 +735,7 @@ class _Lowering:
         )
         col_dt = None
         if vs[0] == "raw":
-            ci_in = self.seg.columns[vs[1]]
+            ci_in = self._raw_column(vs)
             col_dt = ci_in.forward.dtype
             # match to_device's lossless int64->int32 narrowing: the operand
             # dtype must equal the DEVICE dtype or the kernel-side cast wraps
@@ -823,7 +881,7 @@ class _Lowering:
                     raise DeviceFallback("grouped percentileest histogram matrix exceeds device budget")
             return self._hist_spec(info)
         if info.func in ("percentile", "percentiletdigest", "mode"):
-            raise DeviceFallback(f"{info.func} runs host-side (full-values / counter intermediate)")
+            raise DeviceFallback(f"{info.func} runs host-side (full-values / counter intermediate)", reason="aggregation_full_values")
         if info.func in ("sum", "min", "max", "avg", "minmaxrange"):
             if info.arg is None:
                 raise PlanError(f"{info.func} requires an argument")
@@ -846,7 +904,7 @@ class _Lowering:
             stepspecs = tuple(self.filter_spec(s) for s in steps)
             col = self.use_col(info.arg.name)
             return ("funnel_steps", col, _pow2(max(ci.cardinality, 1)), stepspecs)
-        raise DeviceFallback(f"aggregation {info.func} has no device lowering yet")
+        raise DeviceFallback(f"aggregation {info.func} has no device lowering yet", reason="aggregation_function")
 
     def _mv_agg_spec(self, info: AggregationInfo, grouped: bool) -> tuple:
         """MV aggregations over the flattened layout (reference:
@@ -931,25 +989,88 @@ class _Lowering:
     # two-MV-key device group-by
     MAX_MV2_PAIRS = 1 << 23
 
+    def expr_key(self, g: Expr) -> tuple[tuple, "KeyBuckets"]:
+        """An expression GROUP BY key over one single-value dictionary-coded
+        column (DATETRUNC('hour', ts), DATETIMECONVERT, ts / 3600000): the
+        expression is evaluated over the column's dictionary, the distinct
+        results in ascending order are the key's buckets, and the device
+        gathers each row's bucket through a code -> bucket operand, padded to
+        a power of two so that segments whose dictionaries differ share a
+        program. Returns the key's entry in the group spec and what its
+        bucket indices decode through. Any other expression falls back,
+        under a reason of its own."""
+        from pinot_tpu.common.trace import span
+        from pinot_tpu.query.context import _collect_identifiers
+        from pinot_tpu.query.transforms import apply_scalar
+        from pinot_tpu.segment.dictionary import Dictionary
+
+        names: set[str] = set()
+        _collect_identifiers(g, names)
+        if len(names) != 1:
+            raise DeviceFallback(
+                f"GROUP BY expression over {len(names)} columns runs host-side",
+                reason="group_key_several_columns" if names else "group_key_no_column",
+            )
+        (col,) = names
+        if col in VIRTUAL_COLUMNS:
+            raise DeviceFallback(f"GROUP BY virtual column {col} runs host-side", reason="group_key_virtual_column")
+        ci = self.seg.columns.get(col)
+        if ci is None:
+            raise PlanError(f"unknown column {col!r}")
+        if not ci.is_dict_encoded or ci.is_mv:
+            raise DeviceFallback(
+                f"GROUP BY expression over {'multi-value' if ci.is_mv else 'raw'} column {col} runs host-side",
+                reason="group_key_mv_column" if ci.is_mv else "group_key_raw_column",
+            )
+        with span("server.plan.group_key", column=col) as sp:
+            values = np.asarray(ci.dictionary.values)
+
+            def over(e: Expr) -> np.ndarray:
+                if isinstance(e, ast.Identifier):
+                    return values
+                if isinstance(e, ast.Literal):
+                    return np.full(len(values), e.value)
+                out = apply_scalar(e, over)
+                if out is NotImplemented:
+                    raise DeviceFallback(f"GROUP BY expression {g} runs host-side", reason="group_key_expression_form")
+                return out
+
+            results = np.asarray(over(g))
+            if results.dtype.kind not in "iuf":
+                raise DeviceFallback(f"GROUP BY expression {g} is not numeric", reason="group_key_not_numeric")
+            buckets, index = np.unique(results, return_inverse=True)
+            remap = np.zeros(_pow2(max(len(index), 1)), dtype=np.int32)
+            remap[: len(index)] = index
+            sp.set_attr("buckets", len(buckets))
+        self.use_col(col)
+        dt = DataType.DOUBLE if buckets.dtype.kind == "f" else DataType.LONG
+        return ("remap", col, self.op_idx(remap)), KeyBuckets(Dictionary(dt, buckets.astype(dt.np_dtype)))
+
     def group_spec(self) -> tuple:
         cols = []
         cards = []
         mv_cols: list[str] = []
+        self.group_cols = []
         for g in self.ctx.group_by:
             if not isinstance(g, ast.Identifier):
-                raise DeviceFallback("expression GROUP BY keys run host-side for now")
+                key, buckets = self.expr_key(g)
+                cols.append(key)
+                cards.append(buckets.cardinality)
+                self.group_cols.append((str(g), buckets))
+                continue
             if g.name in VIRTUAL_COLUMNS:
-                raise DeviceFallback(f"GROUP BY virtual column {g.name} runs host-side")
+                raise DeviceFallback(f"GROUP BY virtual column {g.name} runs host-side", reason="group_key_virtual_column")
             ci = self.seg.columns.get(g.name)
             if ci is None:
                 raise PlanError(f"unknown column {g.name!r}")
             if not ci.is_dict_encoded:
-                raise DeviceFallback(f"GROUP BY on raw column {g.name} runs host-side for now")
+                raise DeviceFallback(f"GROUP BY on raw column {g.name} runs host-side for now", reason="group_key_raw_column")
             if ci.is_mv:
                 mv_cols.append(g.name)
             self.use_col(g.name)
             cols.append(g.name)
             cards.append(ci.cardinality)
+            self.group_cols.append((g.name, ci))
         if len(mv_cols) > 2:
             raise DeviceFallback("3+ MV GROUP BY keys run host-side (explode)")
         if len(mv_cols) == 2 and mv_cols[0] == mv_cols[1]:
@@ -1030,7 +1151,7 @@ class _Lowering:
         int32 dictionary's values, and +, -, *, % of such."""
         kind = vspec[0]
         if kind == "raw":
-            ci = self.seg.columns[vspec[1]]
+            ci = self._raw_column(vspec)
             return ci.forward.dtype == np.int32 or narrows_to_int32(ci)
         if kind == "dictval":
             return self.operands[vspec[2]].dtype == np.int32
@@ -1064,7 +1185,8 @@ class _Lowering:
         pairs = padded_len(len(self.seg.columns[a].forward)) * lb
         if pairs > self.MAX_MV2_PAIRS:
             raise DeviceFallback(
-                f"two-MV-key pair space {pairs} exceeds device budget {self.MAX_MV2_PAIRS}"
+                f"two-MV-key pair space {pairs} exceeds device budget {self.MAX_MV2_PAIRS}",
+                reason="mv_pair_space",
             )
         pad = padded_len(self.seg.n_docs)
         off = ci_b.offsets()[: self.seg.n_docs].astype(np.int32)
@@ -1169,6 +1291,7 @@ def plan_filter_mask(seg: ImmutableSegment, filt, valid_mask=None, kleene: bool 
         spec=("mask", fspec),
         operands=tuple(lo.operands),
         columns=tuple(lo.columns),
+        value_columns=tuple(lo.value_columns),
         group_cols=[],
         aggs=[],
     )
@@ -1232,7 +1355,8 @@ def plan_segment(seg: ImmutableSegment, ctx: QueryContext, valid_mask=None) -> S
             spec=spec,
             operands=tuple(lo.operands),
             columns=tuple(lo.columns),
-            group_cols=[(c, seg.columns[c]) for c in (gspec[1] if gspec else ())],
+            value_columns=tuple(lo.value_columns),
+            group_cols=lo.group_cols if gspec else [],
             aggs=list(ctx.aggregations),
         )
         return plan
@@ -1249,7 +1373,8 @@ def plan_segment(seg: ImmutableSegment, ctx: QueryContext, valid_mask=None) -> S
             spec=spec,
             operands=tuple(lo.operands),
             columns=tuple(lo.columns),
-            group_cols=[(c, seg.columns[c]) for c in gspec[1]],
+            value_columns=tuple(lo.value_columns),
+            group_cols=lo.group_cols,
             aggs=[],
         )
 
@@ -1319,6 +1444,7 @@ def plan_segment(seg: ImmutableSegment, ctx: QueryContext, valid_mask=None) -> S
         spec=spec,
         operands=tuple(lo.operands),
         columns=tuple(lo.columns),
+        value_columns=tuple(lo.value_columns),
         select_decode=decode,
         aggs=[],
         ob_decomp=ob_decomp,

@@ -253,11 +253,17 @@ def _unit_ms(u: str) -> int:
     return _UNIT_MS[uu]
 
 
+#: the calls `rewrite_time_convert` rewrites; the three evaluators (plan, host, multistage) ask for it by these
+TIME_REWRITES = ("timeconvert", "datetimeconvert", "datetrunc")
+
+
 def rewrite_time_convert(expr) -> "object | None":
     """Rewrite TIMECONVERT(v,'fromUnit','toUnit') or DATETIMECONVERT(v,
     'S:UNIT:EPOCH','S:UNIT:EPOCH','N:UNIT') into CAST(v*a/b bucketed, 'LONG')
-    AST nodes both execution paths lower natively. Returns None when expr is
-    not one of these calls (caller continues normal dispatch)."""
+    AST nodes, and DATETRUNC('unit', v) over epoch milliseconds into the
+    one-argument DATETRUNC_<UNIT>(v) of DEVICE_FUNCS; both execution paths
+    lower these natively. Returns None when expr is not one of these calls
+    (caller continues normal dispatch)."""
     from pinot_tpu.query import ast
 
     if not isinstance(expr, ast.FunctionCall):
@@ -275,6 +281,14 @@ def rewrite_time_convert(expr) -> "object | None":
         # CAST(x / k, LONG) truncates; inputs are non-negative epochs
         return e if k == 1 else _cast_long(ast.BinaryOp("/", e, ast.Literal(k)))
 
+    if name == "datetrunc":
+        # DATETRUNC('unit', millis): the two-argument form (DateTruncTransformFunction's
+        # input unit, time zone and output unit keep their defaults: MILLISECONDS, UTC)
+        unit = expr.args[0].value if expr.args and isinstance(expr.args[0], ast.Literal) else None
+        fn = f"datetrunc_{str(unit).lower()}"
+        if len(expr.args) != 2 or fn not in DEVICE_FUNCS:
+            raise ValueError("DATETRUNC requires ('second'|'minute'|'hour'|'day'|'week'|'month'|'quarter'|'year', millis)")
+        return ast.FunctionCall(fn, [expr.args[1]])
     if name == "timeconvert":
         if len(expr.args) != 3 or len(lits) != 2:
             raise ValueError("TIMECONVERT requires (value, 'fromUnit', 'toUnit')")
@@ -300,6 +314,51 @@ def rewrite_time_convert(expr) -> "object | None":
         bucketed = _mul(_div_floor(ms, gran), gran)
         return _cast_long(_div_floor(bucketed, fout))
     return None
+
+
+def apply_scalar(expr, ev):
+    """One step of a scalar value expression over numpy arrays, whatever they
+    are the values of (a segment's rows for the host executor, a column's
+    dictionary for the planner's expression GROUP BY key): `ev(child)` gives a
+    child's values. Covers + - * / %, CAST, the time rewrites and
+    DEVICE_FUNCS; returns NotImplemented for any other node."""
+    from pinot_tpu.query import ast
+
+    if isinstance(expr, ast.BinaryOp):
+        l, r = ev(expr.left), ev(expr.right)
+        if expr.op == "+":
+            return l + r
+        if expr.op == "-":
+            return l - r
+        if expr.op == "*":
+            return l * r
+        if expr.op == "/":
+            return l.astype(np.float64) / r.astype(np.float64)
+        if expr.op == "%":
+            return np.mod(l, r)
+    if isinstance(expr, ast.FunctionCall):
+        name = expr.name
+        if name in TIME_REWRITES:
+            rw = rewrite_time_convert(expr)
+            if rw is not None:
+                return ev(rw)
+        if name == "cast":
+            from pinot_tpu.query.plan import PlanError
+
+            v = ev(expr.args[0])
+            target = str(expr.args[1].value).upper()
+            if target in ("INT", "LONG", "TIMESTAMP", "BOOLEAN"):
+                return np.trunc(v.astype(np.float64)).astype(np.int64) if np.issubdtype(v.dtype, np.floating) else v
+            if target in ("FLOAT", "DOUBLE"):
+                return v.astype(np.float64)
+            if target == "STRING":
+                return np.asarray([str(x) for x in v], dtype=object)
+            raise PlanError(f"unsupported CAST target {target}")
+        if name in DEVICE_FUNCS:
+            _, fn = DEVICE_FUNCS[name]
+            # the device lambdas take the array module first — numpy works too
+            return np.asarray(fn(np, *[ev(a) for a in expr.args]))
+    return NotImplemented
 
 
 # ---------------------------------------------------------------------------

@@ -284,20 +284,22 @@ def test_mv_keys_and_the_sort_compaction_path_keep_their_specs():
 
 
 # ---------------------------------------------------------------------------
-# SSB-shaped group-bys are the parent's programs, name for name
+# SSB-shaped group-bys keep their programs, name for name
 # ---------------------------------------------------------------------------
 
 SSB_PROGRAMS = {
     # flights 2 to 4 over an SSB-shaped table: integer metrics only. The names are
-    # program_name() of the parent commit's plans (88b50b1) over this same table.
+    # program_name() of these plans over this same table as of PR 35, which named every
+    # program anew once (a raw value column is "@0" in the spec, not its name: plan.raw_value);
+    # before it they were 838d2a87, 5ec0973e, 6728849d, 5d951c51, the same from PR 28's parent on.
     "SELECT d_year, p_brand1, SUM(lo_revenue) FROM lineorder WHERE p_category = 'MFGR#12' AND s_region = 'AMERICA' "
-    "GROUP BY d_year, p_brand1 ORDER BY d_year, p_brand1 LIMIT 10": "seg_groupby_838d2a87",
+    "GROUP BY d_year, p_brand1 ORDER BY d_year, p_brand1 LIMIT 10": "seg_groupby_25e3d56c",
     "SELECT c_nation, s_nation, d_year, SUM(lo_revenue) FROM lineorder WHERE c_region = 'ASIA' AND s_region = 'ASIA' "
-    "AND d_year >= 1992 AND d_year <= 1997 GROUP BY c_nation, s_nation, d_year ORDER BY d_year LIMIT 10": "seg_groupby_5ec0973e",
+    "AND d_year >= 1992 AND d_year <= 1997 GROUP BY c_nation, s_nation, d_year ORDER BY d_year LIMIT 10": "seg_groupby_e190ae97",
     "SELECT d_year, c_nation, SUM(lo_revenue - lo_supplycost), COUNT(*) FROM lineorder WHERE c_region = 'AMERICA' "
-    "GROUP BY d_year, c_nation ORDER BY d_year, c_nation LIMIT 10": "seg_groupby_6728849d",
+    "GROUP BY d_year, c_nation ORDER BY d_year, c_nation LIMIT 10": "seg_groupby_96dd7fb4",
     "SELECT d_year, SUM(lo_extendedprice * lo_discount), AVG(lo_quantity) FROM lineorder "
-    "GROUP BY d_year ORDER BY d_year LIMIT 10": "seg_groupby_5d951c51",
+    "GROUP BY d_year ORDER BY d_year LIMIT 10": "seg_groupby_89caa50d",
 }
 
 

@@ -310,3 +310,36 @@ def test_dense_grouped_reduction_is_one_fused_pass_on_the_v5e(one_v5e_chip, g):
     text = compiled.as_text()
     assert "scatter" not in text and f"f32[{g},{n // kernels._BLOCK}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < n  # the one-hot alone: n * g * 8
+
+
+def test_an_expression_key_and_the_double_scatter_compile_at_the_tsbs_segment_on_the_v5e(one_v5e_chip):
+    """`tsbs-hosthour-closed`'s launch at its real shape: 4.32M rows (padded),
+    the hour bucket gathered through the plan's code -> bucket operand
+    (`kernels._key_ids`), 4000 hosts x 3 hours in 12,032 slots — past
+    plan.DENSE_REDUCE_MAX_GROUPS, so AVG's DOUBLE sum and its count are
+    scatters (`kernels._grouped_reduce`). The v5e's compiler takes it, keeps
+    the scatters and needs no temporary memory beside the arguments."""
+    import jax
+
+    from pinot_tpu.query import kernels, plan
+    from pinot_tpu.segment.segment import padded_len
+
+    n, ng = padded_len(4_320_000), 12_032
+    assert 4000 * 3 > plan.DENSE_REDUCE_MAX_GROUPS and ng == -(-4000 * 3 // 256) * 256
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    def launch(v, host, ts, remap, mask):
+        cols, ops = {"hostname": host, "ts": ts}, (remap,)
+        gid = kernels._key_ids("hostname", cols, ops) * 3 + kernels._key_ids(("remap", "ts", 0), cols, ops)
+        return kernels._grouped_reduce("sum", v, gid, mask, ng, None), kernels._count_grouped(mask, gid, ng)
+
+    compiled = (
+        jax.jit(launch)
+        .lower(arg((n,), jnp.float64), arg((n,), jnp.int32), arg((n,), jnp.int32), arg((2048,), jnp.int32), arg((n,), jnp.bool_))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count("scatter-add") >= 2 and "gather" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < n

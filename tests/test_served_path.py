@@ -31,6 +31,8 @@ REFUSED_SEED = 2_900_000_010
 #: the cell in which a server is killed 10 s into the window and started again: a window that outlasts the
 #: kill, the restart and the reload
 LOSS_CELL, LOSS_SECONDS, LOSS_SEED = "ssb4-serverloss-closed", 20, 3_300_000_011
+#: the cell whose GROUP BY key is an expression (TSBS double-groupby-1)
+TIME_CELL, TIME_SEED = "tsbs-hosthour-closed", 3_350_000_011
 
 
 def _env(**extra):
@@ -106,6 +108,37 @@ def test_rehearsal_ends_in_a_valid_line_and_is_correct(rehearse, workload):
     assert line["device"]["platform"] == "cpu" and line["rehearsal"] is True
     assert all(c["value"] <= c["limit"] for c in line["compared"].values()), line["compared"]
     assert line["compared"]["max_abs_diff"]["value"] == 0  # integer aggregates: exact
+
+
+def test_a_time_chart_by_host_and_hour_runs_on_the_device_path():
+    """`tsbs-hosthour-closed` at its rehearsal size (40 hosts, 48 hours, 6
+    segments), traced, so that the line holds the per-layer metrics: GROUP BY
+    hostname, DATETRUNC('hour', ts) through broker, server and the fused
+    program (run.py ends with no line where a segment fell back to the host),
+    every compared answer complete, in order and within the configuration's
+    tolerance of the plain reference, the expression key's plan span in the
+    answers' ledgers, and half to two thirds of the segments rejected by the
+    12-hour window (3 or 4 of 6 here)."""
+    _cold(TIME_CELL, TIME_SEED)
+    p = _python(
+        "-m", "perfbench.run", "--workload", TIME_CELL, "--seed", str(TIME_SEED), "--seconds", "4", "--trace", "1", "--rehearsal",
+        env=_env(JAX_PLATFORMS="cpu"), timeout=600,
+    )  # fmt: skip
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    result_line.validate(line, MANIFEST, TIME_CELL, True, chips=1)
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0, p.stdout[-3000:]
+    compared = line["compared"]
+    assert all(c["value"] <= c["limit"] for c in compared.values()), compared
+    assert compared["rows_missing_or_extra"]["value"] == 0 and compared["order_violations"]["value"] == 0
+    assert 0 < compared["max_rel_err"]["limit"] < 1e-6  # DOUBLE means: the configuration's own tolerance, never exact
+    metrics = {k: v["value"] for k, v in line["metrics"].items()}
+    assert metrics["groupkey_plan_ms"] > 0 and 50.0 <= metrics["segments_pruned_share"] <= 66.7, metrics
+    # each query averages a metric drawn of the ten, and the one warm-up query's programs serve them all
+    assert metrics["compiles_in_window"] == 0, metrics
+    # every compared answer is a row a host and hour of its window: 40 hosts x 12 or 13 hours
+    rows = [ln.split("rows=")[1].split(":")[0] for ln in p.stdout.splitlines() if ln.startswith("[perfbench] check #")]
+    assert rows and set(rows) <= {"480", "520"}, rows
 
 
 def test_a_table_kept_twice_survives_the_loss_and_the_return_of_a_server():
