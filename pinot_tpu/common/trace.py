@@ -448,6 +448,9 @@ class PhaseLedger:
                 # operands go with it; one more when a stable operand is first staged) and waits device -> host
                 "hostToDeviceTransfers": 0,
                 "deviceReadbackWaits": 0,
+                # limb reductions (a grouped DOUBLE SUM / AVG on the byte-plane kernel) whose rows did not fit
+                # their exponent window and took the scatter; those dispatched are `deviceWork`'s to tell
+                "groupedLimbFallbacks": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),

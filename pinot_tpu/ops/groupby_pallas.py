@@ -16,13 +16,18 @@ innermost, so each output tile stays resident in VMEM while all chunks
 accumulate into it.
 
 The kernel is exact (integer byte planes, see below) and is what the engine's
-fused programs call on TPU (kernels._grouped_all via pallas_auto); MIN/MAX and
-float aggregates stay on XLA segment reductions.
+fused programs call on TPU (kernels._grouped_all via pallas_auto): the count,
+every int32 SUM / AVG as four byte planes and, where the caller passes a value
+that is not int32 (a DOUBLE, a LONG past int32), that value's SUM as LIMBS
+fixed-point limbs under one exponent window read off the rows ("limbs" below).
+MIN / MAX stay on XLA segment reductions, as does the sum of a value whose
+rows do not fit the window (the caller's choice, on the flag returned here).
 """
 
 from __future__ import annotations
 
 import functools
+import operator
 import os
 from typing import NamedTuple
 
@@ -207,27 +212,151 @@ def _planes2_impl(gid, planes, ng: int, grid: PlanesGrid):
 SAFE_DOCS = (2**31 - 2**24) // 255
 
 
-def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
-    """Fused lossless group-by reduction: byte-plane sums for every int32
-    value array plus the group count, in ONE pallas pass. Returns
-    ([f64 (ng,) sum per input], i64 (ng,) counts).
+# -- a value that is not int32: fixed-point limbs ----------------------------
+#
+# A DOUBLE (or a LONG past int32, read as one) has no byte planes of its own,
+# but the masked rows of one launch share an exponent window: with the largest
+# binary exponent among them at the window's top, every value is an integer
+# multiple of 2^w0 (the window's bit 0) of at most 8*LIMBS bits, and that
+# integer splits into LIMBS signed byte limbs, each in [-255, 255] — exact in
+# bf16 and under the same chunk and SAFE_DOCS bounds as an int32's planes. The
+# kernel sums limbs as it sums bytes; the (LIMBS, ng) int32 limb sums are
+# carried into one sign-magnitude integer per group and rounded to f64 once
+# (`_limbs_to_f64`). So where every masked row fits the window the
+# result is the correctly rounded true sum; where one does not (`fits` False:
+# a spread of exponents past the window, a NaN, an infinity, a value outside
+# float32's normal range) the limbs are garbage and the caller reduces another
+# way (kernels._grouped_all: the scatter, as the other branch of a lax.cond).
+#
+# A value is peeled through float32: v = a + b + c with a = f32(v),
+# b = f32(v - a), c = f32(v - a - b), each subtraction exact, the three
+# significands disjoint (|b| <= ulp(a)/2), so a limb holding bits of two
+# pieces of opposite sign still lies in [-255, 255]. Three pieces hold an IEEE
+# double's 53 bits; the TPU's f64 is a pair of float32s and c is 0 there.
+# Nothing here is assumed of the chip's emulation: the residual, the pieces'
+# disjointness and the bits a piece would lose below the window are all read
+# off the rows and folded into `fits`. After the three subtractions the work is
+# int32 shifts and masks.
+#
+# 12 limbs: 96 bits hold every 53-bit value whose exponent lies within 43 bits
+# of the largest. The kernel's time is linear in its plane rows (PERF.md §6,
+# PR 36, has the v5e's readings by LIMBS).
 
-    Exactness requires the flat doc count <= SAFE_DOCS (asserted). A kernel
-    the compiler refuses raises: nothing substitutes another one."""
-    if gid.shape[0] > SAFE_DOCS:  # not assert: must survive python -O
-        raise ValueError(
-            f"pallas byte-plane accumulator overflows past {SAFE_DOCS} docs; "
-            "use the XLA two-level path for larger inputs"
-        )
-    k = len(values_list)
-    r = 4 * k + 1  # four byte planes a value, and the mask: no pad rows, L's rows are r*G2
+LIMBS = 12
+
+
+def _f32_fields(p):
+    """f32 `p` as sign * m * 2^(field - 150): the 24-bit significand (implicit
+    bit included), the biased exponent field (a denormal's is 1) and -1 / 1."""
+    u = jax.lax.bitcast_convert_type(p, jnp.int32)
+    field = (u >> 23) & 0xFF
+    m = (u & 0x7FFFFF) | jnp.where(field != 0, 1 << 23, 0)
+    return m, jnp.maximum(field, 1), jnp.where(u < 0, jnp.int32(-1), jnp.int32(1))
+
+
+def limb_planes(v, mask):
+    """((LIMBS, n) f32 limb planes of the masked rows of `v`, w0, fits): where
+    `fits`, row i of `v` (0 where masked out) is sum_j planes[j, i] * 2^(8j + w0)
+    exactly and every limb lies in [-255, 255]; where not, the planes say nothing."""
+    r = jnp.where(mask, v.astype(jnp.float64), 0.0)
+    pieces = []
+    for _ in range(3):
+        p = r.astype(jnp.float32)
+        pieces.append(_f32_fields(p))
+        r = r - p.astype(jnp.float64)
+    fits = jnp.all(r == 0)  # false for a NaN, an infinity and what float32 cannot reach, too
+    top = jnp.max(pieces[0][1])
+    w0 = top - (126 + 8 * LIMBS)  # the top piece's highest bit, top - 127, is the window's last
+    lane = (8 * jnp.arange(LIMBS, dtype=jnp.int32) + 8)[:, None]
+    planes, above = 0, None
+    for m, field, sign in pieces:
+        if above is not None:  # below the piece before: the limbs' bound rests on it
+            fits &= jnp.all((m == 0) | (field <= above - 24))
+        above = field
+        s = field - 150 - w0  # how far above the window's bit 0 the piece's bit 0 lies
+        drop = jnp.clip(-s, 0, 24)  # bits of the piece under the window: they must be zeros
+        fits &= jnp.all((m & ((1 << drop) - 1)) == 0)
+        # the piece at bit 8 of a word: limb j is the byte at bit 8j + 8 - s of it, where there is one
+        word = (m >> drop).astype(jnp.uint32) << 8
+        sh = lane - jnp.maximum(s, 0)[None, :]
+        byte = jnp.where(sh < 32, (word[None, :] >> jnp.clip(sh, 0, 31).astype(jnp.uint32)) & 0xFF, 0)
+        planes = planes + sign[None, :] * byte.astype(jnp.int32)
+    return planes.astype(jnp.float32), w0, fits
+
+
+def _scale2(x, k):
+    """f64 `x` * 2^k for int32 `k` within +/-300, exactly: three powers of two
+    that float32 holds, built from their exponent fields."""
+    for _ in range(3):
+        step = jnp.clip(k, -100, 100)
+        x = x * jax.lax.bitcast_convert_type((step + 127) << 23, jnp.float32).astype(jnp.float64)
+        k = k - step
+    return x
+
+
+def _added(parts):
+    """The parts' sum without `sum`'s leading 0, which would leave a `0 + x`
+    in the HLO of an int32-only program that has one block."""
+    return functools.reduce(operator.add, parts)
+
+
+def _carried(sums):
+    """(k, ng) int32 limb sums, |sum| <= 2^31 - 2^24 -> the same number as a
+    list of k + 4 int32 digits of 8 bits, all in [0, 255] but the last, which
+    keeps the sign. int32 all the way: a carry is under 2^23."""
+    digits, carry = [], 0
+    for j in range(sums.shape[0] + 3):
+        t = carry + (sums[j] if j < sums.shape[0] else 0)
+        digits.append(t & 0xFF)
+        carry = t >> 8  # arithmetic: the floor, so the digit is never negative
+    return digits + [carry]
+
+
+def _limbs_to_f64(blocks, w0):
+    """[(LIMBS, ng) int32 limb sums a block of docs] -> (ng,) f64, the blocks'
+    sum_j sums[j] * 2^(8j + w0) rounded once. The limbs are carried into
+    digits of 8 bits, sign and magnitude, in int32 (the chip's int64 is a
+    pair of words, and this stays off it); the leading six digits of the
+    magnitude and the six under them are each an exact f64 of 48 bits, and
+    whatever lies below folds into the last bit of the lower one (it is far
+    under the rounding point, so it only breaks ties): their sum is the one
+    rounding."""
+    total = blocks[0]
+    if len(blocks) > 1:  # a block's digits are small: they add, and carry once more
+        total = _added(jnp.stack(_carried(b)) for b in blocks)
+    neg = _carried(total)[-1] < 0  # the digits under the top one are not negative
+    d = _carried(jnp.where(neg, -total, total))
+    lead = functools.reduce(jnp.maximum, [jnp.where(b != 0, j, 0) for j, b in enumerate(d)])  # the highest digit not 0
+    sticky = functools.reduce(jnp.logical_or, [(b != 0) & (j < lead - 11) for j, b in enumerate(d)])
+    hi = lo = jnp.zeros(neg.shape, jnp.float64)
+    for j, b in enumerate(d):
+        b = jnp.where(sticky & (j == lead - 11), b | 1, b)
+        term = b.astype(jnp.float64) * 2.0 ** (8 * j - 64)  # centred: a float32's exponent holds both ends
+        hi = hi + jnp.where((j <= lead) & (j > lead - 6), term, 0.0)
+        lo = lo + jnp.where((j <= lead - 6) & (j > lead - 12), term, 0.0)
+    mag = _scale2(hi + lo, w0 + 64)
+    return jnp.where(neg, -mag, mag)
+
+
+def _multi_sum(values_list, gid, mask, ng: int, block):
+    """The pass behind both entry points: plane rows of every value and the
+    mask, the kernel over each `block` of docs (all of them where None), the
+    plane sums recombined."""
+    wide = [v.dtype != jnp.int32 for v in values_list]
+    r = sum(LIMBS if w else 4 for w in wide) + 1  # and the mask: no pad rows, L's rows are r*G2
     grid = grid_for(ng, r)
     pad = (-gid.shape[0]) % grid.chunk
     n_padded = gid.shape[0] + pad
     gid = jnp.pad(gid.astype(jnp.int32), (0, pad))
     mask = jnp.pad(mask, (0, pad))
-    rows = []
-    for v in values_list:
+    rows, windows = [], []  # a value's (w0, fits) where it goes in as limbs, None where as byte planes
+    for v, w in zip(values_list, wide):
+        if w:
+            planes, w0, fits = limb_planes(jnp.pad(v, (0, n_padded - v.shape[0])), mask)
+            rows.extend(planes)
+            windows.append((w0, fits))
+            continue
+        windows.append(None)
         v = jnp.pad(v.astype(jnp.int32), (0, n_padded - v.shape[0]))
         v = jnp.where(mask, v, 0)
         rows.extend(
@@ -240,43 +369,58 @@ def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
         )
     rows.append(mask.astype(jnp.float32))
     planes = jnp.stack(rows)
-    out = KERNELS.timed_sync(
-        "ops.grouped_planes2",
-        lambda: _planes2_impl(gid, planes, ng, grid),
-        rows=n_padded,
-        groups=ng,
-        planes=r,
-    )
-    sums = []
-    for i in range(k):
-        p = out[4 * i : 4 * i + 4].astype(jnp.float64)
-        sums.append(p[0] + p[1] * 256.0 + p[2] * 65536.0 + p[3] * 16777216.0)
-    counts = out[4 * k].astype(jnp.int64)
-    return sums, counts
+    outs, step = [], block or n_padded
+    for start in range(0, n_padded, step):
+        end = min(start + step, n_padded)
+        outs.append(
+            KERNELS.timed_sync(
+                "ops.grouped_planes2",
+                lambda: _planes2_impl(gid[start:end], planes[:, start:end], ng, grid),
+                rows=end - start,
+                groups=ng,
+                planes=r,
+            )
+        )
+    sums, at = [], 0
+    for window in windows:
+        if window is None:
+            # a block's byte-plane sums are exact in f64, and so is their sum over the blocks
+            planes4 = [o[at : at + 4].astype(jnp.float64) for o in outs]
+            sums.append(_added(p[0] + p[1] * 256.0 + p[2] * 65536.0 + p[3] * 16777216.0 for p in planes4))
+            at += 4
+        else:
+            w0, fits = window
+            sums.append((_limbs_to_f64([o[at : at + LIMBS] for o in outs], w0), fits))
+            at += LIMBS
+    return sums, _added(o[at].astype(jnp.int64) for o in outs)
+
+
+def pallas_grouped_multi_sum(values_list, gid, mask, ng: int):
+    """Fused lossless group-by reduction: the sum of every value array and
+    the group count, in ONE pallas pass. Returns ([a sum per input], i64 (ng,)
+    counts): an int32 input's sum is f64 (ng,), exact; any other input (read
+    as f64) gives the pair (f64 (ng,), fits) — the correctly rounded sum where
+    the scalar `fits` is True, nothing where it is False (`limb_planes`).
+
+    Exactness requires the flat doc count <= SAFE_DOCS (asserted). A kernel
+    the compiler refuses raises: nothing substitutes another one."""
+    if gid.shape[0] > SAFE_DOCS:  # not assert: must survive python -O
+        raise ValueError(
+            f"pallas byte-plane accumulator overflows past {SAFE_DOCS} docs; "
+            "use the XLA two-level path for larger inputs"
+        )
+    return _multi_sum(values_list, gid, mask, ng, None)
 
 
 def pallas_grouped_multi_sum_blocked(values_list, gid, mask, ng: int):
     """SAFE_DOCS-unbounded variant: statically slices the doc axis into
-    blocks that each respect the int32 plane-accumulator bound and sums the
-    per-block results in f64/i64. Two slices cover 16M docs; per-slice cost
-    is one extra kernel launch."""
+    blocks that each respect the int32 plane-accumulator bound and adds the
+    per-block plane sums in int64 (a wide value's window is the whole
+    launch's, so its limbs add across blocks). Two slices cover 16M docs;
+    per-slice cost is one extra kernel launch."""
     n = gid.shape[0]
-    if n <= SAFE_DOCS:
-        return pallas_grouped_multi_sum(values_list, gid, mask, ng)
-    block = (SAFE_DOCS // PLANES_CHUNK) * PLANES_CHUNK
-    sums_acc = None
-    counts_acc = None
-    for start in range(0, n, block):
-        end = min(start + block, n)
-        s, c = pallas_grouped_multi_sum(
-            [v[start:end] for v in values_list], gid[start:end], mask[start:end], ng
-        )
-        if sums_acc is None:
-            sums_acc, counts_acc = list(s), c
-        else:
-            sums_acc = [a + b for a, b in zip(sums_acc, s)]
-            counts_acc = counts_acc + c
-    return sums_acc, counts_acc
+    block = None if n <= SAFE_DOCS else (SAFE_DOCS // PLANES_CHUNK) * PLANES_CHUNK
+    return _multi_sum(values_list, gid, mask, ng, block)
 
 
 def pallas_grouped_sum_count_exact(values_i32, gid, mask, ng: int):

@@ -226,8 +226,9 @@ def _filter(fspec, cols, ops, n_padded):
 # aggregation partials
 # ---------------------------------------------------------------------------
 
-# Three forms of a grouped SUM/AVG/MIN/MAX, chosen from what the program can
-# see — the value's dtype and the group space — and from nothing else:
+# Four forms of a grouped SUM/AVG/MIN/MAX, chosen from what the program can
+# see — the value's dtype, the group space and, for the third, the rows'
+# exponents — and from nothing else:
 #   * int32 values: exact integer summation without 64-bit arithmetic on the
 #     hot path. Where the Pallas kernel is on (`_grouped_all`), COUNT and every
 #     int32 SUM/AVG ride one byte-plane pass on the MXU; without it docs split
@@ -240,10 +241,18 @@ def _filter(fspec, cols, ops, n_padded):
 #     reduction
 #     (`_dense_grouped`), one compare-select-add a (row, slot) pair in the
 #     program's emulated f64, fused by XLA into one pass over the rows.
-#   * any other value otherwise (more groups, MV keys' value space, the
-#     sort-compaction path's slot budget): a scatter, jax.ops.segment_*.
-#     On the v5e 4M f64 rows scattered into 256 slots take 0.16-0.28 s, a
-#     thousand times a masked f64 sum of the same rows (PERF.md §6, PR 28).
+#   * the SUM / AVG of any other value where no real group count is stated
+#     (more groups, MV keys' value space, the sort-compaction path's slot
+#     budget) and the Pallas kernel is on: fixed-point limbs under the launch's
+#     own exponent window, plane rows of the same byte-plane pass
+#     (groupby_pallas.limb_planes) — exact, and 8.9 ms where the scatter takes
+#     434 at 4.19M rows and 12,032 slots (PERF.md §6, PR 36). The rows decide:
+#     where one does not fit the window the same program scatters instead
+#     (`_grouped_all`: a lax.cond on the flag the limbs come with).
+#   * any other value otherwise (that fallback, MIN / MAX, the kernel off): a
+#     scatter, jax.ops.segment_*. On the v5e 4M f64 rows scattered into 256
+#     slots take 0.16-0.28 s, a thousand times a masked f64 sum of the same
+#     rows (PERF.md §6, PR 28).
 _BLOCK = 8192
 
 
@@ -303,8 +312,9 @@ def _grouped_reduce(kind, v, gid, mask, ng, dense_g):
     """Grouped sum / min / max of `v` (f64, or the int32 ones of a count)
     into the (ng,) partial: dense where the plan stated a real group count
     `dense_g` (plan.with_real_groups: a dense group space of few groups), the
-    scatter otherwise. Both leave the reduction's identity (0, +/-inf) in the
-    slots no row reached."""
+    scatter otherwise — for a sum under the Pallas kernel only as the side of
+    `_grouped_all`'s cond that rows outside the limbs' window take. Both leave
+    the reduction's identity (0, +/-inf) in the slots no row reached."""
     fill, _, scatter = _REDUCTIONS[kind]
     fill = jnp.asarray(fill, v.dtype)
     if dense_g is None:
@@ -464,7 +474,9 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None, den
     """gather/doc_pad: MV GROUP BY evaluates in VALUE space — doc-space
     value/filter vectors gather through the owning-doc ids first. dense_g:
     the real group count of a dense group space, where the plan stated one
-    (`_grouped_reduce` chooses the form of a non-int32 reduction from it)."""
+    (`_grouped_reduce` chooses the form of a non-int32 reduction from it).
+    With the Pallas kernel on, a top-level SUM / AVG that is int32, or that no
+    real group count is stated for, never arrives here: `_grouped_all` has it."""
     kind = aspec[0]
     if kind == "masked_nan_empty":
         # null-handling SUM: the per-group empty check must see the FULL
@@ -557,15 +569,35 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None, den
     raise AssertionError(aspec)
 
 
+_limb_fallbacks = threading.local()  # .flags: what `_fallbacks_of` collects while a program is traced
+
+
+def _fallbacks_of(trace):
+    """`trace()` and the number of its limb reductions that took the scatter,
+    a traced int32 scalar: None where it holds no limb reduction."""
+    _limb_fallbacks.flags = flags = []
+    try:
+        out = trace()
+    finally:
+        _limb_fallbacks.flags = None
+    return out, (sum(f.astype(jnp.int32) for f in flags) if flags else None)
+
+
 def _grouped_all(aggs, cols, ops, mask, gid, ng, gather=None, doc_pad=None, dense_g=None):
-    """Group counts + every agg partial. On TPU the count and ALL int32
-    SUM/AVG aggs fuse into ONE pallas byte-plane matmul pass on the MXU.
+    """Group counts + every agg partial. On TPU the count, ALL int32 SUM/AVG
+    aggs and, where the plan stated no real group count (`dense_g` None), the
+    SUM/AVG of every other value (DOUBLE, LONG past int32) fuse into ONE
+    pallas byte-plane matmul pass on the MXU, such a value as fixed-point
+    limbs (groupby_pallas.limb_planes). Whether its rows fit the limbs' window
+    only the rows say: the reduction is a lax.cond on that flag, the limb sums
+    on one side and today's scatter on the other, with the pass's own counts
+    as an AVG's count on both.
     Every other agg takes its own reduction in `_agg_grouped`: SUM / AVG / MIN
-    / MAX / MINMAXRANGE of a value that is not int32 (DOUBLE, LONG past
-    int32) the dense masked reduction where the plan stated `dense_g`, the
-    real group count (it does up to plan.DENSE_REDUCE_MAX_GROUPS), and the
-    scatter otherwise; int32
-    MIN/MAX, HLL, histograms and presence matrices their scatters.
+    / MAX / MINMAXRANGE of a value that is not int32 the dense masked reduction
+    where the plan stated `dense_g`, the real group count (it does up to
+    plan.DENSE_REDUCE_MAX_GROUPS), and MIN / MAX / MINMAXRANGE the scatter
+    otherwise; int32 MIN/MAX, HLL, histograms and presence matrices their
+    scatters.
     gather/doc_pad: MV GROUP BY (value-space gids) gathers doc-space values
     first."""
     from pinot_tpu.ops import groupby_pallas as gp
@@ -578,19 +610,27 @@ def _grouped_all(aggs, cols, ops, mask, gid, ng, gather=None, doc_pad=None, dens
         for i, a in enumerate(aggs):
             if a[0] in ("sum", "avg"):
                 v_raw = _value(a[1], cols, ops, doc_pad if gather is not None else mask.shape[0])
-                if v_raw.dtype == jnp.int32:
+                if v_raw.dtype == jnp.int32 or dense_g is None:
                     owner[i] = len(vals)
                     vals.append(v_raw if gather is None else v_raw[gather])
         # _blocked splits doc sets past the int32 plane-accumulator bound
         # (SAFE_DOCS) into exact sub-ranges, so big flattened segment sets
         # (16M-row bench) still ride the MXU path
         sums, counts = gp.pallas_grouped_multi_sum_blocked(vals, gid, mask, ng)
+        flags = getattr(_limb_fallbacks, "flags", None)
         parts = []
         for i, a in enumerate(aggs):
             if a[0] == "count":
                 parts.append(counts)
             elif i in owner:
-                parts.append(sums[owner[i]] if a[0] == "sum" else (sums[owner[i]], counts))
+                s = sums[owner[i]]
+                if isinstance(s, tuple):  # limbs: the sum and whether the rows fit its window
+                    s, fits = s
+                    v = vals[owner[i]].astype(_F)
+                    s = jax.lax.cond(fits, lambda s=s: s, lambda v=v: _grouped_reduce("sum", v, gid, mask, ng, None))
+                    if flags is not None:
+                        flags.append(~fits)
+                parts.append(s if a[0] == "sum" else (s, counts))
             else:
                 parts.append(rest(a))
         return counts, tuple(parts)
@@ -841,7 +881,8 @@ def get_packed_kernel(spec: tuple):
     (`dispatch_plan_packed`; a query waits once for its vectors). int64 leaves
     split into hi/lo 32-bit halves (two f64 chunks) so values past 2^53 —
     sparse group gids, raw LONG columns — survive exactly; everything else
-    casts to f64 losslessly.
+    casts to f64 losslessly. A program that holds limb reductions
+    (`_grouped_all`) appends one element more: how many of them scattered.
 
     Unpack metadata is NOT captured at trace time: output shapes can vary
     with input shapes under one spec (select_ob's k is clipped to n_padded),
@@ -853,7 +894,8 @@ def get_packed_kernel(spec: tuple):
         # runs when jax traces the program, once per input signature: the
         # registered kernels reached inside leave their static work behind
         with KERNELS.building(name, n_padded):
-            leaves, _ = jax.tree.flatten(base(cols, ops, n_docs, n_padded))
+            out, fallbacks = _fallbacks_of(lambda: base(cols, ops, n_docs, n_padded))
+        leaves, _ = jax.tree.flatten(out)
         chunks = []
         for l in leaves:
             flat = jnp.ravel(l)
@@ -862,6 +904,8 @@ def get_packed_kernel(spec: tuple):
                 chunks.append(jnp.remainder(flat, 1 << 32).astype(jnp.float64))
             else:
                 chunks.append(flat.astype(jnp.float64))
+        if fallbacks is not None:  # past the tree's leaves: `wait_packed` counts it
+            chunks.append(fallbacks.astype(jnp.float64)[None])
         if not chunks:
             return jnp.zeros((0,), dtype=jnp.float64)
         return jnp.concatenate(chunks)
@@ -936,7 +980,7 @@ KERNELS.register(
     "query.grouped_scatter",
     _scatter_grouped,
     cost_model=_scatter_cost,
-    description="grouped SUM/MIN/MAX of a value that is not int32 as a scatter (no real group count stated, or more than plan.DENSE_REDUCE_MAX_GROUPS); one call a reduction traced",
+    description="grouped SUM/MIN/MAX of a value that is not int32 as a scatter (no real group count stated, or more than plan.DENSE_REDUCE_MAX_GROUPS; under the Pallas kernel a SUM's only where a row lies outside its limbs' window); one call a reduction traced",
 )
 KERNELS.register(
     "query.fused",
@@ -954,8 +998,8 @@ KERNELS.register(
 
 @lru_cache(maxsize=4096)
 def _packed_meta(spec: tuple, col_sig: tuple, op_sig: tuple, n_padded: int):
-    """(treedef, [(shape, dtype)]) of a spec's output tree for one input
-    shape signature — abstract evaluation only, no compile. The signatures
+    """(treedef, [(shape, dtype)], packed size) of a spec's output tree for one
+    input shape signature — abstract evaluation only, no compile. The signatures
     are (name, shape, dtype) a column and (shape, dtype) an operand, read
     off the launch's own arguments: shape tuples and dtype objects."""
     base = build_fn(spec)
@@ -968,7 +1012,8 @@ def _packed_meta(spec: tuple, col_sig: tuple, op_sig: tuple, n_padded: int):
         jax.ShapeDtypeStruct((), np.int32),
     )
     leaves, treedef = jax.tree.flatten(out)
-    return treedef, tuple((tuple(l.shape), np.dtype(l.dtype)) for l in leaves)
+    size = sum(l.size * (2 if l.dtype == np.int64 else 1) for l in leaves)
+    return treedef, tuple((tuple(l.shape), np.dtype(l.dtype)) for l in leaves), size
 
 
 #: opt-in device staging cache for operands DECLARED long-lived by their
@@ -1050,14 +1095,14 @@ class PackedResult:
     output tree, after `wait_packed` — the caller's, for all launches of a
     query at once, or its own if none was made."""
 
-    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_n_cols", "_treedef", "_leaf_meta")
+    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_n_cols", "_treedef", "_leaf_meta", "_size")
 
-    def __init__(self, program: str, rows: int, vec, n_cols: int, treedef, leaf_meta):
+    def __init__(self, program: str, rows: int, vec, n_cols: int, treedef, leaf_meta, size: int):
         # what was launched, for the caller's `server.dispatch` span
         self.program, self.rows = program, rows
         self.wait_ms = 0.0
         self._vec, self._host = vec, None
-        self._n_cols, self._treedef, self._leaf_meta = n_cols, treedef, leaf_meta
+        self._n_cols, self._treedef, self._leaf_meta, self._size = n_cols, treedef, leaf_meta, size
 
     def __call__(self):
         if self._host is None:
@@ -1084,7 +1129,9 @@ class PackedResult:
 def wait_packed(results, checkpoint=None) -> None:
     """THE device->host wait of a query: one `server.device_wait` span and
     one `deviceReadbackWaits` around the arrival of every result vector not
-    yet on the host. The copies were started when the programs were
+    yet on the host, and `groupedLimbFallbacks`: how many of the launches' limb
+    reductions found a row outside their window and scattered (the element
+    past the tree's leaves, `get_packed_kernel`). The copies were started when the programs were
     enqueued, so the first wait covers what is queued on the device and the
     others find their vector there or on its way; nothing else runs inside
     the span. `checkpoint(i)`, where given, runs before the i-th vector is
@@ -1108,6 +1155,9 @@ def wait_packed(results, checkpoint=None) -> None:
             now = time.perf_counter()
             r.wait_ms, t = (now - t) * 1e3, now
     count("deviceReadbackWaits")
+    fallbacks = sum(int(r._host[r._size]) for _, r in pending if r._host.shape[0] > r._size)
+    if fallbacks:
+        count("groupedLimbFallbacks", fallbacks)
     if KERNELS.enabled:
         for _, r in pending:
             KERNELS.record("query.fused_packed", r.wait_ms, rows=r.rows, cols=r._n_cols)
@@ -1134,13 +1184,13 @@ def dispatch_plan_packed(plan, device_segment) -> PackedResult:
         ledger.add_device_work(name, rows, KERNELS.program_work(name, rows))
     # the key as the arrays give it, nothing sorted and no string built: segments of one table
     # plan their columns in one order and share the entry; another order would only add one
-    treedef, leaf_meta = _packed_meta(
+    treedef, leaf_meta, size = _packed_meta(
         plan.spec,
         tuple((k, v.shape, v.dtype) for k, v in cols.items()),
         tuple((o.shape, o.dtype) for o in ops),
         rows,
     )
-    return PackedResult(name, rows, vec, len(cols), treedef, leaf_meta)
+    return PackedResult(name, rows, vec, len(cols), treedef, leaf_meta, size)
 
 
 def run_plan_packed(plan, device_segment):

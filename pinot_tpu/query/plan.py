@@ -43,10 +43,15 @@ MAX_DENSE_GROUPS = 1 << 20
 #: most real groups for which a group-by states its real group count, which
 #: turns its non-int32 SUM / AVG / MIN / MAX into dense masked reductions
 #: (kernels._grouped_reduce). From the v5e's sweep at 4M rows
-#: (benchmarks/grouped_dense_ab.py; PERF.md §6, PR 28): the f64 scatter is
-#: 285-340 ms whatever the group count, the dense form 0.025 ms a slot — 0.3 ms
-#: at 8, 6.5 at 256, 102 at 4096, the largest count measured and still 3.3x
-#: ahead; the two would meet near 12,000, where nothing was measured.
+#: (benchmarks/grouped_dense_ab.py; PERF.md §6, PR 28 and PR 36): the f64
+#: scatter is 285-340 ms up to 4096 groups, the dense form 0.025 ms a slot —
+#: 0.3 ms at 8, 6.5 at 256, 102 at 4096, still 3.3x ahead. Measured since (PR 36):
+#: dense 203 / 299 / 407 / 1626 ms at 8192 / 12,032 / 16,384 / 65,536 groups
+#: against the scatter's 378 / 434 / 418 / 468 — the two meet at 16,384 — and
+#: a SUM as limbs on the Pallas kernel (kernels._grouped_all, which takes
+#: what states no real count) 2.6 ms up to 1024 groups, 4.3 at 4096, 8.9 at
+#: 12,032, 39 at 65,536: under the dense form from 128 groups on, which this
+#: threshold does not yet use (PERF.md §7).
 DENSE_REDUCE_MAX_GROUPS = 4096
 
 # Virtual columns provided at query time (VirtualColumnProvider parity,

@@ -82,7 +82,8 @@ def test_child_time_is_not_counted_twice():
     assert set(fields) == set(RESPONSE_KEYS)
     assert fields["counters"] == {
         "wireRequestBytes": 0, "wireResponseBytes": 0, "serversMerged": 0, "scatterSkewMs": 0,
-        "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
+        "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "groupedLimbFallbacks": 0,
+        "segmentsDispatched": 0, "rowsDispatched": 0,
     }  # fmt: skip
 
 
