@@ -417,15 +417,20 @@ class Broker:
     def execute(self, sql: str, identity: str | None = None) -> ResultTable:
         """One query, under its phase ledger: `broker.request` spans all of
         it, and the ledger (the broker's spans, the servers' merged in)
-        leaves with the answer as `spanTimesMs`, `spanSelfMs`, `counters`
-        and `deviceWork`."""
-        from pinot_tpu.common.trace import request_ledger, span
+        leaves with the answer as `spanTimesMs`, `spanSelfMs`, `spanCpuMs`,
+        `counters` and `deviceWork`. Under the HTTP handler the ledger is the
+        one it opened with the request's first byte: it takes the id here, and
+        the handler makes those fields, once, after it has timed the answer's
+        encoding."""
+        from pinot_tpu.common.trace import active_ledger, request_ledger, span
 
         qid = f"q{next(_request_seq)}"
+        outer = active_ledger()
         with request_ledger(qid, "broker") as ledger:
             with span("broker.request"):
                 result = self._execute_request(sql, identity, qid)
-            result.span_stats = ledger.response_fields()
+            if ledger is not outer:
+                result.span_stats = ledger.response_fields()
         return result
 
     def _execute_request(self, sql: str, identity: str | None, qid: str) -> ResultTable:
@@ -1124,23 +1129,24 @@ class Broker:
             scan["prunedByReason"]["value"] = scan["prunedByReason"].get("value", 0) + pruned
         by_reason = scan["prunedByReason"]
 
-        with span("broker.reduce", phase=ServerQueryPhase.BROKER_REDUCE, role="broker"):
+        with span("broker.reduce", phase=ServerQueryPhase.BROKER_REDUCE, role="broker", cpu=True):
             rows = QueryEngine.reduce(ctx, partials)
-        return build_result(
-            ctx,
-            rows,
-            num_docs_scanned=int(scanned),
-            total_docs=snap.total_docs,
-            num_segments_queried=queried,
-            num_segments_pruned=sum(by_reason.values()),
-            num_segments_pruned_by_value=by_reason.get("value", 0),
-            num_segments_pruned_by_bloom=by_reason.get("bloom", 0),
-            num_segments_pruned_by_geo=by_reason.get("geo", 0),
-            num_entries_scanned_in_filter=scan["entriesInFilter"],
-            num_entries_scanned_post_filter=scan["entriesPostFilter"],
-            scan_profile=scan,
-            time_used_ms=(time.perf_counter() - t0) * 1e3,
-        )
+        with span("broker.result", rows=len(rows)):
+            return build_result(
+                ctx,
+                rows,
+                num_docs_scanned=int(scanned),
+                total_docs=snap.total_docs,
+                num_segments_queried=queried,
+                num_segments_pruned=sum(by_reason.values()),
+                num_segments_pruned_by_value=by_reason.get("value", 0),
+                num_segments_pruned_by_bloom=by_reason.get("bloom", 0),
+                num_segments_pruned_by_geo=by_reason.get("geo", 0),
+                num_entries_scanned_in_filter=scan["entriesInFilter"],
+                num_entries_scanned_post_filter=scan["entriesPostFilter"],
+                scan_profile=scan,
+                time_used_ms=(time.perf_counter() - t0) * 1e3,
+            )
 
     def _execute_streaming(self, ctx: QueryContext, snap: RouteSnapshot, legs, t0, partial=None) -> ResultTable:
         """Selection-only streaming scatter/gather: all servers stream in
@@ -1412,7 +1418,7 @@ class Broker:
         """The scatter of one routed leg, submit to decoded partials (the
         extent of `broker.scatter`)."""
         from pinot_tpu.cluster.routing import AdaptiveServerSelector
-        from pinot_tpu.common.trace import active_ledger, active_trace, bind_request
+        from pinot_tpu.common.trace import active_ledger, active_trace, bind_request, record_span
 
         trace = active_trace()
         hints = dict(ctx.hints)
@@ -1437,6 +1443,7 @@ class Broker:
                 raise
             if self.failure_detector is not None:
                 self.failure_detector.mark_success(sid)
+            # the leg whole — encode, call and decode — is what the hedge's timer waits on
             elapsed_ms = (time.perf_counter() - t0) * 1e3
             if adaptive is not None:
                 adaptive.record(sid, elapsed_ms)
@@ -1509,6 +1516,7 @@ class Broker:
         partials, scanned = [], 0
         scan = scan_stats.new_scan_summary()
         server_ledgers = []
+        read_at = []  # per leg over the wire, the instant its payload was in hand
         for out in results:
             partials.extend(out[0])
             scanned += out[1]
@@ -1517,6 +1525,8 @@ class Broker:
             # (in-process handles share our trace)
             if len(out) > 3 and out[3]:
                 sub = dict(out[3])
+                if "payloadReadAt" in sub:
+                    read_at.append(sub.pop("payloadReadAt"))
                 server_ledger = sub.pop("ledger", None)
                 if server_ledger:
                     server_ledgers.append(server_ledger)
@@ -1529,6 +1539,10 @@ class Broker:
         ledger = active_ledger()
         if ledger is not None:
             ledger.merge_servers(server_ledgers)
+        if read_at:
+            # what the gather costs the query on the clock: from the last payload in hand to here, the last
+            # decode and the merge above (`broker.wire.decode` summed over the legs' threads is what it costs the host)
+            record_span("broker.scatter.tail", (time.perf_counter() - max(read_at)) * 1e3)
         return partials, scanned, n_candidates, pruned, scan
 
     def _execute_multistage(

@@ -386,10 +386,25 @@ class BrokerHTTPService:
                 ):
                     self.send_error(404)
                     return
+                if self.path != "/query/sql":
+                    self._serve_post()
+                    return
+                from pinot_tpu.common.trace import request_ledger
+
+                # the query's ledger opens with its first byte read, as the server's does: `Broker.execute`
+                # joins it, gives it the query id it mints and leaves the answer's ledger fields to be
+                # made here, once, after the answer's encoding has been timed
+                with request_ledger(role="broker"):
+                    self._serve_post()
+
+            def _serve_post(self):
+                from pinot_tpu.common.trace import ServerQueryPhase, active_ledger, span
+
                 n = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(n)
-                _tl_mark("bodyRead")
-                body = json.loads(raw or b"{}")
+                with span("broker.http.read", phase=ServerQueryPhase.REQUEST_DESERIALIZATION, role="broker", bytes=n):
+                    raw = self.rfile.read(n)
+                    _tl_mark("bodyRead")
+                    body = json.loads(raw or b"{}")
                 _tl_mark("parse")
                 if self.path == "/debug/alerts/attach":
                     # controller SLO plane pushing an alert transition: stamp
@@ -432,7 +447,13 @@ class BrokerHTTPService:
                         return
                     res = svc.broker.execute(body["sql"], identity=identity)
                     _tl_mark("execute")
-                    payload = json.dumps(res.to_dict()).encode()
+                    # the rows are all of the answer but its envelope: encoded first and inside the span, so
+                    # that the ledger's fields in the envelope hold the time of the answer's own encoding
+                    with span("broker.http.encode", phase=ServerQueryPhase.RESPONSE_SERIALIZATION, role="broker") as enc:
+                        rows = json.dumps(res.rows).encode()
+                        enc.set_attr("bytes", len(rows))
+                    res.span_stats = active_ledger().response_fields()
+                    payload = res.to_json(rows)
                     _tl_mark("serialize")
                     self.send_response(200)
                 except PermissionError as e:
@@ -1012,6 +1033,9 @@ class RemoteServerClient:
         return headers
 
     def execute_partials(self, table: str, sql: str, segment_names: list[str], hints: dict | None = None):
+        """One leg over the wire: `broker.wire.encode`, `broker.wire.call` (request sent to payload read) and
+        `broker.wire.decode`. The instant the payload was in hand rides element 3 of the answer, beside the
+        server's ledger: the broker's scatter times the gather on the clock from the last of them."""
         from pinot_tpu.common.trace import count, span
 
         hints = dict(hints or {})
@@ -1022,7 +1046,7 @@ class RemoteServerClient:
             ).encode()
         count("wireRequestBytes", len(body))
         try:
-            with get_pool().request(
+            with span("broker.wire.call", server=f"{self._host}:{self._port}") as call, get_pool().request(
                 self._host,
                 self._port,
                 "POST",
@@ -1034,6 +1058,7 @@ class RemoteServerClient:
                 payload = resp.read()
                 status = resp.status
                 retry_after = resp.getheader("Retry-After")
+                call.set_attr("bytes", len(payload))
         except (TimeoutError, OSError) as e:
             raise RuntimeError(f"server {self.base_url} unreachable: {e}") from None
         if status >= 400:
@@ -1056,8 +1081,11 @@ class RemoteServerClient:
                 err.kill_reason = doc["killReason"]  # re-attach across the HTTP hop
             raise err from None
         count("wireResponseBytes", len(payload))
-        with span("broker.wire.decode", bytes=len(payload)):
-            return datatable.decode(payload)
+        with span("broker.wire.decode", cpu=True, bytes=len(payload)):
+            out = datatable.decode(payload)
+        if len(out) > 3:
+            out = out[:3] + ({**(out[3] or {}), "payloadReadAt": call.end},) + out[4:]
+        return out
 
     def cancel_query(self, qid: str) -> bool:
         """Fan-out target for Broker.cancel_query; False when the server

@@ -28,14 +28,25 @@ is active.
 
 One timing primitive, `span(name, **attrs)`, times every layer boundary of
 the served v1 path, traced or not: it folds the duration into the request's
-`PhaseLedger` (per span name: total, self, count — what every broker
-response carries as `spanTimesMs` / `spanSelfMs`, with the request's
-`counters` and `deviceWork`), opens a `jax.profiler.TraceAnnotation` tagged
+`PhaseLedger` (per span name: total, self, count and cpu — what every broker
+response carries as `spanTimesMs` / `spanSelfMs` / `spanCpuMs`, with the
+request's `counters` and `deviceWork`), opens a `jax.profiler.TraceAnnotation` tagged
 with the broker's query id (inert without a profiler session; under one the
 span lies in the `.xplane.pb`'s host plane on the device trace's clock), and
 joins the `RequestTrace` tree when one is active. `phase_timer` and
 `InvocationScope` are `span` with a phase, or with a run-time name. The span
 names and what reads each are listed in PERF.md section 3.
+
+A span opened with `cpu=True` reads a second clock beside `perf_counter`:
+`thread_time`, the CPU time of the thread that ran it (the entry's fourth
+number, `spanCpuMs`). Where such a span has no I/O inside, total less cpu is
+time its thread stood runnable and did not run: the interpreter's lock, or
+the cores. The thread clock is a system call and not free — 0.3 us a read on
+a plain kernel, 5.8 us under gVisor, where it also ticks at 10 ms (PERF.md
+section 6, PR 37) — so it is read where a span asks, by the spans whose cpu
+a per-layer metric reads (PERF.md section 3), and not at every span of
+every query: a loop of many short spans is wrapped in one span that reads it
+(`server.dispatch_all` around a query's `server.dispatch`).
 """
 
 from __future__ import annotations
@@ -345,7 +356,10 @@ class start_trace:
 
 class PhaseLedger:
     """What one request spent where, per span name: total ms, self ms (the
-    total less what child spans cover) and count; the request's counters
+    total less what child spans cover), count and cpu ms (the CPU time of the
+    threads that ran the span, `time.thread_time`; None where the span does
+    not read that clock, and for an interval folded by `record_span`, which
+    no one thread saw whole); the request's counters
     (wire bytes, segments and rows dispatched) and the static work of the
     device programs it launched. One per request per role: the broker's is
     set at `Broker.execute` entry, a server's at its request entry (HTTP
@@ -360,19 +374,23 @@ class PhaseLedger:
         self.qid = qid
         self.role = role
         self._lock = threading.Lock()
-        self.spans: dict[str, list] = {}  # name -> [total ms, self ms, count]
+        self.spans: dict[str, list] = {}  # name -> [total ms, self ms, count, cpu ms | None]
         self.counters: dict[str, int] = {}
         self.device_work: dict[str, dict] = {}
 
-    def fold(self, name: str, ms: float, self_ms: float, parent: "span | None" = None) -> None:
+    def fold(
+        self, name: str, ms: float, self_ms: float, parent: "span | None" = None, cpu_ms: float | None = None
+    ) -> None:
         with self._lock:
             ent = self.spans.get(name)
             if ent is None:
-                self.spans[name] = [ms, self_ms, 1]
+                self.spans[name] = [ms, self_ms, 1, cpu_ms]
             else:
                 ent[0] += ms
                 ent[1] += self_ms
                 ent[2] += 1
+                if cpu_ms is not None:
+                    ent[3] = (ent[3] or 0.0) + cpu_ms
             if parent is not None:
                 parent._child_ms += ms
 
@@ -426,19 +444,22 @@ class PhaseLedger:
             self.counters["serversMerged"] = self.counters.get("serversMerged", 0) + len(docs)
             skew = execute_ms(slowest) - min(execute_ms(d) for d in docs)
             self.counters["scatterSkewMs"] = round(self.counters.get("scatterSkewMs", 0) + skew, 3)
-            for name, (ms, self_ms, n) in slowest.get("spans", {}).items():
-                ent = self.spans.setdefault(name, [0.0, 0.0, 0])
+            for name, (ms, self_ms, n, *cpu_ms) in slowest.get("spans", {}).items():
+                ent = self.spans.setdefault(name, [0.0, 0.0, 0, None])
                 ent[0] += ms
                 ent[1] += self_ms
                 ent[2] += n
+                if cpu_ms and cpu_ms[0] is not None:  # a server from before the second clock sends three numbers
+                    ent[3] = (ent[3] or 0.0) + cpu_ms[0]
 
     def response_fields(self) -> dict:
-        """The four keys every v1 broker response carries."""
+        """The five keys every v1 broker response carries."""
         doc = self.to_wire()
         work = doc["deviceWork"].values()
         return {
             "spanTimesMs": {k: round(v[0], 3) for k, v in doc["spans"].items()},
             "spanSelfMs": {k: round(v[1], 3) for k, v in doc["spans"].items()},
+            "spanCpuMs": {k: round(v[3], 3) for k, v in doc["spans"].items() if v[3] is not None},
             "counters": {
                 "wireRequestBytes": 0,
                 "wireResponseBytes": 0,
@@ -451,6 +472,8 @@ class PhaseLedger:
                 # limb reductions (a grouped DOUBLE SUM / AVG on the byte-plane kernel) whose rows did not fit
                 # their exponent window and took the scatter; those dispatched are `deviceWork`'s to tell
                 "groupedLimbFallbacks": 0,
+                # first stagings host -> HBM inside the query (segment/segment.py `to_device`, span `server.stage`)
+                "segmentsStaged": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),
@@ -488,9 +511,10 @@ def count(name: str, n: int = 1) -> None:
 
 class request_ledger:
     """The dynamic extent of one request in one role. A role's outer entry
-    (the server's HTTP handler) and inner entry (`execute_partials`) share
-    one ledger; another role's (an in-process server under the broker's
-    thread) starts its own, as a remote server would."""
+    (its HTTP handler) and inner entry (`Broker.execute`, the server's
+    `execute_partials`) share one ledger; another role's (an in-process
+    server under the broker's thread) starts its own, as a remote server
+    would."""
 
     __slots__ = ("qid", "role", "_tokens")
 
@@ -502,6 +526,8 @@ class request_ledger:
     def __enter__(self) -> PhaseLedger:
         cur = _ledger.get()
         if cur is not None and cur.role == self.role:
+            if self.qid and not cur.qid:
+                cur.qid = self.qid  # opened before the id was minted (the broker's HTTP handler)
             return cur
         ledger = PhaseLedger(self.qid, self.role)
         self._tokens = (_ledger.set(ledger), _open.set(None))
@@ -517,9 +543,10 @@ class request_ledger:
 class span:
     """The one timing primitive: `with span("server.dispatch", segment=...)`.
 
-    Always: perf_counter at entry and exit, folded into the request's phase
-    ledger (total, self, count; nesting from the context, so a span opened
-    in a scheduler worker lands under the span that submitted it), and —
+    Always: perf_counter at entry and exit — with `cpu=True` thread_time
+    too — folded into the request's phase ledger (total, self, count, cpu;
+    nesting from the context, so a span opened in a scheduler worker lands
+    under the span that submitted it), and —
     under a profiler session — a `jax.profiler.TraceAnnotation(name,
     qid=..., **attrs)`: the span lies in the `.xplane.pb`'s host plane, on
     the device trace's clock, with the request's id in it.
@@ -527,10 +554,12 @@ class span:
     enclosing span's. With `phase=`: the duration also feeds that
     ServerQueryPhase — the trace's `phaseTimesMs`, the `<role>.phase.*Ms`
     timer of `/metrics` when `role` is given, and the HTTP timeline's
-    sub-phases. `ms` holds the duration after exit."""
+    sub-phases. After exit `ms` holds the duration, `cpu_ms` the CPU time of
+    the thread inside it (None without `cpu=True`), and `end` the
+    `perf_counter` instant it closed at."""
 
     __slots__ = (
-        "name", "attrs", "ms", "_phase", "_role", "_late", "_t0", "_child_ms",
+        "name", "attrs", "ms", "cpu_ms", "_phase", "_role", "_late", "_t0", "_cpu0", "_child_ms",
         "_ledger", "_parent", "_token", "_ann", "_trace", "_span",
     )  # fmt: skip
 
@@ -539,10 +568,14 @@ class span:
     #: False: no `Span` in the active trace's tree
     _TREE = True
 
-    def __init__(self, name: str, *, phase: ServerQueryPhase | None = None, role: str | None = None, **attrs):
+    def __init__(
+        self, name: str, *, phase: ServerQueryPhase | None = None, role: str | None = None, cpu: bool = False, **attrs
+    ):
         self.name = name
         self.attrs = attrs
         self.ms = 0.0
+        self.cpu_ms = None
+        self._cpu0 = 0.0 if cpu else None  # the thread clock's reading at entry; None: the span does not read it
         self._phase = phase
         self._role = role
         self._late = None
@@ -564,8 +597,14 @@ class span:
         )
         if ann is not None:
             ann.__enter__()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
+
+    @property
+    def end(self) -> float:
+        return self._t0 + self.ms * 1e-3
 
     def set_attr(self, key: str, value) -> None:
         """An attribute known only inside the span (rows matched, whether
@@ -587,6 +626,9 @@ class span:
 
     def __exit__(self, *exc):
         ms = self.ms = (time.perf_counter() - self._t0) * 1e3
+        cpu_ms = None
+        if self._cpu0 is not None:
+            cpu_ms = self.cpu_ms = (time.thread_time() - self._cpu0) * 1e3
         ann = self._ann
         if ann is not None:
             if self._late:
@@ -599,7 +641,7 @@ class span:
             if parent is not None and parent._ledger is not led:
                 parent = None
             if self._FOLD:
-                led.fold(self.name, ms, max(ms - self._child_ms, 0.0), parent)
+                led.fold(self.name, ms, max(ms - self._child_ms, 0.0), parent, cpu_ms)
             elif parent is not None and self._child_ms:
                 led.pass_up(parent, self._child_ms)  # a span outside the ledger hides no child from its parent
         tr = self._trace

@@ -164,7 +164,9 @@ class QueryEngine:
             if probe_sink is not None
             else contextlib.nullcontext()
         )
-        with cm:
+        # one span around the query's run of dispatches reads the thread clock for all of them: a pair of
+        # reads a query where a pair a launch would be thirty (common/trace.py, the second clock)
+        with cm, span("server.dispatch_all", cpu=True):
             for seg in self.segments if segments is None else segments:
                 default_accountant.checkpoint()
                 if ctx.deadline is not None:
@@ -192,8 +194,8 @@ class QueryEngine:
                             sp.set_attr("rows", disp[2].rows)
                             sp.set_attr("compiled", runtime.compile_requests() != compiles)
                     pend.append((seg, disp))
-        # pruning is microseconds a segment, between the dispatches: one ledger entry a query, no span each
-        record_span("server.prune", prune_ms)
+            # pruning is microseconds a segment, between the dispatches: one ledger entry a query, no span each
+            record_span("server.prune", prune_ms)
         return pend, pruned
 
     def _resolve_partials(self, ctx: QueryContext, pend: list, pruned: int):
@@ -231,22 +233,20 @@ class QueryEngine:
             default_accountant.checkpoint()
             if ctx.deadline is not None:
                 ctx.deadline.check(f"segment {seg.name}")
-            # per-segment CPU attribution (ThreadResourceUsageAccountant
-            # sampleThreadCPUTime parity): thread_time_ns deltas exclude time
-            # this thread spent descheduled or blocked
-            t_cpu = time.thread_time_ns()
-            t_wall = time.perf_counter()
-            with InvocationScope(f"segment:{seg.name}") as scope, span("server.unpack", segment=seg.name):
+            with InvocationScope(f"segment:{seg.name}") as scope, span("server.unpack", cpu=True, segment=seg.name) as unpack:
                 if obs:
                     with scan_stats.collect_probes(summary["indexProbeEntries"]):
                         partial, matched = self._finish_segment(seg, ctx, disp)
                 else:
                     partial, matched = self._finish_segment(seg, ctx, disp)
                 scope.set_attr("numDocsMatched", int(matched))
+            # per-segment CPU attribution (ThreadResourceUsageAccountant
+            # sampleThreadCPUTime parity): the span's thread time excludes what
+            # this thread spent descheduled or blocked
             default_accountant.sample(
                 segments=1,
                 allocated_bytes=seg.size_bytes,
-                cpu_ns=time.thread_time_ns() - t_cpu,
+                cpu_ns=int(unpack.cpu_ms * 1e6),
             )
             if obs:
                 mode = "device" if disp[0] == "dev" else disp[3]
@@ -257,8 +257,7 @@ class QueryEngine:
                     seg.name,
                     docs_scanned=int(matched),
                     bytes_touched=seg.size_bytes,
-                    device_ms=(time.perf_counter() - t_wall) * 1e3
-                    + (disp[2].wait_ms if disp[0] == "dev" else 0.0),
+                    device_ms=unpack.ms + (disp[2].wait_ms if disp[0] == "dev" else 0.0),
                 )
                 if seg_stats["fullScanFallbacks"]:
                     # offender hop for the roofline runbook: which predicate

@@ -1175,10 +1175,13 @@ def dispatch_plan_packed(plan, device_segment) -> PackedResult:
     kernel = get_packed_kernel(plan.spec)
     _packed_cache_obs.observe()
     rows = device_segment.padded
-    vec = kernel(cols, ops, np.int32(device_segment.n_docs), rows)
-    vec.copy_to_host_async()
-    count("hostToDeviceTransfers")
     name = kernel.__name__  # program_name(plan.spec), without hashing the spec again
+    # the one call into the runtime, apart from the planning around it: the operands' transfer, the enqueue
+    # and whatever PJRT makes the caller wait for (launches in flight, a staging still on its way)
+    with span("server.launch", program=name):
+        vec = kernel(cols, ops, np.int32(device_segment.n_docs), rows)
+        vec.copy_to_host_async()
+    count("hostToDeviceTransfers")
     ledger = active_ledger()
     if ledger is not None:
         ledger.add_device_work(name, rows, KERNELS.program_work(name, rows))

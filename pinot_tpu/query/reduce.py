@@ -25,6 +25,7 @@ from typing import Any
 import numpy as np
 import pandas as pd
 
+from pinot_tpu.common.trace import span
 from pinot_tpu.query import ast
 from pinot_tpu.query import funnel as _funnel
 from pinot_tpu.query.context import QueryContext, canonical
@@ -377,27 +378,29 @@ def reduce_aggregation(ctx: QueryContext, partials: list[list]) -> list[list]:
     from pinot_tpu.query.context import null_handling_enabled
 
     null_on = null_handling_enabled(ctx.options)
-    if not partials:
-        merged = None
-    else:
-        merged = list(partials[0])
-        for p in partials[1:]:
+    with span("broker.reduce.merge", frames=len(partials), rows=1):
+        if not partials:
+            merged = None
+        else:
+            merged = list(partials[0])
+            for p in partials[1:]:
+                merged = [
+                    _merge_agg_partials(a.func, m, x, null_on)
+                    for a, m, x in zip(ctx.aggregations, merged, p)
+                ]
+    with span("broker.reduce.rows", rows=1):
+        env: dict[str, Any] = {}
+        if merged is None:
+            # zero segments contributed (all pruned): under null handling the
+            # SUM holder was never set -> None partial -> NULL
             merged = [
-                _merge_agg_partials(a.func, m, x, null_on)
-                for a, m, x in zip(ctx.aggregations, merged, p)
+                None if null_on and MV_TWIN.get(a.func, a.func) == "sum" else _empty_partial(a.func, a.extra)
+                for a in ctx.aggregations
             ]
-    env: dict[str, Any] = {}
-    if merged is None:
-        # zero segments contributed (all pruned): under null handling the
-        # SUM holder was never set -> None partial -> NULL
-        merged = [
-            None if null_on and MV_TWIN.get(a.func, a.func) == "sum" else _empty_partial(a.func, a.extra)
-            for a in ctx.aggregations
-        ]
-    for a, p in zip(ctx.aggregations, merged):
-        env[a.name] = _finalize(a, p, null_on)
-    aliases = _alias_map(ctx)
-    row = [eval_scalar(it.expr, env, aliases) for it in ctx.select_items]
+        for a, p in zip(ctx.aggregations, merged):
+            env[a.name] = _finalize(a, p, null_on)
+        aliases = _alias_map(ctx)
+        row = [eval_scalar(it.expr, env, aliases) for it in ctx.select_items]
     return [row]
 
 
@@ -436,11 +439,32 @@ def _empty_partial(func: str, extra: tuple = ()):
 
 
 def reduce_group_by(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]:
-    nkeys = len(ctx.group_by)
-    key_cols = [f"k{i}" for i in range(nkeys)]
+    """Merge GROUP BY partials -> rows. Each stage under its own span (the
+    children of `broker.reduce`; a stage the query has not, has no span)."""
     frames = [f for f in frames if len(f)]
     if not frames:
         return []
+    aliases = _alias_map(ctx)
+    with span("broker.reduce.merge", frames=len(frames)) as sp:
+        merged, null_on = _merge_group_frames(ctx, frames)
+        sp.set_attr("rows", len(merged))
+    with span("broker.reduce.rows", rows=len(merged)):
+        rows = _group_envs(ctx, merged, null_on)
+    if ctx.having is not None:
+        with span("broker.reduce.having", rows=len(rows)):
+            rows = [e for e in rows if eval_having(ctx.having, e, aliases)]
+    if ctx.order_by:
+        with span("broker.reduce.order", rows=len(rows), keys=len(ctx.order_by)):
+            rows = _order_rows(rows, ctx.order_by, aliases)
+    with span("broker.reduce.project") as sp:
+        rows = rows[ctx.offset : ctx.offset + ctx.limit]
+        sp.set_attr("rows", len(rows))
+        return [[eval_scalar(it.expr, env, aliases) for it in ctx.select_items] for env in rows]
+
+
+def _merge_group_frames(ctx: QueryContext, frames: list[pd.DataFrame]) -> tuple[pd.DataFrame, bool]:
+    """One frame of the servers' partials, a row a group: (merged, null handling on)."""
+    key_cols = [f"k{i}" for i in range(len(ctx.group_by))]
     df = pd.concat(frames, ignore_index=True)
     # merge partials per group: scalar reducers via .agg, object-valued
     # reducers (sets / value arrays / counters) via .apply (pandas agg
@@ -517,8 +541,13 @@ def reduce_group_by(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]
             merged[col] = g[col].apply(fn).values
     else:
         merged = df.drop_duplicates(subset=key_cols).reset_index(drop=True)
+    return merged, null_on
 
-    aliases = _alias_map(ctx)
+
+def _group_envs(ctx: QueryContext, merged: pd.DataFrame, null_on: bool) -> list[dict]:
+    """The merged groups as row envs: group keys and finalized aggregates by canonical name."""
+    nkeys = len(ctx.group_by)
+    key_cols = [f"k{i}" for i in range(nkeys)]
     # column-wise extraction: iterrows() builds a type-coerced Series per
     # group (~70us each), which dominated the broker reduce for group counts
     # in the thousands; plain Python lists keep per-column dtypes AND make
@@ -545,15 +574,7 @@ def reduce_group_by(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]
         for i, a in enumerate(ctx.aggregations):
             env[a.name] = fin_cols[i][ri]
         rows.append(env)
-
-    if ctx.having is not None:
-        rows = [e for e in rows if eval_having(ctx.having, e, aliases)]
-
-    if ctx.order_by:
-        rows = _order_rows(rows, ctx.order_by, aliases)
-
-    rows = rows[ctx.offset : ctx.offset + ctx.limit]
-    return [[eval_scalar(it.expr, env, aliases) for it in ctx.select_items] for env in rows]
+    return rows
 
 
 def _ob_column(ob, rows: list[dict], aliases) -> list:
@@ -652,47 +673,61 @@ def reduce_distinct(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]
         return []
     nkeys = len(ctx.select_items)
     key_cols = [f"k{i}" for i in range(nkeys)]
-    df = pd.concat(frames, ignore_index=True).drop_duplicates(subset=key_cols)
+    with span("broker.reduce.merge", frames=len(frames)) as sp:
+        df = pd.concat(frames, ignore_index=True).drop_duplicates(subset=key_cols)
+        sp.set_attr("rows", len(df))
     if ctx.order_by:
-        aliases = _alias_map(ctx)
-        name_of = {canonical(it.expr): f"k{i}" for i, it in enumerate(ctx.select_items)}
-        by, asc = [], []
-        for ob in ctx.order_by:
-            cn = canonical(ob.expr)
-            if cn not in name_of and aliases and cn in aliases:
-                cn = canonical(aliases[cn])
-            if cn not in name_of:
-                raise ValueError(f"DISTINCT ORDER BY must reference selected columns: {cn}")
-            by.append(name_of[cn])
-            asc.append(not ob.desc)
-        from pinot_tpu.common.sorting import sort_nulls_largest
+        with span("broker.reduce.order", rows=len(df), keys=len(ctx.order_by)):
+            aliases = _alias_map(ctx)
+            name_of = {canonical(it.expr): f"k{i}" for i, it in enumerate(ctx.select_items)}
+            by, asc = [], []
+            for ob in ctx.order_by:
+                cn = canonical(ob.expr)
+                if cn not in name_of and aliases and cn in aliases:
+                    cn = canonical(aliases[cn])
+                if cn not in name_of:
+                    raise ValueError(f"DISTINCT ORDER BY must reference selected columns: {cn}")
+                by.append(name_of[cn])
+                asc.append(not ob.desc)
+            from pinot_tpu.common.sorting import sort_nulls_largest
 
-        df = sort_nulls_largest(df, by, asc)
-    df = df.iloc[ctx.offset : ctx.offset + ctx.limit]
-    return df[key_cols].values.tolist()
+            df = sort_nulls_largest(df, by, asc)
+    with span("broker.reduce.project") as sp:
+        df = df.iloc[ctx.offset : ctx.offset + ctx.limit]
+        sp.set_attr("rows", len(df))
+        return df[key_cols].values.tolist()
 
 
 def reduce_selection(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]:
     frames = [f for f in frames if len(f)]
     if not frames:
         return []
-    df = pd.concat(frames, ignore_index=True)
-    df = df.iloc[ctx.offset : ctx.offset + ctx.limit]
-    return df.values.tolist()
+    with span("broker.reduce.merge", frames=len(frames)) as sp:
+        df = pd.concat(frames, ignore_index=True)
+        sp.set_attr("rows", len(df))
+    with span("broker.reduce.project") as sp:
+        df = df.iloc[ctx.offset : ctx.offset + ctx.limit]
+        sp.set_attr("rows", len(df))
+        return df.values.tolist()
 
 
 def reduce_selection_order_by(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]:
     frames = [f for f in frames if len(f)]
     if not frames:
         return []
-    df = pd.concat(frames, ignore_index=True)
+    with span("broker.reduce.merge", frames=len(frames)) as sp:
+        df = pd.concat(frames, ignore_index=True)
+        sp.set_attr("rows", len(df))
     key_cols = [c for c in df.columns if str(c).startswith("__key")]
-    asc = [not ob.desc for ob in ctx.order_by[: len(key_cols)]]
-    from pinot_tpu.common.sorting import sort_nulls_largest
+    with span("broker.reduce.order", rows=len(df), keys=len(key_cols)):
+        asc = [not ob.desc for ob in ctx.order_by[: len(key_cols)]]
+        from pinot_tpu.common.sorting import sort_nulls_largest
 
-    df = sort_nulls_largest(df, key_cols, asc)
-    df = df.iloc[ctx.offset : ctx.offset + ctx.limit]
-    return df.drop(columns=key_cols).values.tolist()
+        df = sort_nulls_largest(df, key_cols, asc)
+    with span("broker.reduce.project") as sp:
+        df = df.iloc[ctx.offset : ctx.offset + ctx.limit]
+        sp.set_attr("rows", len(df))
+        return df.drop(columns=key_cols).values.tolist()
 
 
 def apply_gapfill(ctx: QueryContext, rows: list[list]) -> list[list]:

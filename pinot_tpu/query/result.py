@@ -67,8 +67,8 @@ class ResultTable:
     # true = the response was served from cluster/result_cache.py
     cache_hit: bool = False
     # the request's phase ledger as the broker answers it (common/trace.py
-    # PhaseLedger.response_fields): spanTimesMs, spanSelfMs, counters,
-    # deviceWork — on every v1 broker response, traced or not
+    # PhaseLedger.response_fields): spanTimesMs, spanSelfMs, spanCpuMs,
+    # counters, deviceWork — on every v1 broker response, traced or not
     span_stats: dict | None = None
 
     def __post_init__(self):
@@ -116,6 +116,18 @@ class ResultTable:
             d["numLegsFailedOver"] = self.num_legs_failed_over
             d["numStaleRouteRetries"] = self.num_stale_route_retries
         return d
+
+    def to_json(self, rows: bytes) -> bytes:
+        """`json.dumps(self.to_dict()).encode()`, byte for byte, around `rows`:
+        the caller's `json.dumps(self.rows).encode()`. The rows are the large
+        part; a caller that times their encoding can write the reading into
+        `span_stats` before the envelope is made."""
+        import json
+
+        doc = self.to_dict()
+        schema = json.dumps(doc.pop("resultTable")["dataSchema"])
+        head = f'{{"resultTable": {{"dataSchema": {schema}, "rows": '
+        return b"".join((head.encode(), rows, b"}, ", json.dumps(doc)[1:].encode()))
 
     def __repr__(self) -> str:  # human-friendly table
         head = " | ".join(self.columns)

@@ -174,6 +174,23 @@ class ImmutableSegment:
         the TPU emulates f64 and query semantics (Pinot DOUBLE) depend on it —
         unless `fast32` opts into lossy float32 storage for speed.
         """
+        from pinot_tpu.common.trace import count, span
+
+        # the staging on the host's clock: what this thread spends handing the columns over; what the
+        # transfer still owes when it returns is waited for by the first launch that reads them
+        with span("server.stage", segment=self.name, columns=len(self.columns)) as stage:
+            arrays = self._stage_columns(fast32)
+            stage.set_attr("bytes", sum(int(a.nbytes) for a in arrays.values()))
+        count("segmentsStaged")
+        ds = DeviceSegment(
+            name=self.name, host=self, n_docs=self.n_docs, padded=padded_len(self.n_docs), arrays=arrays
+        )
+        from pinot_tpu.common.leakcheck import staging_tracker
+
+        staging_tracker.track(ds)  # HBM staging leak detection (test harness)
+        return ds
+
+    def _stage_columns(self, fast32: bool) -> dict[str, Any]:
         import jax.numpy as jnp
 
         pad = padded_len(self.n_docs)
@@ -206,11 +223,7 @@ class ImmutableSegment:
             elif dt == np.float64 and fast32:
                 fwd = fwd.astype(np.float32)
             arrays[name] = jnp.asarray(fwd)
-        ds = DeviceSegment(name=self.name, host=self, n_docs=self.n_docs, padded=pad, arrays=arrays)
-        from pinot_tpu.common.leakcheck import staging_tracker
-
-        staging_tracker.track(ds)  # HBM staging leak detection (test harness)
-        return ds
+        return arrays
 
 
 def narrows_to_int32(ci: ColumnIndex) -> bool:

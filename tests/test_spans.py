@@ -1,10 +1,12 @@
 """The one span primitive (common/trace.py `span`): nesting and self time,
-the phase ledger that leaves with every v1 broker response (`spanTimesMs`,
-`spanSelfMs`, `counters`, `deviceWork`), the stable names of the fused
-per-segment programs, and the spans' arrival in a profiler trace.
+its second clock (the thread's CPU time), the phase ledger that leaves with
+every v1 broker response (`spanTimesMs`, `spanSelfMs`, `spanCpuMs`,
+`counters`, `deviceWork`), the stable names of the fused per-segment
+programs, and the spans' arrival in a profiler trace.
 
-No test asserts a duration or an overhead: only order relations between
-spans that enclose each other, and counts.
+No test asserts an overhead: order relations between spans that enclose each
+other, counts, children that add up to their parent, and of the two clocks
+that a sleep costs no CPU and a spin costs its wall.
 """
 
 import contextlib
@@ -15,6 +17,7 @@ import signal
 import subprocess
 import sys
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -43,10 +46,20 @@ GROUP_BY = "SELECT d, SUM(v) FROM t GROUP BY d ORDER BY d LIMIT 10"
 
 BROKER_SPANS = {"broker.request", "broker.compile", "broker.route", "broker.scatter", "broker.reduce"}
 SERVER_SPANS = {
-    "server.execute", "server.plan", "server.prune", "server.dispatch", "server.device_wait", "server.unpack",
+    "server.execute", "server.plan", "server.prune", "server.dispatch_all", "server.dispatch", "server.device_wait",
+    "server.unpack",
 }  # fmt: skip
 WIRE_SPANS = {"broker.wire.encode", "broker.wire.decode", "server.wire.decode"}
-RESPONSE_KEYS = ("spanTimesMs", "spanSelfMs", "counters", "deviceWork")
+RESPONSE_KEYS = ("spanTimesMs", "spanSelfMs", "spanCpuMs", "counters", "deviceWork")
+# what a query over HTTP adds inside the widest spans: the front end, a leg on the clock, the answer's making
+INSIDE_SPANS = {
+    "broker.http.read", "broker.http.encode", "broker.wire.call", "broker.scatter.tail", "broker.result", "server.launch",
+}  # fmt: skip
+# the spans that read the thread clock too: what a per-layer metric tells work from waiting in
+CPU_SPANS = {"broker.wire.decode", "broker.reduce", "server.dispatch_all", "server.unpack"}
+#: the thread clock of the benchmark's machine ticks at 10 ms and books a tick late (PERF.md section 6, PR 37)
+TICK_MS = 10.0
+REDUCE_STAGES = ("broker.reduce.merge", "broker.reduce.rows", "broker.reduce.order", "broker.reduce.project")
 
 
 # ---------------------------------------------------------------------------
@@ -73,7 +86,7 @@ def test_child_time_is_not_counted_twice():
                 pass
             record_span("queued", 2.0)
     s = led.to_wire()["spans"]
-    assert s["inner"][2] == 2 and s["outer"][2] == 1 and s["queued"] == [2.0, 2.0, 1]
+    assert s["inner"][2] == 2 and s["outer"][2] == 1 and s["queued"] == [2.0, 2.0, 1, None]
     # self = total less what the children cover: the three levels add up to the outer total
     assert s["leaf"][1] == s["leaf"][0]
     assert s["inner"][1] == pytest.approx(s["inner"][0] - s["leaf"][0])
@@ -83,8 +96,34 @@ def test_child_time_is_not_counted_twice():
     assert fields["counters"] == {
         "wireRequestBytes": 0, "wireResponseBytes": 0, "serversMerged": 0, "scatterSkewMs": 0,
         "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "groupedLimbFallbacks": 0,
-        "segmentsDispatched": 0, "rowsDispatched": 0,
+        "segmentsStaged": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
     }  # fmt: skip
+
+
+def test_a_sleeping_span_costs_no_cpu():
+    with request_ledger("q-sleep") as led:
+        with span("sleeps", cpu=True) as sp:
+            time.sleep(0.1)
+        with span("untimed") as plain:  # the thread clock is read where a span asks
+            pass
+    total, _, _, cpu = led.to_wire()["spans"]["sleeps"]
+    assert total >= 100.0 and cpu <= 2 * TICK_MS + 5.0  # a coarse clock may book a tick or two of earlier work here
+    assert (sp.ms, sp.cpu_ms) == (total, cpu)
+    assert plain.cpu_ms is None and led.to_wire()["spans"]["untimed"][3] is None
+    assert led.response_fields()["spanCpuMs"] == {"sleeps": round(cpu, 3)}
+
+
+def test_a_spinning_span_costs_its_spin_in_cpu():
+    """30 ms of the thread's own CPU time, spun by the thread clock: the span reads them, to a tick or two of a
+    coarse clock, however long the box's other work made that take on the wall (alone on a core the two clocks
+    agree; six workers on the driver's box halve the spin's share, which is what the second clock is there to show)."""
+    with span("spins", cpu=True) as sp:
+        until = time.thread_time() + 0.03
+        while time.thread_time() < until:
+            pass
+        closed_at = time.perf_counter()
+    assert 30.0 <= sp.cpu_ms <= 30.0 + 2 * TICK_MS and sp.cpu_ms <= sp.ms + TICK_MS
+    assert sp.end == pytest.approx(closed_at, abs=5e-3)
 
 
 def test_phase_timer_is_transparent_to_the_ledger():
@@ -166,7 +205,7 @@ def test_merge_of_servers_is_the_max_and_work_adds_up(executes):
         # a server's wait is the longer, the shorter its execution: a span-by-span max would pair
         # the slowest server's `server.execute` with the fastest's `server.device_wait`
         return {
-            "spans": {"server.execute": [ms, 1.0, 1], "server.device_wait": [100.0 - ms, 100.0 - ms, 3]},
+            "spans": {"server.execute": [ms, 1.0, 1, ms / 2], "server.device_wait": [100.0 - ms, 100.0 - ms, 3, None]},
             "counters": {"wireRequestBytes": 7},
             "deviceWork": {"seg_agg_00000001": {"launches": 3, "rows": rows, "kernels": {
                 "ops.grouped_planes": {"calls": 3, "bytes": 10.0, "flops": 20.0}}}},
@@ -178,6 +217,8 @@ def test_merge_of_servers_is_the_max_and_work_adds_up(executes):
     out = led.response_fields()
     assert out["spanTimesMs"] == {"server.execute": max(executes), "server.device_wait": 100.0 - max(executes)}
     assert out["spanSelfMs"] == {"server.execute": 1.0, "server.device_wait": 100.0 - max(executes)}
+    # the cpu is the slowest server's too, whole: not a sum over servers, not another server's
+    assert out["spanCpuMs"] == {"server.execute": max(executes) / 2}  # a span that read no thread clock has none
     rows = 300 * n * (n + 1) // 2
     # what was dispatched is read off the merged device work
     assert out["counters"]["segmentsDispatched"] == 3 * n and out["counters"]["rowsDispatched"] == rows
@@ -191,9 +232,13 @@ def test_merge_of_servers_is_the_max_and_work_adds_up(executes):
     led.merge_servers([doc(10.0, 100)])
     out = led.response_fields()
     assert out["spanTimesMs"]["server.execute"] == max(executes) + 10.0
+    assert out["spanCpuMs"]["server.execute"] == max(executes) / 2 + 5.0
     assert out["counters"]["serversMerged"] == n + 1 and out["counters"]["scatterSkewMs"] == max(executes) - min(executes)
     led.merge_servers([])  # a leg whose segments were all pruned
     assert led.response_fields()["counters"]["serversMerged"] == n + 1
+    # a server from before the second clock sends three numbers a span: it adds no cpu
+    led.merge_servers([{"spans": {"server.execute": [5.0, 5.0, 1]}}])
+    assert led.response_fields()["spanCpuMs"]["server.execute"] == max(executes) / 2 + 5.0
 
 
 def test_span_joins_the_request_trace_tree_when_one_is_active():
@@ -308,8 +353,9 @@ def _post(url, sql):
 def _check_ledger(doc, names, n_segments, wire: bool):
     for key in RESPONSE_KEYS:
         assert key in doc, key
-    total, own = doc["spanTimesMs"], doc["spanSelfMs"]
-    assert set(total) == set(own)
+    total, own, cpu = doc["spanTimesMs"], doc["spanSelfMs"], doc["spanCpuMs"]
+    assert set(total) == set(own) and CPU_SPANS & set(total) == set(cpu)
+    assert all(v >= 0.0 for v in cpu.values())
     assert names <= set(total), sorted(names - set(total))
     assert total["broker.request"] >= total["broker.scatter"] >= total["server.execute"] >= total["server.device_wait"]
     assert all(0.0 <= own[k] <= total[k] + 1e-6 for k in total)
@@ -334,8 +380,94 @@ def test_untraced_query_over_http_answers_with_the_ledger(over_http, sql, kind):
     doc = _post(over_http, sql)
     assert not doc.get("exceptions") and "traceInfo" not in doc
     # the servers run a scheduler here, so the queue wait is a span too
-    _check_ledger(doc, BROKER_SPANS | SERVER_SPANS | WIRE_SPANS | {"server.queue"}, n_segments=4, wire=True)
+    _check_ledger(doc, BROKER_SPANS | SERVER_SPANS | WIRE_SPANS | INSIDE_SPANS | {"server.queue"}, n_segments=4, wire=True)
     assert all(name.startswith(f"seg_{kind}_") for name in doc["deviceWork"])
+    total = doc["spanTimesMs"]
+    # a leg is encode + call + decode, and the tail starts where the last call ended: inside the scatter
+    assert total["broker.scatter.tail"] <= total["broker.scatter"]
+    assert total["broker.http.encode"] > 0.0 and total["broker.http.read"] > 0.0
+    assert CPU_SPANS == set(doc["spanCpuMs"])
+
+
+def test_the_stages_of_a_group_by_add_up_to_the_reduce(over_http):
+    doc = _post(over_http, GROUP_BY)
+    total = doc["spanTimesMs"]
+    assert set(REDUCE_STAGES) <= set(total) and "broker.reduce.having" not in total
+    assert sum(total[n] for n in REDUCE_STAGES) == pytest.approx(total["broker.reduce"], rel=0.02, abs=0.5)
+    assert doc["spanSelfMs"]["broker.reduce"] <= max(0.02 * total["broker.reduce"], 0.5)
+    having = _post(over_http, "SELECT d, SUM(v) FROM t GROUP BY d HAVING SUM(v) > 0 ORDER BY d LIMIT 10")
+    assert "broker.reduce.having" in having["spanTimesMs"]
+    # an aggregation has two stages only, a span each
+    agg = _post(over_http, AGG)["spanTimesMs"]
+    assert {"broker.reduce.merge", "broker.reduce.rows"} <= set(agg) and not {"broker.reduce.order", "broker.reduce.project"} & set(agg)
+
+
+def test_the_answer_carries_the_time_of_its_own_encoding(over_http):
+    """The rows are encoded inside `broker.http.encode`, the envelope with the ledger's fields after it: the
+    spliced answer is what `json.dumps` of the whole would be."""
+    doc = _post(over_http, GROUP_BY)
+    assert doc["spanTimesMs"]["broker.http.encode"] > 0.0
+    assert doc["resultTable"]["rows"] == [[d, sum(v for v in range(200) if v % 5 == d) * 4] for d in range(5)]
+    from pinot_tpu.query.result import ResultTable
+
+    res = ResultTable(columns=["a", "b"], rows=[[1, "x\"y"], [None, 2.5]], num_servers_queried=2, span_stats={"spanTimesMs": {"s": 1.0}})
+    assert res.to_json(json.dumps(res.rows).encode()) == json.dumps(res.to_dict()).encode()
+    assert ResultTable(columns=[], rows=[]).to_json(b"[]") == json.dumps(ResultTable(columns=[], rows=[]).to_dict()).encode()
+
+
+def test_in_process_callers_see_no_front_end_and_no_wire(inproc):
+    doc = inproc.execute(GROUP_BY).to_dict()
+    total = doc["spanTimesMs"]
+    assert not any(n.startswith(("broker.http", "broker.wire")) for n in total) and "broker.scatter.tail" not in total
+    assert {"broker.result", "server.launch", *REDUCE_STAGES} <= set(total)
+    assert doc["spanCpuMs"].keys() == {"broker.reduce", "server.dispatch_all", "server.unpack"}
+
+
+def test_a_query_s_dispatches_sit_under_the_one_span_that_reads_the_thread_clock_for_them(inproc):
+    doc = inproc.execute(AGG).to_dict()
+    total, cpu = doc["spanTimesMs"], doc["spanCpuMs"]
+    # the run of dispatches and the pruning between them, whole: the one reading covers what its span covers
+    assert total["server.dispatch"] + total["server.prune"] <= total["server.dispatch_all"] + 1e-6
+    assert total["server.launch"] <= total["server.dispatch"] <= total["server.dispatch_all"]
+    assert "server.dispatch" not in cpu and 0.0 <= cpu["server.dispatch_all"] <= total["server.dispatch_all"] + TICK_MS
+    assert doc["spanSelfMs"]["server.dispatch_all"] <= total["server.dispatch_all"] - total["server.dispatch"] + 1e-6
+
+
+def test_the_hedge_and_the_selector_are_fed_the_leg_whole(tmp_path):
+    """What the hedge's timer waits on is the leg's future — encode, call and decode — so that is what its
+    latency model holds: a handle that spends 30 ms after its answer arrived, as a decode would, is 30 ms slower."""
+
+    class SlowAfterTheAnswer:
+        def __init__(self, server):
+            self._server = server
+
+        def __getattr__(self, name):
+            return getattr(self._server, name)
+
+        def execute_partials(self, *args, **kwargs):
+            out = self._server.execute_partials(*args, **kwargs)
+            time.sleep(0.03)
+            return out
+
+    controller = Controller(PropertyStore(), tmp_path)
+    controller.register_server("server_0", SlowAfterTheAnswer(Server("server_0")))
+    _load(controller, n_segments=1)
+    broker = Broker(controller, cache_config=CacheConfig(enabled=False))
+    doc = broker.execute(AGG).to_dict()
+    assert broker._hedge_ewma[("server_0", "t")] >= 30.0 + doc["spanTimesMs"]["server.execute"]
+
+
+def test_first_touch_of_a_segment_is_staged_inside_the_query_and_counted(tmp_path):
+    controller = Controller(PropertyStore(), tmp_path)
+    controller.register_server("server_0", Server("server_0"))
+    _load(controller, n_segments=3)
+    broker = Broker(controller, cache_config=CacheConfig(enabled=False))
+    first, second = broker.execute(AGG).to_dict(), broker.execute(AGG).to_dict()
+    assert first["counters"]["segmentsStaged"] == 3 and first["spanTimesMs"]["server.stage"] > 0.0
+    # staging is inside the dispatch that first touched the segment
+    assert first["spanTimesMs"]["server.stage"] <= first["spanTimesMs"]["server.dispatch"]
+    assert second["counters"]["segmentsStaged"] == 0
+    assert "server.stage" not in second["spanTimesMs"]
 
 
 def test_sampled_query_over_http_keeps_ledger_and_subtrees(over_http):
@@ -485,3 +617,39 @@ def test_spans_reach_the_profiler_trace_with_the_query_id(tmp_path, inproc):
     programs = {str(st["program"]) for st in found["server.dispatch"]}
     assert programs == set(doc["deviceWork"])
     assert all(int(st["rows"]) > 0 and str(st["segment"]).startswith("t_") for st in found["server.dispatch"])
+
+
+def test_launch_and_staging_reach_the_profiler_trace_with_the_query_id(tmp_path):
+    """What `gap_attribution` names a device gap by under `server.dispatch`: the launch, and a fresh table's
+    first staging, both annotations of the host plane with the broker's query id."""
+    import jax
+    from jax.profiler import ProfileData
+
+    controller = Controller(PropertyStore(), tmp_path / "ds")
+    controller.register_server("server_0", Server("server_0"))
+    _load(controller, n_segments=2)
+    broker = Broker(controller, cache_config=CacheConfig(enabled=False))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    with time_limit(120):
+        jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+        try:
+            doc = broker.execute(AGG).to_dict()
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = list((tmp_path / "trace").rglob("*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in ("server.launch", "server.stage", "server.dispatch"):
+                        found.setdefault(ev.name, []).append((ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    assert len(found["server.launch"]) == len(found["server.stage"]) == len(found["server.dispatch"]) == 2
+    assert {str(st["qid"]) for evs in found.values() for _, _, st in evs} == {str(next(iter(found["server.dispatch"]))[2]["qid"])}
+    assert {str(st["program"]) for _, _, st in found["server.launch"]} == set(doc["deviceWork"])
+    assert all(str(st["segment"]).startswith("t_") and int(st["bytes"]) > 0 and int(st["columns"]) == 2 for _, _, st in found["server.stage"])
+    # each lies inside a dispatch: the innermost `server.*` span over a gap there is the launch or the staging
+    for name in ("server.launch", "server.stage"):
+        assert all(any(a <= s and e <= b for a, b, _ in found["server.dispatch"]) for s, e, _ in found[name])
