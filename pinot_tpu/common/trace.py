@@ -472,6 +472,9 @@ class PhaseLedger:
                 # limb reductions (a grouped DOUBLE SUM / AVG on the byte-plane kernel) whose rows did not fit
                 # their exponent window and took the scatter; those dispatched are `deviceWork`'s to tell
                 "groupedLimbFallbacks": 0,
+                # stages of a group-by reduce (HAVING, ORDER BY, the select list) that left the columns for a
+                # dict a group or the comparison sort (query/reduce.py `reduce_group_by`)
+                "reduceRowStages": 0,
                 # first stagings host -> HBM inside the query (segment/segment.py `to_device`, span `server.stage`)
                 "segmentsStaged": 0,
                 **doc["counters"],

@@ -25,11 +25,11 @@ from typing import Any
 import numpy as np
 import pandas as pd
 
-from pinot_tpu.common.trace import span
+from pinot_tpu.common.trace import count, span
 from pinot_tpu.query import ast
 from pinot_tpu.query import funnel as _funnel
 from pinot_tpu.query.context import QueryContext, canonical
-from pinot_tpu.query.result import ResultTable
+from pinot_tpu.query.result import PlainRows, ResultTable
 
 # number of partial slots per aggregation function
 PART_COUNTS = {"avg": 2, "minmaxrange": 2, "avgmv": 2, "minmaxrangemv": 2}
@@ -72,30 +72,43 @@ def parts_of(func: str) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _column_name(expr: ast.Expr, names, aliases: dict[str, ast.Expr] | None = None) -> str | None:
+    """The one of `names` (an env's keys, the groups' columns) that `expr`
+    reads as it stands, or None where it is a constant or has to be computed:
+    an Identifier by its name or down its alias chain, anything else by its
+    canonical text (a whole expression may itself be a group key, e.g. GROUP
+    BY year-1990; an aggregation is a column under its canonical name)."""
+    if isinstance(expr, ast.Literal):
+        return None
+    if isinstance(expr, ast.Identifier):
+        if expr.name in names:
+            return expr.name
+        if aliases and expr.name in aliases:
+            return _column_name(aliases[expr.name], names, aliases)
+        return None
+    cn = canonical(expr)
+    if cn in names:
+        return cn
+    # COUNT(DISTINCT x) was canonicalized to distinctcount(x)
+    if isinstance(expr, ast.FunctionCall) and expr.name == "count" and expr.distinct:
+        alt = canonical(ast.FunctionCall("distinctcount", expr.args))
+        if alt in names:
+            return alt
+    return None
+
+
 def eval_scalar(expr: ast.Expr, env: dict[str, Any], aliases: dict[str, ast.Expr] | None = None):
     if isinstance(expr, ast.Literal):
         return expr.value
-    # a whole expression may itself be a group key (e.g. GROUP BY year-1990)
-    if not isinstance(expr, ast.Identifier):
-        cn = canonical(expr)
-        if cn in env:
-            return env[cn]
+    name = _column_name(expr, env, aliases)
+    if name is not None:
+        return env[name]
     if isinstance(expr, ast.Identifier):
-        if expr.name in env:
-            return env[expr.name]
         if aliases and expr.name in aliases:
-            return eval_scalar(aliases[expr.name], env, aliases)
+            return eval_scalar(aliases[expr.name], env, aliases)  # an alias of something computed
         raise KeyError(f"unknown reference {expr.name!r} in post-aggregation context")
     if isinstance(expr, ast.FunctionCall):
-        name = canonical(expr)
-        if name in env:
-            return env[name]
-        # COUNT(DISTINCT x) was canonicalized to distinctcount(x)
-        if expr.name == "count" and expr.distinct:
-            alt = canonical(ast.FunctionCall("distinctcount", expr.args))
-            if alt in env:
-                return env[alt]
-        raise KeyError(f"aggregation {name!r} not computed")
+        raise KeyError(f"aggregation {canonical(expr)!r} not computed")
     if isinstance(expr, ast.BinaryOp):
         l = eval_scalar(expr.left, env, aliases)
         r = eval_scalar(expr.right, env, aliases)
@@ -439,8 +452,11 @@ def _empty_partial(func: str, extra: tuple = ()):
 
 
 def reduce_group_by(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]:
-    """Merge GROUP BY partials -> rows. Each stage under its own span (the
-    children of `broker.reduce`; a stage the query has not, has no span)."""
+    """Merge GROUP BY partials -> rows. The merged groups stay columns from
+    pandas' merge on; a row is made once, at the end, for the groups OFFSET /
+    LIMIT keep. Each stage under its own span (the children of
+    `broker.reduce`; a stage the query has not, has no span). A stage that
+    could not stay in columns counts one `reduceRowStages`."""
     frames = [f for f in frames if len(f)]
     if not frames:
         return []
@@ -449,17 +465,20 @@ def reduce_group_by(ctx: QueryContext, frames: list[pd.DataFrame]) -> list[list]
         merged, null_on = _merge_group_frames(ctx, frames)
         sp.set_attr("rows", len(merged))
     with span("broker.reduce.rows", rows=len(merged)):
-        rows = _group_envs(ctx, merged, null_on)
+        groups = _group_columns(ctx, merged, null_on)
     if ctx.having is not None:
-        with span("broker.reduce.having", rows=len(rows)):
-            rows = [e for e in rows if eval_having(ctx.having, e, aliases)]
+        with span("broker.reduce.having", rows=groups.n):
+            count("reduceRowStages")  # eval_having's three-valued logic reads a row env
+            keep = [i for i, e in enumerate(groups.envs()) if eval_having(ctx.having, e, aliases)]
+            groups = groups.take(np.asarray(keep, dtype=np.intp))
+    order = np.arange(groups.n)
     if ctx.order_by:
-        with span("broker.reduce.order", rows=len(rows), keys=len(ctx.order_by)):
-            rows = _order_rows(rows, ctx.order_by, aliases)
+        with span("broker.reduce.order", rows=groups.n, keys=len(ctx.order_by)):
+            order = _order_groups(groups, ctx.order_by, aliases)
     with span("broker.reduce.project") as sp:
-        rows = rows[ctx.offset : ctx.offset + ctx.limit]
-        sp.set_attr("rows", len(rows))
-        return [[eval_scalar(it.expr, env, aliases) for it in ctx.select_items] for env in rows]
+        groups = groups.take(order[ctx.offset : ctx.offset + ctx.limit])  # the cut first: only the kept rows are made
+        sp.set_attr("rows", groups.n)
+        return _project(groups, [it.expr for it in ctx.select_items], aliases)
 
 
 def _merge_group_frames(ctx: QueryContext, frames: list[pd.DataFrame]) -> tuple[pd.DataFrame, bool]:
@@ -544,100 +563,149 @@ def _merge_group_frames(ctx: QueryContext, frames: list[pd.DataFrame]) -> tuple[
     return merged, null_on
 
 
-def _group_envs(ctx: QueryContext, merged: pd.DataFrame, null_on: bool) -> list[dict]:
-    """The merged groups as row envs: group keys and finalized aggregates by canonical name."""
-    nkeys = len(ctx.group_by)
-    key_cols = [f"k{i}" for i in range(nkeys)]
-    # column-wise extraction: iterrows() builds a type-coerced Series per
-    # group (~70us each), which dominated the broker reduce for group counts
-    # in the thousands; plain Python lists keep per-column dtypes AND make
-    # the env-build loop ~10x cheaper
-    key_vals = [merged[f"k{i}"].tolist() for i in range(nkeys)]
-    part_vals = {c: merged[c].tolist() for c in merged.columns if c not in key_cols}
-    group_names = [canonical(g) for g in ctx.group_by]
+class _Groups:
+    """The merged groups as columns: for each group key and each aggregation,
+    under the name a row env gives it (`canonical(g)`, `a.name`), the list of
+    its Python values. ORDER BY and the select list resolve an expression to a
+    column once (`values`, by `_column_name` as `eval_scalar` does); what names
+    no column is left to `eval_scalar` / `eval_having` over row envs, made once
+    an answer and only then (`envs`)."""
+
+    __slots__ = ("n", "cols", "_envs")
+
+    def __init__(self, n: int, cols: dict[str, list]):
+        self.n = n
+        self.cols = cols
+        self._envs: list[dict] | None = None
+
+    def values(self, expr: ast.Expr, aliases) -> tuple[list, bool]:
+        """(`expr` over every group, whether it took a row env a group: an
+        expression that is neither a column nor a constant is `eval_scalar`'s,
+        and the stage counts that)."""
+        if isinstance(expr, ast.Literal):
+            return [expr.value] * self.n, False
+        name = _column_name(expr, self.cols, aliases)
+        if name is not None:
+            return self.cols[name], False
+        return [eval_scalar(expr, e, aliases) for e in self.envs()], True
+
+    def envs(self) -> list[dict]:
+        """A dict a group, as `eval_scalar` / `eval_having` read one: the only place one is made."""
+        if self._envs is None:
+            names = list(self.cols)
+            self._envs = [dict(zip(names, vals)) for vals in zip(*self.cols.values())]
+        return self._envs
+
+    def take(self, idx: np.ndarray) -> _Groups:
+        """The groups `idx` names, in its order."""
+        at = idx.tolist()
+
+        def pick(vals):
+            return [vals[i] for i in at]
+
+        out = _Groups(len(at), {k: pick(v) for k, v in self.cols.items()})
+        if self._envs is not None:
+            out._envs = pick(self._envs)
+        return out
+
+
+def _group_columns(ctx: QueryContext, merged: pd.DataFrame, null_on: bool) -> _Groups:
+    """The merged frame as `_Groups`: keys as they are, aggregates finalized, both a column at a time."""
     n_rows = len(merged)
-    fin_cols = []
+    cols: dict[str, list] = {}
+    # Series.tolist(): plain Python values of the column's own dtype (iterrows()
+    # would coerce a row to one type, and cost ~70us a group)
+    for i, g in enumerate(ctx.group_by):
+        vals = merged[f"k{i}"].tolist()
+        if null_on:  # NaN key = the null group (host NaN substitution)
+            vals = [None if _is_null_partial(k) else k for k in vals]
+        cols[canonical(g)] = vals
     for i, a in enumerate(ctx.aggregations):
         if parts_of(a.func) == 2:
-            parts = (part_vals[f"a{i}p0"], part_vals[f"a{i}p1"])
+            parts = (merged[f"a{i}p0"].tolist(), merged[f"a{i}p1"].tolist())
         else:
-            parts = part_vals[f"a{i}p0"]
-        fin_cols.append(_finalize_column(a, parts, null_on, n_rows))
-    rows = []
-    for ri in range(n_rows):
-        env: dict[str, Any] = {}
-        for i, name in enumerate(group_names):
-            k = key_vals[i][ri]
-            if null_on and _is_null_partial(k):
-                k = None  # NaN key = the null group (host NaN substitution)
-            env[name] = k
-        for i, a in enumerate(ctx.aggregations):
-            env[a.name] = fin_cols[i][ri]
-        rows.append(env)
-    return rows
+            parts = merged[f"a{i}p0"].tolist()
+        cols[a.name] = _finalize_column(a, parts, null_on, n_rows)
+    return _Groups(n_rows, cols)
 
 
-def _ob_column(ob, rows: list[dict], aliases) -> list:
-    """Evaluate one ORDER BY expression over every row env. The canonical
-    env key is row-independent, so it is resolved ONCE and the per-row work
-    collapses to a dict lookup; only expressions not materialized in the env
-    (post-agg arithmetic, alias chains) pay full eval_scalar per row."""
-    expr = ob.expr
-    if rows:
-        if isinstance(expr, ast.Identifier):
-            if expr.name in rows[0]:
-                return [e[expr.name] for e in rows]
-        elif not isinstance(expr, ast.Literal):
-            cn = canonical(expr)
-            if cn in rows[0]:
-                return [e[cn] for e in rows]
-    return [eval_scalar(expr, e, aliases) for e in rows]
+_EXACT_INT = 1 << 53  # past it float64 collapses distinct ints
+_NUMBER_TYPES = (int, float, np.integer, np.floating)
 
 
-def _order_rows(rows: list[dict], order_by, aliases) -> list[dict]:
-    """ORDER BY over merged group rows. Numeric keys ride one stable
-    np.lexsort (nulls-as-largest, DESC via negation — same ordering as
-    _OrderKey); any non-numeric or precision-risky key (strings, |int|>2^53)
-    falls back to the general Python sort over the SAME pre-evaluated
-    columns, so eval_scalar never runs per-comparison either way."""
-    cols = [_ob_column(ob, rows, aliases) for ob in order_by]
-    descs = [ob.desc for ob in order_by]
-    n = len(rows)
-    lex: list[np.ndarray] = []
-    numeric = True
-    for vals, desc in zip(cols, descs):
-        arr = np.empty(n, np.float64)
-        mask = np.empty(n, np.float64)
-        for i, v in enumerate(vals):
-            if v is None or (isinstance(v, float) and math.isnan(v)):
-                # nulls rank as the largest value: first under DESC, last ASC
-                mask[i] = 0.0 if desc else 1.0
-                arr[i] = 0.0
-            elif isinstance(v, bool) or not isinstance(v, (int, float, np.integer, np.floating)):
-                numeric = False
-                break
-            elif isinstance(v, (int, np.integer)) and abs(int(v)) > (1 << 53):
-                numeric = False  # float64 would collapse distinct keys
-                break
-            else:
-                mask[i] = 1.0 if desc else 0.0
-                arr[i] = -float(v) if desc else float(v)
-        if not numeric:
-            break
-        lex.append(mask)
-        lex.append(arr)
-    if numeric:
-        if not lex:
-            return rows
-        # np.lexsort: LAST key is primary -> reversed, ob_1's null-group mask
-        # dominates, then its values, then ob_2's mask/values, ...
+def _lexsort_key(vals: list, desc: bool) -> tuple[np.ndarray, np.ndarray] | None:
+    """One ORDER BY key as the two float64 columns `np.lexsort` reads: the null
+    mask (nulls rank largest: first under DESC, last under ASC) and the value,
+    negated under DESC. Numbers ride as they are; strings as the rank of each
+    value among the column's distinct values, which Python's `sorted` orders
+    (so the collation is `_OrderKey`'s: code points). None for a column only
+    `_OrderKey` can order: mixed types, bool, bytes, an int float64 cannot hold."""
+    kinds = set(map(type, vals)) - {type(None)}
+    if str in kinds and kinds <= {str, float}:
+        codes, distinct = pd.factorize(np.asarray(vals, dtype=object))  # None and NaN: -1, the nulls
+        distinct = distinct.tolist()
+        if float in kinds and not all(type(d) is str for d in distinct):
+            return None  # a number among the strings (a NaN is a null, and no distinct value)
+        rank = np.empty(len(distinct), np.float64)
+        rank[sorted(range(len(distinct)), key=distinct.__getitem__)] = np.arange(len(distinct))
+        null = codes < 0
+        arr = rank[codes]
+    elif all(issubclass(k, _NUMBER_TYPES) and k is not bool for k in kinds):
+        try:
+            arr = np.asarray(vals, dtype=np.float64)  # None -> nan
+        except OverflowError:
+            return None
+        null = np.isnan(arr)
+        if any(issubclass(k, (int, np.integer)) for k in kinds) and (np.abs(arr[~null]) >= _EXACT_INT).any():
+            return None
+    else:
+        return None
+    return (null != desc).astype(np.float64), np.where(null, 0.0, -arr if desc else arr)
+
+
+def _order_groups(groups: _Groups, order_by, aliases) -> np.ndarray:
+    """The permutation ORDER BY puts the groups in: every key a numeric column
+    or a rank (`_lexsort_key`), then one stable `np.lexsort`. Where a key can be
+    neither, the general `_OrderKey` sort over the same pre-evaluated columns,
+    over indices; it is stable too, so ties keep the merge's order either way."""
+    cols, lex = [], []
+    rowwise = False
+    for ob in order_by:
+        vals, by_row = groups.values(ob.expr, aliases)
+        rowwise |= by_row
+        cols.append(vals)
+        if lex is not None:
+            key = _lexsort_key(vals, ob.desc)
+            lex = lex + list(key) if key is not None else None  # None from the first key only `_OrderKey` can order
+    if lex is not None:
+        # np.lexsort: LAST key is primary -> reversed, ob_1's null mask
+        # dominates, then its values, then ob_2's mask / values, ...
         order = np.lexsort(lex[::-1])
-        return [rows[i] for i in order]
-    idx = sorted(
-        range(n),
-        key=lambda i: tuple(_OrderKey(c[i], d) for c, d in zip(cols, descs)),
-    )
-    return [rows[i] for i in idx]
+    else:
+        rowwise = True
+        descs = [ob.desc for ob in order_by]
+        order = np.asarray(
+            sorted(range(groups.n), key=lambda i: tuple(_OrderKey(c[i], d) for c, d in zip(cols, descs))),
+            dtype=np.intp,
+        )
+    if rowwise:
+        count("reduceRowStages")
+    return order
+
+
+def _project(groups: _Groups, exprs: list, aliases) -> list[list]:
+    """The select list over `groups`, as row lists: a column an item, one `zip`.
+    `PlainRows` where no column holds a numpy scalar (each is asked for its types)."""
+    out, plain, rowwise = [], True, False
+    for expr in exprs:
+        vals, by_row = groups.values(expr, aliases)
+        rowwise |= by_row
+        plain = plain and not any(issubclass(k, np.generic) for k in set(map(type, vals)))
+        out.append(vals)
+    if rowwise:
+        count("reduceRowStages")
+    rows = map(list, zip(*out))
+    return PlainRows(rows) if plain else list(rows)
 
 
 class _OrderKey:

@@ -13,6 +13,14 @@ from typing import Any
 import numpy as np
 
 
+class PlainRows(list):
+    """Row lists whose maker vouches that every cell is a Python value already
+    (made of `ndarray.tolist()`, `str`, `None`): `ResultTable` takes them as
+    they are. Any other list of rows it converts cell by cell."""
+
+    __slots__ = ()
+
+
 @dataclass
 class ResultTable:
     columns: list[str]
@@ -72,7 +80,8 @@ class ResultTable:
     span_stats: dict | None = None
 
     def __post_init__(self):
-        self.rows = [[_plain(v) for v in row] for row in self.rows]
+        if not isinstance(self.rows, PlainRows):
+            self.rows = [[_plain(v) for v in row] for row in self.rows]
         if not self.column_types:
             self.column_types = [_infer_type(self.rows, i) for i in range(len(self.columns))]
 

@@ -96,7 +96,7 @@ def test_child_time_is_not_counted_twice():
     assert fields["counters"] == {
         "wireRequestBytes": 0, "wireResponseBytes": 0, "serversMerged": 0, "scatterSkewMs": 0,
         "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "groupedLimbFallbacks": 0,
-        "segmentsStaged": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
+        "reduceRowStages": 0, "segmentsStaged": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
     }  # fmt: skip
 
 
@@ -395,8 +395,10 @@ def test_the_stages_of_a_group_by_add_up_to_the_reduce(over_http):
     assert set(REDUCE_STAGES) <= set(total) and "broker.reduce.having" not in total
     assert sum(total[n] for n in REDUCE_STAGES) == pytest.approx(total["broker.reduce"], rel=0.02, abs=0.5)
     assert doc["spanSelfMs"]["broker.reduce"] <= max(0.02 * total["broker.reduce"], 0.5)
+    # no stage of it left the columns; a HAVING reads a row env a group, and says so
+    assert doc["counters"]["reduceRowStages"] == 0
     having = _post(over_http, "SELECT d, SUM(v) FROM t GROUP BY d HAVING SUM(v) > 0 ORDER BY d LIMIT 10")
-    assert "broker.reduce.having" in having["spanTimesMs"]
+    assert "broker.reduce.having" in having["spanTimesMs"] and having["counters"]["reduceRowStages"] == 1
     # an aggregation has two stages only, a span each
     agg = _post(over_http, AGG)["spanTimesMs"]
     assert {"broker.reduce.merge", "broker.reduce.rows"} <= set(agg) and not {"broker.reduce.order", "broker.reduce.project"} & set(agg)
