@@ -1,8 +1,10 @@
-"""The reduce's row-by-row work: the merged groups made row envs
-(`broker.reduce.rows`: the columns finalized and a dict built a group), the
-HAVING evaluated an env (`broker.reduce.having`, where a query has one) and
-the kept rows projected through the select list (`broker.reduce.project`:
-the slice and `eval_scalar` an item a row) — their sum, median."""
+"""The reduce between the merge and the sort, and after it: the merged groups
+made columns (`broker.reduce.rows`: each aggregate finalized as one array over
+the groups; since PR 39 no dict a group), the HAVING evaluated a group
+(`broker.reduce.having`, where a query has one: the one stage that still reads
+a row env) and the kept rows cut to the LIMIT and projected through the select
+list (`broker.reduce.project`: the columns taken at the kept positions, a row
+made only of what the answer keeps) — their sum, median."""
 
 from perfbench.layer_metrics._inside import median_sum
 

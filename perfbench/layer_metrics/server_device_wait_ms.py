@@ -1,6 +1,7 @@
 """Time a query's server thread blocked on device readbacks
-(`server.device_wait`, summed over its segments), median: the queue on the
-device plus the programs, as the host sees them."""
+(`server.device_wait`: since PR 34 one wait a query, for all its segments'
+result vectors together, where there was one a segment before), median: the
+queue on the device plus the programs, as the host sees them."""
 
 from perfbench.layer_metrics._spans import median_difference
 

@@ -13,6 +13,7 @@ works on the codes, so partials of different segments share their keys.
 
 from __future__ import annotations
 
+import operator
 from dataclasses import dataclass, field
 from typing import Callable, NamedTuple
 
@@ -43,8 +44,8 @@ class Spec:
     """One query template, for the reference.
 
     where(cols, params) -> bool mask; keys: group-by column names; aggs: list
-    of (kind, value_fn) with kind in sum|count|avg and value_fn(cols) -> array
-    (ignored for count); select: output order, key column names and "agg<i>";
+    of (kind, value_fn) with kind in sum|count|avg|min|max and value_fn(cols)
+    -> array (ignored for count); select: output order, key column names and "agg<i>";
     order: [(select name, descending)], the query's ORDER BY; exact: whether
     every aggregate is an integer the program must return exactly.
     """
@@ -71,11 +72,15 @@ class Template:
 
 
 def partial(spec: Spec, params: dict, cols: dict[str, Column], acc_dtype=np.float64) -> dict:
-    """Per-segment partial: {"n": matched rows, "groups": {key codes: [sum, ...]}}.
+    """Per-segment partial: {"n": matched rows, "kinds": each aggregate's kind,
+    "groups": {key codes: [an aggregate's sum or extreme, ..., matched rows of the group]}}.
 
     Sums accumulate in float64, which is exact for the integer columns here
     (every partial sum stays far below 2**53). `acc_dtype=np.float32` is the
-    lower-precision control, never the reference.
+    lower-precision control, never the reference; it changes how sums and
+    averages are added up and leaves `min` and `max`, which add nothing, alone.
+    A group's extreme over no rows (a query without GROUP BY that matched
+    nothing) is the identity of its kind, +inf for `min` and -inf for `max`.
     """
     mask = spec.where(cols, params)
     idx = np.flatnonzero(mask)
@@ -93,6 +98,11 @@ def partial(spec: Spec, params: dict, cols: dict[str, Column], acc_dtype=np.floa
     for kind, fn in spec.aggs:
         if kind == "count":
             sums.append(counts.astype(np.float64))
+        elif kind in _EXTREMES:
+            ufunc, identity = _EXTREMES[kind]
+            extreme = np.full(len(uniq), identity)
+            ufunc.at(extreme, inv, np.asarray(fn(cols))[idx].astype(np.float64))
+            sums.append(extreme)
         elif acc_dtype is np.float32:
             sums.append(_sum_float32(np.asarray(fn(cols))[idx], inv, counts))
         else:
@@ -103,7 +113,11 @@ def partial(spec: Spec, params: dict, cols: dict[str, Column], acc_dtype=np.floa
         for i, g in enumerate(uniq)
         if counts[i] or not spec.keys
     }
-    return {"n": n, "groups": groups}
+    return {"n": n, "kinds": [kind for kind, _ in spec.aggs], "groups": groups}
+
+
+#: a kind that keeps a group's extreme: how two values combine, and what no value at all reads
+_EXTREMES = {"min": (np.minimum, np.inf), "max": (np.maximum, -np.inf)}
 
 
 def _sum_float32(vals: np.ndarray, inv: np.ndarray, counts: np.ndarray) -> np.ndarray:
@@ -117,14 +131,18 @@ def _sum_float32(vals: np.ndarray, inv: np.ndarray, counts: np.ndarray) -> np.nd
 
 
 def merge(partials: list[dict]) -> dict:
+    """The segments' partials as one: a group's sums and counts add up, of its
+    extremes the smaller or the larger stands, each field by its aggregate's kind."""
+    kinds = partials[0]["kinds"] if partials else []
+    combine = [min if k == "min" else max if k == "max" else operator.add for k in kinds] + [operator.add]  # the last field: the group's rows
     out: dict[int, list] = {}
     n = 0
     for p in partials:
         n += p["n"]
         for g, vals in p["groups"].items():
             cur = out.get(g)
-            out[g] = vals if cur is None else [a + b for a, b in zip(cur, vals)]
-    return {"n": n, "groups": out}
+            out[g] = vals if cur is None else [f(a, b) for f, a, b in zip(combine, cur, vals)]
+    return {"n": n, "kinds": kinds, "groups": out}
 
 
 def finish(spec: Spec, merged: dict, vocabs: dict[str, np.ndarray]) -> list[list]:

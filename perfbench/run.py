@@ -39,6 +39,7 @@ import numpy as np
 
 from perfbench import check, loadgen, refeval, result_line
 from perfbench import loss as loss_mod
+from perfbench import tables as tables_mod
 from perfbench.cluster import (
     Roles, RunFailure, ServerControl, child_env, chip_pin, http_json, kernel_calls, metric_total, ready_doc, require,
 )  # fmt: skip
@@ -78,10 +79,10 @@ def _datagen_job(job: dict) -> dict:
     return datagen.build_and_upload(job)
 
 
-def _create_table(dataset: str, controller_url: str, replication: int) -> None:
+def _create_tables(dataset: str, controller_url: str, declared: list[dict]) -> None:
     from perfbench import datagen
 
-    datagen.create_table(datagen.dataset_module(dataset), controller_url, replication)
+    datagen.create_tables(datagen.dataset_module(dataset), controller_url, declared)
 
 
 def settle(submitted: list) -> tuple[list[dict], list]:
@@ -95,14 +96,8 @@ def settle(submitted: list) -> tuple[list[dict], list]:
             raise  # a worker died (out of memory): nothing more can be submitted
         except Exception as e:  # whatever the upload client or the controller's answer raised in the worker
             refused.append((job, f"{type(e).__name__}: {str(e)[:500]}"))
-            say(f"segment {job['index']} failed to upload: {refused[-1][1]}")
+            say(f"segment {job['index']}{'' if job['table']['fact'] else ' of ' + job['table']['name']} failed to upload: {refused[-1][1]}")
     return done, refused
-
-
-def segment_plan(config: dict) -> list[int]:
-    rows, seg = config["rows"], config["segmentRows"]
-    require(rows % seg == 0, f"rows {rows} not a multiple of segmentRows {seg}")
-    return [seg] * (rows // seg)
 
 
 def evict_cache(config_dir: Path, keep: int) -> None:
@@ -155,7 +150,9 @@ class Cluster:
         cfg_dir.mkdir(parents=True, exist_ok=True)
         self.dir = cfg_dir / str(seed)
         self.seed = seed
-        self.sizes = segment_plan(self.config)
+        # every table of the deployment, the one the templates query (the fact table) last; `sizes` is its segments
+        self.tables = tables_mod.declared(self.config, self.ds)
+        self.sizes = tables_mod.segment_sizes(self.tables[-1])
         self.servers: dict[str, str] = {}
         self.controls: dict[str, ServerControl] = {}
         self.control_files: dict[str, Path] = {}
@@ -209,7 +206,7 @@ class Cluster:
         else:
             self._generate()
             self._wait_hosted(timeout=60)
-            marker.write_text(json.dumps({"rows": self.config["rows"], "segments": len(self.sizes)}))
+            marker.write_text(json.dumps({"rows": self.config["rows"], "segments": len(self.sizes), "tables": self.timing["tables"]}))
             self.timing["generate_upload_load_s"] = time.perf_counter() - t0
         self.cached = cached
         self.roles.check_alive()
@@ -233,62 +230,89 @@ class Cluster:
         env_before = dict(os.environ)
         os.environ.clear()
         os.environ.update({**self.env, "JAX_PLATFORMS": "cpu"})  # what the spawned workers inherit
+        done: list[dict] = []
+        again = 0
         try:
-            jobs = [
-                {"dataset": self.config["dataset"], "seed": self.seed, "index": i, "rows": n,
-                 "config": self.config, "controller_url": self.controller, "out_dir": str(self.dir / "built")}
-                for i, n in enumerate(self.sizes)
-            ]  # fmt: skip
-            # The controller reads, changes and writes a table's ideal state without a lock, so of two uploads
-            # that end in the same moment (a) both can be given the same server and (b) one's entry can be
-            # overwritten: the server holds that segment, the broker routes past it, and every answer comes a
-            # segment short (14 of 15 queried, seed 3260000704; PERF.md, PR 26). Against (a), with several servers
-            # the last round, one a server, goes one upload at a time: the controller gives a segment to the
-            # server that hosts fewest. Against (b), a segment the ideal state lacks is uploaded again, alone.
-            # An upload that the controller refused (its 2 s lease ran out under it and the write was fenced, or
-            # the connection broke) is sent again too, once: the parent's client sent it up to three times.
-            last = len(self.servers) if len(self.servers) > 1 else 0
-            with ProcessPoolExecutor(workers_for(len(jobs)), mp_context=get_context("spawn")) as pool:
-                pool.submit(_create_table, self.config["dataset"], self.controller, self.config["replication"]).result()
-                done, refused = settle([(job, pool.submit(_datagen_job, job)) for job in jobs[: len(jobs) - last]])
-                for job in jobs[len(jobs) - last :]:
-                    d, r = settle([(job, pool.submit(_datagen_job, job))])
-                    done, refused = done + d, refused + r
-                if refused:
-                    self.roles.check_alive()
-                    say(f"sending segments {[job['index'] for job, _ in refused]} again")
-                    d, r = settle([(job, pool.submit(_datagen_job, job)) for job, _ in refused])
-                    require(not r, f"{len(r)} segments failed to upload twice, first: {r[0][1] if r else ''}")
-                    done += d
-                routed = self.ideal_state()
-                lost = [job for job in jobs if f"{self.ds.TABLE}_{job['index']}" not in routed]
-                if lost:
-                    say(f"the ideal state lacks segments {[job['index'] for job in lost]} of {len(jobs)}: uploading them again")
-                done += [pool.submit(_datagen_job, job).result() for job in lost]
-            self.timing["segments_uploaded_again"] = len(lost) + len(refused)
+            with ProcessPoolExecutor(workers_for(len(self.sizes)), mp_context=get_context("spawn")) as pool:
+                try:  # every table's schema and table config; a declaration the program cannot read ends the run here
+                    pool.submit(_create_tables, self.config["dataset"], self.controller, self.tables).result()
+                except ValueError as e:
+                    raise RunFailure(f"configuration {self.config['name']}: {e}") from None
+                for table in self.tables:  # dimension tables first: the fact table's queries may look them up
+                    d, n = self._upload(pool, table)
+                    done, again = done + d, again + n
+            self.timing["segments_uploaded_again"] = again
         finally:
             os.environ.clear()
             os.environ.update(env_before)
+        by_table = {t["name"]: [d for d in done if d["table"] == t["name"]] for t in self.tables}
+        fact = by_table[self.tables[-1]["name"]]  # the four older keys stay the fact table's
         for k in ("gen_s", "build_s", "upload_s"):
-            self.timing[f"datagen_{k}_per_segment"] = float(np.mean([d[k] for d in done]))
-        self.timing["segment_file_bytes"] = float(sum(d["fileBytes"] for d in done))
+            self.timing[f"datagen_{k}_per_segment"] = float(np.mean([d[k] for d in fact]))
+        self.timing["segment_file_bytes"] = float(sum(d["fileBytes"] for d in fact))
+        self.timing["tables"] = {}
+        for table in self.tables:
+            mine = by_table[table["name"]]
+            detail = {"rows": table["rows"], "segments": len(mine), "fileBytes": sum(d["fileBytes"] for d in mine),
+                      "index_s_per_segment": float(np.mean([d["index_s"] for d in mine]))}  # fmt: skip
+            if any(d["starRecords"] for d in mine):
+                detail["starRecords_per_segment"] = float(np.mean([sum(d["starRecords"]) for d in mine]))
+            self.timing["tables"][table["name"]] = detail
 
-    def ideal_state(self) -> dict:
-        """The controller's ideal state of the table: what the broker routes by."""
-        return http_json(f"{self.controller}/tables/{self.ds.TABLE}/idealstate")
+    def _upload(self, pool: ProcessPoolExecutor, table: dict) -> tuple[list[dict], int]:
+        """(each segment's job result, how many were sent a second time) of one table."""
+        jobs = [
+            {"dataset": self.config["dataset"], "seed": self.seed, "index": i, "rows": n, "table": table,
+             "config": self.config, "controller_url": self.controller, "out_dir": str(self.dir / "built")}
+            for i, n in enumerate(tables_mod.segment_sizes(table))
+        ]  # fmt: skip
+        # The controller reads, changes and writes a table's ideal state without a lock, so of two uploads
+        # that end in the same moment (a) both can be given the same server and (b) one's entry can be
+        # overwritten: the server holds that segment, the broker routes past it, and every answer comes a
+        # segment short (14 of 15 queried, seed 3260000704; PERF.md, PR 26). Against (a), with several servers
+        # the last round, one a server, goes one upload at a time: the controller gives a segment to the
+        # server that hosts fewest. Against (b), a segment the ideal state lacks is uploaded again, alone.
+        # An upload that the controller refused (its 2 s lease ran out under it and the write was fenced, or
+        # the connection broke) is sent again too, once: the parent's client sent it up to three times.
+        last = len(self.servers) if len(self.servers) > 1 else 0
+        done, refused = settle([(job, pool.submit(_datagen_job, job)) for job in jobs[: len(jobs) - last]])
+        for job in jobs[len(jobs) - last :]:
+            d, r = settle([(job, pool.submit(_datagen_job, job))])
+            done, refused = done + d, refused + r
+        if refused:
+            self.roles.check_alive()
+            say(f"sending segments {[job['index'] for job, _ in refused]} again")
+            d, r = settle([(job, pool.submit(_datagen_job, job)) for job, _ in refused])
+            require(not r, f"{len(r)} segments failed to upload twice, first: {r[0][1] if r else ''}")
+            done += d
+        routed = self.ideal_state(table["name"])
+        lost = [job for job in jobs if f"{table['name']}_{job['index']}" not in routed]
+        if lost:
+            say(f"the ideal state lacks segments {[job['index'] for job in lost]} of {len(jobs)}: uploading them again")
+        done += [pool.submit(_datagen_job, job).result() for job in lost]
+        return done, len(lost) + len(refused)
+
+    def ideal_state(self, table: str | None = None) -> dict:
+        """The controller's ideal state of a table (the fact table's unless named): what the broker routes by."""
+        return http_json(f"{self.controller}/tables/{table or self.ds.TABLE}/idealstate")
+
+    def hosted(self, table: str) -> dict[str, list[str]]:
+        """The segments of a table that each server says it hosts."""
+        return {sid: http_json(f"{url}/segments/{table}") for sid, url in self.servers.items()}
 
     def _wait_hosted(self, timeout: float) -> None:
-        """Until the table is loaded as the configuration says (`hosted_error`)."""
-        want = sorted(f"{self.ds.TABLE}_{i}" for i in range(len(self.sizes)))
+        """Until every table is loaded as the configuration says (`hosted_error`):
+        one at `"everyServer"` is then on every server, whole."""
         deadline = time.monotonic() + timeout
-        while True:
-            hosted = {sid: http_json(f"{url}/segments/{self.ds.TABLE}") for sid, url in self.servers.items()}
-            wrong = hosted_error(hosted, self.ideal_state(), want, int(self.config["replication"]))
-            if wrong is None:
-                return
-            self.roles.check_alive()
-            require(time.monotonic() < deadline, wrong)
-            time.sleep(0.25)
+        for table in self.tables:
+            want = sorted(tables_mod.segment_names(table))
+            while True:
+                wrong = hosted_error(self.hosted(table["name"]), self.ideal_state(table["name"]), want, int(table["replication"]))
+                if wrong is None:
+                    break
+                self.roles.check_alive()
+                require(time.monotonic() < deadline, wrong if table["fact"] else f"table {table['name']}: {wrong}")
+                time.sleep(0.25)
 
     def restart_server(self, sid: str, timeout: float) -> None:
         """A killed server started again as a supervisor would: the same argv,
@@ -297,9 +321,12 @@ class Cluster:
         self.servers[sid] = self.roles.restart(sid, timeout)
         self.controls[sid] = ServerControl(self.control_files[sid], timeout)
 
-    def share_of(self, sid: str) -> set[str]:
-        """The segments the ideal state gives a server."""
-        return {seg for seg, replicas in self.ideal_state().items() if replicas.get(sid) == "ONLINE"}
+    def share_of(self, sid: str) -> dict[str, set[str]]:
+        """The segments the ideal state gives a server, table by table."""
+        return {
+            t["name"]: {seg for seg, replicas in self.ideal_state(t["name"]).items() if replicas.get(sid) == "ONLINE"}
+            for t in self.tables
+        }
 
     def snapshot(self) -> dict[str, dict]:
         """Counters of each server's own endpoints."""
@@ -339,9 +366,14 @@ class Cluster:
 
 
 def warm_up(cluster: Cluster, traffic: dict, seed: int, trace_dir: Path | None = None) -> None:
-    """Every template of the mix, with parameters of its own stream, one
-    after the other (the first query also stages the table onto the chip),
-    then the mix itself for a moment through the load generator. A traced
+    """The mix's `warmup.statements` as they stand, where it lists any (for
+    a mix whose drawn queries each reach a part of the table: the program
+    stages a segment at the first query that reaches it, and statements that
+    between them reach every segment keep those first touches out of the
+    window); then every template of the mix, with parameters of its own
+    stream, one after the other (where every query reaches every segment the
+    first of them stages the table onto the chip), then the mix itself for a
+    moment through the load generator. A traced
     run (`trace_dir`) takes a first, thrown-away trace over that moment: the
     profiler's first start and stop in a new checkout on a new machine froze
     server, broker and launcher for 4 s and 17 s (PERF.md, PR 23), and
@@ -350,6 +382,11 @@ def warm_up(cluster: Cluster, traffic: dict, seed: int, trace_dir: Path | None =
     rng = np.random.default_rng([seed, 777_001])
     client = loadgen.Client(cluster.broker, int(traffic["warmup"].get("timeoutMs", 900_000)))
     try:
+        for i, sql in enumerate(traffic["warmup"].get("statements", [])):
+            t0 = time.perf_counter()
+            doc = client.send(sql)
+            require(not doc.get("exceptions"), f"warm-up statement {i} failed: {doc.get('exceptions')}")
+            say(f"warm-up statement {i}: {time.perf_counter() - t0:.2f} s")
         for name in traffic["templates"]:
             for _ in range(int(traffic["warmup"]["runsPerTemplate"])):
                 t0 = time.perf_counter()
@@ -436,20 +473,21 @@ class Faults(threading.Thread):
         loss.ready_s = self.now()
         say(f"fault: {sid} started again {loss.restart_s:.3f} s in, ready {loss.ready_s:.3f} s in at {url} on {rt['platform']}")
         require(rt["platform"] == cluster.runtime[sid]["platform"], f"{sid} came back on {rt['platform']!r}")
-        share = cluster.share_of(sid)
-        hosts: set[str] = set()
+        share = cluster.share_of(sid)  # table by table: a dimension table it kept whole comes back whole
+        n_share = sum(len(segs) for segs in share.values())
+        hosts: dict[str, set[str]] = {}
 
         def hosts_its_share() -> bool:
             nonlocal hosts
-            hosts = set(http_json(f"{url}/segments/{cluster.ds.TABLE}"))
-            return bool(share) and share <= hosts
+            hosts = {table: set(http_json(f"{url}/segments/{table}")) for table in share}
+            return n_share > 0 and all(share[table] <= hosts[table] for table in share)
 
-        if not self.wait_for(hosts_its_share, lambda: f"{sid} hosts {len(hosts)} of its {len(share)} segments"):
+        if not self.wait_for(hosts_its_share, lambda: f"{sid} hosts {sum(len(h) for h in hosts.values())} of its {n_share} segments"):
             return
         loss.hosted_s = self.now()
         timers = {k: v for k, v in http_json(f"{url}/metrics?format=json").items() if "segmentLoad" in k}
         loss.server_load_timers = timers
-        say(f"fault: {sid} hosts its {len(share)} segments again {loss.hosted_s:.3f} s in; its load timers {json.dumps(timers)}")
+        say(f"fault: {sid} hosts its {n_share} segments again {loss.hosted_s:.3f} s in; its load timers {json.dumps(timers)}")
         # its own count of fused device programs, from what it read when it hosted its share (a server that
         # is still loading runs the segments it has of a leg whose answer the broker then throws away)
         base = kernel_calls(url, "query.fused_packed")
@@ -631,7 +669,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.check_per_template is not None:
         traffic["check"] = {**traffic["check"], "perTemplate": args.check_per_template}
     if args.rehearsal:
-        cell["config"].update(cell["config"]["rehearsal"])
+        tables_mod.rehearse(cell["config"])
     if args.control == "no-replica":  # the cell's fault schedule over a table that keeps one copy of each segment
         require(bool(traffic.get("faults")), "--control no-replica needs a cell whose traffic has a fault schedule")
         cell["config"]["replication"] = 1

@@ -17,6 +17,9 @@ from perfbench.tools import gap_attribution
 ROOT = Path(__file__).resolve().parents[2]
 GATHER_CELLS = ["ssb-groupby-closed", "ssb4-groupby-closed", "ssb4-serverloss-closed", "tsbs-hosthour-closed"]
 STAGING_CELLS = ["tsbs-hosthour-closed", "ssb4-serverloss-closed"]
+# since PR 40's second hand-in `tsbs-hosthour-closed` stages its table in set-up (`warmup.statements`):
+# its windows count 0 first touches and have none to time
+STAGE_TIMED_CELLS = ["ssb4-serverloss-closed"]
 ALL_CELLS = [w["name"] for w in load_manifest(ROOT)["workloads"]]
 
 # name -> (what the three answers below must read, the cells that list it)
@@ -32,7 +35,7 @@ INSIDE = {
     "server_host_cpu_ms": (10.0 + 2.0, [c for c in ALL_CELLS[:7] if c not in ("ssb-q1-rate", "tpch-q1q6-closed")]),
     "server_launch_ms": (7.0, ALL_CELLS[:7]),
     "segments_staged_in_window": (3.0, STAGING_CELLS),  # a sum over the window, not a median: 2 + 0 + 1
-    "segment_stage_ms": ((800.0 + 300.0) / 2, STAGING_CELLS),  # ms a query that staged: the median of the two
+    "segment_stage_ms": ((800.0 + 300.0) / 2, STAGE_TIMED_CELLS),  # ms a query that staged: the median of the two
     "broker_result_ms": (4.0, ALL_CELLS[:7]),
 }
 
@@ -100,9 +103,12 @@ def test_an_entry_of_the_inside_is_what_its_reader_says(name):
     assert m["layer"] in {e["layer"] for e in load_manifest(ROOT)["per_layer"] if e["name"] not in INSIDE}
 
 
-def test_the_entries_of_the_inside_come_last_and_in_order():
+def test_the_entries_of_the_inside_stand_together_and_in_order():
+    """A new entry goes at the end: PR 39's `reduce_row_stages_per_query` follows the eleven."""
     names = [m["name"] for m in load_manifest(ROOT)["per_layer"]]
-    assert names[-len(INSIDE) :] == list(INSIDE)
+    first = names.index(next(iter(INSIDE)))
+    assert names[first : first + len(INSIDE)] == list(INSIDE)
+    assert names[first + len(INSIDE) :] == ["reduce_row_stages_per_query"]
 
 
 def test_a_window_with_no_first_touch_stages_nothing_and_has_no_cost_to_read():

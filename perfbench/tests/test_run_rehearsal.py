@@ -55,7 +55,11 @@ def test_a_rehearsal_ends_in_a_valid_line(workload, trace):
     # each number compared stands beside its limit: the line's last key, and stderr's last lines
     assert list(line)[-1] == "compared" and line["compared"]["failed_queries"] == {"value": 0, "limit": line["attempted"] // 100}
     assert all(c["value"] <= c["limit"] for c in line["compared"].values())
-    assert out.rstrip().splitlines()[-1].startswith("perfbench: compared failed_queries=0 limit=")
+    # ... in the line's order: a cell whose traffic has a fault schedule names `recovered_s` last, every other `failed_queries`
+    lost = bool(cell["traffic"].get("faults"))
+    assert list(line["compared"])[-1] == ("recovered_s" if lost else "failed_queries")
+    assert out.rstrip().splitlines()[-1].startswith(f"perfbench: compared {'recovered_s=' if lost else 'failed_queries=0 limit='}")
+    assert "perfbench: compared failed_queries=0 limit=" in out
     if trace:
         # the CPU has no device plane: the rehearsal reduces the trace recorded on the v5e
         assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
@@ -63,9 +67,11 @@ def test_a_rehearsal_ends_in_a_valid_line(workload, trace):
         assert line["breakdown"]["device_ops"]
         # every server traced into a directory of its own
         assert trace_files(out) == [f"server_{i}" for i in range(cell["config"]["servers"])]
-    # a traffic file without `faults`: no fault thread, no tail, nothing of a loss in the line
-    assert "[perfbench] fault:" not in out and "[perfbench] tail:" not in out
-    assert not {"fault_at_s", "restart_ready_s", "loss", "tail_queries"} & set(line) and "recovered_s" not in line["metrics"]
+    if lost:  # what the loss itself has to show is `test_server_loss_cell.py`'s
+        assert "[perfbench] fault: killed server_" in out and line["compared"]["not_recovered"] == {"value": 0, "limit": 0}
+    else:  # a traffic file without `faults`: no fault thread, no tail, nothing of a loss in the line
+        assert "[perfbench] fault:" not in out and "[perfbench] tail:" not in out
+        assert not {"fault_at_s", "restart_ready_s", "loss", "tail_queries"} & set(line) and "recovered_s" not in line["metrics"]
 
 
 def trace_files(out: str) -> list[str]:
@@ -150,8 +156,8 @@ from perfbench import run
 if __name__ == "__main__":
     ideal_state, asked = run.Cluster.ideal_state, []
 
-    def first_answer_lacks_a_segment(self):
-        ideal = ideal_state(self)
+    def first_answer_lacks_a_segment(self, table=None):
+        ideal = ideal_state(self, table)
         asked.append(1)
         if len(asked) == 1:
             del ideal[f"{self.ds.TABLE}_3"]

@@ -5,6 +5,7 @@ The program's side of the same queries is `tests/test_group_key_expr.py`
 and the rehearsal in `tests/test_served_path.py`."""
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -95,6 +96,22 @@ def test_the_cell_is_what_the_issue_names():
     listed = [m["name"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "tsbs-hosthour-closed" in m.get("workloads", [])]
     assert {"groupkey_plan_ms", "segments_pruned_share", "grouped_double_hbm_share"} <= set(listed) and "queries_per_s" not in listed
     assert json.loads((BENCH / "configs" / "tsbs-cpu-1srv.json").read_text())["name"] == cell["entry"]["config"]
+
+
+def test_the_warm_up_statements_reach_every_segment():
+    """A drawn 12-hour window reaches 4 or 5 of the 16 segments, and the program
+    stages a segment at the first query that reaches it: the mix's
+    `warmup.statements` are its own query over the table's four quarters, so
+    that set-up stages the whole table and no first touch falls into the window."""
+    traffic = load_cell(load_manifest(), "tsbs-hosthour-closed")["traffic"]
+    one = ds.TEMPLATES["double-groupby-1"]
+    spans = []
+    for sql in traffic["warmup"]["statements"]:
+        lo, hi = (int(x) for x in re.findall(r"ts [><]=? (\d+)", sql))
+        assert sql == one.render({"lo": lo, "hi": hi, "avgs": "AVG(usage_user)"})  # the template's own text: no program of another shape
+        spans.append((lo, hi))
+    assert spans == [(ds.START_MS + k * 12 * ds.HOUR_MS, ds.START_MS + (k + 1) * 12 * ds.HOUR_MS) for k in range(ds.TABLE_HOURS // 12)]
+    assert "segment_stage_ms" not in [m["name"] for m in load_manifest()["per_layer"] if "tsbs-hosthour-closed" in m.get("workloads", [])]
 
 
 def test_a_query_draws_its_metrics_as_well_as_its_window():
