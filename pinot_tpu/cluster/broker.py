@@ -11,6 +11,7 @@ the reduce is the shared reduce module.
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import threading
 import time
@@ -51,6 +52,17 @@ def _collect_tables(stmt) -> list[str]:
 
     walk(stmt)
     return out
+
+
+def _calls(node, function: str) -> bool:
+    """Whether a statement (or any node of one) holds a call of `function`."""
+    if isinstance(node, ast.FunctionCall) and node.name == function:
+        return True
+    if dataclasses.is_dataclass(node) and not isinstance(node, type):
+        return any(_calls(getattr(node, f.name), function) for f in dataclasses.fields(node))
+    if isinstance(node, (list, tuple)):
+        return any(_calls(x, function) for x in node)
+    return False
 
 
 _request_seq = itertools.count()
@@ -741,6 +753,10 @@ class Broker:
         `_REALTIME` twin — hybrid queries route through both halves, so a
         mutation on either changes the key."""
         if self.caches is None or normalized is None or not snaps:
+            return None
+        if _calls(stmt, "lookup"):
+            # a lookUp reads dimension tables the snapshots do not name: their reload
+            # moves no token of this key, so such an answer is computed every time
             return None
         from pinot_tpu.cluster.result_cache import options_fingerprint
 

@@ -400,22 +400,17 @@ class Controller:
 
     def delete_table(self, name: str) -> int:
         """Drop a table: every segment (server unload + deep-store cleanup),
-        the dimension-table registration, and then the ENTIRE
+        and then the ENTIRE
         /tables/{name}/ subtree — pauseStatus, watermarks, and any other
         table-scoped key would otherwise poison a recreated table
         (DeleteTableCommand / PinotHelixResourceManager.deleteOfflineTable
         parity). Returns the number of segments removed."""
-        cfg = self.get_table(name)
         segs = [
             p.split("/")[-1]
             for p in self.store.list(f"/tables/{name}/segments/")
         ]
         for s in segs:
             self.delete_segment(name, s)
-        if cfg is not None and (cfg.extra or {}).get("isDimTable"):
-            from pinot_tpu.cluster.dimension import unregister_dim_table
-
-            unregister_dim_table(name)
         counter = routing_version_path(name)
         for p in list(self.store.list(f"/tables/{name}/")):
             if p != counter:  # it outlives the table: a table made again repeats no token
@@ -674,7 +669,7 @@ class Controller:
                 with span("controller.upload.transition", phase=ServerQueryPhase.SEGMENT_UPLOAD_TRANSITION, role="controller", server=sid):
                     if self._transitions is not None:
                         try:
-                            handles[sid].add_segment(table, name, str(seg_dir))
+                            self.add_to_server(handles[sid], table, name, seg_dir, config)
                             self._transitions.record_external_view(table, name, sid, "ONLINE")
                         except Exception as e:  # pinotlint: disable=deadline-swallow — segment-add control plane; failure enqueues a retryable helix transition
                             _LOG.warning("%s of %s not confirmed by %s (%s): queued for redelivery", name, table, sid, e)
@@ -682,7 +677,7 @@ class Controller:
                             if isinstance(e, ServerTimedOut):
                                 at_work.append(sid)
                     else:
-                        handles[sid].add_segment(table, name, str(seg_dir))
+                        self.add_to_server(handles[sid], table, name, seg_dir, config)
             # A server that is down is the queue's to bring back, and the upload is acknowledged without it. One that
             # took the call and was slow is still loading: it would host the segment while the view, which brokers
             # route by, lacked it, and whoever took the acknowledgement for "loaded" was refused its first query
@@ -695,28 +690,30 @@ class Controller:
                     time.sleep(0.05)
         finally:
             self._loading.difference_update(loading)
-        self._refresh_dim_table(table, config)
         return assigned
 
-    def _refresh_dim_table(self, table: str, config: TableConfig | None = None) -> None:
-        """Dimension tables reload their in-memory PK map on any segment
-        change (DimensionTableDataManager refresh semantics)."""
+    def dim_table_spec(self, table: str, config: TableConfig | None = None) -> dict | None:
+        """`{"primaryKeyColumns": [...]}` of a table whose config flags it
+        isDimTable, else None: what a server is told with every segment of
+        such a table, so that it keeps the table's manager itself
+        (DimensionTableDataManager lives on the servers)."""
         config = config or self.get_table(table)
         if config is None or not (config.extra or {}).get("isDimTable"):
-            return
-        from pinot_tpu.cluster.dimension import DimensionTableDataManager, register_dim_table
-        from pinot_tpu.segment.loader import load_segment
-
+            return None
         schema = self.get_schema(table)
-        mgr = DimensionTableDataManager(
-            table, schema.primary_key_columns if schema else [], schema=schema
-        )
-        segs = []
-        for _, meta in sorted(self.all_segment_metadata(table).items()):
-            if meta.get("location"):
-                segs.append(load_segment(meta["location"]))
-        mgr.load_segments(segs)
-        register_dim_table(mgr)
+        keys = list(schema.primary_key_columns) if schema else []
+        if not keys:
+            raise ValueError(f"dimension table {table!r} needs primaryKeyColumns in its schema")
+        return {"primaryKeyColumns": keys}
+
+    def add_to_server(self, handle, table: str, segment_name: str, seg_dir, config: TableConfig | None = None) -> None:
+        """The OFFLINE -> ONLINE transition of one replica, as every path that
+        delivers one makes it (upload, the transition queue, a rebalance)."""
+        spec = self.dim_table_spec(table, config)
+        if spec is None:
+            handle.add_segment(table, segment_name, str(seg_dir))
+        else:
+            handle.add_segment(table, segment_name, str(seg_dir), dim_table=spec)
 
     @staticmethod
     def _compute_partitions(segment: ImmutableSegment, config: TableConfig) -> dict:
@@ -799,7 +796,6 @@ class Controller:
             import shutil
 
             shutil.rmtree(meta["location"], ignore_errors=True)
-        self._refresh_dim_table(table)
 
     def reload_segments(self, table: str, segment_name: str | None = None) -> list[str]:
         """Rebuild segments from deep-store data under the CURRENT table

@@ -324,7 +324,7 @@ class TransitionManager:
             return False
         try:
             if msg["action"] == "add":
-                srv.add_segment(msg["table"], msg["segment"], msg["dir"])
+                self.controller.add_to_server(srv, msg["table"], msg["segment"], msg["dir"])
             else:
                 srv.remove_segment(msg["table"], msg["segment"])
         except Exception:  # pinotlint: disable=deadline-swallow — helix transition apply; False requeues the message

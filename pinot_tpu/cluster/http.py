@@ -717,7 +717,7 @@ class ServerHTTPService:
                     body = json.loads(self.rfile.read(n) or b"{}")
                     try:
                         if self.path == "/segments/add":
-                            svc.server.add_segment(body["table"], body["segment"], body["dir"])
+                            svc.server.add_segment(body["table"], body["segment"], body["dir"], dim_table=body.get("dimTable"))
                         else:
                             svc.server.remove_segment(body["table"], body["segment"])
                         payload = b'{"status": "ok"}'
@@ -951,6 +951,7 @@ class ServerHTTPService:
                     reg.timer(ServerTimer.QUERY_EXECUTION)
                     # the HBM gauges are taken when they are read, not per kernel record
                     KERNELS.publish_hbm_gauges()
+                    svc.server.publish_dim_gauges()  # resident bytes of dimension tables and lookup operands, likewise
                     _serve_metrics(self, reg)
                 elif self.path == "/debug/resources":
                     # leak-tracker + scheduler backlog (NettyLeakListener-
@@ -1186,10 +1187,11 @@ class RemoteServerClient:
     #: raises ServerTimedOut, and the controller waits for the view instead
     LOAD_TIMEOUT_S = 60.0
 
-    def add_segment(self, table: str, segment_name: str, seg_dir) -> None:
+    def add_segment(self, table: str, segment_name: str, seg_dir, dim_table: dict | None = None) -> None:
+        # `dimTable`: the primary-key columns of a table flagged isDimTable; the server rebuilds its manager (Server.add_segment)
         self._post_json(
             "/segments/add",
-            {"table": table, "segment": segment_name, "dir": str(seg_dir)},
+            {"table": table, "segment": segment_name, "dir": str(seg_dir), **({"dimTable": dim_table} if dim_table else {})},
             timeout_s=max(self.timeout, self.LOAD_TIMEOUT_S),
         )
 

@@ -212,7 +212,7 @@ def rebalance_table(
                 meta = controller.segment_metadata(table, seg) or {}
                 loc = meta.get("location")
                 if loc:
-                    handles[sid].add_segment(table, seg, loc)
+                    controller.add_to_server(handles[sid], table, seg, loc)
                 controller.set_segment_state(table, seg, sid, "ONLINE")
             # de-route old replicas first, then physically remove (drain):
             # brokers routing off the updated ideal state stop picking the

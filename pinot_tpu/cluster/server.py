@@ -62,6 +62,13 @@ class Server:
         # in-flight Helix-style segment state transitions; non-zero means a
         # segment is mid-load and /health/ready must answer 503
         self._pending_transitions = 0
+        from pinot_tpu.cluster.dimension import DimensionRegistry
+
+        #: the dimension tables this server hosts (tables flagged isDimTable), rebuilt
+        #: from its own segments at every load and drop; what its queries' lookUp reads
+        self.dim_tables = DimensionRegistry()
+        # table -> primary-key columns, as the state transition that brought a segment of it said
+        self._dim_keys: dict[str, list[str]] = {}
 
         self._fast32 = fast32
         self._scheduler = scheduler
@@ -147,14 +154,58 @@ class Server:
 
     # -- state transitions (Helix OFFLINE->ONLINE analog) --------------------
 
-    def add_segment(self, table: str, segment_name: str, seg_dir: str | Path) -> None:
+    def add_segment(self, table: str, segment_name: str, seg_dir: str | Path, dim_table: dict | None = None) -> None:
+        """`dim_table`: `{"primaryKeyColumns": [...]}` where the table's config
+        flags it isDimTable (the controller says so with every transition of
+        such a table): the table's manager is rebuilt from the segments this
+        server hosts before the transition is confirmed, so a server that
+        reloads is not ready before its dimension tables are whole."""
         with self._lock:
             self._pending_transitions += 1
         try:
             self._add_segment_inner(table, segment_name, seg_dir)
+            if dim_table is not None:
+                with self._lock:
+                    self._dim_keys[table] = list(dim_table.get("primaryKeyColumns") or [])
+            self._rebuild_dim_table(table)
         finally:
             with self._lock:
                 self._pending_transitions -= 1
+
+    def _rebuild_dim_table(self, table: str) -> None:
+        """The table's manager made anew from the segments hosted here, in the
+        order of their names (a later one wins a repeated key); nothing for a
+        table that is no dimension table."""
+        from pinot_tpu.common.metrics import server_metrics
+        from pinot_tpu.common.trace import span
+
+        with self._lock:
+            keys = self._dim_keys.get(table)
+            segs = [seg for _, seg in sorted(self._tables.get(table, {}).items())]
+        if keys is None:
+            return
+        if not segs:  # the last segment went: the table is hosted here no more
+            with self._lock:
+                self._dim_keys.pop(table, None)
+            self.dim_tables.drop(table)
+            self.publish_dim_gauges()
+            return
+        with span("server.dimtable.load", table=table, segments=len(segs)) as sp:
+            mgr = self.dim_tables.rebuild(table, keys, segs, schema=segs[0].schema)
+            sp.set_attr("rows", mgr.size)
+            sp.set_attr("generation", mgr.generation)
+        server_metrics().timer("server.dimTableLoadMs").update_ms(sp.ms)
+        self.publish_dim_gauges()
+
+    def publish_dim_gauges(self) -> None:
+        """`server.dimTableBytes`, `server.lookupOperandBytes`: what the hosted dimension
+        tables' columns and the lookup operands built from them hold (each operand once more on the chip)."""
+        from pinot_tpu.common.metrics import server_metrics
+
+        tables, operands = self.dim_tables.resident_bytes()
+        m = server_metrics()
+        m.gauge("server.dimTableBytes").set(float(tables))
+        m.gauge("server.lookupOperandBytes").set(float(operands))
 
     def _add_segment_inner(self, table: str, segment_name: str, seg_dir: str | Path) -> None:
         from pinot_tpu.common.trace import ServerQueryPhase, span
@@ -317,6 +368,7 @@ class Server:
                 with self._lock:
                     self._tables.setdefault(table, {})[name] = seg
                     self._engines.pop(table, None)
+                self._rebuild_dim_table(table)
                 out["repaired"] += 1
                 m.meter("storage.scrub.repaired").mark()
             except Exception:  # noqa: BLE001  # pinotlint: disable=deadline-swallow — scrub repair is best-effort; the unrepairable meter is the alert signal and queries keep serving the in-memory copy
@@ -377,6 +429,7 @@ class Server:
             self._tables.get(table, {}).pop(segment_name, None)
             self._engines.pop(table, None)
             self._local_segs.pop((table, segment_name), None)
+        self._rebuild_dim_table(table)
 
     def segments_of(self, table: str) -> list[str]:
         with self._lock:
@@ -485,6 +538,7 @@ class Server:
             deadline=deadline,
             on_done=lambda: self._unregister_query(qid),
             trace_ctx=body.get("trace_ctx"),
+            dim_tables=self.dim_tables,
         )
 
     def _engine(self, table: str) -> QueryEngine:
@@ -558,7 +612,7 @@ class Server:
         self._register_query(broker_qid, deadline)
         try:
             emitted = 0
-            for seg, partial, matched, seg_scan in eng.partials_iter(ctx, segs):
+            for seg, partial, matched, seg_scan in self._serving_dim_tables(eng.partials_iter(ctx, segs)):
                 try:
                     FAULTS.maybe_fail("stream.consume")
                 except InjectedFault:
@@ -592,6 +646,18 @@ class Server:
                     return
         finally:
             self._unregister_query(broker_qid)
+
+    def _serving_dim_tables(self, it):
+        """`it`, each step of it taken with this server's dimension tables in scope
+        (a generator's frames run in whatever context its consumer is in)."""
+        it = iter(it)
+        while True:
+            with self.dim_tables.serving():
+                try:
+                    item = next(it)
+                except StopIteration:
+                    return
+            yield item
 
     def _resolve_segments(self, table: str, segment_names: list[str]):
         with self._lock:
@@ -733,7 +799,7 @@ class Server:
         def body():
             with m.timer(ServerTimer.QUERY_EXECUTION).time(), default_accountant.scope(
                 qid, table=table, tenant=tenant
-            ):
+            ), self.dim_tables.serving():
                 eng = self._engine(table)
                 with span("server.plan", phase=ServerQueryPhase.BUILD_QUERY_PLAN, role="server"):
                     ctx = eng.make_context(sql)

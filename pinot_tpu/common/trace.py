@@ -477,6 +477,13 @@ class PhaseLedger:
                 "reduceRowStages": 0,
                 # first stagings host -> HBM inside the query (segment/segment.py `to_device`, span `server.stage`)
                 "segmentsStaged": 0,
+                # lookUp on the device (query/plan.py `lookup_node`, query/kernels.py `_lookup_codes`): fk code ->
+                # destination code operands the query had to build, their bytes (staged to the chip with its
+                # launches) — both 0 once every (segment, foreign key, table generation, destination) it reads
+                # has been read before — and the rows it launched whose foreign key had no dimension row
+                "lookupOperandBuilds": 0,
+                "lookupOperandBytesStaged": 0,
+                "lookupMisses": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),

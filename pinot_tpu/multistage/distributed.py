@@ -17,6 +17,7 @@ assigned replica set (RunCtx.scan_local_all)."""
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import uuid
 
@@ -117,9 +118,13 @@ def run_assigned_stages(
     deadline=None,
     on_done=None,
     trace_ctx: dict | None = None,
+    dim_tables=None,
 ):
     """Server-side half of a distributed query: rebuild the plan, then run
     every (stage, worker) assigned to `my_id` on daemon threads.
+
+    dim_tables: the server's `DimensionRegistry`; each worker thread runs with
+    it in scope, so a leaf's lookUp reads the tables that server hosts.
 
     deadline_ts: absolute wall-clock query deadline shipped by the broker;
     workers check it at operator block boundaries and the mailbox receive
@@ -164,6 +169,10 @@ def run_assigned_stages(
     done = threading.Semaphore(0)
 
     def run(sid: int, w: int):
+        with dim_tables.serving() if dim_tables is not None else contextlib.nullcontext():
+            _run(sid, w)
+
+    def _run(sid: int, w: int):
         try:
             stage = plan.stages[sid]
             has_scan = bool(stage.is_leaf)

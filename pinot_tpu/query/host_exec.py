@@ -24,6 +24,24 @@ from pinot_tpu.query.reduce import parts_of
 from pinot_tpu.segment.segment import ImmutableSegment
 
 
+def lookup_call(expr: "ast.FunctionCall"):
+    """(the serving server's dimension table, destination column, the key
+    expressions) of a lookUp('dimTable','destColumn','pk1',expr1[,'pk2',expr2...])
+    call, checked as the host evaluator and the device lowering both need it."""
+    from pinot_tpu.cluster.dimension import get_dim_table
+
+    if len(expr.args) < 4 or len(expr.args) % 2 != 0:
+        raise PlanError("lookup requires (dimTable, destColumn, pkCol, pkExpr, ...)")
+    lits = expr.args[:2]
+    if not all(isinstance(a, ast.Literal) for a in lits):
+        raise PlanError("lookup dimTable/destColumn must be string literals")
+    dim = get_dim_table(str(lits[0].value))
+    pk_cols = [str(a.value) for a in expr.args[2::2] if isinstance(a, ast.Literal)]
+    if pk_cols != dim.pk_columns:
+        raise PlanError(f"lookup join keys {pk_cols} must match dim table PK {dim.pk_columns}")
+    return dim, str(lits[1].value), list(expr.args[3::2])
+
+
 def eval_value(seg: ImmutableSegment, expr: ast.Expr) -> np.ndarray:
     if isinstance(expr, ast.Identifier):
         if expr.name == "$docId":
@@ -92,24 +110,12 @@ def eval_value(seg: ImmutableSegment, expr: ast.Expr) -> np.ndarray:
             return out
         if name == "lookup":
             # lookUp('dimTable','destColumn','pk1',expr1[,'pk2',expr2...])
-            # (LookupTransformFunction parity; host-side PK-map probes)
-            from pinot_tpu.cluster.dimension import get_dim_table
-
-            if len(expr.args) < 4 or len(expr.args) % 2 != 0:
-                raise PlanError("lookup requires (dimTable, destColumn, pkCol, pkExpr, ...)")
-            lits = expr.args[:2]
-            if not all(isinstance(a, ast.Literal) for a in lits):
-                raise PlanError("lookup dimTable/destColumn must be string literals")
-            dim = get_dim_table(str(lits[0].value))
-            dest = str(lits[1].value)
-            pk_cols = [str(a.value) for a in expr.args[2::2] if isinstance(a, ast.Literal)]
-            key_arrays = [eval_value(seg, a) for a in expr.args[3::2]]
-            if pk_cols != dim.pk_columns:
-                raise PlanError(
-                    f"lookup join keys {pk_cols} must match dim table PK {dim.pk_columns}"
-                )
-            keys = list(zip(*[a.tolist() for a in key_arrays]))
-            return dim.lookup_column(dest, keys)
+            # (LookupTransformFunction parity): the key columns looked up whole,
+            # one sorted probe a column (cluster/dimension.py), no tuple a row
+            dim, dest, key_exprs = lookup_call(expr)
+            if any(_mv_column(seg, a) is not None for a in key_exprs):
+                raise PlanError(f"lookUp by a multi-value column is not supported: {expr}")
+            return dim.lookup_column(dest, [np.asarray(eval_value(seg, a)) for a in key_exprs])
         if name == "coalesce":
             # first non-null argument per row (CoalesceTransformFunction):
             # null = the column null-vector OR a NaN/None cell. Accumulate in
@@ -291,6 +297,42 @@ def _mv_any_match(ci, flat_pred: np.ndarray) -> np.ndarray:
     return m
 
 
+def value_predicate(v: np.ndarray, f) -> np.ndarray:
+    """The mask of one filter leaf over `v`, the single values of its
+    expression: a Compare against a literal on its right, a Between, an In, a
+    Like or a RegexpLike. What `filter_mask` evaluates over a segment's rows,
+    and what the device lowering of a lookUp filter evaluates over the
+    destination's distinct values (plan._Lowering.lookup_filter), so that the
+    two paths hold one meaning."""
+    if isinstance(f, ast.Compare):
+        rv = _coerce_lit(f.right.value)
+        if isinstance(rv, str) and v.dtype == object:
+            v = v.astype(str)
+        with np.errstate(invalid="ignore"):
+            return np.asarray(_CMPS[f.op](v, rv), dtype=bool)
+    if isinstance(f, ast.Between):
+        if v.dtype == object:
+            v = v.astype(str)
+        with np.errstate(invalid="ignore"):
+            m = (v >= f.low.value) & (v <= f.high.value)
+        return ~m if f.negated else m
+    if isinstance(f, ast.In):
+        vals = [x.value for x in f.values if isinstance(x, ast.Literal)]
+        if v.dtype == object:
+            v = v.astype(str)
+            vals = [str(x) for x in vals]
+        m = np.isin(v, np.asarray(vals))
+        return ~m if f.negated else m
+    if isinstance(f, ast.Like):
+        rx = re.compile(_like_to_regex(f.pattern))
+        m = np.asarray([bool(rx.fullmatch(x)) for x in v.astype(str)], dtype=bool)
+        return ~m if f.negated else m
+    if isinstance(f, ast.RegexpLike):
+        rx = re.compile(f.pattern)
+        return np.asarray([bool(rx.search(x)) for x in v.astype(str)], dtype=bool)
+    raise PlanError(f"no predicate over values: {f}")
+
+
 def filter_mask(seg: ImmutableSegment, f: ast.FilterExpr | None) -> np.ndarray:
     n = seg.n_docs
     if f is None:
@@ -326,10 +368,9 @@ def filter_mask(seg: ImmutableSegment, f: ast.FilterExpr | None) -> np.ndarray:
             m = _mv_any_match(mvci, _CMPS[pos_op](flat, rv))
             return ~m if op == ast.CompareOp.NEQ else m
         lv = eval_value(seg, left)
-        rv = eval_value(seg, right) if not isinstance(right, ast.Literal) else _coerce_lit(right.value)
-        if isinstance(rv, str) and lv.dtype == object:
-            lv = lv.astype(str)
-        return np.asarray(_CMPS[op](lv, rv), dtype=bool)
+        if isinstance(right, ast.Literal):
+            return value_predicate(lv, ast.Compare(op, left, right))
+        return np.asarray(_CMPS[op](lv, eval_value(seg, right)), dtype=bool)
     if isinstance(f, ast.Between):
         lo = f.low.value if isinstance(f.low, ast.Literal) else None
         hi = f.high.value if isinstance(f.high, ast.Literal) else None
@@ -342,11 +383,7 @@ def filter_mask(seg: ImmutableSegment, f: ast.FilterExpr | None) -> np.ndarray:
                 v = v.astype(str)
             m = _mv_any_match(mvci, (v >= lo) & (v <= hi))
             return ~m if f.negated else m
-        v = eval_value(seg, f.expr)
-        if v.dtype == object:
-            v = v.astype(str)
-        m = (v >= lo) & (v <= hi)
-        return ~m if f.negated else m
+        return value_predicate(eval_value(seg, f.expr), f)
     if isinstance(f, ast.In):
         vals = [x.value for x in f.values if isinstance(x, ast.Literal)]
         mvci = _mv_column(seg, f.expr)
@@ -357,21 +394,9 @@ def filter_mask(seg: ImmutableSegment, f: ast.FilterExpr | None) -> np.ndarray:
                 vals = [str(x) for x in vals]
             m = _mv_any_match(mvci, np.isin(v, np.asarray(vals)))
             return ~m if f.negated else m
-        v = eval_value(seg, f.expr)
-        if v.dtype == object:
-            v = v.astype(str)
-            vals = [str(x) for x in vals]
-        m = np.isin(v, np.asarray(vals))
-        return ~m if f.negated else m
-    if isinstance(f, ast.Like):
-        rx = re.compile(_like_to_regex(f.pattern))
-        v = eval_value(seg, f.expr).astype(str)
-        m = np.asarray([bool(rx.fullmatch(x)) for x in v])
-        return ~m if f.negated else m
-    if isinstance(f, ast.RegexpLike):
-        rx = re.compile(f.pattern)
-        v = eval_value(seg, f.expr).astype(str)
-        return np.asarray([bool(rx.search(x)) for x in v])
+        return value_predicate(eval_value(seg, f.expr), f)
+    if isinstance(f, (ast.Like, ast.RegexpLike)):
+        return value_predicate(eval_value(seg, f.expr), f)
     if isinstance(f, ast.IsNull):
         if isinstance(f.expr, ast.Identifier):
             nv = seg.extras.get("null", {}).get(f.expr.name)

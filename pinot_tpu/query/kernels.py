@@ -173,6 +173,12 @@ def _filter(fspec, cols, ops, n_padded):
         return (i >= ops[fspec[1]]) & (i < ops[fspec[2]])
     if kind == "in_lut":
         return ops[fspec[2]][cols[fspec[1]]]
+    if kind == "lookup_range":
+        # a lookUp filter whose passing destination codes are one run (plan.lookup_filter)
+        codes = _lookup_codes(fspec[1], cols, ops)
+        return (codes >= ops[fspec[2]]) & (codes <= ops[fspec[3]])
+    if kind == "lookup_lut":
+        return ops[fspec[2]][_lookup_codes(fspec[1], cols, ops)]
     if kind == "cmp_raw":
         v = cols[fspec[2]]
         o = ops[fspec[3]]
@@ -569,18 +575,29 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None, den
     raise AssertionError(aspec)
 
 
-_limb_fallbacks = threading.local()  # .flags: what `_fallbacks_of` collects while a program is traced
+_limb_fallbacks = threading.local()  # .flags: what `_side_counts` collects while a program is traced
+_lookup_trace = threading.local()  # .state: a traced program's lookUp codes by node, its gathered words by operand, the codes whose misses it counts
 
 
-def _fallbacks_of(trace):
-    """`trace()` and the number of its limb reductions that took the scatter,
-    a traced int32 scalar: None where it holds no limb reduction."""
+def _side_counts(trace, n_docs):
+    """`trace()` and what rides back past its leaves, traced int32 scalars:
+    the number of its limb reductions that took the scatter (None where it
+    holds no limb reduction), and the number of its rows — of the `n_docs`
+    real ones — whose foreign key had no dimension row, summed over its
+    (dimension table, foreign key) pairs (None where it holds no lookUp)."""
     _limb_fallbacks.flags = flags = []
+    _lookup_trace.state = state = {"codes": {}, "words": {}, "misses": []}
     try:
         out = trace()
     finally:
-        _limb_fallbacks.flags = None
-    return out, (sum(f.astype(jnp.int32) for f in flags) if flags else None)
+        _limb_fallbacks.flags = _lookup_trace.state = None
+    misses = None
+    if state["codes"]:
+        misses = jnp.int32(0)
+        for codes, miss in state["misses"]:
+            real = jnp.arange(codes.shape[0], dtype=jnp.int32) < n_docs
+            misses = misses + jnp.sum((codes == miss) & real, dtype=jnp.int32)
+    return out, (sum(f.astype(jnp.int32) for f in flags) if flags else None), misses
 
 
 def _grouped_all(aggs, cols, ops, mask, gid, ng, gather=None, doc_pad=None, dense_g=None):
@@ -882,7 +899,9 @@ def get_packed_kernel(spec: tuple):
     split into hi/lo 32-bit halves (two f64 chunks) so values past 2^53 —
     sparse group gids, raw LONG columns — survive exactly; everything else
     casts to f64 losslessly. A program that holds limb reductions
-    (`_grouped_all`) appends one element more: how many of them scattered.
+    (`_grouped_all`) appends one element more: how many of them scattered;
+    one that holds lookUp gathers (`_lookup_codes`) another, last: how many of
+    its rows had a foreign key without a dimension row.
 
     Unpack metadata is NOT captured at trace time: output shapes can vary
     with input shapes under one spec (select_ob's k is clipped to n_padded),
@@ -894,7 +913,7 @@ def get_packed_kernel(spec: tuple):
         # runs when jax traces the program, once per input signature: the
         # registered kernels reached inside leave their static work behind
         with KERNELS.building(name, n_padded):
-            out, fallbacks = _fallbacks_of(lambda: base(cols, ops, n_docs, n_padded))
+            out, fallbacks, misses = _side_counts(lambda: base(cols, ops, n_docs, n_padded), n_docs)
         leaves, _ = jax.tree.flatten(out)
         chunks = []
         for l in leaves:
@@ -904,8 +923,9 @@ def get_packed_kernel(spec: tuple):
                 chunks.append(jnp.remainder(flat, 1 << 32).astype(jnp.float64))
             else:
                 chunks.append(flat.astype(jnp.float64))
-        if fallbacks is not None:  # past the tree's leaves: `wait_packed` counts it
-            chunks.append(fallbacks.astype(jnp.float64)[None])
+        for tail in (fallbacks, misses):  # past the tree's leaves, in this order: `wait_packed` counts them
+            if tail is not None:
+                chunks.append(tail.astype(jnp.float64)[None])
         if not chunks:
             return jnp.zeros((0,), dtype=jnp.float64)
         return jnp.concatenate(chunks)
@@ -950,8 +970,63 @@ KERNELS.register(
 def _key_ids(key, cols, ops):
     """The ids of one GROUP BY key: a dictionary-coded column's own codes, or,
     for an expression key ("remap", column, operand: plan.expr_key), the
-    codes gathered through the plan's code -> bucket operand."""
-    return cols[key] if isinstance(key, str) else ops[key[2]][cols[key[1]]]
+    codes gathered through the plan's code -> bucket operand; a lookUp key
+    ("lookup_key", node: plan.lookup_key) reads the node a lookUp filter of
+    the same call reads."""
+    if isinstance(key, str):
+        return cols[key]
+    return _lookup_codes(key[1], cols, ops) if key[0] == "lookup_key" else ops[key[2]][cols[key[1]]]
+
+
+def _lookup_cost(shape: dict) -> tuple[float, float]:
+    """One lookUp gather, by what it must move at least: every row's code in
+    and word of destination codes out (4 B each), the operand's entries (4 B) once."""
+    return max(float(shape.get("rows", 0)), 0.0) * 8.0 + float(shape.get("entries", 0)) * 4.0, 0.0
+
+
+def _lookup_gather(table, codes):
+    """The rows' words through a resident fk code -> destination codes
+    operand (`table`, a power of two past the dictionary: no code lies
+    outside it), one call a gather traced under the registry name
+    `query.lookup_gather` so that a launch's `deviceWork` says how many
+    gathers it holds, over how many rows and entries."""
+    return KERNELS.timed_sync(
+        "query.lookup_gather",
+        lambda: table.at[codes].get(mode="promise_in_bounds"),
+        rows=codes.shape[0],
+        entries=table.shape[0],
+    )
+
+
+def _lookup_codes(node, cols, ops):
+    """The rows' destination codes of a lookUp node (plan.lookup_node):
+    ("lookup", fk, operand, shift, mask, miss code, counts misses), the field
+    of the word gathered through the operand. Within one traced packed
+    program an operand is gathered once however many destinations, filters
+    and keys read it, and the first node of a (dimension table, fk) leaves
+    its codes for `_side_counts`."""
+    state = getattr(_lookup_trace, "state", None)
+    if state is not None and node in state["codes"]:
+        return state["codes"][node]
+    _, fk, operand, shift, mask, miss, counts = node
+    words = state["words"].get((fk, operand)) if state is not None else None
+    if words is None:
+        words = _lookup_gather(ops[operand], cols[fk])
+    codes = (words >> ops[shift]) & ops[mask]
+    if state is not None:
+        state["words"][(fk, operand)] = words
+        state["codes"][node] = codes
+        if counts:
+            state["misses"].append((codes, ops[miss]))
+    return codes
+
+
+KERNELS.register(
+    "query.lookup_gather",
+    _lookup_gather,
+    cost_model=_lookup_cost,
+    description="a lookUp's rows gathered through the resident foreign-key code -> destination code operand; one call a gather traced",
+)
 
 
 def _scatter_cost(shape: dict) -> tuple[float, float]:
@@ -994,6 +1069,16 @@ KERNELS.register(
     cost_model=_fused_cost,
     description="fused segment program, outputs packed into one f64 vector",
 )
+
+
+@lru_cache(maxsize=4096)
+def _holds_lookup(spec) -> bool:
+    """Whether a plan spec holds a lookUp node, in a filter or a key (plan.lookup_node)."""
+    if not isinstance(spec, tuple) or not spec:
+        return False
+    if spec[0] == "lookup":
+        return True
+    return any(_holds_lookup(x) for x in spec if isinstance(x, tuple))
 
 
 @lru_cache(maxsize=4096)
@@ -1095,14 +1180,15 @@ class PackedResult:
     output tree, after `wait_packed` — the caller's, for all launches of a
     query at once, or its own if none was made."""
 
-    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_n_cols", "_treedef", "_leaf_meta", "_size")
+    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_n_cols", "_treedef", "_leaf_meta", "_size", "_lookups")
 
-    def __init__(self, program: str, rows: int, vec, n_cols: int, treedef, leaf_meta, size: int):
+    def __init__(self, program: str, rows: int, vec, n_cols: int, treedef, leaf_meta, size: int, lookups: bool = False):
         # what was launched, for the caller's `server.dispatch` span
         self.program, self.rows = program, rows
         self.wait_ms = 0.0
         self._vec, self._host = vec, None
         self._n_cols, self._treedef, self._leaf_meta, self._size = n_cols, treedef, leaf_meta, size
+        self._lookups = lookups  # the vector's last element counts lookUp misses (`_holds_lookup`)
 
     def __call__(self):
         if self._host is None:
@@ -1129,9 +1215,10 @@ class PackedResult:
 def wait_packed(results, checkpoint=None) -> None:
     """THE device->host wait of a query: one `server.device_wait` span and
     one `deviceReadbackWaits` around the arrival of every result vector not
-    yet on the host, and `groupedLimbFallbacks`: how many of the launches' limb
-    reductions found a row outside their window and scattered (the element
-    past the tree's leaves, `get_packed_kernel`). The copies were started when the programs were
+    yet on the host, `groupedLimbFallbacks`: how many of the launches' limb
+    reductions found a row outside their window and scattered, and
+    `lookupMisses`: how many of their rows had a foreign key without a dimension
+    row (the elements past the tree's leaves, `get_packed_kernel`). The copies were started when the programs were
     enqueued, so the first wait covers what is queued on the device and the
     others find their vector there or on its way; nothing else runs inside
     the span. `checkpoint(i)`, where given, runs before the i-th vector is
@@ -1155,9 +1242,13 @@ def wait_packed(results, checkpoint=None) -> None:
             now = time.perf_counter()
             r.wait_ms, t = (now - t) * 1e3, now
     count("deviceReadbackWaits")
-    fallbacks = sum(int(r._host[r._size]) for _, r in pending if r._host.shape[0] > r._size)
+    # past the leaves: the limb reductions that scattered, where the program holds any, then the lookUp misses likewise
+    fallbacks = sum(int(r._host[r._size]) for _, r in pending if r._host.shape[0] - r._lookups > r._size)
     if fallbacks:
         count("groupedLimbFallbacks", fallbacks)
+    misses = sum(int(r._host[-1]) for _, r in pending if r._lookups)
+    if misses:
+        count("lookupMisses", misses)
     if KERNELS.enabled:
         for _, r in pending:
             KERNELS.record("query.fused_packed", r.wait_ms, rows=r.rows, cols=r._n_cols)
@@ -1193,7 +1284,7 @@ def dispatch_plan_packed(plan, device_segment) -> PackedResult:
         tuple((o.shape, o.dtype) for o in ops),
         rows,
     )
-    return PackedResult(name, rows, vec, len(cols), treedef, leaf_meta, size)
+    return PackedResult(name, rows, vec, len(cols), treedef, leaf_meta, size, _holds_lookup(plan.spec))
 
 
 def run_plan_packed(plan, device_segment):

@@ -146,6 +146,10 @@ class _Lowering:
         # null docmask operand index per frozenset of columns: one decode +
         # one device transfer however many Kleene leaves reference them
         self._null_mask_ops: dict[frozenset, int] = {}
+        # (dimension table, destination, foreign-key column) -> the spec node of its gather (lookup_node)
+        self._lookups: dict[tuple, tuple] = {}
+        # (dimension table, foreign-key column, operand word) -> the operand's index: one gather serves its destinations
+        self._lookup_words: dict[tuple, int] = {}
 
     # -- operand / column registration --------------------------------------
 
@@ -315,6 +319,10 @@ class _Lowering:
         if name == "map_value":
             # map-index key reads return object values: host-side
             raise DeviceFallback("map_value runs host-side (map index probe)")
+        if name == "lookup":
+            # on the device a lookUp is a GROUP BY key or a filter's left side (lookup_node);
+            # as a value (an aggregate's argument, a selected column) the host evaluates it
+            raise DeviceFallback("lookUp as a value (an aggregate's argument, a selected column) runs host-side", reason="lookup_in_value")
         if name == "cast":
             if len(expr.args) != 2 or not isinstance(expr.args[1], ast.Literal):
                 raise PlanError("CAST requires CAST(expr AS type)")
@@ -386,6 +394,111 @@ class _Lowering:
             return ("const", True)
         return ("in_lut", col, self.op_idx(lut))
 
+    # -- lookUp -----------------------------------------------------------------
+
+    def lookup_node(self, expr: ast.FunctionCall) -> tuple:
+        """lookUp('dim', 'dest', 'pk', fk) over one single-value dictionary-coded
+        column `fk`: (the spec node of the rows' destination codes, the
+        dimension table, dest). The node is ("lookup", fk, operand, shift,
+        mask, miss code, counts misses): the serving server's resident
+        fk code -> codes table (DimensionTableDataManager.operand: built once
+        a segment, foreign key and table generation, marked stable here, so
+        staged once and kept on the chip) holds every destination's code as a
+        bit field, so a launch gathers once a foreign key and operand word
+        however many destinations its query reads, a query in the steady
+        state builds and ships nothing, and every segment shares one program;
+        a key without a dimension row gathers the miss code, one past the
+        destination's last. The first node of each (dimension table, fk) has
+        the launch count its misses (kernels._lookup_codes). Any other form
+        of the call falls back, under a reason of its own."""
+        from pinot_tpu.common.trace import count, span
+        from pinot_tpu.query.host_exec import lookup_call
+        from pinot_tpu.query.kernels import mark_stable_operand
+
+        dim, dest, key_exprs = lookup_call(expr)
+        if len(key_exprs) != 1:
+            raise DeviceFallback(f"lookUp by the composite key {dim.pk_columns} runs host-side", reason="lookup_composite_key")
+        (key,) = key_exprs
+        if not isinstance(key, ast.Identifier) or key.name in VIRTUAL_COLUMNS:
+            raise DeviceFallback(f"lookUp by the expression {key} runs host-side", reason="lookup_key_expression")
+        fk = key.name
+        node = self._lookups.get((dim.table, dest, fk))
+        if node is not None:
+            return node, dim, dest
+        ci = self.seg.columns.get(fk)
+        if ci is None:
+            raise PlanError(f"unknown column {fk!r}")
+        if ci.is_mv:
+            raise DeviceFallback(f"lookUp by the multi-value column {fk} runs host-side", reason="lookup_mv_key")
+        if not ci.is_dict_encoded:
+            raise DeviceFallback(f"lookUp by the raw column {fk} runs host-side", reason="lookup_raw_key")
+        if not dim.has_column(dest):
+            raise DeviceFallback(f"lookUp of {dest!r}, which {dim.table} has not, runs host-side", reason="lookup_unknown_column")
+        self.use_col(fk)
+        word, shift, mask = dim.field(dest)
+        if (dim.table, fk, word) not in self._lookup_words:
+            with span("server.plan.lookup", dim=dim.table, dest=dest) as sp:
+                operand, built = dim.operand(ci.dictionary, word)
+                sp.set_attr("cached", not built)
+            if built:
+                mark_stable_operand(operand)
+                count("lookupOperandBuilds")
+                count("lookupOperandBytesStaged", operand.nbytes)
+            self._lookup_words[(dim.table, fk, word)] = self.op_idx(operand)
+        first = not any(d == dim.table and f == fk for d, _, f in self._lookups)
+        miss = len(dim.dest_values(dest))
+        node = ("lookup", fk, self._lookup_words[(dim.table, fk, word)], self.op_idx(np.int32(shift)),
+                self.op_idx(np.int32(mask)), self.op_idx(np.int32(miss)), first)  # fmt: skip
+        self._lookups[(dim.table, dest, fk)] = node
+        return node, dim, dest
+
+    @staticmethod
+    def _is_lookup(expr) -> bool:
+        return isinstance(expr, ast.FunctionCall) and expr.name == "lookup"
+
+    def lookup_filter(self, f) -> tuple:
+        """A Compare against a literal, Between, In, Like or RegexpLike whose
+        left side is a lookUp. The predicate is evaluated host-side over the
+        destination's distinct values and its null substitute (the host
+        executor's own `value_predicate`: a few hundred values, whatever the
+        foreign key's dictionary holds), and the rows' gathered destination
+        codes are tested against the result: two integer compares where the
+        codes that pass (or those that fail) are one run — every =, <>, <, >
+        and BETWEEN over a sorted dictionary — else a gather through a small
+        boolean table. Composing the predicate onto the foreign key's codes
+        would save nothing on the device (it is the same gather through the
+        same million entries) and would build and ship an operand of the
+        foreign key's size with every query and segment, which the resident
+        operand exists to avoid."""
+        from pinot_tpu.query.host_exec import value_predicate
+
+        node, dim, dest = self.lookup_node(f.left if isinstance(f, ast.Compare) else f.expr)
+        hits = value_predicate(dim.decode_table(dest), f)
+        if not hits.any():
+            return ("const", False)
+        if hits.all():
+            return ("const", True)
+        for want, wrap in ((True, lambda spec: spec), (False, lambda spec: ("not", spec))):
+            at = np.flatnonzero(hits == want)
+            if at[-1] - at[0] + 1 == len(at):
+                return wrap(("lookup_range", node, self.op_idx(np.int32(at[0])), self.op_idx(np.int32(at[-1]))))
+        lut = np.zeros(_pow2(len(hits)), dtype=bool)
+        lut[: len(hits)] = hits
+        return ("lookup_lut", node, self.op_idx(lut))
+
+    def lookup_key(self, g: ast.FunctionCall) -> tuple[tuple, "KeyBuckets"]:
+        """A lookUp as a GROUP BY key: a gathered key as `expr_key`'s, whose
+        buckets are the destination's distinct values and one bucket more for
+        the rows whose key has no dimension row ('null', or NaN for a numeric
+        destination, as the host evaluator answers) — the same for every
+        segment, so all of them share one program and one dense group space."""
+        from pinot_tpu.segment.dictionary import Dictionary
+
+        node, dim, dest = self.lookup_node(g)
+        table = dim.decode_table(dest)
+        dt = DataType.STRING if table.dtype == object else DataType.DOUBLE
+        return ("lookup_key", node), KeyBuckets(Dictionary(dt, table.astype(str) if table.dtype == object else table))
+
     # -- filters -------------------------------------------------------------
 
     def filter_spec(self, f: FilterExpr | None) -> tuple:
@@ -414,6 +527,12 @@ class _Lowering:
             return ("not", k)
         if isinstance(f, ast.Compare):
             return self._compare(f)
+        if isinstance(f, (ast.Between, ast.In, ast.Like, ast.RegexpLike)) and self._is_lookup(f.expr):
+            if isinstance(f, ast.Between) and not (isinstance(f.low, ast.Literal) and isinstance(f.high, ast.Literal)):
+                raise PlanError("BETWEEN bounds must be literals")
+            if isinstance(f, ast.In) and not all(isinstance(v, ast.Literal) for v in f.values):
+                raise PlanError("IN values must be literals")
+            return self.lookup_filter(f)
         if isinstance(f, ast.Between):
             spec = self._range(f.expr, f.low, f.high, True, True)
             return ("not", spec) if f.negated else spec
@@ -544,6 +663,8 @@ class _Lowering:
             lv, rv = self.value_spec(left), self.value_spec(right)
             return ("cmp2", op.name, lv, rv)
         value = right.value
+        if self._is_lookup(left):
+            return self.lookup_filter(ast.Compare(op, left, right))
         if isinstance(left, ast.Identifier) and left.name not in VIRTUAL_COLUMNS:
             ci = self.seg.columns.get(left.name)
             if ci is None:
@@ -1009,6 +1130,8 @@ class _Lowering:
         from pinot_tpu.query.transforms import apply_scalar
         from pinot_tpu.segment.dictionary import Dictionary
 
+        if self._is_lookup(g):
+            return self.lookup_key(g)
         names: set[str] = set()
         _collect_identifiers(g, names)
         if len(names) != 1:

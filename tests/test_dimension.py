@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from pinot_tpu.cluster import Broker, Controller, PropertyStore, Server
-from pinot_tpu.cluster.dimension import DimensionTableDataManager, get_dim_table, unregister_dim_table
+from pinot_tpu.cluster.dimension import DimensionTableDataManager
 from pinot_tpu.common import DataType, Schema, TableConfig
 from pinot_tpu.segment import SegmentBuilder
 
@@ -52,12 +52,13 @@ def cluster(tmp_path):
             "customers_0",
         ),
     )
-    yield controller
-    unregister_dim_table("customers")
+    return controller
 
 
 def test_dim_table_registered_and_refreshed(cluster):
-    dim = get_dim_table("customers")
+    # the table's manager is the hosting server's own, rebuilt from the segments it hosts
+    server = cluster.servers()["s0"]
+    dim = server.dim_tables.get("customers")
     assert dim.size == 3
     assert dim.lookup((2,))["nation"] == "FR"
     # refresh on new upload: later rows win per PK
@@ -73,7 +74,8 @@ def test_dim_table_registered_and_refreshed(cluster):
             "customers_1",
         ),
     )
-    dim = get_dim_table("customers")
+    assert server.dim_tables.get("customers").generation > dim.generation
+    dim = server.dim_tables.get("customers")
     assert dim.size == 4
     assert dim.lookup((2,))["nation"] == "DE"
 

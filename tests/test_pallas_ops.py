@@ -343,3 +343,45 @@ def test_an_expression_key_and_the_double_scatter_compile_at_the_tsbs_segment_on
     text = compiled.as_text()
     assert text.count("scatter-add") >= 2 and "gather" in text
     assert compiled.memory_analysis().temp_size_in_bytes < n
+
+
+def test_a_star_query_compiles_at_the_ssb_segment_on_the_v5e(one_v5e_chip, monkeypatch):
+    """`ssbstar-groupby-closed`'s Q2.1 launch at its real shape: 4M rows, four
+    lookUps — the part's category and brand out of one gather through the
+    resident fk code -> codes operand of a million entries, the supplier's
+    region and the year out of one each (`kernels._lookup_codes`) — two of
+    them filters by an id range, two the
+    keys of 8 years x 1001 brands (a miss bucket each) in 8192 slots on the
+    byte-plane kernel, and the launch's count of rows whose key had no
+    dimension row. The v5e's compiler takes it with the gathers and
+    the kernel in it, and with little memory beside the arguments."""
+    import jax
+
+    from pinot_tpu.query import kernels
+
+    n = 4096 * 1024
+    ng = -(-8 * 1001 // 256) * 256
+    # operands: 0 part's word, 1 supplier's, 2 the dates', then shifts, masks, miss codes, bounds, strides
+    part_category, part_brand = ("lookup", "lo_partkey", 0, 3, 4, 5, True), ("lookup", "lo_partkey", 0, 6, 7, 8, False)
+    spec = (
+        "agg",
+        ("and", (("lookup_range", part_category, 9, 10), ("lookup_range", ("lookup", "lo_suppkey", 1, 11, 12, 13, True), 14, 15))),
+        ("groups", (("lookup_key", ("lookup", "lo_orderdate", 2, 17, 18, 19, True)), ("lookup_key", part_brand)), ng, 16),
+        (("sum", ("raw", "@0")),),
+    )  # fmt: skip
+    assert kernels._holds_lookup(spec) and not kernels._holds_lookup(("agg", ("const", True), None, (("count",),)))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    cols = {c: arg((n,), jnp.int32) for c in ("lo_partkey", "lo_suppkey", "lo_orderdate", "@0")}
+    ops = (arg((1 << 20,), jnp.int32), arg((1 << 15,), jnp.int32), arg((4096,), jnp.int32)) + tuple(
+        arg((), jnp.int32) for _ in range(13)
+    ) + (arg((2,), jnp.int32),) + tuple(arg((), jnp.int32) for _ in range(3))  # fmt: skip
+    monkeypatch.setattr(gp, "interpret_mode", lambda: False)
+    monkeypatch.setenv("PINOT_TPU_PALLAS", "1")
+    kernel = kernels.get_packed_kernel.__wrapped__(spec)
+    compiled = kernel.lower(cols, ops, arg((), jnp.int32), n).compile()
+    text = compiled.as_text()
+    assert text.count(" gather(") == 3 and "tpu_custom_call" in text  # one gather a foreign key
+    assert compiled.memory_analysis().temp_size_in_bytes < 40 * n  # a few row-sized temporaries, no (rows, groups) one-hot

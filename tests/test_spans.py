@@ -97,6 +97,7 @@ def test_child_time_is_not_counted_twice():
         "wireRequestBytes": 0, "wireResponseBytes": 0, "serversMerged": 0, "scatterSkewMs": 0,
         "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "groupedLimbFallbacks": 0,
         "reduceRowStages": 0, "segmentsStaged": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
+        "lookupOperandBuilds": 0, "lookupOperandBytesStaged": 0, "lookupMisses": 0,
     }  # fmt: skip
 
 
