@@ -262,13 +262,13 @@ def _filter(fspec, cols, ops, n_padded):
 _BLOCK = 8192
 
 
-def _blocked(v):
+def _blocked(v, block=_BLOCK):
     n = v.shape[0]
-    nb = -(-n // _BLOCK)
-    pad = nb * _BLOCK - n
+    nb = -(-n // block)
+    pad = nb * block - n
     if pad:
         v = jnp.pad(v, (0, pad))
-    return v.reshape(nb, _BLOCK)
+    return v.reshape(nb, block)
 
 
 def _exact_int_grouped_sum(v, gid, mask, ng):  # pinotlint: disable=kernel-registry — vmap here is traced inline inside the fused kernel; device time lands under query.fused, not a separate root
@@ -975,12 +975,49 @@ def _key_ids(key, cols, ops):
     the same call reads."""
     if isinstance(key, str):
         return cols[key]
-    return _lookup_codes(key[1], cols, ops) if key[0] == "lookup_key" else ops[key[2]][cols[key[1]]]
+    return _lookup_codes(key[1], cols, ops) if key[0] == "lookup_key" else _key_gather(ops[key[2]], cols[key[1]])
 
 
-def _lookup_cost(shape: dict) -> tuple[float, float]:
-    """One lookUp gather, by what it must move at least: every row's code in
-    and word of destination codes out (4 B each), the operand's entries (4 B) once."""
+_GATHER_LANES = 128  # a row of the table as the program views it: one vector register's lanes
+_GATHER_BLOCK = 1 << 16  # codes a block: its gathered (block, lanes) int32 rows are 32 MiB
+
+
+def _gather_rows(table, codes, in_bounds=False):
+    """`table[codes]`, bit for bit, for a row-length vector of dictionary
+    codes through a resident integer operand. XLA's gather of one element a
+    code is a loop of 8.6 ns a row on a v5e whatever the table's size, so the
+    table is viewed in the program as (entries / 128, 128) — a reshape of the
+    operand, which stays one-dimensional in HBM — the row `code >> 7` is
+    gathered and the lane `code & 127` picked by a compare against an iota
+    and a sum, in which every lane but one adds an integer 0. The codes are
+    walked in blocks of `_GATHER_BLOCK`, so that the gathered rows are a
+    block's and never (rows, 128): 2 GiB for a segment of 4M rows. A table
+    that is no whole number of rows (an expression key over a few values) is
+    padded. `in_bounds` promises that no code lies outside the table; without
+    it one that does reads what jnp's indexing reads (a negative code counts
+    from the end, what is still outside is clamped)."""
+    entries = table.shape[0]
+    shift = _GATHER_LANES.bit_length() - 1
+    rows = jnp.pad(table, (0, -entries % _GATHER_LANES)).reshape(-1, _GATHER_LANES)
+    lanes = jnp.arange(_GATHER_LANES, dtype=jnp.int32)
+
+    def block(c):
+        c = c.astype(jnp.int32)  # a column's codes are staged as narrow as its dictionary allows
+        if not in_bounds:
+            c = jnp.clip(jnp.where(c < 0, c + entries, c), 0, entries - 1)
+        hit = (c & (_GATHER_LANES - 1))[:, None] == lanes
+        return jnp.sum(jnp.where(hit, rows.at[c >> shift].get(mode="promise_in_bounds"), 0), axis=1, dtype=table.dtype)
+
+    n = codes.shape[0]
+    if n <= _GATHER_BLOCK:
+        return block(codes)
+    return jax.lax.map(block, _blocked(codes, _GATHER_BLOCK)).reshape(-1)[:n]
+
+
+def _gather_cost(shape: dict) -> tuple[float, float]:
+    """One gather of the rows through a resident operand, by what it must
+    move at least: every row's code in and word out (4 B each), the operand's
+    entries (4 B) once."""
     return max(float(shape.get("rows", 0)), 0.0) * 8.0 + float(shape.get("entries", 0)) * 4.0, 0.0
 
 
@@ -992,7 +1029,19 @@ def _lookup_gather(table, codes):
     gathers it holds, over how many rows and entries."""
     return KERNELS.timed_sync(
         "query.lookup_gather",
-        lambda: table.at[codes].get(mode="promise_in_bounds"),
+        lambda: _gather_rows(table, codes, in_bounds=True),
+        rows=codes.shape[0],
+        entries=table.shape[0],
+    )
+
+
+def _key_gather(table, codes):
+    """The rows' buckets of an expression key through the plan's code ->
+    bucket operand (plan.expr_key), one call a gather traced under the
+    registry name `query.key_gather`, as `_lookup_gather` is under its own."""
+    return KERNELS.timed_sync(
+        "query.key_gather",
+        lambda: _gather_rows(table, codes),
         rows=codes.shape[0],
         entries=table.shape[0],
     )
@@ -1024,8 +1073,14 @@ def _lookup_codes(node, cols, ops):
 KERNELS.register(
     "query.lookup_gather",
     _lookup_gather,
-    cost_model=_lookup_cost,
+    cost_model=_gather_cost,
     description="a lookUp's rows gathered through the resident foreign-key code -> destination code operand; one call a gather traced",
+)
+KERNELS.register(
+    "query.key_gather",
+    _key_gather,
+    cost_model=_gather_cost,
+    description="an expression GROUP BY key's rows gathered through the plan's code -> bucket operand; one call a gather traced",
 )
 
 

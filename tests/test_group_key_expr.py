@@ -153,6 +153,31 @@ def test_the_key_is_planned_over_the_dictionary(table):
     assert bare.spec[2][1] == ("hostname",)
 
 
+def test_device_work_names_the_keys_gather_once_a_launch(table, monkeypatch):
+    """The rows' buckets are gathered through the operand in blocks (four to
+    eight a segment here); a launch's `deviceWork` names `query.key_gather`
+    once, over the segment's padded rows and not a block's."""
+    from pinot_tpu.common.trace import request_ledger
+    from pinot_tpu.query import kernels
+    from pinot_tpu.segment.segment import padded_len
+
+    _, _, segs = table
+    monkeypatch.setattr(kernels, "_GATHER_BLOCK", 512)
+    kernels.get_packed_kernel.cache_clear()  # the programs are traced afresh, under the small block
+    try:
+        with request_ledger("key-gather") as led:
+            res = QueryEngine(segs).execute("SELECT DATETRUNC('hour', ts), COUNT(*) FROM cpu GROUP BY DATETRUNC('hour', ts) LIMIT 1000")
+    finally:
+        kernels.get_packed_kernel.cache_clear()
+    assert sum(r[1] for r in res.rows) == sum(seg.n_docs for seg in segs)
+    padded = [padded_len(seg.n_docs) for seg in segs]
+    assert all(p >= 4 * kernels._GATHER_BLOCK for p in padded)
+    entries = [1024, 512, 1024]  # 700, 400 and 600 timestamps, each padded to a power of two
+    (work,) = led.to_wire()["deviceWork"].values()
+    assert (work["launches"], work["rows"]) == (3, sum(padded))
+    assert work["kernels"]["query.key_gather"] == {"calls": 3, "bytes": float(sum(p * 8 + e * 4 for p, e in zip(padded, entries))), "flops": 0.0}
+
+
 #: queries that differ only in which raw column they read as a value: `{m}` is the column
 ONE_SHAPE = {
     "host-and-hour": "SELECT hostname, DATETRUNC('hour', ts), AVG({m}) FROM cpu WHERE ts >= %d GROUP BY hostname, DATETRUNC('hour', ts) LIMIT 100000" % WINDOW[0],

@@ -465,6 +465,33 @@ def test_a_wide_dimension_table_is_gathered_a_word_at_a_time(tmp_path, dests, ga
     assert doc["counters"]["lookupOperandBuilds"] == gathers and doc["counters"]["lookupMisses"] == int((fact["wide_id"] > 3000).sum())
 
 
+def test_a_blocked_gather_is_counted_once_over_the_segments_rows(star, monkeypatch):
+    """The gather walks a segment's codes in blocks (four a segment here); a
+    launch's `deviceWork` still names `query.lookup_gather` once a (foreign
+    key, word), over the segment's padded rows and not a block's."""
+    from pinot_tpu.query import kernels
+    from pinot_tpu.segment.segment import padded_len
+
+    monkeypatch.setattr(kernels, "_GATHER_BLOCK", 256)
+    kernels.get_packed_kernel.cache_clear()  # the programs are traced afresh, under the small block
+    try:
+        doc = star["broker"].execute(sql_of(CASES["group-two-tables"])).to_dict()
+    finally:
+        kernels.get_packed_kernel.cache_clear()
+    assert rows_of(doc["resultTable"]["rows"]) == want_of(star, CASES["group-two-tables"])
+    server, rows, moved = star["server"], 0, 0.0
+    for s in range(3):
+        seg = server.get_segment_object("orders", f"orders_{s}")
+        assert padded_len(seg.n_docs) == 4 * kernels._GATHER_BLOCK
+        rows += padded_len(seg.n_docs)
+        for dim, fk in (("customers", "cust_id"), ("products", "prod_id")):
+            operand, _ = server.dim_tables.get(dim).operand(seg.columns[fk].dictionary, 0)
+            moved += padded_len(seg.n_docs) * 8 + len(operand) * 4
+    (work,) = doc["deviceWork"].values()
+    assert (work["launches"], work["rows"]) == (3, rows)
+    assert work["kernels"]["query.lookup_gather"] == {"calls": 3 * 2, "bytes": moved, "flops": 0.0}
+
+
 # ---------------------------------------------------------------------------
 # the table as columns
 # ---------------------------------------------------------------------------

@@ -342,6 +342,8 @@ def test_an_expression_key_and_the_double_scatter_compile_at_the_tsbs_segment_on
     )
     text = compiled.as_text()
     assert text.count("scatter-add") >= 2 and "gather" in text
+    # the gather (`kernels._gather_rows`) walks the rows in blocks: no (rows, 128) array, and a block's rows need no HBM
+    assert f"[{kernels._GATHER_BLOCK},{kernels._GATHER_LANES}]" in text and f"[{n},{kernels._GATHER_LANES}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < n
 
 
@@ -384,4 +386,5 @@ def test_a_star_query_compiles_at_the_ssb_segment_on_the_v5e(one_v5e_chip, monke
     compiled = kernel.lower(cols, ops, arg((), jnp.int32), n).compile()
     text = compiled.as_text()
     assert text.count(" gather(") == 3 and "tpu_custom_call" in text  # one gather a foreign key
+    assert f"[{kernels._GATHER_BLOCK},{kernels._GATHER_LANES}]" in text and f"[{n},{kernels._GATHER_LANES}]" not in text  # a block of rows at a time
     assert compiled.memory_analysis().temp_size_in_bytes < 40 * n  # a few row-sized temporaries, no (rows, groups) one-hot
