@@ -484,6 +484,12 @@ class PhaseLedger:
                 "lookupOperandBuilds": 0,
                 "lookupOperandBytesStaged": 0,
                 "lookupMisses": 0,
+                # the star-tree swap (query/startree_exec.py, engine._dispatch_segment): segments of the query whose
+                # program was launched over a star table in their place, the records those tables hold, and the star
+                # tables wrapped as segments inside the query (a table's first use, which the dispatch behind it stages)
+                "starTreeSegments": 0,
+                "starTreeRecords": 0,
+                "starTreeBuilds": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),

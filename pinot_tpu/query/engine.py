@@ -249,8 +249,7 @@ class QueryEngine:
                 cpu_ns=int(unpack.cpu_ms * 1e6),
             )
             if obs:
-                mode = "device" if disp[0] == "dev" else disp[3]
-                seg_stats = scan_stats.segment_scan_stats(ctx, seg, mode, int(matched), n_post)
+                seg_stats = scan_stats.segment_scan_stats(ctx, seg, self._scan_mode(disp), int(matched), n_post)
                 scan_stats.fold_segment_stats(summary, seg_stats)
                 HEAT.record(
                     ctx.table,
@@ -322,8 +321,7 @@ class QueryEngine:
             partial, matched = self._finish_segment(seg, ctx, disp)
             seg_stats = None
             if obs:
-                mode = "device" if disp[0] == "dev" else disp[3]
-                seg_stats = scan_stats.segment_scan_stats(ctx, seg, mode, int(matched), n_post)
+                seg_stats = scan_stats.segment_scan_stats(ctx, seg, self._scan_mode(disp), int(matched), n_post)
                 HEAT.record(
                     ctx.table,
                     seg.name,
@@ -531,12 +529,15 @@ class QueryEngine:
     def _dispatch_segment(self, seg: ImmutableSegment, ctx: QueryContext):
         """Async half of segment execution: plan + ENQUEUE the fused device
         program without any device->host sync. Returns ("ready", partial,
-        matched) when the segment resolved host-side (star-tree swap, host
-        fallback), else ("dev", plan, result, vmask) with `result` (a
+        matched, mode) when the segment resolved host-side (a host fallback),
+        else ("dev", plan, result, vmask, swap) with `result` (a
         kernels.PackedResult) still in flight and on its way to the host —
         _resolve_partials waits for a query's results together,
-        _finish_segment for its own where none did. Splitting here is what
-        lets a query enqueue every segment before it waits for any."""
+        _finish_segment for its own where none did. `swap` is None, or the
+        star-tree swap (startree_exec.StarSwap) whose program was launched in
+        the segment's place: a star-answered segment is enqueued like any
+        other. Splitting here is what lets a query enqueue every segment
+        before it waits for any."""
         valid = seg.extras.get("valid_docs")
         from pinot_tpu.query.context import null_handling_enabled
 
@@ -549,12 +550,22 @@ class QueryEngine:
         ):
             # star-tree pre-aggregates over ALL docs; unusable under upsert
             # visibility (invalidated docs are baked into the agg table)
+            from pinot_tpu.common.trace import count
             from pinot_tpu.query import startree_exec
 
-            res = startree_exec.try_execute(self, seg, ctx)
-            if res is not None:
-                # trailing element = execution mode, for scan-path attribution
-                return ("ready",) + res + ("startree",)
+            swap = startree_exec.swap(seg, ctx)
+            if swap is not None:
+                count("starTreeSegments")
+                count("starTreeRecords", swap.seg.n_docs)
+                try:
+                    plan = plan_segment(swap.seg, swap.ctx)
+                except DeviceFallback as e:
+                    # the star table on the host: exact, and still the few records in the raw rows' place
+                    mark_device_fallback(e, f"segment {swap.seg.name}")
+                    partial, matched = self._host_segment(swap.seg, swap.ctx)
+                    # trailing element = execution mode, for scan-path attribution
+                    return ("ready", swap.convert(ctx, partial), matched, "startree")
+                return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(swap.seg)), None, swap)
         vmask = valid(seg.n_docs) if valid is not None else None
         try:
             # plan_segment threads valid_docs into the kernel as a docmask
@@ -565,13 +576,24 @@ class QueryEngine:
             # the device path is counted, whichever engine it ran under
             mark_device_fallback(e, f"segment {seg.name}")
             return ("ready",) + self._host_segment(seg, ctx, extra_mask=vmask) + ("host",)
-        return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(seg)), vmask)
+        return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(seg)), vmask, None)
+
+    @staticmethod
+    def _scan_mode(disp) -> str:
+        """How a dispatch executed, for scan-path attribution: "device", "host" or "startree"."""
+        if disp[0] == "dev":
+            return "startree" if disp[4] is not None else "device"
+        return disp[3]
 
     def _finish_segment(self, seg: ImmutableSegment, ctx: QueryContext, disp):
         """Sync half: convert an in-flight dispatch to (partial, matched)."""
         if disp[0] == "ready":
             return disp[1], disp[2]
-        _, plan, unpack, vmask = disp
+        _, plan, unpack, vmask, swap = disp
+        if swap is not None:
+            # the star program's result in the star context, then mapped back to the layout the query asked for
+            partial, matched = self._finish_segment(swap.seg, swap.ctx, ("dev", plan, unpack, vmask, None))
+            return swap.convert(ctx, partial), matched
         out = unpack()  # waits only where the caller has not waited for the query's vectors already
         qt = ctx.query_type
         if qt == QueryType.AGGREGATION:

@@ -90,27 +90,41 @@ def build_star_table(seg: ImmutableSegment, config: StarTreeIndexConfig) -> Star
         if col not in seg.columns:
             raise ValueError(f"star-tree pair {p}: unknown column {col!r}")
         if col not in needed_cols:
-            needed_cols[col] = seg.columns[col].materialize().astype(np.float64)
+            # an INT / LONG source accumulates and is stored as int64: exact by construction (a float64 pair
+            # holds a sum only up to 2^53); FLOAT and DOUBLE sources stay float64
+            ci = seg.columns[col]
+            acc = np.int64 if ci.data_type in (DataType.INT, DataType.LONG) else np.float64
+            needed_cols[col] = ci.materialize().astype(acc)
     for col, vals in needed_cols.items():
         df[f"v::{col}"] = vals
 
     g = df.groupby(dims, sort=True)
     out = g.size().rename("__count").reset_index()
+    # ... unless a record's sum could leave int64 (a LONG null's placeholder is its minimum): numpy would wrap
+    # where the scan, which adds such a column as float64, only rounds. Those sums are accumulated as float64
+    most = int(out["__count"].max()) if len(out) else 0
+
+    def may_wrap(col: str) -> bool:
+        stats = seg.columns[col].stats
+        return max(abs(int(stats.min_value)), abs(int(stats.max_value))) * most >= 2**63
+
+    wraps = {col for col, vals in needed_cols.items() if vals.dtype == np.int64 and may_wrap(col)}
     arrays: dict[str, np.ndarray] = {"__count": out["__count"].to_numpy(np.int64)}
     for d in dims:
         arrays[d] = out[d].to_numpy(np.int32)
     for p in pairs:
         func, col = p.split("__", 1)
-        if func == "SUM":
-            arrays[p] = g[f"v::{col}"].sum().to_numpy(np.float64)
-        elif func == "MIN":
-            arrays[p] = g[f"v::{col}"].min().to_numpy(np.float64)
-        elif func == "MAX":
-            arrays[p] = g[f"v::{col}"].max().to_numpy(np.float64)
-        elif func == "AVG":
+        vals = g[f"v::{col}"]
+        if func in ("SUM", "AVG"):
             # AVG pair stores SUM (count comes from __count), like Pinot's
             # AvgPair value aggregator
-            arrays[f"SUM__{col}"] = g[f"v::{col}"].sum().to_numpy(np.float64)
+            if col in wraps:  # the same records in the same order: the keys sorted
+                vals = pd.Series(needed_cols[col].astype(np.float64)).groupby([df[d] for d in dims], sort=True)
+            arrays[f"SUM__{col}"] = vals.sum().to_numpy()
+        elif func == "MIN":
+            arrays[p] = vals.min().to_numpy()
+        elif func == "MAX":
+            arrays[p] = vals.max().to_numpy()
         else:
             raise ValueError(f"unsupported star-tree aggregation {func}")
     pairs = [p for p in arrays if "__" in p and not p.startswith("__")]
@@ -119,7 +133,9 @@ def build_star_table(seg: ImmutableSegment, config: StarTreeIndexConfig) -> Star
 
 def star_table_as_segment(seg: ImmutableSegment, st: StarTable) -> ImmutableSegment:
     """Wrap a StarTable as an engine-queryable segment: dimension columns
-    share the parent's dictionaries; pre-agg columns are raw metrics."""
+    share the parent's dictionaries; pre-agg columns are raw metrics, LONG
+    where the pair is stored as integers and DOUBLE otherwise (every pair of
+    a table persisted before integer pairs were kept)."""
     schema = Schema(seg.schema.name + "__star")
     star = ImmutableSegment(name=seg.name + "__star", schema=schema, n_docs=st.n_rows)
     for d in st.dimensions:
@@ -130,7 +146,7 @@ def star_table_as_segment(seg: ImmutableSegment, st: StarTable) -> ImmutableSegm
         star.columns[d] = ColumnIndex(d, parent.data_type, parent.dictionary, ids, stats)
     for name in ["__count", *st.function_column_pairs]:
         vals = st.arrays[name]
-        dt = DataType.LONG if name == "__count" else DataType.DOUBLE
+        dt = DataType.LONG if vals.dtype.kind == "i" else DataType.DOUBLE
         schema.add(FieldSpec(name, dt, FieldType.METRIC))
         stats = ColumnStats.collect(name, dt, vals, len(np.unique(vals)))
         star.columns[name] = ColumnIndex(name, dt, None, vals.astype(dt.np_dtype), stats)

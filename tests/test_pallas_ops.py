@@ -269,6 +269,8 @@ def one_v5e_chip():
     [
         (256, 1),  # TPC-H Q1's count plane: the narrowest left operand, 8 bf16 rows
         (256, 5),  # SSB Q4.1
+        (256, 9),  # SSB Q4.1 answered from a star table: two stored sums (revenue, supplycost) and the mask in one pass
+        (4608, 9),  # SSB Q4.2 likewise: 7 x 25 x 25 groups
         (7168, 5),  # SSB Q2.x: G2 = 56, not a multiple of the bf16 sublane tile
         (7168, 13),
         (437504, 5),  # SSB Q3.2-Q3.4: nine hi tiles, the widest left operand (15 MB)

@@ -98,6 +98,7 @@ def test_child_time_is_not_counted_twice():
         "hostToDeviceTransfers": 0, "deviceReadbackWaits": 0, "groupedLimbFallbacks": 0,
         "reduceRowStages": 0, "segmentsStaged": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
         "lookupOperandBuilds": 0, "lookupOperandBytesStaged": 0, "lookupMisses": 0,
+        "starTreeSegments": 0, "starTreeRecords": 0, "starTreeBuilds": 0,
     }  # fmt: skip
 
 
