@@ -490,6 +490,11 @@ class PhaseLedger:
                 "starTreeSegments": 0,
                 "starTreeRecords": 0,
                 "starTreeBuilds": 0,
+                # the compact group space (query/plan.py `group_spec`'s "groups_compact", query/kernels.py
+                # `_compact_groups`): segments of the query launched under it, and those of them whose groups passed
+                # its slots and were launched again under the plan they had before it (engine._launch_again)
+                "groupCompactSegments": 0,
+                "groupCompactFallbacks": 0,
                 **doc["counters"],
                 # what was dispatched is what `deviceWork` holds, program by program
                 "segmentsDispatched": sum(w["launches"] for w in work),

@@ -373,7 +373,8 @@ def execute_sharded(table: ShardedTable, sql: str):
                     float(ci.stats.min_value),
                     float(ci.stats.max_value),
                 )
-    plan: SegmentPlan = plan_segment(table.proto, ctx)
+    # planned without "groups_compact": its overflow is answered by a second launch, which this path does not have
+    plan: SegmentPlan = plan_segment(table.proto, ctx, compact=False)
     gspec = plan.spec[2]
     if gspec is not None and gspec[0] == "groups_mv2":
         # mv2's per-doc offset/length tables index the proto doc space,

@@ -17,6 +17,7 @@ import time
 import numpy as np
 import pandas as pd
 
+from pinot_tpu.common.trace import count
 from pinot_tpu.query import ast, host_exec, reduce as reduce_mod
 from pinot_tpu.query.context import QueryContext, QueryType
 from pinot_tpu.query.kernels import dispatch_plan_packed, wait_packed
@@ -24,6 +25,17 @@ from pinot_tpu.query.plan import DeviceFallback, SegmentPlan, mark_device_fallba
 from pinot_tpu.query.result import ResultTable
 from pinot_tpu.query.sql import parse_sql
 from pinot_tpu.segment.segment import DeviceSegment, ImmutableSegment
+
+
+def _is_compact(plan: SegmentPlan) -> bool:
+    """Whether a plan groups under plan.group_spec's "groups_compact"."""
+    return plan.spec[0] == "agg" and plan.spec[2] is not None and plan.spec[2][0] == "groups_compact"
+
+
+def _overflowed(disp) -> bool:
+    """Whether a launch under the compact group spec, its vector on the host,
+    found more combinations of key values than it has slots."""
+    return disp[0] == "dev" and _is_compact(disp[1]) and int(disp[2]()[-1]) > disp[1].spec[2][2]
 
 
 def _describe_spec(spec: tuple, next_id: int, parent: int) -> list[list]:
@@ -224,6 +236,14 @@ class QueryEngine:
                 ctx.deadline.check(f"segment {launched[i][0].name}")
 
         wait_packed([res for _, res in launched], checkpoint)
+        # the compact launches whose groups passed their slots (the rows decide): enqueued again together under the
+        # plan they had before that kind, and waited for once more
+        again = [i for i, (_, disp) in enumerate(pend) if _overflowed(disp)]
+        if again:
+            for i in again:
+                seg, disp = pend[i]
+                pend[i] = (seg, self._launch_again(seg, ctx, disp))
+            wait_packed([pend[i][1][2] for i in again if pend[i][1][0] == "dev"])
         for seg, disp in pend:
             if disp[0] == "pruned":
                 out.append(disp[1])  # no scan, no sample
@@ -550,33 +570,47 @@ class QueryEngine:
         ):
             # star-tree pre-aggregates over ALL docs; unusable under upsert
             # visibility (invalidated docs are baked into the agg table)
-            from pinot_tpu.common.trace import count
             from pinot_tpu.query import startree_exec
 
             swap = startree_exec.swap(seg, ctx)
             if swap is not None:
                 count("starTreeSegments")
                 count("starTreeRecords", swap.seg.n_docs)
-                try:
-                    plan = plan_segment(swap.seg, swap.ctx)
-                except DeviceFallback as e:
-                    # the star table on the host: exact, and still the few records in the raw rows' place
-                    mark_device_fallback(e, f"segment {swap.seg.name}")
-                    partial, matched = self._host_segment(swap.seg, swap.ctx)
-                    # trailing element = execution mode, for scan-path attribution
-                    return ("ready", swap.convert(ctx, partial), matched, "startree")
-                return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(swap.seg)), None, swap)
-        vmask = valid(seg.n_docs) if valid is not None else None
+                return self._launch(seg, ctx, None, swap)
+        # plan_segment threads valid_docs into the kernel as a docmask
+        # operand, so upsert tables run the fused device path too
+        return self._launch(seg, ctx, valid(seg.n_docs) if valid is not None else None, None)
+
+    def _launch(self, seg: ImmutableSegment, ctx: QueryContext, vmask, swap, compact: bool = True):
+        """Plan and enqueue one segment, or the star table of `swap` in its
+        place: `_dispatch_segment`'s return. `compact=False` is the second
+        launch of a segment whose groups passed the compact slots
+        (plan.group_spec's "groups_compact"), under the plan it had before
+        that kind."""
+        target, tctx = (seg, ctx) if swap is None else (swap.seg, swap.ctx)
         try:
-            # plan_segment threads valid_docs into the kernel as a docmask
-            # operand, so upsert tables run the fused device path too
-            plan = plan_segment(seg, ctx, valid_mask=vmask)
+            plan = plan_segment(target, tctx, valid_mask=vmask, compact=compact)
         except DeviceFallback as e:
             # the same meter the multistage leaf marks: a segment that left
             # the device path is counted, whichever engine it ran under
-            mark_device_fallback(e, f"segment {seg.name}")
-            return ("ready",) + self._host_segment(seg, ctx, extra_mask=vmask) + ("host",)
-        return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(seg)), vmask, None)
+            mark_device_fallback(e, f"segment {target.name}")
+            partial, matched = self._host_segment(target, tctx, extra_mask=vmask)
+            if swap is None:
+                return ("ready", partial, matched, "host")
+            # the star table on the host: exact, and still the few records in the raw rows' place;
+            # the trailing element = execution mode, for scan-path attribution
+            return ("ready", swap.convert(ctx, partial), matched, "startree")
+        if _is_compact(plan):
+            count("groupCompactSegments")
+        return ("dev", plan, dispatch_plan_packed(plan, self._device_seg(target)), vmask, swap)
+
+    def _launch_again(self, seg: ImmutableSegment, ctx: QueryContext, disp):
+        """An overflowed compact launch, enqueued again under the plan the
+        segment had before that kind: the dense space up to
+        plan.MAX_DENSE_GROUPS, the sort-compaction path past it. Exact either
+        way; the first launch's result is dropped."""
+        count("groupCompactFallbacks")
+        return self._launch(seg, ctx, disp[3], disp[4], compact=False)
 
     @staticmethod
     def _scan_mode(disp) -> str:
@@ -601,15 +635,20 @@ class QueryEngine:
             return self._convert_agg(seg, ctx, plan, parts), int(matched)
         if qt in (QueryType.GROUP_BY, QueryType.DISTINCT):
             gspec = plan.spec[2]
-            if gspec is not None and gspec[0] == "groups_sparse":
-                matched, counts, parts, uniq, n_unique = out
-                if int(n_unique) > gspec[2]:
-                    # more present groups than compact slots: the kernel's
-                    # clipped slots collided — results unusable, rerun host
+            if gspec is not None and gspec[0] in ("groups_sparse", "groups_compact"):
+                matched, counts, parts, slot_gids, n_present = out
+                if int(n_present) > gspec[2]:
+                    # more present groups than slots: the kernel's clipped
+                    # slots collided — results unusable. A sparse segment
+                    # reruns host; a compact one is launched again (here for
+                    # `partials_iter` and `_execute_segment`: a query's
+                    # batched path has done so already, `_resolve_partials`)
+                    if gspec[0] == "groups_compact":
+                        return self._finish_segment(seg, ctx, self._launch_again(seg, ctx, disp))
                     return self._host_segment(seg, ctx, extra_mask=vmask)
                 return (
                     self._convert_groups(
-                        seg, ctx, plan, np.asarray(counts), parts, dense_gids=np.asarray(uniq)
+                        seg, ctx, plan, np.asarray(counts), parts, dense_gids=np.asarray(slot_gids)
                     ),
                     int(matched),
                 )

@@ -141,6 +141,31 @@ def _filter_k3(fspec, cols, ops, n_padded):
     raise AssertionError(fspec)
 
 
+_LUT_WORDS_MAX = 128  # words of 32 entries: the widest boolean table that `_in_lut` reads as bits (4,096 entries)
+
+
+def _in_lut(lut, ids):
+    """`lut[ids]` for a boolean table over a column's dictionary ids (IN, a
+    disjunction of equalities, LIKE, a string function's predicate). XLA's
+    gather of one element a row is 8.6 ns a row on a v5e from a table of 256
+    entries — 36 ms a 4M-row segment and membership test, the whole of SSB
+    Q3.3's launch once its group space is compact (PERF.md §6, PR 45) — so a
+    table of up to 4,096 entries is packed 32 entries a word on the device
+    (it is tiny) and a row picks its word by a compare against every word,
+    then its bit: a dense pass of (words, rows) compares, as
+    `_compact_key`'s second. A larger table keeps the gather. An id outside
+    the table is in no word and reads False."""
+    words = -(-lut.shape[0] // 32)
+    if words > _LUT_WORDS_MAX:
+        return lut[ids]
+    ids = ids.astype(jnp.int32)
+    bits = jnp.pad(lut, (0, 32 * words - lut.shape[0])).reshape(words, 32).astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32)
+    packed = jnp.sum(bits, axis=1, dtype=jnp.uint32)
+    at = (ids >> 5)[None] == jnp.arange(words, dtype=jnp.int32)[:, None]
+    mine = jnp.sum(jnp.where(at, packed[:, None], jnp.uint32(0)), axis=0, dtype=jnp.uint32)
+    return (mine >> (ids & 31).astype(jnp.uint32)) & 1 == 1
+
+
 def _filter(fspec, cols, ops, n_padded):
     kind = fspec[0]
     if kind == "k3root":
@@ -172,7 +197,7 @@ def _filter(fspec, cols, ops, n_padded):
         i = jnp.arange(n_padded, dtype=jnp.int32)
         return (i >= ops[fspec[1]]) & (i < ops[fspec[2]])
     if kind == "in_lut":
-        return ops[fspec[2]][cols[fspec[1]]]
+        return _in_lut(ops[fspec[2]], cols[fspec[1]])
     if kind == "lookup_range":
         # a lookUp filter whose passing destination codes are one run (plan.lookup_filter)
         codes = _lookup_codes(fspec[1], cols, ops)
@@ -259,6 +284,13 @@ def _filter(fspec, cols, ops, n_padded):
 #     scatter, jax.ops.segment_*. On the v5e 4M f64 rows scattered into 256
 #     slots take 0.16-0.28 s, a thousand times a masked f64 sum of the same
 #     rows (PERF.md §6, PR 28).
+# Over which group space they run is the plan's (plan.group_spec): the keys'
+# dense product or, where that product reaches plan.COMPACT_MIN_GROUPS, the
+# compact space of `_compact_groups` — each key renumbered by the values the
+# filter leaves, plan.COMPACT_SLOTS slots whatever the product — where the
+# same four forms reduce over a slot id as they do over the sort-compaction
+# path's: 2.6 ms a 4M-row launch of SSB Q3.2 where the 437,500 dense groups
+# take 99 (PERF.md §6, PR 45).
 _BLOCK = 8192
 
 
@@ -575,6 +607,69 @@ def _agg_grouped(aspec, cols, ops, mask, gid, ng, gather=None, doc_pad=None, den
     raise AssertionError(aspec)
 
 
+def _compact_key(ids, mask, width):
+    """One GROUP BY key renumbered by the values the filter leaves: the rows'
+    ranks among the present values, how many are present, and the present
+    values in ascending order (then `width`s). Presence is kept 32 values a
+    word: a row's value is the bit `id & 31` of the word `id >> 5`, a word of
+    the key is the OR over the masked rows that fall into it, and a row's
+    rank is the number of set bits under its own — the bits of the words
+    before it (a running count, picked with the row's word by a compare
+    against every word) and those below its bit in its word. Two dense
+    passes of (words, rows) compares, as `_dense_grouped` makes one of
+    (values, rows): a thirty-second of it, and no gather of `rank[ids]` at
+    2.6 ns a row. A row the mask drops gets the rank of whatever it holds."""
+    ids = ids.astype(jnp.int32)
+    word, bit = _blocked(ids >> 5), _blocked(jnp.uint32(1) << (ids & 31).astype(jnp.uint32))
+    at = word[None] == jnp.arange(-(-width // 32), dtype=jnp.int32)[:, None, None]
+    zero = jnp.uint32(0)
+    present = jax.lax.reduce(jnp.where(at & _blocked(mask)[None], bit[None], zero), zero, jax.lax.bitwise_or, (1, 2))
+    held = jax.lax.population_count(present).astype(jnp.int32)
+    before = jnp.cumsum(held) - held
+    mine = jnp.sum(jnp.where(at, present[:, None, None], zero), axis=0, dtype=jnp.uint32)  # the presence word each row falls into
+    ranks = jnp.sum(jnp.where(at, before[:, None, None], 0), axis=0, dtype=jnp.int32) + jax.lax.population_count(
+        mine & (bit - jnp.uint32(1))
+    ).astype(jnp.int32)
+    values = jnp.arange(width, dtype=jnp.int32)
+    is_present = (present[values >> 5] >> (values & 31).astype(jnp.uint32)) & 1 == 1
+    return ranks.reshape(-1)[: ids.shape[0]], jnp.sum(held), jnp.sort(jnp.where(is_present, values, width))
+
+
+def _compact_groups(gcols, widths, slots, dense_strides, cols, ops, mask):
+    """The compact group space of plan.group_spec's "groups_compact": (the
+    rows' slot ids, (slots,) int64 slot -> dense group id, the number of
+    combinations of present key values). A key is renumbered (`_compact_key`,
+    one registered call a key) or, where the plan found it too wide,
+    carried whole; the compact id is the ranks under row-major strides of the
+    present counts, device scalars that saturate one past `slots`. Where the
+    combinations pass `slots` the ids are clipped and the caller's result is
+    void; a slot past the combinations counts no row."""
+    ranks, present, values = [], [], []
+    for key, (how, width) in zip(gcols, widths):
+        ids = _key_ids(key, cols, ops)
+        if how == "whole":
+            ranks.append(ids.astype(jnp.int32))
+            present.append(jnp.int32(width))
+            values.append(jnp.arange(width, dtype=jnp.int32))
+            continue
+        r, n, v = KERNELS.timed_sync(
+            "query.group_compact", lambda: _compact_key(ids, mask, width), rows=ids.shape[0], groups=width
+        )
+        ranks.append(r)
+        present.append(n)
+        values.append(v)
+    strides, total = [], jnp.int32(1)
+    for n in reversed(present):
+        strides.insert(0, total)
+        total = jnp.minimum(total * n, slots + 1)
+    cid = sum(r * s for r, s in zip(ranks, strides))
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    gids = jnp.zeros((slots,), dtype=jnp.int64)
+    for v, n, s, dense in zip(values, present, strides, dense_strides):
+        gids = gids + v[(slot // jnp.maximum(s, 1)) % jnp.maximum(n, 1)].astype(jnp.int64) * dense
+    return jnp.clip(cid, 0, slots - 1), gids, total
+
+
 _limb_fallbacks = threading.local()  # .flags: what `_side_counts` collects while a program is traced
 _lookup_trace = threading.local()  # .state: a traced program's lookUp codes by node, its gathered words by operand, the codes whose misses it counts
 
@@ -665,10 +760,12 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
     Shared by build_fn (valid derived from an n_docs scalar) and
     build_masked_fn (the sharded executor's flattened multi-segment space,
     where validity comes per-position). Every group-spec kind — dense,
-    MV-key, MV-pair cartesian, sparse sort-compaction — evaluates here, so
+    MV-key, MV-pair cartesian, compact, sparse sort-compaction — evaluates here, so
     the sharded path supports the same group shapes as the per-segment one
     (groups_mv2 excluded: its per-doc offset/length operand tables index the
-    proto's doc space, which does not exist in the sharded flat layout)."""
+    proto's doc space, which does not exist in the sharded flat layout; and
+    planned without the compact kind, whose overflow is answered by a second
+    launch)."""
     n_padded = valid.shape[0]
     mask = valid & _filter(fspec, cols, ops, n_padded)
     matched = jnp.sum(mask, dtype=jnp.int32).astype(_I)
@@ -747,6 +844,17 @@ def _agg_eval(fspec, gspec, aggs, cols, ops, valid):
         cid = jnp.clip(jnp.searchsorted(uniq, gid64), 0, u_slots - 1).astype(jnp.int32)
         counts, parts = _grouped_all(aggs, cols, ops, mask, cid, u_slots)
         return matched, counts, parts, uniq, n_unique
+    if gspec[0] == "groups_compact":
+        # a large product of keys: each key renumbered by the values the
+        # filter leaves, every aggregate over the compact slot id as over the
+        # sort-compaction path's. The slot table and the number of
+        # combinations ride back: past the slots the engine throws the
+        # result away and launches the segment under the spec it had before
+        # this kind (engine._finish_segment)
+        _, gcols, slots, strides_idx, widths = gspec
+        cid, slot_gids, total = _compact_groups(gcols, widths, slots, ops[strides_idx], cols, ops, mask)
+        counts, parts = _grouped_all(aggs, cols, ops, mask, cid, slots)
+        return matched, counts, parts, slot_gids, total
     # ("groups", cols, ng, strides[, real groups]): the plan appends the real
     # group count where a non-int32 reduction can use it (plan.group_spec)
     _, gcols, ng, strides_idx, *real = gspec
@@ -959,6 +1067,22 @@ def _dense_cost(shape: dict) -> tuple[float, float]:
     return rows * (float(shape.get("width", 8)) + 5.0), rows * groups * 2.0
 
 
+def _compact_cost(shape: dict) -> tuple[float, float]:
+    """One key renumbered: the rows' ids (4 B) and mask (1 B) stream for the
+    presence, the ids again for the ranks, which are written (4 B); a
+    compare, a select and an OR a (row, word of 32 values) pair for the
+    presence, a compare and two selects for the ranks, so flops / (6 x rows)
+    is the key's width in words."""
+    rows = max(float(shape.get("rows", 0)), 0.0)
+    return rows * 13.0, rows * -(-max(int(shape.get("groups", 1)), 1) // 32) * 6.0
+
+
+KERNELS.register(
+    "query.group_compact",
+    _compact_key,
+    cost_model=_compact_cost,
+    description="one GROUP BY key of a large group space renumbered by the values the filter leaves (presence bits 32 values a word, then the rows' ranks: two dense passes over the words); one call a key traced",
+)
 KERNELS.register(
     "query.grouped_dense",
     _dense_grouped,
@@ -1235,17 +1359,19 @@ class PackedResult:
     output tree, after `wait_packed` — the caller's, for all launches of a
     query at once, or its own if none was made."""
 
-    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_n_cols", "_treedef", "_leaf_meta", "_size", "_lookups")
+    __slots__ = ("program", "rows", "wait_ms", "_vec", "_host", "_tree", "_n_cols", "_treedef", "_leaf_meta", "_size", "_lookups")
 
     def __init__(self, program: str, rows: int, vec, n_cols: int, treedef, leaf_meta, size: int, lookups: bool = False):
         # what was launched, for the caller's `server.dispatch` span
         self.program, self.rows = program, rows
         self.wait_ms = 0.0
-        self._vec, self._host = vec, None
+        self._vec, self._host, self._tree = vec, None, None
         self._n_cols, self._treedef, self._leaf_meta, self._size = n_cols, treedef, leaf_meta, size
         self._lookups = lookups  # the vector's last element counts lookUp misses (`_holds_lookup`)
 
     def __call__(self):
+        if self._tree is not None:  # asked before: a compact launch's overflow check reads the tree ahead of its conversion
+            return self._tree
         if self._host is None:
             wait_packed((self,))
         v = self._host
@@ -1264,7 +1390,8 @@ class PackedResult:
                 if dtype != np.float64:
                     chunk = chunk.astype(dtype)
             out.append(chunk.reshape(shape))
-        return jax.tree.unflatten(self._treedef, out)
+        self._tree = jax.tree.unflatten(self._treedef, out)
+        return self._tree
 
 
 def wait_packed(results, checkpoint=None) -> None:

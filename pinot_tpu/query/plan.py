@@ -26,6 +26,7 @@ raises `DeviceFallback` and the engine runs the host executor instead
 
 from __future__ import annotations
 
+import math
 import re
 from dataclasses import dataclass, field
 from typing import Any
@@ -53,6 +54,25 @@ MAX_DENSE_GROUPS = 1 << 20
 #: 12,032, 39 at 65,536: under the dense form from 128 groups on, which this
 #: threshold does not yet use (PERF.md §7).
 DENSE_REDUCE_MAX_GROUPS = 4096
+
+#: the dense product of a single-value group-by's key cardinalities from which
+#: the program contracts the groups the filter leaves and not the product
+#: (`group_spec`'s kind "groups_compact", kernels._compact_groups): every key
+#: is renumbered by the values that survive the filter and the byte-plane
+#: kernel runs over COMPACT_SLOTS slots, whatever the product — also past
+#: MAX_DENSE_GROUPS, where the sort-compaction path stays as the fallback of
+#: a segment whose surviving combinations pass the slots. On the v5e at 4M
+#: rows a compact launch is 2.1-2.9 ms whatever the product, a dense one 1.8-2.3
+#: ms at 4,096 groups, 4.3-4.5 at 16,384, 22 at 65,536, 99 at 437,500
+#: (benchmarks/planes_ab.py --compact; PERF.md §6, PR 45): they meet between
+#: 4,096 and 8,192. The constant stands at 16,384 so that no group-by of
+#: fewer groups changes its program (PERF.md §7).
+COMPACT_MIN_GROUPS = 16384
+#: slots of the compact group space: one hi tile of the kernel at G2 = 32
+COMPACT_SLOTS = 4096
+#: the widest key that is renumbered, by two dense passes of (32 values a
+#: word, rows) compares; a wider one is carried whole, at its cardinality
+COMPACT_MAX_KEY_CARD = 4096
 
 # Virtual columns provided at query time (VirtualColumnProvider parity,
 # pinot-segment-local/.../segment/virtualcolumn/VirtualColumnProvider.java).
@@ -91,6 +111,12 @@ class PlanError(ValueError):
 
 def _pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
+
+
+def _eighths(n: int) -> int:
+    """`n` rounded up to eighths of its next power of two, at least 8 (6 -> 8, 175 -> 192, 4000 -> 4096)."""
+    step = max(8, _pow2(n) // 8)
+    return -(-n // step) * step
 
 
 def group_strides(cards: list, dtype=np.int64) -> np.ndarray:
@@ -134,9 +160,10 @@ class SegmentPlan:
 
 
 class _Lowering:
-    def __init__(self, seg: ImmutableSegment, ctx: QueryContext):
+    def __init__(self, seg: ImmutableSegment, ctx: QueryContext, compact: bool = True):
         self.seg = seg
         self.ctx = ctx
+        self.compact = compact  # False: a large dense group space keeps the spec it had before "groups_compact" (plan_segment)
         self.operands: list[Any] = []
         self.columns: list[str] = []
         self.value_columns: list[str] = []  # see raw_value
@@ -1175,6 +1202,16 @@ class _Lowering:
         return ("remap", col, self.op_idx(remap)), KeyBuckets(Dictionary(dt, buckets.astype(dt.np_dtype)))
 
     def group_spec(self) -> tuple:
+        """The GROUP BY keys as the program's group spec, by the shape of the
+        plan alone: ("groups", ...) a dense space of the keys' product, up to
+        MAX_DENSE_GROUPS; ("groups_mv", ...) / ("groups_mv2", ...) with one or
+        two multi-value keys; ("groups_compact", keys, slots, dense strides,
+        widths) where the product of single-value keys reaches
+        COMPACT_MIN_GROUPS — each key renumbered on the device by the values
+        the filter leaves, COMPACT_SLOTS slots, whatever the product; and
+        ("groups_sparse", ...), the sort-compaction path, past
+        MAX_DENSE_GROUPS where a key too wide to renumber keeps the compact
+        form out, and as the fallback of a compact segment that overflowed."""
         cols = []
         cards = []
         mv_cols: list[str] = []
@@ -1209,6 +1246,25 @@ class _Lowering:
         for c in cards:
             num_groups *= max(c, 1)
         if num_groups > MAX_DENSE_GROUPS:
+            if mv_cols:
+                raise DeviceFallback("high-cardinality MV GROUP BY runs host-side")
+            if num_groups >= (1 << 62):
+                raise DeviceFallback("group cardinality product overflows int64 gids")
+        # what the keys too wide to renumber contribute to the compact space whatever the filter
+        carried = math.prod(c for c in cards if c > COMPACT_MAX_KEY_CARD)
+        if self.compact and not mv_cols and num_groups >= COMPACT_MIN_GROUPS and carried <= COMPACT_SLOTS:
+            # a large product of single-value keys: the program renumbers
+            # each key by the values the filter leaves and contracts over
+            # COMPACT_SLOTS slots (kernels._compact_groups), the slot table
+            # rides back as the sort-compaction path's does. The rows decide
+            # whether the slots suffice; where they do not the engine
+            # launches the segment again under the spec below, which is what
+            # `compact=False` plans. Widths are rounded as with_real_groups
+            # rounds, so near-alike dictionaries share a compile.
+            widths = tuple(("rank", _eighths(max(c, 1))) if c <= COMPACT_MAX_KEY_CARD else ("whole", c) for c in cards)
+            self._group_ng = COMPACT_SLOTS
+            return ("groups_compact", tuple(cols), COMPACT_SLOTS, self.op_idx(group_strides(cards, np.int64)), widths)
+        if num_groups > MAX_DENSE_GROUPS:
             # high-cardinality product: sort-compaction path — dense 64-bit
             # gids are sorted on device, run-length compacted to slots, and
             # the aggregation runs over the compact slot space. The slot
@@ -1217,10 +1273,6 @@ class _Lowering:
             # (hash-table group ids) — redesigned as sort-compaction, which
             # is what maps onto the TPU (lax.sort rides the VPU; a serial
             # hash table would not vectorize).
-            if mv_cols:
-                raise DeviceFallback("high-cardinality MV GROUP BY runs host-side")
-            if num_groups >= (1 << 62):
-                raise DeviceFallback("group cardinality product overflows int64 gids")
             strides64 = group_strides(cards, np.int64)
             u = min(_pow2(max(self.seg.n_docs, 256)), MAX_DENSE_GROUPS)
             self._group_ng = u
@@ -1257,8 +1309,7 @@ class _Lowering:
         and compile-cache key, it has always had."""
         if gspec[0] != "groups":
             return gspec
-        step = max(8, _pow2(self._group_real) // 8)
-        real = min(-(-self._group_real // step) * step, gspec[2])
+        real = min(_eighths(self._group_real), gspec[2])
         if real > DENSE_REDUCE_MAX_GROUPS:
             return gspec
 
@@ -1425,12 +1476,16 @@ def plan_filter_mask(seg: ImmutableSegment, filt, valid_mask=None, kleene: bool 
     )
 
 
-def plan_segment(seg: ImmutableSegment, ctx: QueryContext, valid_mask=None) -> SegmentPlan:
+def plan_segment(seg: ImmutableSegment, ctx: QueryContext, valid_mask=None, compact: bool = True) -> SegmentPlan:
     """Lower a query against one segment. Raises DeviceFallback when the host
     executor must take over. `valid_mask` lets the caller pass an
     already-materialized upsert validity snapshot (avoids computing the
-    bitmap twice when lowering later falls back to the host path)."""
-    lo = _Lowering(seg, ctx)
+    bitmap twice when lowering later falls back to the host path).
+    `compact=False` plans a large group space without "groups_compact"
+    (`group_spec`): the engine's second launch of a segment whose groups
+    passed the compact slots, and the sharded executor, which has no second
+    launch."""
+    lo = _Lowering(seg, ctx, compact)
     from pinot_tpu.query.context import null_handling_enabled as _nhe
 
     if _nhe(ctx.options):

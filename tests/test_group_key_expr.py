@@ -13,6 +13,7 @@ import pytest
 
 from pinot_tpu.common import DataType, FieldSpec, Schema
 from pinot_tpu.common.metrics import ServerMeter, server_metrics
+from pinot_tpu.common.trace import request_ledger
 from pinot_tpu.query import QueryEngine
 from pinot_tpu.query.kernels import _plan_inputs, program_name
 from pinot_tpu.query.plan import DeviceFallback, plan_segment
@@ -108,7 +109,8 @@ def test_expression_key_on_the_device(table, form, filtered):
 OTHER_SPACES = {
     # ids in value space: the bucket gathers through the owning doc
     "a-multi-value-key": ("labels", "datetrunc-hour", "groups_mv"),
-    # one segment's 700 timestamps x 5 hosts x 700 quotients: past MAX_DENSE_GROUPS, so sorted and compacted
+    # one segment's 700 timestamps x 5 hosts x 700 quotients: past MAX_DENSE_GROUPS, and more combinations than the
+    # compact space's slots, so launched again, sorted and compacted
     "sort-compaction": ("ts, hostname", "division", "groups_sparse"),
 }
 
@@ -124,10 +126,13 @@ def test_expression_key_in_the_other_group_spaces(table, space):
         f"SELECT {keys}, {sql_key}, COUNT(*), AVG(usage_user) FROM cpu WHERE hostname <> 'host_3' "
         f"GROUP BY {keys}, {sql_key} LIMIT 100000"
     )
-    assert plan_segment(segs[0], eng.make_context(sql)).spec[2][0] == kind
+    assert plan_segment(segs[0], eng.make_context(sql), compact=False).spec[2][0] == kind
     before = fallbacks()
-    res = eng.execute(sql)
+    with request_ledger(f"q-{space}") as led:
+        res = eng.execute(sql)
     assert fallbacks() == before
+    counters = led.response_fields()["counters"]
+    assert (counters["groupCompactSegments"], counters["groupCompactFallbacks"]) == ((1, 1) if kind == "groups_sparse" else (0, 0))
     by = [k.strip() for k in keys.split(",")] + ["key"]
     rows = df[df.hostname != "host_3"].assign(key=lambda d: np_key(d.ts.to_numpy()))
     want = (rows.explode("labels") if "labels" in by else rows).groupby(by).agg(n=("usage_user", "size"), avg=("usage_user", "mean"))

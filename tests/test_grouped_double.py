@@ -519,9 +519,12 @@ def test_mv_keys_and_the_sort_compaction_path_keep_their_specs():
         "m0",
     )
     mv = gspec_of(seg, "SELECT tags, SUM(x) FROM m GROUP BY tags LIMIT 10")
-    sparse = gspec_of(seg, "SELECT hi, hj, SUM(x) FROM m GROUP BY hi, hj LIMIT 10")
+    wide = "SELECT hi, hj, SUM(x) FROM m GROUP BY hi, hj LIMIT 10"
+    sparse = plan_segment(seg, QueryEngine([seg]).make_context(wide), compact=False).spec[2]
     assert mv[0] == "groups_mv" and len(mv) == 6
     assert sparse[0] == "groups_sparse" and len(sparse) == 4
+    # the same product as the engine first launches it (plan.group_spec): the compact space, its five entries as planned
+    assert gspec_of(seg, wide) == ("groups_compact", ("hi", "hj"), plan_mod.COMPACT_SLOTS, 0, (("rank", 3072), ("rank", 3072)))
 
 
 # ---------------------------------------------------------------------------

@@ -88,3 +88,17 @@ def test_a_segment_of_rows_never_gathers_rows_by_lanes(call):
     if stats is None or not hasattr(stats, "temp_size_in_bytes"):
         pytest.skip("this backend reports no memory analysis of a compiled program")
     assert stats.temp_size_in_bytes < rows * LANES  # a quarter of that array's bytes
+
+
+@pytest.mark.parametrize("entries", [1, 2, 8, 32, 64, 256, 1000, 4096, 8192])
+@pytest.mark.parametrize("share", [0.0, 0.1, 1.0])
+def test_a_membership_table_read_as_bits_is_the_gather(entries, share):
+    """`kernels._in_lut`: up to 4,096 entries a table is packed 32 entries a
+    word and a row picks its word and its bit; past that it is `lut[ids]` itself."""
+    rng = np.random.default_rng(entries)
+    lut = rng.random(entries) < share
+    ids = rng.integers(0, entries, 5000).astype(np.int8 if entries <= 64 else np.int16 if entries <= 4096 else np.int32)
+    got = np.asarray(jax.jit(kernels._in_lut)(jnp.asarray(lut), jnp.asarray(ids)))
+    assert got.dtype == bool and np.array_equal(got, lut[ids])
+    text = jax.jit(kernels._in_lut).lower(jnp.asarray(lut), jnp.asarray(ids)).as_text()
+    assert ("gather" in text) == (entries > 32 * kernels._LUT_WORDS_MAX)

@@ -119,7 +119,9 @@ def test_batched_partials_equal_the_one_segment_path(kind, request):
     ctx = eng.make_context(sql)
     plans = [plan_segment(seg, ctx, valid_mask=_valid(seg)) for seg in segs]  # every segment on the device path
     if kind == "sparse_groupby":
-        assert all(p.spec[2][0] == "groups_sparse" for p in plans)
+        # first the compact space, whose 4,096 slots the ~600 distinct rows a segment pass; then the sort-compaction path
+        assert all(p.spec[2][0] == "groups_compact" for p in plans)
+        assert all(plan_segment(seg, ctx, compact=False).spec[2][0] == "groups_sparse" for seg in segs)
     if kind == "upsert_docmask":
         assert all(any(o.dtype == bool and o.ndim == 1 for o in p.operands) for p in plans)
     if kind == "stable_operand":
@@ -127,7 +129,9 @@ def test_batched_partials_equal_the_one_segment_path(kind, request):
     with request_ledger("q-batched", "server") as led:
         partials, scanned, _ = eng.partials(ctx)
     counters = led.to_wire()["counters"]
-    assert counters["deviceReadbackWaits"] == 1
+    # one wait a query; one more where compact launches overflowed, for all of them enqueued again together
+    again = counters.get("groupCompactFallbacks", 0)
+    assert again == (len(segs) if kind == "sparse_groupby" else 0) and counters["deviceReadbackWaits"] == 1 + bool(again)
     want = [eng._execute_segment(seg, ctx) for seg in segs]
     assert len(partials) == len(segs) and scanned == sum(m for _, m in want)
     for got, (partial, _) in zip(partials, want):

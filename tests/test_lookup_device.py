@@ -289,6 +289,7 @@ def test_rows_without_a_dimension_row_are_counted_and_kept(star):
 
 def test_every_segment_shares_one_dense_group_space(star):
     """The key's buckets are the destination's dictionary and one for misses, whatever a segment's foreign keys are."""
+    from pinot_tpu.query.kernels import program_name
     from pinot_tpu.query.plan import plan_segment
 
     server = star["server"]
@@ -297,6 +298,10 @@ def test_every_segment_shares_one_dense_group_space(star):
     with server.dim_tables.serving():
         plans = [plan_segment(seg, ctx) for seg in eng.segments]
     assert len({p.spec for p in plans}) == 1
+    # the group spec and the program's name as PR 45's parent gave them: far under plan.COMPACT_MIN_GROUPS, the plan it always had
+    region, brand = ("lookup", "cust_id", 0, 1, 2, 3, True), ("lookup", "prod_id", 4, 5, 6, 7, True)
+    assert plans[0].spec[2] == ("groups", (("lookup_key", region), ("lookup_key", brand)), 256, 8)
+    assert program_name(plans[0].spec) == "seg_groupby_8444e3b6"
     cards = [[ci.cardinality for _, ci in p.group_cols] for p in plans]
     assert cards == [[3 + 1, 7 + 1]] * 3  # regions AM, AS, EU; brands #0..#6
     assert [len({ci.cardinality for ci in (seg.columns["cust_id"], seg.columns["prod_id"])}) for seg in eng.segments] != [1, 1, 1]

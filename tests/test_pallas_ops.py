@@ -390,3 +390,42 @@ def test_a_star_query_compiles_at_the_ssb_segment_on_the_v5e(one_v5e_chip, monke
     assert text.count(" gather(") == 3 and "tpu_custom_call" in text  # one gather a foreign key
     assert f"[{kernels._GATHER_BLOCK},{kernels._GATHER_LANES}]" in text and f"[{n},{kernels._GATHER_LANES}]" not in text  # a block of rows at a time
     assert compiled.memory_analysis().temp_size_in_bytes < 40 * n  # a few row-sized temporaries, no (rows, groups) one-hot
+
+
+def test_a_compact_group_space_compiles_at_the_ssb_segment_on_the_v5e(one_v5e_chip, monkeypatch):
+    """`ssb-citygroups-closed`'s Q3.3 launch at its real shape: 4M rows, two
+    cities a side (membership tables over the cities' dictionaries, read as
+    bits: `kernels._in_lut`) and six years out of 250 x 250 x 7 dense
+    groups, each key renumbered by the values the filter leaves
+    (`kernels._compact_groups`: presence bits 32 values a word, the rows' ranks)
+    and the byte-plane kernel over plan.COMPACT_SLOTS slots in one hi tile.
+    The v5e's compiler takes it with no gather of the rows, no (values, rows)
+    or (rows, slots) array in memory, and a kernel of 160 left rows."""
+    import jax
+
+    from pinot_tpu.query import kernels, plan
+
+    n = 4096 * 1024
+    spec = (
+        "agg",
+        ("and", (("in_lut", "c_city", 0), ("in_lut", "s_city", 1), ("range_ids", "d_year", 2, 3))),
+        ("groups_compact", ("c_city", "s_city", "d_year"), plan.COMPACT_SLOTS, 4, (("rank", 256), ("rank", 256), ("rank", 8))),
+        (("sum", ("raw", "@0")),),
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    cols = {c: arg((n,), jnp.int32) for c in ("d_year", "c_city", "s_city", "@0")}
+    ops = (arg((256,), jnp.bool_), arg((256,), jnp.bool_), arg((), jnp.int32), arg((), jnp.int32), arg((3,), jnp.int64))
+    monkeypatch.setattr(gp, "interpret_mode", lambda: False)
+    monkeypatch.setenv("PINOT_TPU_PALLAS", "1")
+    kernel = kernels.get_packed_kernel.__wrapped__(spec)
+    compiled = kernel.lower(cols, ops, arg((), jnp.int32), n).compile()
+    text = compiled.as_text()
+    grid = gp.grid_for(plan.COMPACT_SLOTS, 5)
+    assert (grid.g2, gp._hi_tiles(plan.COMPACT_SLOTS, grid)) == (32, 1)
+    assert f"s32[{5 * grid.g2},{gp.G1_TILE}]" in text and "tpu_custom_call" in text  # the kernel's output: one hi tile
+    assert f"[{n}]" not in "".join(line for line in text.splitlines() if " gather(" in line)  # the slot table's, never the rows'
+    assert "popcnt" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * n  # row-sized temporaries; one (256 values, rows) mask alone is 256 * n

@@ -99,6 +99,7 @@ def test_child_time_is_not_counted_twice():
         "reduceRowStages": 0, "segmentsStaged": 0, "segmentsDispatched": 0, "rowsDispatched": 0,
         "lookupOperandBuilds": 0, "lookupOperandBytesStaged": 0, "lookupMisses": 0,
         "starTreeSegments": 0, "starTreeRecords": 0, "starTreeBuilds": 0,
+        "groupCompactSegments": 0, "groupCompactFallbacks": 0,
     }  # fmt: skip
 
 

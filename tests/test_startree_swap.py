@@ -181,6 +181,16 @@ def test_ssb_answers_with_the_two_trees_equal_the_scan_to_the_unit(ssb, template
         assert counters["deviceReadbackWaits"] == 1 and counters["segmentsDispatched"] == SSB_SEGMENTS
 
 
+def test_a_star_tables_plan_keeps_the_spec_and_program_it_had_before_the_compact_group_space(ssb):
+    """Q2.1 over tree A's table of the first segment: 7 years x 900-odd brands, under plan.COMPACT_MIN_GROUPS.
+    The group spec and the program's name are PR 45's parent's, written down."""
+    ds, indexed, _ = ssb
+    t = ds.TEMPLATES["q2.1"]
+    swap = startree_exec.swap(indexed.segments[0], indexed.make_context(t.render(t.draw(np.random.default_rng(SSB_SEED)))))
+    plan = plan_mod.plan_segment(swap.seg, swap.ctx)
+    assert (plan.spec[2], kernels.program_name(plan.spec)) == (("groups", ("d_year", "p_brand1"), 6656, 4), "seg_groupby_a2212025")
+
+
 def test_ssb_integer_pairs_are_staged_as_integers(ssb):
     _, indexed, _ = ssb
     seg = indexed.segments[0]
@@ -371,10 +381,10 @@ def test_a_star_plan_that_falls_back_answers_from_the_star_table_on_the_host(sal
     real = engine_mod.plan_segment
     hosted = []
 
-    def refuses_star_tables(seg, ctx, valid_mask=None):
+    def refuses_star_tables(seg, ctx, **kw):
         if seg.name.endswith("__star"):
             raise DeviceFallback("a star table the device cannot plan", reason="test")
-        return real(seg, ctx, valid_mask=valid_mask)
+        return real(seg, ctx, **kw)
 
     real_host = QueryEngine._host_segment
 
